@@ -101,7 +101,7 @@ def count_build_loops(jaxpr, prefix: str = "") -> int:
     """Number of tree-grow ``while`` loops staged under the ``build``
     profiler phase (TD005's counting pass).
 
-    ``name_stack`` is NOT inherited by nested call jaxprs on jax 0.4.x —
+    ``name_stack`` is NOT inherited by nested call jaxprs —
     the ``pjit``/``shard_map`` equation itself carries the scope and its
     sub-jaxpr equations start empty — so the walk threads the
     accumulated stack down as ``prefix``. Batching renames the scope

@@ -53,21 +53,6 @@ def cache_size(jitted) -> int:
             f"{jitted!r} is not a jitted function (no _cache_size)")
 
 
-def _unregister(cb) -> None:
-    # jax's public monitoring API (0.4.x) has register but not
-    # unregister; the private helper is the supported test-time path.
-    from jax._src import monitoring as _m
-    for name in ("_unregister_event_duration_listener_by_callback",):
-        fn = getattr(_m, name, None)
-        if fn is not None:
-            fn(cb)
-            return
-    # last resort: drop it from the listener list directly
-    lst = getattr(_m, "_event_duration_secs_listeners", None)
-    if lst is not None and cb in lst:
-        lst.remove(cb)
-
-
 class RecompileGuard:
     """Context manager counting XLA backend compiles in its scope.
 
@@ -102,7 +87,8 @@ class RecompileGuard:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         if self._cb is not None:
-            _unregister(self._cb)
+            import jax
+            jax.monitoring.unregister_event_duration_listener(self._cb)
             self._cb = None
         rep = TraceReport(label=self.label)
         if self.compiles > self.max_compiles:

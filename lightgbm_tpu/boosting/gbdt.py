@@ -42,7 +42,8 @@ from ..config import Config
 from ..dataset import Dataset
 from ..objectives import Objective
 from ..ops import pallas_histogram as PH
-from ..ops.histogram import block_rows_for, resolve_impl
+from ..ops.histogram import (block_rows_for, pallas_shape_reason,
+                             resolve_impl)
 from ..ops.split import SplitParams
 from ..tree import Tree
 from .tree_builder import build_tree, TreeArrays
@@ -136,11 +137,6 @@ class GBDT:
 
         F = self.train_set.num_features
         self.B = int(self.train_set.max_num_bin)
-        # resolve hist_impl='auto' EAGERLY, before any jit traces the
-        # tree builder: on TPU this probe-compiles the Pallas kernel once
-        # and falls back to matmul if Mosaic rejects it, so first
-        # hardware contact degrades instead of crashing
-        config._values["hist_impl"] = resolve_impl(config.hist_impl)
         # EFB: bins are bundled [R, G]; histogram sizing follows the
         # bundle lattice, split finding stays in feature space
         bp = self.train_set.bundle_plan
@@ -287,6 +283,16 @@ class GBDT:
                 "feature_shard_storage needs tree_learner=feature and "
                 "more than one device "
                 f"({n_dev} visible); storing the matrix unsharded")
+        # resolve hist_impl='auto' by rule (backend + the FINAL lattice
+        # width — feature mode may have unbundled above) and keep why a
+        # TPU run is NOT on the Pallas kernel, next to the other gate
+        # reasons
+        requested, lattice_bins = config.hist_impl, self._bundle_bins or self.B
+        config._values["hist_impl"] = resolve_impl(requested, lattice_bins)
+        self.hist_impl_reason = (
+            pallas_shape_reason(lattice_bins)
+            if requested == "auto" and config.hist_impl == "matmul"
+            else "")
         # column-sharded storage keeps only the local feature slice of
         # the matrix AND the hist cache per device: one divisor feeds
         # both the hist-sub gate and the capacity gate below
@@ -1047,7 +1053,7 @@ class GBDT:
                 kw["cegb"] = (t, ps, coupled, lazy,
                               self._cegb_feat_used, self._cegb_used_rows)
         if (self.plan is None and self._bundle_meta is None
-                and resolve_impl(cfg.hist_impl) == "native"):
+                and cfg.hist_impl == "native"):
             # column-major copy of the bin matrix for the native
             # PARTITION custom call (dense_bin.hpp stores per-feature
             # columns for the same reason: the split feature's column is
@@ -1144,9 +1150,9 @@ class GBDT:
         mode = "on" if env == "1" else str(cfg.fused_split)
         if mode == "off":
             return "fused_split=off"
-        impl = resolve_impl(cfg.hist_impl)
-        if impl != "pallas":
-            return f"hist_impl resolves to {impl} (epilogue is Pallas)"
+        if cfg.hist_impl != "pallas":
+            return (f"hist_impl resolves to {cfg.hist_impl} (epilogue is "
+                    "Pallas)")
         if self.chunked:
             return "chunked rounds accumulate histograms across chunks"
         if self.plan is not None:
@@ -1166,14 +1172,8 @@ class GBDT:
         if (self.mono_type_pf is not None
                 and cfg.monotone_constraints_method == "advanced"):
             return "advanced monotone re-reads sibling histograms"
-        F = self.train_set.num_features
-        W = max(1, min(int(cfg.leaf_batch), int(cfg.num_leaves) - 1))
-        if not (PH.fused_plan_ok(F, self.B, 2 * W)
-                and PH.fused_plan_ok(F, self.B, W)):
-            return (f"chunk plan unaligned for (F={F}, B={self.B}, "
-                    f"W={W})")
-        if mode != "on" and not PH.fused_probe_ok():
-            return "fused probe failed to compile on this backend"
+        if mode != "on":
+            return PH.FUSED_SPLIT_TPU_REASON
         return ""
 
     # -- class-batched multiclass build (ISSUE 8) ----------------------
@@ -1666,8 +1666,8 @@ class GBDT:
 
     def _fused_data_args(self):
         """The large per-instance device arrays the fused step reads,
-        as a pytree jit ARGUMENT. On jax 0.4.x, closed-over concrete
-        arrays are embedded into the lowered module as dense HLO
+        as a pytree jit ARGUMENT. Closed-over concrete arrays
+        would be embedded into the lowered module as dense HLO
         constants — a multi-MB (at Higgs scale, multi-hundred-MB)
         constant per dataset that XLA then burns compile time
         constant-folding over. Passing them as arguments keeps the
@@ -1733,7 +1733,7 @@ class GBDT:
         fmask = self._feature_mask()
         if (self._bins_cm is None and self.plan is None
                 and self._bundle_meta is None
-                and resolve_impl(self.config.hist_impl) == "native"):
+                and self.config.hist_impl == "native"):
             # the lazy column-major copy must exist BEFORE tracing: a
             # trace-time build inside _build_one_tree would store a
             # tracer on self

@@ -71,7 +71,6 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..native import jax_ffi as _jax_ffi
 
 from ..ops.histogram import (build_histograms, resolve_impl, HIST_CH,
                              merge_histograms, _pvary)
@@ -122,9 +121,8 @@ def _round_int(x):
 
 def build_tree(*args, hist_impl: str = "auto", traced: bool = False,
                class_batched: bool = False, **kwargs):
-    """Unjitted entry: resolves ``hist_impl='auto'`` EAGERLY (the Pallas
-    probe must compile outside any trace — staged into an ambient trace
-    its try/except would pass vacuously) and dispatches to the jitted
+    """Unjitted entry: resolves ``hist_impl='auto'`` by
+    ``ops.histogram.resolve_impl``'s rule and dispatches to the jitted
     core. Same contract as :func:`_build_tree_impl` below.
 
     ``traced=True`` runs the plain (unjitted) core for callers that are
@@ -139,7 +137,8 @@ def build_tree(*args, hist_impl: str = "auto", traced: bool = False,
     :func:`_build_tree_class_batched`. The native FFI kernels carry no
     vmap batching rule, so the batched build remaps native -> scatter
     (bit-identical; tests/test_histogram.py native parity)."""
-    impl = resolve_impl(hist_impl)
+    impl = resolve_impl(hist_impl, kwargs.get("bundle_bins")
+                        or kwargs["num_bins"])
     if class_batched:
         if impl == "native":
             impl = "scatter"
@@ -221,10 +220,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
       are gathered and psum'd (communication O(top_k·B), not O(F·B));
       the split is chosen from those global sub-histograms.
     """
-    # 'auto' reaching here means a traced caller with no warm probe
-    # cache — resolve_impl then answers conservatively (no mid-trace
-    # probe); the eager wrapper above handles direct callers
-    hist_impl = resolve_impl(hist_impl)
+    hist_impl = resolve_impl(hist_impl, bundle_bins or num_bins)
     # Row compaction redirects the bins stream through a gathered index
     # order. It pays off when the kernel's per-row cost dominates the
     # one-time [R, F] gather: the matmul one-hot (R*F*B bf16), the CPU
@@ -495,7 +491,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     # random thresholds, gain scale/penalty (feature_contri, CEGB),
     # advanced monotone bounds, forced-split gathers, every parallel /
     # EFB / feature-sharded plan (they need the full histogram for the
-    # merge collective or subtraction), and unaligned chunk plans.
+    # merge collective or subtraction).
     use_smooth = split_params.path_smooth > 0.0
     pen_on = use_mono and split_params.monotone_penalty > 0.0
     use_fused = bool(
@@ -503,8 +499,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         and not use_bundle and not use_rand and not use_cegb
         and not use_forced and not use_mono_adv
         and gain_scale is None and cat_sorted_mask is None
-        and not feature_sharded
-        and PH.fused_plan_ok(F, B, 2 * W) and PH.fused_plan_ok(F, B, W))
+        and not feature_sharded)
 
     # quantized training: histograms come back int32 (exact); descale to
     # (sum_g, sum_h, count) f32 once per build — the single-pass analog of
@@ -534,7 +529,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             (S, mat.shape[1], nb_in, HIST_CH),
             jnp.int32 if q else jnp.float32)
         bf16 = bool((not q) and jnp.dtype(hist_dtype) == jnp.bfloat16)
-        h = _jax_ffi().ffi_call(target, out_sds)(
+        h = jax.ffi.ffi_call(target, out_sds)(
             mat, g, part[0], part[1], part[2], slots.astype(jnp.int32),
             bf16_round=bf16)
         if axis_name is not None:
@@ -1616,7 +1611,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                 # metadata (feature-parallel pads the TRAIN matrix's
                 # feature axis; valid matrices stay unpadded)
                 F_mat = bmat.shape[1]
-                out = _jax_ffi().ffi_call(
+                out = jax.ffi.ffi_call(
                     "lgbtpu_relabel",
                     jax.ShapeDtypeStruct(rl.shape, jnp.int32))(
                     bmat, rl.astype(jnp.int32),
@@ -1670,7 +1665,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             # partition of each split leaf's segment; only those rows
             # are touched (and only they change row_leaf)
             mat_p = bins if bins_cm is None else bins_cm
-            outs = _jax_ffi().ffi_call(
+            outs = jax.ffi.ffi_call(
                 "lgbtpu_partition",
                 (jax.ShapeDtypeStruct((R,), jnp.int32),
                  jax.ShapeDtypeStruct((R,), jnp.int32),

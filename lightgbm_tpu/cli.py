@@ -140,7 +140,8 @@ def run(params: Dict[str, str]) -> int:
 
     # persistent XLA compile cache (engine.enable_compilation_cache):
     # CLI processes are one-shot, so without it every invocation repays
-    # the full compile+warmup; with it only the first run on a host does
+    # the full compile+warmup; with it only the first run of a checkout
+    # does
     from .engine import enable_compilation_cache
     enable_compilation_cache()
 

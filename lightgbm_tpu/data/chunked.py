@@ -144,7 +144,7 @@ class ChunkedTreeBuilder:
                  block_rows: int = 0,
                  cat_sorted_mask: Optional[jax.Array] = None,
                  hist_sub: bool = True):
-        impl = resolve_impl(hist_impl)
+        impl = resolve_impl(hist_impl, num_bins)
         if impl not in ("scatter", "matmul"):
             # native/pallas have no carried-init formulation that is
             # bit-stable under chunking (post-add reorders f32 sums)

@@ -30,48 +30,39 @@ __all__ = ["Booster", "PredictSession", "train", "cv", "CVBooster",
 
 
 def enable_compilation_cache():
-    """Wire jax's persistent XLA compilation cache so the multi-second
-    compile+warmup of the training/predict programs is paid once per
-    HOST instead of once per process (r05 measured 6.27 s compile+warmup
-    per run). Default dir ``~/.cache/lightgbm_tpu/xla``;
-    ``LIGHTGBM_TPU_CACHE_DIR`` overrides it,
-    ``LIGHTGBM_TPU_COMPILE_CACHE=0`` disables, and ``=1`` force-enables
-    on the CPU backend (where it is otherwise opt-in — this jaxlib has
-    segfaulted deserializing CPU executables). Called by :func:`train`
-    and the CLI; safe to call repeatedly and never overrides a cache
-    dir the user already configured in jax. Returns the active cache
-    dir, or None when disabled/unsupported."""
+    """Point jax's persistent XLA compilation cache at a place that
+    outlives the process, so the compile of the training/predict
+    programs (seconds with the Pallas kernel, minutes for the full-width
+    matmul formulation) is paid once per checkout. ONE rule, shared by
+    :func:`train`, the CLI and the prediction server:
+
+    - ``JAX_COMPILATION_CACHE_DIR`` set: jax already reads it; nothing
+      is set in code.
+    - otherwise ``<checkout>/.xla_cache`` — a fixed path beside the
+      code (the path is part of the cache key, so a directory that
+      moves never hits).
+    - the CPU backend stays off unless the variable is set: jaxlib
+      0.9.0 has segfaulted (de)serializing CPU executables (see
+      tests/conftest.py).
+
+    Safe to call repeatedly. Returns the cache dir in force, or None."""
     import os
-    on = os.environ.get("LIGHTGBM_TPU_COMPILE_CACHE", "")
-    if on == "0":
-        return None
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
     import jax
-    cur = getattr(jax.config, "jax_compilation_cache_dir", None)
-    if cur:
-        return cur
-    if jax.default_backend() == "cpu" and on != "1":
-        # CPU is OPT-IN (LIGHTGBM_TPU_COMPILE_CACHE=1): this jaxlib's
-        # CPU executable (de)serialization has produced hard segfaults
-        # (see tests/conftest.py round-5 note); accelerator backends
-        # default on, where the cache pays the compile+warmup once per
-        # host
+    if jax.default_backend() == "cpu":
         return None
-    d = os.environ.get("LIGHTGBM_TPU_CACHE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "lightgbm_tpu", "xla")
-    try:
+    from .native import cache_root
+    d = os.path.join(cache_root(), ".xla_cache")
+    if jax.config.jax_compilation_cache_dir != d:
         os.makedirs(d, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", d)
-    except Exception as e:  # unwritable dir / ancient jax: train anyway
-        log.warning(f"persistent compilation cache unavailable: {e}")
-        return None
-    # cache every program: the helper jits are small and fast to
-    # compile, but a warm process should pay ZERO recompiles
-    for k, v in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                 ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(k, v)
-        except Exception:
-            pass
+        # cache every program: the helper jits are small and fast to
+        # compile, but a warm process should pay ZERO recompiles
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          0.0)
     return d
 
 

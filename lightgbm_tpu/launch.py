@@ -25,8 +25,16 @@ discovery of ``dask.py:415``. Remote ranks spawn over ``ssh`` (BatchMode
 — keys must be set up, as with mpirun); hosts named ``localhost`` /
 ``127.0.0.1`` spawn directly. The coordinator is the first host at
 ``--port``. On Cloud TPU pods, prefer the platform launcher +
-jax.distributed auto-detection; this launcher covers single-host
+jax.distributed auto-detection; this launcher covers CPU-mesh
 multi-process setups and explicit host lists.
+
+One process per chip set: a TPU chip belongs to one process at a time,
+and ONE process drives all chips of a host (``tree_learner=data`` over
+``jax.devices()``) — that is the supported multi-chip path. So several
+local workers on a TPU host are refused unless the workers are pinned
+to the CPU backend (``JAX_PLATFORMS=cpu``): each would try to claim
+every chip and all but the first would fail or hang. The launcher
+itself never imports jax, so it never holds a chip.
 """
 
 from __future__ import annotations
@@ -52,6 +60,34 @@ def _free_port() -> int:
     port = s.getsockname()[1]
     s.close()
     return port
+
+
+def _local_tpu_chips() -> List[str]:
+    """Device nodes of this host's TPU chips — read from /dev so the
+    launcher stays off jax (a parent that touched jax would hold the
+    chip its workers need)."""
+    import glob
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _refuse_shared_chips(n_local: int) -> None:
+    """Raise when ``n_local`` > 1 workers on this host would contend
+    for its TPU chips (see the module docstring)."""
+    if n_local <= 1:
+        return
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return
+    chips = _local_tpu_chips()
+    if chips:
+        raise RuntimeError(
+            f"refusing to start {n_local} workers on a TPU host "
+            f"({len(chips)} chip(s): {', '.join(chips)}): a chip belongs "
+            "to one process, and every worker would claim them all. One "
+            "process drives all chips of a host — train with "
+            "tree_learner=data in a single process; use this launcher "
+            "for one worker per host (--hostfile) or for CPU meshes "
+            "(JAX_PLATFORMS=cpu)")
 
 
 def _wait_fail_fast(procs: List[subprocess.Popen]) -> int:
@@ -82,6 +118,7 @@ def launch(script_argv: List[str], num_processes: int,
     exit code (killing the stragglers, fail-fast) or 0."""
     if num_processes < 1:
         raise ValueError("num_processes must be >= 1")
+    _refuse_shared_chips(num_processes)
     coord = coordinator or f"127.0.0.1:{_free_port()}"
     procs = []
     try:
@@ -154,6 +191,7 @@ def launch_hosts(script_argv: List[str], hosts: List[Tuple[str, int]],
     (worker discovery -> machines string -> per-worker network init).
     """
     total = sum(s for _, s in hosts)
+    _refuse_shared_chips(sum(s for h, s in hosts if h in _LOCAL_HOSTS))
     if hosts[0][0] in _LOCAL_HOSTS and any(
             h not in _LOCAL_HOSTS for h, _ in hosts):
         raise ValueError(

@@ -2,10 +2,11 @@
 
 The reference ships its parser as part of the C++ core
 (``src/io/parser.cpp``); here ``parser.c`` is compiled ON FIRST USE with
-``gcc -O3 -shared -fPIC`` into a content-hashed cache file and loaded
-via ctypes — no install-time build step, and every caller keeps a pure
-Python fallback, so a missing/broken toolchain only costs speed
-(~10-40x on large text files), never functionality.
+``gcc -O3 -shared -fPIC`` into a content-hashed cache file under
+``<checkout>/.native_cache`` and loaded via ctypes — no install-time
+build step, and every caller keeps a pure Python fallback, so a
+missing/broken toolchain only costs speed (~10-40x on large text
+files), never functionality.
 
 Set ``LIGHTGBM_TPU_NO_NATIVE=1`` to force the Python paths.
 """
@@ -20,20 +21,9 @@ from typing import Optional
 
 import numpy as np
 
-__all__ = ["native_lib", "capi_lib", "hist_lib", "jax_ffi",
+__all__ = ["native_lib", "capi_lib", "hist_lib", "cache_root",
            "parse_delimited", "parse_libsvm"]
 
-
-def jax_ffi():
-    """The jax FFI namespace across versions: ``jax.ffi`` where it
-    exists (0.5+), else ``jax.extend.ffi`` (0.4.x) — same surface
-    (include_dir / pycapsule / register_ffi_target / ffi_call)."""
-    import jax
-    ffi = getattr(jax, "ffi", None)
-    if ffi is not None:
-        return ffi
-    import jax.extend as jex
-    return jex.ffi
 
 _LIB = None
 _TRIED = False
@@ -47,17 +37,27 @@ _DOUBLE_P = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
 
 
 
+def cache_root() -> str:
+    """The checkout: the directory that holds the ``lightgbm_tpu``
+    package. Everything the program builds at run time lives beside the
+    code — native .so files in ``.native_cache/``, XLA executables in
+    ``.xla_cache/`` (engine.enable_compilation_cache) — never under
+    ``~`` or a temp name, so a fresh machine that receives the checkout
+    receives (or rebuilds in place) exactly what it needs."""
+    return os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
+
 def _compile_and_load(src_name: str, so_prefix: str, extra_gcc=(),
                       compiler: str = "gcc"):
-    """Compile a bundled C/C++ source into the content-hashed per-user
-    cache (0700 — a predictable /tmp path would let another local user
+    """Compile a bundled C/C++ source into the content-hashed cache
+    (0700 — a predictable /tmp path would let another local user
     pre-plant a malicious .so) and ctypes-load it. Raises on failure."""
     src = os.path.join(os.path.dirname(__file__), src_name)
     with open(src, "rb") as f:
         code = f.read()
     tag = hashlib.sha256(code + repr(extra_gcc).encode()).hexdigest()[:16]
-    cache_dir = os.environ.get("LIGHTGBM_TPU_CACHE") or os.path.join(
-        os.path.expanduser("~"), ".cache", "lightgbm_tpu")
+    cache_dir = os.path.join(cache_root(), ".native_cache")
     os.makedirs(cache_dir, mode=0o700, exist_ok=True)
     so = os.path.join(cache_dir, f"{so_prefix}_{tag}.so")
     if not os.path.exists(so):
@@ -187,7 +187,7 @@ def hist_lib():
     if os.environ.get("LIGHTGBM_TPU_NO_NATIVE"):
         return None
     try:
-        ffi = jax_ffi()
+        from jax import ffi
         inc = ffi.include_dir()
         lib = _compile_and_load(
             "hist_ffi.cc", "lightgbm_tpu_hist_ffi",

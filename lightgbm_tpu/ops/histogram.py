@@ -23,9 +23,10 @@ Design (TPU-first, NOT a translation):
 - Rows are processed in fixed-size blocks via lax.scan so the bf16 one-hot
   temporary stays bounded; all shapes static for XLA.
 - Padded rows carry row_leaf == -1 and never match a leaf id.
-- A Pallas kernel generating the one-hot in VMEM (skipping the HBM
-  round-trip) is the planned round-2 upgrade; this XLA formulation is the
-  portable baseline and the semantics oracle for it.
+- ops/pallas_histogram.py generates the one-hot in VMEM (skipping the
+  HBM round-trip) and is what ``auto`` picks on a TPU; this XLA
+  formulation is the portable baseline, the semantics oracle for it,
+  and what ``auto`` picks by rule for lattices the kernel cannot take.
 - Class batching (``class_batch``, boosting/tree_builder.py
   ``_build_tree_class_batched``): the multiclass trainer vmaps the whole
   build over the class axis, so these kernels run under a batching
@@ -50,11 +51,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..native import jax_ffi as _jax_ffi
 import numpy as np
 
-__all__ = ["build_histograms", "resolve_impl", "merge_histograms",
-           "HIST_CH"]
+__all__ = ["build_histograms", "resolve_impl", "pallas_shape_reason",
+           "merge_histograms", "HIST_CH"]
 
 # channels per histogram cell: (sum_grad, sum_hess, count)
 HIST_CH = 3
@@ -118,117 +118,51 @@ def block_rows_for(num_rows: int, num_features: int, num_bins: int) -> int:
 def _pvary(x, axis_name):
     """Mark a scan carry as varying over a shard_map axis (no-op when
     it already is — pcast rejects varying->varying)."""
-    vma = getattr(getattr(x, "aval", None), "vma", None)
-    if vma is not None and axis_name in vma:
+    if axis_name in jax.typeof(x).vma:
         return x
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axis_name, to="varying")
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, axis_name)
-    return x  # 0.4.x shard_map: no varying-mark concept — no-op
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
-# Pallas training-path survivability: the fused kernel has never met a
-# given chip's Mosaic toolchain until first hardware contact, and the
-# reference's equivalent defense is a GPU->CPU treelearner fallback
-# (gpu_tree_learner.cpp logs and degrades rather than aborting). The
-# verdict is probed ONCE, eagerly, and cached for the process.
-_PALLAS_TRAIN_OK: Optional[bool] = None
+# The Pallas kernel expands bin ids on the MXU in bf16, which is exact
+# up to 256 (ops/pallas_histogram.py module docstring).
+PALLAS_MAX_BINS = 256
 
 
-def _reset_pallas_probe() -> None:
-    """Forget the cached Pallas probe verdicts (tests only) — both the
-    training-path verdict here and the fused build+split verdict in
-    ops.pallas_histogram (they gate independently: a chip can run the
-    histogram kernel yet reject the fused epilogue)."""
-    global _PALLAS_TRAIN_OK
-    _PALLAS_TRAIN_OK = None
-    from . import pallas_histogram
-    pallas_histogram._FUSED_PROBE.clear()
+def pallas_shape_reason(num_bins: int) -> str:
+    """Why the Pallas kernel cannot take a ``num_bins``-wide lattice
+    ('' = it can). The rule ``resolve_impl`` applies under ``auto`` and
+    the message an explicit ``hist_impl=pallas`` raises with."""
+    if num_bins > PALLAS_MAX_BINS:
+        return (f"num_bins={num_bins} > {PALLAS_MAX_BINS} (bin ids are "
+                "expanded in bf16, exact only up to 256)")
+    return ""
 
 
-def _probe_pallas_training() -> bool:
-    """Compile + run a tiny Pallas histogram eagerly, once; cache verdict.
+def resolve_impl(impl: str, num_bins: int) -> str:
+    """Resolve ``hist_impl='auto'`` to a concrete kernel from the
+    backend and the lattice width alone — no probe compile, no
+    fallback: a kernel this rule picks either compiles or its compile
+    error propagates.
 
-    Mosaic may reject the kernel on a chip/toolchain this code has never
-    met; default-params training must degrade to the matmul formulation
-    instead of crashing. Runs eagerly so the verdict exists before any
-    outer jit traces ``build_histograms``.
-    """
-    global _PALLAS_TRAIN_OK
-    if _PALLAS_TRAIN_OK is None:
-        try:
-            from . import pallas_histogram
-            # F=2, B=64 resolves to the lane-ALIGNED kernel plan
-            # (fc*Bp = 128) — the same shape class production configs
-            # take; a tiny unaligned probe would validate the wrong path
-            r, l = 256, 2
-            out = pallas_histogram.build_histograms_pallas(
-                jnp.zeros((r, 2), jnp.uint8),
-                jnp.ones((r, HIST_CH), jnp.float32),
-                jnp.zeros((r,), jnp.int32),
-                jnp.arange(l, dtype=jnp.int32),
-                num_bins=64, hist_dtype="bfloat16")
-            jax.block_until_ready(out)
-            _PALLAS_TRAIN_OK = True
-        except Exception as e:  # Mosaic lowering / runtime rejection
-            from .. import log as _log
-            # default to caching the False verdict (an unrecognized
-            # failure repeating the doomed probe compile on EVERY
-            # booster setup would stall each one for seconds); only a
-            # known-TRANSIENT class — momentary device OOM / device
-            # busy — leaves the cache unset so the next resolve retries
-            msg = f"{type(e).__name__}: {e}"
-            transient = any(s in msg for s in (
-                "RESOURCE_EXHAUSTED", "Resource exhausted",
-                "out of memory", "OOM", "DEADLINE_EXCEEDED",
-                "UNAVAILABLE", "ABORTED"))
-            _log.warning(
-                "Pallas histogram kernel unavailable on this backend "
-                f"({msg}); falling back to the XLA matmul formulation"
-                + (" (transient error — will re-probe on next resolve)"
-                   if transient else ""))
-            if transient:
-                return False
-            _PALLAS_TRAIN_OK = False
-    return _PALLAS_TRAIN_OK
+    - tpu: ``pallas``, or ``matmul`` when :func:`pallas_shape_reason`
+      names a reason (GBDT surfaces it as ``hist_impl_reason``);
+    - cpu: ``native`` (the runtime-compiled C kernel, native/hist.c —
+      dense_bin.hpp ConstructHistogram cache locality, ~5x the XLA
+      scatter) when a C toolchain built it, else ``scatter``;
+    - anything else: ``matmul``.
 
-
-def _trace_state_clean() -> bool:
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:
-        return True
-
-
-def resolve_impl(impl: str) -> str:
-    """Resolve ``hist_impl='auto'`` to a concrete kernel for this backend.
-
-    Call eagerly (GBDT setup does) before any tracing: on TPU the Pallas
-    kernel is the default but only after a one-time probe compile proves
-    Mosaic accepts it — otherwise the matmul formulation. When invoked
-    mid-trace with the probe not yet run (a direct jitted caller), the
-    probe CANNOT run meaningfully — its ops would be staged into the
-    ambient trace and the try/except would pass vacuously, poisoning the
-    cache — so resolution stays conservatively on matmul instead.
-    """
+    ``num_bins`` is the width of the lattice the histogram is built in
+    (the bundle width under EFB)."""
     if impl != "auto":
         return impl
     backend = jax.default_backend()
     if backend == "cpu":
-        # the runtime-compiled C kernel (native/hist.c — dense_bin.hpp
-        # ConstructHistogram cache locality) beats the XLA scatter by
-        # ~5x; scatter remains the no-toolchain fallback
         from .. import native as _native
         if _native.hist_lib() is not None:
             return "native"
         return "scatter"     # XLA lowers the scatter to per-row adds
-    if backend == "tpu":
-        if _PALLAS_TRAIN_OK is None and not _trace_state_clean():
-            return "matmul"
-        return "pallas" if _probe_pallas_training() else "matmul"
+    if backend == "tpu" and not pallas_shape_reason(num_bins):
+        return "pallas"
     return "matmul"
 
 
@@ -268,10 +202,9 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
       impl: "matmul" (MXU one-hot formulation), "scatter" (XLA
         scatter-add), "native" (the C kernel as an XLA FFI custom call
         on CPU — the true dense_bin.hpp:105 sequential pass; bit-equal
-        to scatter), "pallas" (fused TPU kernel), or "auto" (backend
-        default: pallas on tpu after a probe, native on cpu when a
-        toolchain exists, else scatter; matmul elsewhere). All produce
-        identical histograms up to f32 accumulation order.
+        to scatter), "pallas" (fused TPU kernel; num_bins <= 256), or
+        "auto" (:func:`resolve_impl`'s rule). All produce identical
+        histograms up to f32 accumulation order.
 
     Quantized mode (gradient_discretizer.hpp:22 + the packed int16/int32
     histograms of cuda_histogram_constructor.cu): when ``gh`` is int8
@@ -332,10 +265,7 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
         block_rows = R
     nb = R // block_rows
     cdt = jnp.dtype(hist_dtype)
-    if impl == "auto":
-        # resolves at trace time (impl is static); the Pallas probe cache
-        # is normally warmed eagerly by GBDT setup via resolve_impl
-        impl = resolve_impl(impl)
+    impl = resolve_impl(impl, B)
 
     if impl == "pallas":
         from .pallas_histogram import build_histograms_pallas
@@ -376,7 +306,7 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
             nr_in = jnp.asarray(nr_in, jnp.int32).reshape((1,))
             out_sds = jax.ShapeDtypeStruct((L, F, B, HIST_CH), acc_dt_n)
             target = "lgbtpu_hist_i8" if quant else "lgbtpu_hist_f32"
-            hist = _jax_ffi().ffi_call(target, out_sds)(
+            hist = jax.ffi.ffi_call(target, out_sds)(
                 bins, gh, row_leaf.astype(jnp.int32),
                 leaf_ids.astype(jnp.int32), rg_in, nr_in,
                 bf16_round=bf16_round, use_gather=has_rg)

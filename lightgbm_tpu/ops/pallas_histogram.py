@@ -2,15 +2,38 @@
 
 The XLA formulation in ops/histogram.py materializes a ``[block, F*B]``
 bf16 one-hot in HBM and feeds it to the MXU; at Higgs scale that is ~14GB
-of HBM traffic per histogram build, and HBM bandwidth — not MXU FLOPs —
-is the TPU bottleneck (reference hot loop analog:
+of HBM traffic per histogram build (reference hot loop analog:
 ``src/io/dense_bin.hpp:105`` ConstructHistogram,
 ``src/treelearner/cuda/cuda_histogram_constructor.cu`` shared-memory
-kernels). This kernel builds the one-hot *in VMEM* per (row-block,
-feature-chunk) grid step, multiplies on the MXU, and accumulates into a
-VMEM-resident output block — the one-hot never touches HBM. HBM traffic
-drops to the irreducible streams: bins [R, Fc] uint8 + gh [R, 3] in,
-hist [F*B, L*3] out.
+kernels). This kernel builds the one-hot *in VMEM* per (feature-chunk,
+row-block) grid step, multiplies on the MXU, and accumulates into a
+VMEM-resident output block — the one-hot never touches HBM.
+
+Layout (what real Mosaic on a v5e accepts — every op in the histogram
+kernel body is a 2-D elementwise op, a 2-D broadcast, an iota or a
+matmul; no reshape, gather, concatenate or narrow-dtype arithmetic):
+
+- **rows ride the lane axis.** Operands arrive transposed — bins
+  ``[n_chunks, fc, R]``, gh ``[3, R]``, leaf ``[1, R]`` — so every HBM
+  array is dense (a ``[R, 8]`` operand would pad to 128 lanes, 16x) and
+  every block's last dim is the row block (a multiple of 128), its
+  second-to-last the array's full extent. Any feature-chunk width is a
+  legal block; F is padded up to ``n_chunks * fc`` with zero columns
+  whose histogram rows are sliced away.
+- **one-hot by a 0/1 expansion matmul.** ``expand[fc*Bp, fc] @
+  bins[fc, blk]`` copies each feature row to its ``Bp`` one-hot rows on
+  the MXU (exact: bin ids <= 255 are exact in bf16, one nonzero term per
+  output); comparing with the row's own bin id gives ``onehot[fc*Bp,
+  blk]`` with no 3-D broadcast + reshape. This is why the kernel takes
+  ``num_bins <= 256`` only (``ops.histogram.pallas_shape_reason``).
+- **addends channel-major.** Output lane ``c = channel * L + slot``;
+  ``ghl[c, r] = (leaf[r] == slot_of[c]) ? gh[channel_of[c], r] : 0`` is
+  two selects over a sublane-broadcast row and a lane-broadcast column,
+  in 32-bit, narrowed to the matmul dtype at the end (v5e has no int8
+  VPU multiply). ``3 * L`` pads to 128 lanes, so a 21- or 42-slot
+  build fills one MXU column tile.
+- ``hist[fc*Bp, lanes] += onehot @ ghl^T`` (contract the row axis of
+  both — the q @ k^T form).
 
 Grid: ``(feature_chunks, row_blocks)`` with rows innermost, so each
 feature chunk's accumulator stays pinned in VMEM across the whole row
@@ -23,15 +46,17 @@ Numerics match ops/histogram.py's matmul path: addends cast to
 Class batching: the multiclass class-batched build
 (boosting/tree_builder.py ``_build_tree_class_batched``) vmaps the
 whole tree build, so ``pallas_call`` here lowers through its batching
-rule — ONE kernel launch whose grid gains the class axis, bit-equal to
-K sequential launches (validated in interpret mode for both the plain
-and scalar-prefetch paths). Caveat: vmap batches EVERY operand, so the
-bins matrix — logically shared across classes — is presented K× to the
-root-histogram launch ([K, R, Fc] view). XLA keeps it as a broadcast
-(no HBM copy), but the kernel's block streams read it per class: the
-root build's bins traffic is K× the sequential path's single pass.
-In-loop builds index per-class rows anyway, so only the root round
-pays; the K× MXU utilization win dominates on every measured shape.
+rule — ONE kernel launch whose grid gains the class axis. The root
+round instead uses :func:`build_root_histograms_classes`, which streams
+the (class-shared) bins once for all K classes.
+
+Compile verdicts on v5e (tests/test_mosaic_aot.py compiles every
+variant against a v5e topology without a chip): the histogram kernel
+(f32/bf16/int8, with and without ``num_rows``, under vmap and under
+shard_map with check_vma) and the class-root kernel compile;
+:func:`fused_build_best_splits` does NOT — its epilogue is
+``ops.split.eval_split_lattice``, whose ``cumsum`` has no Pallas TPU
+lowering (``FUSED_SPLIT_TPU_REASON``) — and runs in interpret mode only.
 """
 
 from __future__ import annotations
@@ -41,156 +66,204 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .histogram import HIST_CH
+from .histogram import HIST_CH, pallas_shape_reason
 from . import split as _split
 
-__all__ = ["build_histograms_pallas", "pallas_available",
-           "fused_build_best_splits", "fused_plan_ok", "fused_probe_ok",
-           "fused_candidate_bytes", "build_root_histograms_classes"]
+__all__ = ["build_histograms_pallas", "fused_build_best_splits",
+           "fused_candidate_bytes", "build_root_histograms_classes",
+           "FUSED_SPLIT_TPU_REASON"]
+
+# Why gbdt's fused-split gate stays closed unless forced: the compiler's
+# own words (jax 0.9.0 Pallas TPU lowering; tests/test_mosaic_aot.py
+# asserts the message still holds, so a jax that learns cumsum trips it).
+FUSED_SPLIT_TPU_REASON = (
+    "fused split epilogue does not lower on TPU (Unimplemented primitive "
+    "in Pallas TPU lowering for KernelType.TC: cumsum)")
+
+_FB_CAP = 2048            # one-hot rows (fc * Bp) per feature chunk
+_VMEM_BUDGET = 24 << 20   # what _plan sizes the row block against
+_VMEM_LIMIT = 64 << 20    # Mosaic's scoped-VMEM ceiling for the kernels
+                          # (v5e: 128 MiB physical, 16 MiB default scope)
 
 
-def pallas_available() -> bool:
-    """True when the Pallas TPU lowering path can run (a TPU backend)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def _ceil_to(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
-def _kernel(bins_ref, gh_ref, leaf_ref, lids_ref, out_ref, *,
-            num_bins: int, cdt, fb_pad: int, lb3_pad: int, acc_dt,
-            nr_ref=None, blk_rows: int = 0):
-    """One (feature-chunk, row-block) grid step.
+def _plan(F: int, B: int, n_cols: int, cdt_bytes: int):
+    """(row_block, feature_chunk, n_chunks, padded_bins, lanes).
 
-    bins_ref: [blk, Fc] int32 (pre-padded; out-of-range bin == no match)
-    gh_ref:   [blk, 8] f32   (grad, hess, in-bag count, 5 zero lanes)
-              — or int8 quantized grid values (see ops/histogram.py)
-    leaf_ref: [blk, 8] int32 current leaf per row broadcast (-1 dead)
-    lids_ref: [8, L_pad] int32 leaf slots this build targets (-2 pad)
-    out_ref:  [fb_pad, lb3_pad] f32 (int32 when quantized) accumulator
-              (same block every row step; both dims padded to MXU/VPU
-              tile multiples)
-    nr_ref:   scalar-prefetch [1] int32 live-row bound, or None — row
-              blocks at or past ceil(nr / blk) are SKIPPED entirely (the
-              index maps also clamp their DMAs to an already-fetched
-              block), so a compacted stream pays only for its live
-              prefix — the dense_bin.hpp:105 data_indices bound.
+    ``Bp`` is the power of two >= max(B, 8) (bins >= B never match), so
+    a one-hot row's feature and bin are a shift and a mask of its index
+    and ``fc * Bp`` is a sublane multiple. Features split into the
+    fewest balanced chunks with ``fc * Bp <= _FB_CAP``. The row block
+    is sized so the step's 32-bit intermediates (expanded bins, compare
+    mask, selected addends — Mosaic need not materialize them all, the
+    estimate assumes it does), the narrow matmul operands, the
+    double-buffered inputs and the resident accumulator fit
+    ``_VMEM_BUDGET``."""
+    Bp = max(8, 1 << (max(B, 2) - 1).bit_length())
+    n_fb = -(-F // max(1, min(F, _FB_CAP // Bp)))
+    fc = -(-F // n_fb)
+    lanes = _ceil_to(n_cols, 128)
+    fb = fc * Bp
+    per_row = ((fb + lanes) * (8 + cdt_bytes)
+               + 2 * 4 * (fc + HIST_CH + 1))
+    blk = (_VMEM_BUDGET - 2 * fb * lanes * 4) // per_row
+    blk = max(128, min(4096, blk // 128 * 128))
+    return blk, fc, n_fb, Bp, lanes
+
+
+def _onehot_t(bins_ref, *, Bp: int, cdt):
+    """[fc*Bp, blk] one-hot of a [fc, blk] int32 bin block (row
+    ``f*Bp + b`` is 1 where ``bins[f, r] == b``)."""
+    fc, _ = bins_ref.shape
+    fb = fc * Bp
+    shift = Bp.bit_length() - 1
+    row = jax.lax.broadcasted_iota(jnp.int32, (fb, fc), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (fb, fc), 1)
+    expand = ((row >> shift) == col).astype(jnp.bfloat16)
+    bins_x = jnp.dot(
+        expand, bins_ref[:].astype(jnp.float32).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32)               # [fb, blk]
+    bin_of_row = (jax.lax.broadcasted_iota(jnp.int32, (fb, 1), 0)
+                  & (Bp - 1)).astype(jnp.float32)
+    return jnp.where(bins_x == bin_of_row, 1, 0).astype(cdt)
+
+
+def _accumulate(out_ref, onehot, addends, *, cdt, acc_dt):
+    """out[fb, lanes] += onehot[fb, blk] @ addends[lanes, blk]^T."""
+    # float32 mode must not silently drop to the MXU's bf16 passes
+    prec = jax.lax.Precision.HIGHEST if cdt == jnp.float32 else None
+    out_ref[:] += jax.lax.dot_general(
+        onehot, addends.astype(cdt), (((1,), (1,)), ((), ())),
+        precision=prec, preferred_element_type=acc_dt)
+
+
+def _slot_addends(gh_ref, leaf_ref, cols_ref):
+    """[lanes, blk] 32-bit addends: lane-row ``c`` holds channel
+    ``cols[c, 1]`` of gh for the rows whose leaf is slot ``cols[c, 0]``
+    (pad lanes carry slot -2 and never match)."""
+    hit = leaf_ref[:] == cols_ref[:, 0:1]
+    ch = cols_ref[:, 1:2]
+    val = jnp.where(ch == 0, gh_ref[0:1, :],
+                    jnp.where(ch == 1, gh_ref[1:2, :], gh_ref[2:3, :]))
+    return jnp.where(hit, val, 0)
+
+
+def _accumulate_step(nr_ref, bins_ref, gh_ref, leaf_ref, cols_ref,
+                     acc_ref, *, Bp: int, cdt, acc_dt, blk: int):
+    """One (feature-chunk, row-block) grid step of the accumulation.
+
+    nr_ref:   scalar-prefetch [1] int32 live-row bound — row blocks at
+              or past ceil(nr / blk) are SKIPPED (the index maps also
+              clamp their DMAs to an already-fetched block), so a
+              compacted stream pays only for its live prefix — the
+              dense_bin.hpp:105 data_indices bound. An unbounded build
+              passes its row count.
+    bins_ref: [fc, blk] int32
+    gh_ref:   [3, blk] f32 (grad, hess, in-bag count) — int32 grid
+              values when quantized
+    leaf_ref: [1, blk] int32 current leaf per row (-1 dead)
+    cols_ref: [lanes, 2] int32 (slot, channel) per output lane
+    acc_ref:  [fc*Bp, lanes] f32 (int32 when quantized) accumulator,
+              the same block every row step
     """
+    # program_id is read at kernel top level (inside a pl.when body it
+    # misses the interpret-mode grid-env substitution)
     j = pl.program_id(1)
-    blk, fc = bins_ref.shape
-    l_pad = lids_ref.shape[1]
 
-    def compute():
-        bb = bins_ref[:]                                  # [blk, Fc] int32
-        iota_b = jax.lax.broadcasted_iota(
-            jnp.int32, (blk, fc, num_bins), 2)
-        onehot = (bb[:, :, None] == iota_b).astype(cdt).reshape(
-            blk, fc * num_bins)
-        if fb_pad != fc * num_bins:
-            onehot = jnp.pad(onehot,
-                             ((0, 0), (0, fb_pad - fc * num_bins)))
+    @pl.when(j == 0)
+    def _():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        # leaf mask: [blk, L_pad]; pad slots are -2 and never match
-        mask = (leaf_ref[:, 0:1] == lids_ref[0:1, :]).astype(cdt)
-        ghb = gh_ref[:].astype(cdt)                       # [blk, 8]
-        # NOTE: ghb[:, None, :HIST_CH] (newaxis + partial slice in one
-        # index) lowers via lax.gather, which Mosaic rejects at this
-        # shape ("Shape mismatch in input, indices and output" — first
-        # real-hardware finding, r5). A static slice + expand_dims is
-        # the same math with no gather.
-        gh3 = jnp.expand_dims(ghb[:, :HIST_CH], 1)        # [blk, 1, 3]
-        ghl = (jnp.expand_dims(mask, 2) * gh3).reshape(
-            blk, l_pad * HIST_CH)
-        if lb3_pad != l_pad * HIST_CH:
-            ghl = jnp.pad(ghl,
-                          ((0, 0), (0, lb3_pad - l_pad * HIST_CH)))
-
-        return jax.lax.dot_general(
-            onehot, ghl, (((0,), (0,)), ((), ())),
-            preferred_element_type=acc_dt)            # [fb_pad, lb3_pad]
-
-    if nr_ref is None:
-        @pl.when(j == 0)
-        def _():
-            out_ref[:] = compute()
-
-        @pl.when(j > 0)
-        def _():
-            out_ref[:] = out_ref[:] + compute()
-    else:
-        nb_used = (nr_ref[0] + blk_rows - 1) // blk_rows
-        # the first step must still initialize the accumulator (zero
-        # when even block 0 is past the bound)
-        @pl.when(j == 0)
-        def _():
-            out_ref[:] = jnp.where(nb_used > 0, compute(),
-                                   jnp.zeros_like(out_ref))
-
-        @pl.when((j > 0) & (j < nb_used))
-        def _():
-            out_ref[:] = out_ref[:] + compute()
+    @pl.when(j * blk < nr_ref[0])
+    def _():
+        _accumulate(acc_ref, _onehot_t(bins_ref, Bp=Bp, cdt=cdt),
+                    _slot_addends(gh_ref, leaf_ref, cols_ref),
+                    cdt=cdt, acc_dt=acc_dt)
 
 
-try:  # pallas imports kept optional so CPU-only installs never pay for them
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+def _rows_to_lanes(a, r_pad: int, **pad_kw):
+    """[R, C] -> [C, r_pad]: rows onto the lane axis, padded."""
+    return jnp.pad(a, ((0, r_pad - a.shape[0]), (0, 0)), **pad_kw).T
 
 
-def _plan_chunks(F: int, B: int, L: int, vmem_budget: int = 10 << 20):
-    """Pick (row_block, feature_chunk, padded_bins, padded_leaves).
-
-    Mosaic-friendliness: the one-hot is built at ``Bp`` bins (power of
-    two >= B; bins >= B simply never match) and ``fc`` is chosen so
-    ``fc * Bp`` is a multiple of the 128-lane tile — then the kernel's
-    reshape/matmul operands are exactly lane-aligned and its pads
-    compile away. ``l_pad`` is lifted to a multiple of 128 for the same
-    reason (ghl width l_pad*3 is then 128-aligned). Shapes with no
-    aligned divisor fall back to in-kernel padding (still correct)."""
-    Bp = 1 << int(np.ceil(np.log2(max(B, 2))))
-    l_pad = max(128, -(-L // 128) * 128)
-    out_cap = 4 << 20      # resident accumulator block budget
-    # feature chunk: fc | F, fc * Bp ≡ 0 (mod 128), fc * Bp <= 4096,
-    # and the [fc*Bp, l_pad*3] f32 accumulator under its own cap (it
-    # stays VMEM-resident across the whole row stream)
-    fc = 0
-    for cand in range(min(F, max(1, 4096 // Bp)), 0, -1):
-        if F % cand == 0 and (cand * Bp) % 128 == 0 \
-                and cand * Bp * l_pad * HIST_CH * 4 <= out_cap:
-            fc = cand
-            break
-    if fc == 0:
-        # no aligned divisor (e.g. odd tiny F): legacy padding path,
-        # with the cheap narrow leaf pad (alignment can't compile away
-        # here anyway)
-        Bp = B
-        l_pad = max(8, -(-L // 8) * 8)
-        fc = max(1, min(F, 4096 // max(B, 1)))
-        while F % fc != 0 or (fc > 1 and -(-(fc * B) // 128) * 128
-                              * -(-(l_pad * HIST_CH) // 128) * 128 * 4
-                              > out_cap):
-            fc -= 1
-    out_b = (-(-(fc * Bp) // 128) * 128
-             * -(-(l_pad * HIST_CH) // 128) * 128 * 4)
-    # row block: onehot (cdt bytes, estimate 2) + double-buffered bins
-    # int32 + ghl row width, inside what the accumulator leaves free
-    per_row = fc * Bp * 2 + fc * 4 * 2 + l_pad * HIST_CH * 4
-    blk = max(256, (vmem_budget - out_b) // max(1, per_row))
-    blk = int(2 ** np.floor(np.log2(blk)))
-    blk = min(blk, 4096)
-    return blk, fc, Bp, l_pad
+def _bins_chunks(bins, r_pad: int, fc: int, n_fb: int):
+    """[R, F] -> [n_fb, fc, r_pad] int32 (zero feature/row padding)."""
+    F = bins.shape[1]
+    b = jnp.pad(bins.astype(jnp.int32), ((0, 0), (0, n_fb * fc - F)))
+    return _rows_to_lanes(b, r_pad).reshape(n_fb, fc, r_pad)
 
 
-def _compiler_params(**kw):
-    """pltpu.CompilerParams across jax versions (TPUCompilerParams
-    before the rename)."""
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
+def _leaf_lanes(row_leaf, r_pad: int):
+    """[R] -> [1, r_pad] int32; padded rows get leaf -1."""
+    return _rows_to_lanes(row_leaf.astype(jnp.int32)[:, None], r_pad,
+                          constant_values=-1)
+
+
+def _slot_cols(leaf_ids, lanes: int):
+    """[lanes, 2] int32 (slot, channel) map of the channel-major output
+    lanes."""
+    L = leaf_ids.shape[0]
+    pad = lanes - L * HIST_CH
+    slot = jnp.pad(jnp.tile(leaf_ids.astype(jnp.int32), HIST_CH),
+                   (0, pad), constant_values=-2)
+    chan = jnp.pad(jnp.repeat(jnp.arange(HIST_CH, dtype=jnp.int32), L),
+                   (0, pad))
+    return jnp.stack([slot, chan], axis=1)
+
+
+def _live_rows(num_rows, R: int):
+    """The [1] int32 scalar-prefetch live-row bound (all R rows when the
+    caller gave none)."""
+    return jnp.reshape(jnp.asarray(R if num_rows is None else num_rows,
+                                   jnp.int32), (1,))
+
+
+def _stream_specs(fc: int, blk: int, lanes: int):
+    """Block specs of the row-stream operands (bins, gh, leaf, cols).
+    Steps past the live-row bound ``s`` revisit the last live row block
+    (no fresh DMA)."""
+    def rb(j, s):
+        return jnp.minimum(j, jnp.maximum((s[0] + blk - 1) // blk - 1, 0))
+    return [
+        pl.BlockSpec((None, fc, blk), lambda i, j, s: (i, 0, rb(j, s))),
+        pl.BlockSpec((HIST_CH, blk), lambda i, j, s: (0, rb(j, s))),
+        pl.BlockSpec((1, blk), lambda i, j, s: (0, rb(j, s))),
+        pl.BlockSpec((lanes, 2), lambda i, j, s: (0, 0)),
+    ]
+
+
+def _out_vma(*operands):
+    """Varying-manual-axes of the kernel outputs: inside shard_map with
+    check_vma on, pallas_call needs it spelled on each out_shape."""
+    return frozenset().union(*(jax.typeof(x).vma for x in operands))
+
+
+def _compiler_params():
+    # feature chunks are independent; the row dim revisits the same
+    # accumulator block and must stay sequential
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _check_shape(num_bins: int):
+    reason = pallas_shape_reason(num_bins)
+    if reason:
+        raise ValueError(f"hist_impl=pallas cannot take this shape: "
+                         f"{reason}")
+
+
+def _unpack_hist(out, *, F: int, B: int, L: int, fc: int, n_fb: int,
+                 Bp: int, lanes: int):
+    """[n_fb*fc*Bp, lanes] kernel output -> [L, F, B, 3]."""
+    hist = out.reshape(n_fb * fc, Bp, lanes)[:F, :B, :L * HIST_CH]
+    return hist.reshape(F, B, HIST_CH, L).transpose(3, 0, 1, 2)
 
 
 @functools.partial(
@@ -210,117 +283,58 @@ def build_histograms_pallas(bins: jax.Array, gh: jax.Array,
     int8 ``gh`` selects the quantized path (int8 MXU dot, exact int32
     output — see ops/histogram.py docstring).
     ``num_rows`` (traced int32 scalar): dynamic live-row bound for a
-    COMPACTED stream (VERDICT r4 #3) — it rides in as a scalar-prefetch
-    operand, row blocks at or past ``ceil(num_rows / blk)`` are skipped
-    by ``pl.when`` and their index maps clamp to an already-fetched
-    block (no fresh DMA), so histogram subtraction's row-stream savings
+    COMPACTED stream — it rides in as a scalar-prefetch operand, row
+    blocks at or past ``ceil(num_rows / blk)`` are skipped by
+    ``pl.when`` and their index maps clamp to an already-fetched block
+    (no fresh DMA), so histogram subtraction's row-stream savings
     survive on the chip. Rows past ``num_rows`` must carry
     ``row_leaf == -1`` (they are never read when the bound is exact,
     but the trailing partial block is still masked by leaf ids).
     ``interpret=True`` runs the kernel in the Pallas interpreter —
     CPU-testable parity with the real TPU lowering.
+    Raises ValueError for ``num_bins > 256`` (see module docstring).
     """
-    if not _HAS_PALLAS:
-        raise RuntimeError("pallas unavailable in this jax build")
     R, F = bins.shape
     L = int(leaf_ids.shape[0])
     B = int(num_bins)
+    _check_shape(B)
     quant = gh.dtype == jnp.int8
     cdt = jnp.int8 if quant else jnp.dtype(hist_dtype)
     acc_dt = jnp.int32 if quant else jnp.float32
-    blk, fc, Bp, l_pad = _plan_chunks(F, B, L)
-
-    r_pad = ((R + blk - 1) // blk) * blk
-    if r_pad != R:
-        bins = jnp.pad(bins, ((0, r_pad - R), (0, 0)))
-        gh = jnp.pad(gh, ((0, r_pad - R), (0, 0)))
-        row_leaf = jnp.pad(row_leaf, (0, r_pad - R), constant_values=-1)
-
-    n_fb = F // fc
-    n_rb = r_pad // blk
-    # with an aligned plan these equal fc*Bp / l_pad*3 exactly and the
-    # kernel's pads compile away; otherwise they round up to the tile
-    fb_pad = -(-(fc * Bp) // 128) * 128
-    lb3_pad = -(-(l_pad * HIST_CH) // 128) * 128
-
-    gh8 = jnp.pad(gh, ((0, 0), (0, 8 - HIST_CH)))
-    leaf8 = jnp.broadcast_to(row_leaf[:, None].astype(jnp.int32),
-                             (r_pad, 8))
-    lids8 = jnp.broadcast_to(
-        jnp.pad(leaf_ids.astype(jnp.int32), (0, l_pad - L),
-                constant_values=-2)[None, :], (8, l_pad))
-
-    kern = functools.partial(_kernel, num_bins=Bp, cdt=cdt,
-                             fb_pad=fb_pad, lb3_pad=lb3_pad,
-                             acc_dt=acc_dt)
-    if num_rows is None:
-        out = pl.pallas_call(
-            kern,
-            grid=(n_fb, n_rb),
-            in_specs=[
-                pl.BlockSpec((blk, fc), lambda i, j: (j, i)),
-                pl.BlockSpec((blk, 8), lambda i, j: (j, 0)),
-                pl.BlockSpec((blk, 8), lambda i, j: (j, 0)),
-                pl.BlockSpec((8, l_pad), lambda i, j: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((fb_pad, lb3_pad),
-                                   lambda i, j: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((n_fb * fb_pad, lb3_pad),
-                                           acc_dt),
-            # feature chunks are independent; the row dim revisits the
-            # same accumulator block and must stay sequential
-            compiler_params=_compiler_params(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
-        )(bins.astype(jnp.int32), gh8, leaf8, lids8)
-    else:
-        nr = jnp.reshape(jnp.asarray(num_rows, jnp.int32), (1,))
-
-        def _row_clamp(s, j):
-            # last live block; skipped steps revisit it (no new DMA)
-            jmax = jnp.maximum((s[0] + blk - 1) // blk - 1, 0)
-            return jnp.minimum(j, jmax)
-
-        def kern_nr(s_ref, *refs):
-            kern(*refs, nr_ref=s_ref, blk_rows=blk)
-
-        out = pl.pallas_call(
-            kern_nr,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(n_fb, n_rb),
-                in_specs=[
-                    pl.BlockSpec((blk, fc),
-                                 lambda i, j, s: (_row_clamp(s, j), i)),
-                    pl.BlockSpec((blk, 8),
-                                 lambda i, j, s: (_row_clamp(s, j), 0)),
-                    pl.BlockSpec((blk, 8),
-                                 lambda i, j, s: (_row_clamp(s, j), 0)),
-                    pl.BlockSpec((8, l_pad), lambda i, j, s: (0, 0)),
-                ],
-                out_specs=pl.BlockSpec((fb_pad, lb3_pad),
-                                       lambda i, j, s: (i, 0)),
-            ),
-            out_shape=jax.ShapeDtypeStruct((n_fb * fb_pad, lb3_pad),
-                                           acc_dt),
-            compiler_params=_compiler_params(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
-        )(nr, bins.astype(jnp.int32), gh8, leaf8, lids8)
-
-    hist = out.reshape(n_fb, fb_pad, lb3_pad)[:, :fc * Bp,
-                                              :l_pad * HIST_CH]
-    hist = hist.reshape(n_fb, fc, Bp, l_pad, HIST_CH)[:, :, :B, :L, :]
-    return hist.reshape(F, B, L, HIST_CH).transpose(2, 0, 1, 3)
+    blk, fc, n_fb, Bp, lanes = _plan(F, B, L * HIST_CH,
+                                     jnp.dtype(cdt).itemsize)
+    r_pad = _ceil_to(R, blk)
+    live = _live_rows(num_rows, R)
+    fb = fc * Bp
+    out = pl.pallas_call(
+        functools.partial(_accumulate_step, Bp=Bp, cdt=cdt, acc_dt=acc_dt,
+                          blk=blk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_fb, r_pad // blk),
+            in_specs=_stream_specs(fc, blk, lanes),
+            out_specs=pl.BlockSpec((fb, lanes), lambda i, j, s: (i, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_fb * fb, lanes), acc_dt,
+            vma=_out_vma(bins, gh, row_leaf, leaf_ids, live)),
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+    )(live, _bins_chunks(bins, r_pad, fc, n_fb),
+      _rows_to_lanes(gh.astype(acc_dt), r_pad), _leaf_lanes(row_leaf, r_pad),
+      _slot_cols(leaf_ids, lanes))
+    return _unpack_hist(out, F=F, B=B, L=L, fc=fc, n_fb=n_fb, Bp=Bp,
+                        lanes=lanes)
 
 
 # ---------------------------------------------------------------------------
-# Fused histogram → split-find kernel (ISSUE 14 / ROADMAP item 1).
+# Fused histogram → split-find kernel (ISSUE 14). INTERPRET MODE ONLY:
+# see FUSED_SPLIT_TPU_REASON.
 #
-# Same accumulation grid as `_kernel`; on the LAST row step of each
-# feature chunk an epilogue runs ops/split.py's dense gain lattice
+# Same accumulation step as the histogram kernel; on the LAST row step
+# of each feature chunk an epilogue runs ops/split.py's dense gain lattice
 # (`eval_split_lattice`) on the VMEM-resident accumulator and emits one
-# [l_pad, 128] candidate record block per chunk — gain, global feature,
+# [l_rec, 128] candidate record block per chunk — gain, global feature,
 # bin, missing-direction, winner left/right (G, H, count), constrained
 # outputs, and the chunk's leaf totals. A tiny XLA argmax over chunks
 # (`fused_build_best_splits` postlude) then replaces the full-lattice
@@ -342,40 +356,34 @@ def build_histograms_pallas(bins: jax.Array, gh: jax.Array,
 _REC_LANES = 128
 
 
-def fused_plan_ok(F: int, B: int, L: int) -> bool:
-    """True when `_plan_chunks` yields a lane-aligned plan — the fused
-    epilogue reshapes the accumulator [fb_pad, lb3_pad] into
-    [fc, Bp, l_pad, 3], which is only exact when the pads compile away."""
-    _, fc, Bp, l_pad = _plan_chunks(F, B, L)
-    return (fc * Bp) % 128 == 0 and (l_pad * HIST_CH) % 128 == 0
-
-
 def fused_candidate_bytes(F: int, B: int, L: int) -> int:
     """HBM bytes of the fused kernel's candidate-record output stream.
 
     This is the only lattice-sized traffic the fused build pass writes:
-    one [l_pad, _REC_LANES] f32 record block per feature chunk, in place
+    one [l_rec, _REC_LANES] f32 record block per feature chunk, in place
     of the two-pass path's [F, B, L, 3] histogram write + re-read. Used
     by the telemetry cost model's analytical byte counts."""
-    _, fc, _, l_pad = _plan_chunks(F, B, L)
-    n_fb = -(-F // fc)
-    return n_fb * l_pad * _REC_LANES * 4
+    _, _, n_fb, _, _ = _plan(F, B, L * HIST_CH, 2)
+    return n_fb * _ceil_to(L, 8) * _REC_LANES * 4
 
 
 def _split_epilogue(acc, chunk_idx, fmeta, lmeta, fmask, *, params,
-                    fc: int, Bp: int, l_pad: int, use_mono: bool,
+                    fc: int, Bp: int, L: int, use_mono: bool,
                     use_smooth: bool, pen_on: bool, quant: bool):
     """Gain lattice + per-chunk argmax over the VMEM-resident accumulator.
 
-    acc:   [fc*Bp, l_pad*3] (f32, or int32 quantized)
+    acc:   [fc*Bp, lanes] (f32, or int32 quantized), channel-major lanes
     fmeta: [8, fc] int32 — rows 0 num_bins_pf, 1 nan_bin, 2 is_cat,
            3 mono_type (this chunk's feature slice)
-    lmeta: [8, l_pad] f32 — rows 0 parent_output, 1 leaf_lo, 2 leaf_hi,
+    lmeta: [8, l_rec] f32 — rows 0 parent_output, 1 leaf_lo, 2 leaf_hi,
            3 mono_pen, 4 g_scale, 5 h_scale
-    fmask: [l_pad, fc] int32 candidate-feature mask
-    Returns the [l_pad, _REC_LANES] candidate record block.
+    fmask: [l_rec, fc] int32 candidate-feature mask
+    Returns the [l_rec, _REC_LANES] candidate record block.
     """
-    hist = acc.reshape(fc, Bp, l_pad, HIST_CH).transpose(2, 0, 1, 3)
+    l_rec = lmeta.shape[1]
+    hist = acc[:, :L * HIST_CH].reshape(fc, Bp, HIST_CH, L)
+    hist = jnp.pad(hist.transpose(3, 0, 1, 2),
+                   ((0, l_rec - L), (0, 0), (0, 0), (0, 0)))
     lat = _split.eval_split_lattice(
         hist, fmeta[0], fmeta[1], fmeta[2] != 0, params,
         feature_mask=(fmask != 0),
@@ -387,18 +395,18 @@ def _split_epilogue(acc, chunk_idx, fmeta, lmeta, fmask, *, params,
         quant_scales=(jnp.stack([lmeta[4], lmeta[5]], axis=1)
                       if quant else None))
     N = fc * Bp * 2
-    flat = lat["net"].reshape(l_pad, N)
+    flat = lat["net"].reshape(l_rec, N)
     best = jnp.argmax(flat, axis=1)
-    # gather-free winner select (Mosaic rejects lax.gather): one-hot the
-    # argmax and reduce. where() keeps -inf/0 products out of the sum.
-    sel = (jax.lax.broadcasted_iota(jnp.int32, (l_pad, N), 1)
+    # gather-free winner select: one-hot the argmax and reduce. where()
+    # keeps -inf/0 products out of the sum.
+    sel = (jax.lax.broadcasted_iota(jnp.int32, (l_rec, N), 1)
            == best[:, None])
 
     def pick1(a):
-        return jnp.sum(jnp.where(sel, a.reshape(l_pad, N), 0.0), axis=1)
+        return jnp.sum(jnp.where(sel, a.reshape(l_rec, N), 0.0), axis=1)
 
     def pick3(a):
-        return jnp.sum(jnp.where(sel[:, :, None], a.reshape(l_pad, N, 3),
+        return jnp.sum(jnp.where(sel[:, :, None], a.reshape(l_rec, N, 3),
                                  0.0), axis=1)
 
     gain = pick1(flat)
@@ -416,41 +424,8 @@ def _split_epilogue(acc, chunk_idx, fmeta, lmeta, fmask, *, params,
         rsum[:, 0], rsum[:, 1], rsum[:, 2],
         pick1(lat["out_l"]), pick1(lat["out_r"]),
         tot0[:, 0], tot0[:, 1], tot0[:, 2],
-    ], axis=1)                              # [l_pad, 15]
+    ], axis=1)                              # [l_rec, 15]
     return jnp.pad(rec, ((0, 0), (0, _REC_LANES - rec.shape[1])))
-
-
-def _fused_kernel(bins_ref, gh_ref, leaf_ref, lids_ref, fmeta_ref,
-                  lmeta_ref, fmask_ref, *refs, num_bins: int, cdt,
-                  fb_pad: int, lb3_pad: int, acc_dt, n_rb: int,
-                  emit_hist: bool, params, fc: int, Bp: int, l_pad: int,
-                  use_mono: bool, use_smooth: bool, pen_on: bool,
-                  quant: bool, nr_ref=None, blk_rows: int = 0):
-    """Accumulation grid step + last-row-step split epilogue.
-
-    Output refs: emit_hist → (hist_out, cand_out) with the histogram
-    block doubling as the accumulator; else (cand_out, acc_scratch) with
-    the accumulator in VMEM scratch — the histogram never leaves the
-    chip."""
-    if emit_hist:
-        acc_ref, cand_ref = refs
-    else:
-        cand_ref, acc_ref = refs
-    _kernel(bins_ref, gh_ref, leaf_ref, lids_ref, acc_ref,
-            num_bins=num_bins, cdt=cdt, fb_pad=fb_pad, lb3_pad=lb3_pad,
-            acc_dt=acc_dt, nr_ref=nr_ref, blk_rows=blk_rows)
-    # program_id must be read at kernel top level (inside a pl.when body
-    # it misses the interpret-mode grid-env substitution)
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == n_rb - 1)
-    def _():
-        cand_ref[:] = _split_epilogue(
-            acc_ref[:], i, fmeta_ref[:], lmeta_ref[:],
-            fmask_ref[:], params=params, fc=fc, Bp=Bp, l_pad=l_pad,
-            use_mono=use_mono, use_smooth=use_smooth, pen_on=pen_on,
-            quant=quant)
 
 
 @functools.partial(
@@ -475,6 +450,9 @@ def fused_build_best_splits(bins: jax.Array, gh: jax.Array,
                             emit_hist: bool = False):
     """One VMEM-resident pass: build histograms AND find best splits.
 
+    Runs in interpret mode only — on a TPU the pallas_call raises the
+    compiler's own message (``FUSED_SPLIT_TPU_REASON``).
+
     Contract mirrors `build_histograms_pallas` for the row-stream
     operands plus `ops.split.find_best_splits` for the metadata; returns
     ``(best, hist)`` where ``best`` is the find_best_splits dict (gain,
@@ -483,7 +461,7 @@ def fused_build_best_splits(bins: jax.Array, gh: jax.Array,
     per-leaf (G, H, count) totals for root-sum bootstrapping) and
     ``hist`` is the [L, F, B, 3] histogram when ``emit_hist=True``
     (feeds the subtraction cache) or ``None`` (pure mode — the histogram
-    never touches HBM; only [n_chunks * l_pad, 128] candidate records do).
+    never touches HBM; only [n_chunks * l_rec, 128] candidate records do).
 
     Winners are bit-equal to ``find_best_splits`` over the scatter-path
     histogram: the epilogue runs the identical `eval_split_lattice` ops
@@ -493,174 +471,124 @@ def fused_build_best_splits(bins: jax.Array, gh: jax.Array,
 
     Gates the caller must respect (`find_best_splits` fallback):
     sorted-subset categoricals, extra-trees random thresholds,
-    gain scale/penalty (feature_contri, CEGB), advanced monotone bounds,
-    and unaligned chunk plans (check `fused_plan_ok`).
+    gain scale/penalty (feature_contri, CEGB), advanced monotone bounds.
     """
-    if not _HAS_PALLAS:
-        raise RuntimeError("pallas unavailable in this jax build")
     R, F = bins.shape
     L = int(leaf_ids.shape[0])
     B = int(num_bins)
+    _check_shape(B)
     quant = gh.dtype == jnp.int8
     if quant and quant_scales is None:
         raise ValueError("int8 gh requires quant_scales")
     cdt = jnp.int8 if quant else jnp.dtype(hist_dtype)
     acc_dt = jnp.int32 if quant else jnp.float32
-    blk, fc, Bp, l_pad = _plan_chunks(F, B, L)
-    fb_pad = -(-(fc * Bp) // 128) * 128
-    lb3_pad = -(-(l_pad * HIST_CH) // 128) * 128
-    if fb_pad != fc * Bp or lb3_pad != l_pad * HIST_CH:
-        raise ValueError(
-            "fused split kernel needs an aligned chunk plan "
-            f"(F={F}, B={B}, L={L}); gate on fused_plan_ok() first")
-
-    r_pad = ((R + blk - 1) // blk) * blk
-    if r_pad != R:
-        bins = jnp.pad(bins, ((0, r_pad - R), (0, 0)))
-        gh = jnp.pad(gh, ((0, r_pad - R), (0, 0)))
-        row_leaf = jnp.pad(row_leaf, (0, r_pad - R), constant_values=-1)
-    n_fb = F // fc
+    blk, fc, n_fb, Bp, lanes = _plan(F, B, L * HIST_CH,
+                                     jnp.dtype(cdt).itemsize)
+    fb = fc * Bp
+    f_pad = n_fb * fc
+    l_rec = _ceil_to(L, 8)
+    r_pad = _ceil_to(R, blk)
     n_rb = r_pad // blk
-
-    gh8 = jnp.pad(gh, ((0, 0), (0, 8 - HIST_CH)))
-    leaf8 = jnp.broadcast_to(row_leaf[:, None].astype(jnp.int32),
-                             (r_pad, 8))
-    lids8 = jnp.broadcast_to(
-        jnp.pad(leaf_ids.astype(jnp.int32), (0, l_pad - L),
-                constant_values=-2)[None, :], (8, l_pad))
 
     use_mono = mono_type is not None
     use_smooth = params.path_smooth > 0.0
     pen_on = use_mono and params.monotone_penalty > 0.0
 
-    zi = jnp.zeros((F,), jnp.int32)
-    fmeta = jnp.stack([
-        num_bins_pf.astype(jnp.int32), nan_bin_pf.astype(jnp.int32),
-        is_cat_pf.astype(jnp.int32),
-        mono_type.astype(jnp.int32) if use_mono else zi,
-        zi, zi, zi, zi], axis=0)                          # [8, F]
+    def _frow(a, fill):
+        # pad features (F..f_pad) are trivial: one bin, masked out
+        return jnp.pad(a.astype(jnp.int32), (0, f_pad - F),
+                       constant_values=fill)
 
-    zf = jnp.zeros((l_pad,), jnp.float32)
+    zi = jnp.zeros((f_pad,), jnp.int32)
+    fmeta = jnp.stack([
+        _frow(num_bins_pf, 1), _frow(nan_bin_pf, -1), _frow(is_cat_pf, 0),
+        _frow(mono_type, 0) if use_mono else zi,
+        zi, zi, zi, zi], axis=0)                          # [8, f_pad]
+    fmeta = fmeta.reshape(8, n_fb, fc).transpose(1, 0, 2)
+
+    zf = jnp.zeros((l_rec,), jnp.float32)
 
     def _lrow(a, fill=0.0):
         if a is None:
             return zf
-        return jnp.pad(a.astype(jnp.float32), (0, l_pad - L),
+        return jnp.pad(a.astype(jnp.float32), (0, l_rec - L),
                        constant_values=fill)
 
     if quant:
         qsf = quant_scales.astype(jnp.float32)
-        srow_g = jnp.broadcast_to(qsf[0], (l_pad,))
-        srow_h = jnp.broadcast_to(qsf[1], (l_pad,))
+        srow_g = jnp.broadcast_to(qsf[0], (l_rec,))
+        srow_h = jnp.broadcast_to(qsf[1], (l_rec,))
     else:
         srow_g = srow_h = zf
     lmeta = jnp.stack([
         _lrow(parent_output), _lrow(leaf_lo), _lrow(leaf_hi),
         _lrow(mono_pen, fill=1.0), srow_g, srow_h, zf, zf],
-        axis=0)                                           # [8, l_pad]
+        axis=0)                                           # [8, l_rec]
 
     if feature_mask is None:
-        fmask = jnp.ones((l_pad, F), jnp.int32)
+        fm2 = jnp.ones((L, F), jnp.int32)
     else:
         fm2 = (feature_mask if feature_mask.ndim == 2
                else jnp.broadcast_to(feature_mask[None, :], (L, F)))
-        fmask = jnp.pad(fm2.astype(jnp.int32), ((0, l_pad - L), (0, 0)),
-                        constant_values=1)
+    fmask = jnp.pad(fm2.astype(jnp.int32), ((0, l_rec - L), (0, 0)),
+                    constant_values=1)
+    fmask = jnp.pad(fmask, ((0, 0), (0, f_pad - F)))
+    fmask = fmask.reshape(l_rec, n_fb, fc).transpose(1, 0, 2)
 
-    kern = functools.partial(
-        _fused_kernel, num_bins=Bp, cdt=cdt, fb_pad=fb_pad,
-        lb3_pad=lb3_pad, acc_dt=acc_dt, n_rb=n_rb, emit_hist=emit_hist,
-        params=params, fc=fc, Bp=Bp, l_pad=l_pad, use_mono=use_mono,
-        use_smooth=use_smooth, pen_on=pen_on, quant=quant)
+    def kern(nr_ref, *refs):
+        """Accumulation step + last-row-step split epilogue. Output
+        refs: emit_hist → (hist_out, cand_out) with the histogram block
+        doubling as the accumulator; else (cand_out, acc_scratch) — the
+        histogram never leaves the chip."""
+        *stream, fmeta_ref, lmeta_ref, fmask_ref, out0, out1 = refs
+        acc_ref, cand_ref = (out0, out1) if emit_hist else (out1, out0)
+        _accumulate_step(nr_ref, *stream, acc_ref, Bp=Bp, cdt=cdt,
+                         acc_dt=acc_dt, blk=blk)
+        i = pl.program_id(0)
+        j = pl.program_id(1)
 
-    cand_shape = jax.ShapeDtypeStruct((n_fb * l_pad, _REC_LANES),
+        @pl.when(j == n_rb - 1)
+        def _():
+            cand_ref[:] = _split_epilogue(
+                acc_ref[:], i, fmeta_ref[:], lmeta_ref[:], fmask_ref[:],
+                params=params, fc=fc, Bp=Bp, L=L, use_mono=use_mono,
+                use_smooth=use_smooth, pen_on=pen_on, quant=quant)
+
+    cand_shape = jax.ShapeDtypeStruct((n_fb * l_rec, _REC_LANES),
                                       jnp.float32)
-    hist_shape = jax.ShapeDtypeStruct((n_fb * fb_pad, lb3_pad), acc_dt)
-    if emit_hist:
-        out_shape = (hist_shape, cand_shape)
-        scratch = []
-    else:
-        out_shape = (cand_shape,)
-        scratch = [pltpu.VMEM((fb_pad, lb3_pad), acc_dt)]
-    operands = (bins.astype(jnp.int32), gh8, leaf8, lids8, fmeta, lmeta,
-                fmask)
-
-    if num_rows is None:
-        def _specs(w):
-            row = [
-                pl.BlockSpec((blk, fc), lambda i, j: (j, i)),
-                pl.BlockSpec((blk, 8), lambda i, j: (j, 0)),
-                pl.BlockSpec((blk, 8), lambda i, j: (j, 0)),
-            ]
-            meta = [
-                pl.BlockSpec((8, l_pad), lambda i, j: (0, 0)),
-                pl.BlockSpec((8, fc), lambda i, j: (0, i)),
-                pl.BlockSpec((8, l_pad), lambda i, j: (0, 0)),
-                pl.BlockSpec((l_pad, fc), lambda i, j: (0, i)),
-            ]
-            hist_o = [pl.BlockSpec((fb_pad, lb3_pad), lambda i, j: (i, 0))]
-            cand_o = [pl.BlockSpec((l_pad, _REC_LANES),
-                                   lambda i, j: (i, 0))]
-            return row + meta, (hist_o + cand_o if w else cand_o)
-
-        in_specs, out_specs = _specs(emit_hist)
-        outs = pl.pallas_call(
-            kern,
+    hist_shape = jax.ShapeDtypeStruct((n_fb * fb, lanes), acc_dt)
+    hist_o = pl.BlockSpec((fb, lanes), lambda i, j, s: (i, 0))
+    cand_o = pl.BlockSpec((l_rec, _REC_LANES), lambda i, j, s: (i, 0))
+    outs = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
             grid=(n_fb, n_rb),
-            in_specs=in_specs,
-            out_specs=tuple(out_specs) if emit_hist else out_specs[0],
-            out_shape=out_shape if emit_hist else out_shape[0],
-            scratch_shapes=scratch,
-            compiler_params=_compiler_params(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
-        )(*operands)
-    else:
-        nr = jnp.reshape(jnp.asarray(num_rows, jnp.int32), (1,))
-
-        def _row_clamp(s, j):
-            jmax = jnp.maximum((s[0] + blk - 1) // blk - 1, 0)
-            return jnp.minimum(j, jmax)
-
-        def kern_nr(s_ref, *refs):
-            kern(*refs, nr_ref=s_ref, blk_rows=blk)
-
-        in_specs = [
-            pl.BlockSpec((blk, fc), lambda i, j, s: (_row_clamp(s, j), i)),
-            pl.BlockSpec((blk, 8), lambda i, j, s: (_row_clamp(s, j), 0)),
-            pl.BlockSpec((blk, 8), lambda i, j, s: (_row_clamp(s, j), 0)),
-            pl.BlockSpec((8, l_pad), lambda i, j, s: (0, 0)),
-            pl.BlockSpec((8, fc), lambda i, j, s: (0, i)),
-            pl.BlockSpec((8, l_pad), lambda i, j, s: (0, 0)),
-            pl.BlockSpec((l_pad, fc), lambda i, j, s: (0, i)),
-        ]
-        hist_o = pl.BlockSpec((fb_pad, lb3_pad), lambda i, j, s: (i, 0))
-        cand_o = pl.BlockSpec((l_pad, _REC_LANES), lambda i, j, s: (i, 0))
-        outs = pl.pallas_call(
-            kern_nr,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(n_fb, n_rb),
-                in_specs=in_specs,
-                out_specs=((hist_o, cand_o) if emit_hist else cand_o),
-                scratch_shapes=tuple(scratch),
-            ),
-            out_shape=out_shape if emit_hist else out_shape[0],
-            compiler_params=_compiler_params(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
-        )(nr, *operands)
+            in_specs=_stream_specs(fc, blk, lanes) + [
+                pl.BlockSpec((None, 8, fc), lambda i, j, s: (i, 0, 0)),
+                pl.BlockSpec((8, l_rec), lambda i, j, s: (0, 0)),
+                pl.BlockSpec((None, l_rec, fc), lambda i, j, s: (i, 0, 0)),
+            ],
+            out_specs=(hist_o, cand_o) if emit_hist else cand_o,
+            scratch_shapes=(() if emit_hist
+                            else (pltpu.VMEM((fb, lanes), acc_dt),)),
+        ),
+        out_shape=(hist_shape, cand_shape) if emit_hist else cand_shape,
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+    )(_live_rows(num_rows, R), _bins_chunks(bins, r_pad, fc, n_fb),
+      _rows_to_lanes(gh.astype(acc_dt), r_pad), _leaf_lanes(row_leaf, r_pad),
+      _slot_cols(leaf_ids, lanes), fmeta, lmeta, fmask)
 
     if emit_hist:
         hist_raw, cand = outs
-        hist = hist_raw.reshape(n_fb, fb_pad, lb3_pad)
-        hist = hist.reshape(n_fb, fc, Bp, l_pad, HIST_CH)[:, :, :B, :L, :]
-        hist = hist.reshape(F, B, L, HIST_CH).transpose(2, 0, 1, 3)
+        hist = _unpack_hist(hist_raw, F=F, B=B, L=L, fc=fc, n_fb=n_fb,
+                            Bp=Bp, lanes=lanes)
     else:
         hist, cand = None, outs
 
     # ---- XLA postlude: tiny argmax over chunks replaces the full scan
-    cand = cand.reshape(n_fb, l_pad, _REC_LANES)[:, :L, :]
+    cand = cand.reshape(n_fb, l_rec, _REC_LANES)[:, :L, :]
     bc = jnp.argmax(cand[:, :, 0], axis=0)                # [L] first-max
     rec = jnp.take_along_axis(cand, bc[None, :, None], axis=0)[0]
     gain = rec[:, 0]
@@ -685,79 +613,35 @@ def fused_build_best_splits(bins: jax.Array, gh: jax.Array,
     return best, hist
 
 
-_FUSED_PROBE: dict = {}
-
-
-def fused_probe_ok() -> bool:
-    """One-time compile-and-run probe of the fused kernel on the real
-    backend (mirrors ops.histogram's pallas training probe); always True
-    caching aside. CPU/interpret callers skip this (fused_split="on")."""
-    if "ok" in _FUSED_PROBE:
-        return _FUSED_PROBE["ok"]
-    if not pallas_available():
-        _FUSED_PROBE["ok"] = False
-        return False
-    try:
-        F, B, L, R = 16, 8, 4, 256
-        bins = jnp.zeros((R, F), jnp.int32)
-        gh = jnp.ones((R, HIST_CH), jnp.float32)
-        rl = jnp.zeros((R,), jnp.int32)
-        best, _ = fused_build_best_splits(
-            bins, gh, rl, jnp.arange(L, dtype=jnp.int32), num_bins=B,
-            params=_split.SplitParams(),
-            num_bins_pf=jnp.full((F,), B, jnp.int32),
-            nan_bin_pf=jnp.full((F,), -1, jnp.int32),
-            is_cat_pf=jnp.zeros((F,), bool))
-        jax.block_until_ready(best["gain"])
-        _FUSED_PROBE["ok"] = True
-    except Exception:  # pragma: no cover - only on real hardware quirks
-        _FUSED_PROBE["ok"] = False
-    return _FUSED_PROBE["ok"]
-
-
-def _reset_fused_probe():
-    _FUSED_PROBE.clear()
-
-
 # ---------------------------------------------------------------------------
 # Class-shared root histogram (ISSUE-14 satellite): the class-batched
 # multiclass build vmaps the whole tree build, which batches EVERY
 # pallas operand — the bins matrix, logically shared across classes, is
 # presented K× to the root launch. This kernel instead streams bins ONCE
-# and reduces all K classes' (g, h, count) lanes against the same
-# one-hot: ghl is [blk, K*3] with the root-leaf row mask applied
-# elementwise, so the MXU emits [fc*Bp, K*3] per chunk.
+# and reduces all K classes' (g, h, count) rows against the same
+# one-hot: the addends are [K*3, blk] with the root-leaf row mask
+# applied elementwise, so the MXU emits [fc*Bp, K*3] per chunk.
 # ---------------------------------------------------------------------------
 
 
-def _class_kernel(bins_ref, ghk_ref, leaf_ref, out_ref, *, num_bins: int,
-                  cdt, fb_pad: int, kc_pad: int, acc_dt,
-                  root_slot: int):
+def _class_kernel(bins_ref, ghk_ref, leaf_ref, out_ref, *, Bp: int, cdt,
+                  acc_dt, root_slot: int):
     j = pl.program_id(1)
-    blk, fc = bins_ref.shape
-
-    def compute():
-        bb = bins_ref[:]
-        iota_b = jax.lax.broadcasted_iota(
-            jnp.int32, (blk, fc, num_bins), 2)
-        onehot = (bb[:, :, None] == iota_b).astype(cdt).reshape(
-            blk, fc * num_bins)
-        if fb_pad != fc * num_bins:
-            onehot = jnp.pad(onehot,
-                             ((0, 0), (0, fb_pad - fc * num_bins)))
-        mask = (leaf_ref[:, 0:1] == root_slot).astype(cdt)  # [blk, 1]
-        ghl = mask * ghk_ref[:].astype(cdt)                 # [blk, kc_pad]
-        return jax.lax.dot_general(
-            onehot, ghl, (((0,), (0,)), ((), ())),
-            preferred_element_type=acc_dt)                  # [fb_pad, kc_pad]
 
     @pl.when(j == 0)
     def _():
-        out_ref[:] = compute()
+        out_ref[:] = jnp.zeros_like(out_ref)
 
-    @pl.when(j > 0)
-    def _():
-        out_ref[:] = out_ref[:] + compute()
+    addends = jnp.where(leaf_ref[:] == root_slot, ghk_ref[:], 0)
+    # the MXU wants the full lane tile; padding here (sublane-aligned
+    # concat in VMEM) keeps the HBM operand at K*3 rows
+    pad = out_ref.shape[1] - addends.shape[0]
+    if pad:
+        addends = jnp.concatenate(
+            [addends, jnp.zeros((pad, addends.shape[1]), addends.dtype)],
+            axis=0)
+    _accumulate(out_ref, _onehot_t(bins_ref, Bp=Bp, cdt=cdt), addends,
+                cdt=cdt, acc_dt=acc_dt)
 
 
 @functools.partial(
@@ -773,52 +657,41 @@ def build_root_histograms_classes(bins: jax.Array, gh_k: jax.Array,
     bins [R, F], gh_k [K, R, 3] (f32 or int8 quantized), row_leaf [R]
     int32 → [K, F, B, 3] (f32; int32 when quantized). Bit-equal to K
     independent `build_histograms_pallas` root launches: the per-class
-    lanes hit the same MXU contraction against the same one-hot, in the
+    rows hit the same MXU contraction against the same one-hot, in the
     same row-block order."""
-    if not _HAS_PALLAS:
-        raise RuntimeError("pallas unavailable in this jax build")
     R, F = bins.shape
     K = int(gh_k.shape[0])
     B = int(num_bins)
+    _check_shape(B)
     quant = gh_k.dtype == jnp.int8
     cdt = jnp.int8 if quant else jnp.dtype(hist_dtype)
     acc_dt = jnp.int32 if quant else jnp.float32
-    blk, fc, Bp, _ = _plan_chunks(F, B, max(K, 1))
-    fb_pad = -(-(fc * Bp) // 128) * 128
     kc = K * HIST_CH
-    kc_pad = -(-kc // 128) * 128
-
-    r_pad = ((R + blk - 1) // blk) * blk
-    if r_pad != R:
-        bins = jnp.pad(bins, ((0, r_pad - R), (0, 0)))
-        gh_k = jnp.pad(gh_k, ((0, 0), (0, r_pad - R), (0, 0)))
-        row_leaf = jnp.pad(row_leaf, (0, r_pad - R), constant_values=-1)
-    n_fb = F // fc
-    n_rb = r_pad // blk
-
-    ghk = gh_k.transpose(1, 0, 2).reshape(r_pad, kc)      # [R, K*3]
-    if kc_pad != kc:
-        ghk = jnp.pad(ghk, ((0, 0), (0, kc_pad - kc)))
-    leaf8 = jnp.broadcast_to(row_leaf[:, None].astype(jnp.int32),
-                             (r_pad, 8))
+    blk, fc, n_fb, Bp, lanes = _plan(F, B, kc, jnp.dtype(cdt).itemsize)
+    fb = fc * Bp
+    r_pad = _ceil_to(R, blk)
+    kc8 = _ceil_to(kc, 8)
+    # [kc8, r_pad]: row k*3 + channel
+    ghk_t = jnp.pad(gh_k.astype(acc_dt).transpose(0, 2, 1).reshape(kc, R),
+                    ((0, kc8 - kc), (0, r_pad - R)))
 
     out = pl.pallas_call(
-        functools.partial(_class_kernel, num_bins=Bp, cdt=cdt,
-                          fb_pad=fb_pad, kc_pad=kc_pad, acc_dt=acc_dt,
+        functools.partial(_class_kernel, Bp=Bp, cdt=cdt, acc_dt=acc_dt,
                           root_slot=root_slot),
-        grid=(n_fb, n_rb),
+        grid=(n_fb, r_pad // blk),
         in_specs=[
-            pl.BlockSpec((blk, fc), lambda i, j: (j, i)),
-            pl.BlockSpec((blk, kc_pad), lambda i, j: (j, 0)),
-            pl.BlockSpec((blk, 8), lambda i, j: (j, 0)),
+            pl.BlockSpec((None, fc, blk), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((kc8, blk), lambda i, j: (0, j)),
+            pl.BlockSpec((1, blk), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((fb_pad, kc_pad), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_fb * fb_pad, kc_pad), acc_dt),
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        out_specs=pl.BlockSpec((fb, lanes), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_fb * fb, lanes), acc_dt,
+            vma=_out_vma(bins, gh_k, row_leaf)),
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(bins.astype(jnp.int32), ghk, leaf8)
+    )(_bins_chunks(bins, r_pad, fc, n_fb), ghk_t,
+      _leaf_lanes(row_leaf, r_pad))
 
-    hist = out.reshape(n_fb, fb_pad, kc_pad)[:, :fc * Bp, :kc]
-    hist = hist.reshape(n_fb, fc, Bp, K, HIST_CH)[:, :, :B, :, :]
+    hist = out.reshape(n_fb * fc, Bp, lanes)[:F, :B, :kc]
     return hist.reshape(F, B, K, HIST_CH).transpose(2, 0, 1, 3)

@@ -90,23 +90,6 @@ def resolve_hist_merge(mode: str, n_shards: int) -> str:
     return mode
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """jax.shard_map across jax versions: the top-level API (with
-    `check_vma`) landed after 0.4.x, where the same callable lives at
-    jax.experimental.shard_map.shard_map with the flag named
-    `check_rep`. One shim so both call sites stay version-agnostic."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    # 0.4.x replication checking has no rule for while_loop (the tree
-    # builder's core) — disable it; it is a static checker only, the
-    # computed values are identical
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def make_mesh(devices: Optional[Sequence[jax.Device]] = None,
               axis_name: str = AXIS) -> Mesh:
     """1-D data mesh over all (or the given) devices."""
@@ -470,7 +453,7 @@ def _build_tree_fp_jit(mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
         valid_in_specs = tuple([rep] * (2 * n_valid))
         mat_spec = rep
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         step, mesh=mesh,
         in_specs=(mat_spec, fsh2, rep, rep, rep, rep, rep, rep,
                   fsh, fsh, fsh, fsh, fsh, rep, valid_in_specs,
@@ -544,12 +527,12 @@ def _build_tree_dp_jit(mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
     gh_spec = P(None, axis_name, None) if class_batched else row2
     rl_spec = P(None, axis_name) if class_batched else row
     out_valid_specs = tuple([rl_spec] * n_valid)
-    fn = _shard_map(
+    fn = jax.shard_map(
         step, mesh=mesh,
         in_specs=(row2, gh_spec, row, rep, rep, rep, rep, valid_in_specs,
                   extras_specs),
         out_specs=(tree_specs, rl_spec, out_valid_specs),
-        check_vma=False if rs else None)
+        check_vma=not rs)
     return fn(bins, gh, row_leaf0, num_bins_pf, nan_bin_pf, is_cat_pf,
               feature_mask, valid_flat, extras)
 

@@ -97,6 +97,10 @@ class ModelRegistry:
     def __init__(self, *, warmup_rows: int = 256, history: int = 4,
                  metrics: Optional[ServingMetrics] = None,
                  compiled_predict: bool = False, replicas: int = 0):
+        # every model this registry loads is warmed (compiled) off the
+        # serving path: same persistent-cache rule as training
+        from ..engine import enable_compilation_cache
+        enable_compilation_cache()
         self.warmup_rows = int(warmup_rows)
         self.history = int(history)
         self.metrics = metrics or ServingMetrics()

@@ -340,7 +340,7 @@ class TelemetrySession:
             from .costmodel import chip_peaks
             peaks = chip_peaks()
             if peaks is not None:
-                self._g_mfu.set(achieved / peaks[1])
+                self._g_mfu.set(achieved / peaks.bf16_tflops)
         for (name, metric), value in [((n, m), v) for n, m, v, _ in
                                       (evals or [])]:
             self._g_metric.labels(name, metric).set(value)

@@ -120,8 +120,8 @@ class DeviceWatch:
 
     def stop(self) -> None:
         if self._cb is not None:
-            from ..analysis.recompile_guard import _unregister
-            _unregister(self._cb)
+            import jax
+            jax.monitoring.unregister_event_duration_listener(self._cb)
             self._cb = None
 
     def sample(self) -> Dict[str, Dict[str, int]]:

@@ -104,7 +104,7 @@ DEFAULT_TOLERANCES: Dict[str, Tolerance] = {
     "ingest_prefetch_overlap": Tolerance("throughput", 10.0),
     # fused build+split pass (ISSUE 14): the byte counts are pure
     # functions of the probe lattice and the kernel's chunk plan — any
-    # drift means the cost model or _plan_chunks changed; the scan
+    # drift means the cost model or the kernel _plan changed; the scan
     # wall-clock gets the usual noisy-CI band
     "hist_bytes_twopass": Tolerance("static", 1.1),
     "hist_bytes_fused": Tolerance("static", 1.1),

@@ -478,6 +478,25 @@ def test_launcher_spawns_coordinated_workers(tmp_path):
     assert m0 == m1
 
 
+def test_launcher_refuses_local_workers_that_would_share_chips(
+        monkeypatch):
+    """A chip belongs to one process: N local workers on a TPU host
+    are refused (one process drives all chips) unless they are pinned
+    to the CPU backend. The launcher itself never imports jax."""
+    from lightgbm_tpu import launch as L
+    monkeypatch.setattr(L, "_local_tpu_chips",
+                        lambda: ["/dev/accel0", "/dev/accel1"])
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(RuntimeError, match="one process"):
+        L.launch(["t.py"], num_processes=2)
+    with pytest.raises(RuntimeError, match="one process"):
+        L.launch_hosts(["t.py"], [("localhost", 2)],
+                       _popen=lambda *a, **k: None)
+    L._refuse_shared_chips(1)                  # one worker owns them all
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    L._refuse_shared_chips(4)                  # CPU mesh workers
+
+
 def test_launcher_fail_fast(tmp_path):
     from lightgbm_tpu.launch import launch
     bad = tmp_path / "bad.py"

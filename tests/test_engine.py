@@ -198,3 +198,39 @@ def test_add_features_from(breast_cancer):
                      params={"_allow_no_label": True}).construct()
     with _pytest.raises(ValueError, match="num_data"):
         dA.add_features_from(dC)
+
+
+def _cache_rule(monkeypatch, backend, env_dir):
+    """Run engine.enable_compilation_cache under a fake backend and
+    return (returned dir, every jax.config.update call it made)."""
+    import jax
+    calls = []
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    return lgb.enable_compilation_cache(), calls
+
+
+def test_compile_cache_env_dir_wins_and_nothing_is_set_in_code(
+        monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it itself; the program
+    reports it and touches no config (on any backend)."""
+    for backend in ("tpu", "cpu"):
+        d, calls = _cache_rule(monkeypatch, backend, str(tmp_path))
+        assert d == str(tmp_path) and calls == []
+
+
+def test_compile_cache_defaults_to_checkout_xla_cache(monkeypatch):
+    """Unset: <checkout>/.xla_cache on an accelerator — a fixed path
+    beside the code, never ~ / a temp name / a pid — and off on CPU."""
+    import os
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    d, calls = _cache_rule(monkeypatch, "tpu", None)
+    assert d == os.path.join(repo, ".xla_cache")
+    assert ("jax_compilation_cache_dir", d) in calls
+    d, calls = _cache_rule(monkeypatch, "cpu", None)
+    assert d is None and calls == []

@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.ops import histogram as H
 from lightgbm_tpu.ops import pallas_histogram as PH
 from lightgbm_tpu.ops.split import (SplitParams, find_best_splits,
                                     monotone_penalty_factor)
@@ -20,18 +19,12 @@ R, F, B, L = 512, 8, 16, 6
 
 @pytest.fixture
 def interp(monkeypatch):
-    """Route the Pallas kernels through the interpreter, and forget the
-    probe verdicts on both sides: a verdict cached while the patch is
-    live (interpret kernels compile anywhere) would poison later tests
-    that call the real kernel, and vice versa."""
-    H._reset_pallas_probe()
+    """Route the Pallas kernels through the interpreter."""
     for name in ("fused_build_best_splits", "build_histograms_pallas",
                  "build_root_histograms_classes"):
         monkeypatch.setattr(PH, name,
                             ft.partial(getattr(PH, name),
                                        interpret=True))
-    yield
-    H._reset_pallas_probe()
 
 
 def _stream(rng, quant=False, R=R, F=F, B=B, L=L):
@@ -235,10 +228,9 @@ def _train(rng, **overrides):
 
 def test_gbdt_gate_reasons(rng, interp):
     """The eager fused-split gate names its binding reason: every
-    epilogue-inexpressible config trips it, and the auto-mode
-    real-backend probe fails closed on CPU (the interp patch keeps the
-    two-pass pallas TRAINING path runnable; the fused probe gates on
-    the real backend regardless)."""
+    epilogue-inexpressible config trips it, and auto stays closed with
+    the TPU compiler's own reason (the epilogue has no Mosaic
+    lowering; only fused_split=on, below, opens it)."""
     gb = _train(rng, fused_split="off",
                 hist_impl="pallas")._gbdt
     assert not gb.fused_split_ok and "off" in gb.fused_split_reason
@@ -252,14 +244,14 @@ def test_gbdt_gate_reasons(rng, interp):
     gb = _train(rng, fused_split="on", hist_impl="pallas",
                 tree_learner="data")._gbdt
     assert not gb.fused_split_ok and "parallel" in gb.fused_split_reason
-    # auto on CPU: the real-backend probe fails to compile -> fallback
     gb = _train(rng, fused_split="auto", hist_impl="pallas")._gbdt
-    assert not gb.fused_split_ok and "probe" in gb.fused_split_reason
+    assert (not gb.fused_split_ok
+            and gb.fused_split_reason == PH.FUSED_SPLIT_TPU_REASON)
 
 
 def test_gbdt_gate_trust_mode(rng, interp):
-    """fused_split="on" is trust mode — it skips the probe, so with the
-    interpreter patch the gate opens end to end."""
+    """fused_split="on" forces the kernel: with the interpreter patch
+    the gate opens end to end (on a TPU the compile error surfaces)."""
     gb = _train(rng, fused_split="on", hist_impl="pallas")._gbdt
     assert gb.fused_split_ok and gb.fused_split_reason == ""
 
@@ -306,14 +298,6 @@ def test_chunked_subtraction_cache_parity(rng, quant):
                         num_boost_round=3)
         preds[sub] = bst.predict(X)
     np.testing.assert_array_equal(preds[True], preds[False])
-
-
-def test_fused_probe_reset_clears_both_caches(monkeypatch):
-    """ops.histogram._reset_pallas_probe forgets the fused verdict too
-    (a chip can pass the histogram probe yet reject the epilogue)."""
-    PH._FUSED_PROBE["ok"] = True
-    H._reset_pallas_probe()
-    assert "ok" not in PH._FUSED_PROBE
 
 
 @pytest.mark.slow

@@ -62,8 +62,7 @@ def test_psum_merge_across_shards(rng):
     """Data-parallel histogram merge == single-device histogram
     (ReduceScatter semantics, data_parallel_tree_learner.cpp:284)."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from lightgbm_tpu.parallel.data_parallel import _shard_map as \
-        shard_map  # version shim: jax.shard_map past 0.4.x
+    from jax import shard_map
 
     n_dev = len(jax.devices())
     assert n_dev == 8, "conftest should force 8 cpu devices"
@@ -124,60 +123,53 @@ def test_pallas_interpret_matches_oracle(rng):
     np.testing.assert_allclose(pls, xla, rtol=1e-5, atol=1e-5)
 
 
-def test_pallas_kernel_body_is_gather_free():
-    """First real-Mosaic contact (round 5) rejected the kernel: a mixed
-    newaxis + partial-slice index (``ghb[:, None, :HIST_CH]``) lowered
-    via lax.gather, and Mosaic's gather rule only accepts a narrow shape
-    class ("Shape mismatch in input, indices and output"). The kernel
-    body must stay free of gather so it keeps compiling on hardware the
-    interpreter cannot stand in for. Traced here with production-shaped
-    block operands (the aligned 64-bin plan)."""
+def test_pallas_kernel_body_uses_only_mosaic_safe_ops():
+    """What real Mosaic on a v5e rejected in this kernel, in order of
+    discovery: a lax.gather from a mixed newaxis+slice index, a 2-D ->
+    3-D reshape of the addends, int8 vector multiplies, and cumsum. The
+    body is now 2-D selects/compares/iotas/casts and two matmuls; this
+    keeps it so on every CPU run (tests/test_mosaic_aot.py, slow, runs
+    the real compiler)."""
     import functools
-    import unittest.mock as mock
-
-    from jax.experimental import pallas as pl
 
     from lightgbm_tpu.ops import pallas_histogram as PH
-    from lightgbm_tpu.ops.histogram import HIST_CH
 
-    F, B, L = 16, 64, 8
-    blk, fc, Bp, l_pad = PH._plan_chunks(F, B, L)
-    fb_pad = -(-(fc * Bp) // 128) * 128
-    lb3_pad = -(-(l_pad * HIST_CH) // 128) * 128
-    kern = functools.partial(PH._kernel, num_bins=Bp, cdt=jnp.bfloat16,
-                             fb_pad=fb_pad, lb3_pad=lb3_pad,
-                             acc_dt=jnp.float32)
+    F, B, L = 28, 255, 21
+    blk, fc, n_fb, Bp, lanes = PH._plan(F, B, 3 * L, 1)
+    assert fc < F, "exercise a chunked plan"
 
     class _Ref:
         def __init__(self, a):
             self.a = a
+            self.shape = a.shape
 
         def __getitem__(self, idx):
             return self.a[idx]
 
-        def __setitem__(self, idx, val):
-            pass
-
-        @property
-        def shape(self):
-            return self.a.shape
-
-    def body(bins, gh, leaf, lids):
-        out = _Ref(jnp.zeros((fb_pad, lb3_pad), jnp.float32))
-        with mock.patch.object(pl, "program_id",
-                               lambda i: jnp.int32(1)), \
-             mock.patch.object(pl, "when",
-                               lambda c: (lambda f: f())):
-            kern(_Ref(bins), _Ref(gh), _Ref(leaf), _Ref(lids), out)
-        return jnp.zeros(())
+    def body(bins, gh, leaf, cols):
+        onehot = PH._onehot_t(_Ref(bins), Bp=Bp, cdt=jnp.int8)
+        add = PH._slot_addends(_Ref(gh), _Ref(leaf), _Ref(cols))
+        return onehot, add.astype(jnp.int8)
 
     jaxpr = jax.make_jaxpr(body)(
-        jnp.zeros((blk, fc), jnp.int32), jnp.zeros((blk, 8), jnp.float32),
-        jnp.zeros((blk, 8), jnp.int32), jnp.zeros((8, l_pad), jnp.int32))
-    prims = {e.primitive.name for e in jaxpr.jaxpr.eqns}
-    assert "gather" not in prims, (
-        "pallas kernel body reintroduced a lax.gather — Mosaic rejects "
-        f"it on real TPUs (primitives: {sorted(prims)})")
+        jnp.zeros((fc, blk), jnp.int32), jnp.zeros((3, blk), jnp.int32),
+        jnp.zeros((1, blk), jnp.int32), jnp.zeros((lanes, 2), jnp.int32))
+
+    def prims(jp):
+        for e in jp.eqns:
+            yield e.primitive.name, [v.aval for v in e.invars]
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from prims(sub)
+
+    seen = list(prims(jaxpr.jaxpr))
+    names = {n for n, _ in seen}
+    banned = {"gather", "reshape", "cumsum", "concatenate", "scatter",
+              "scatter-add", "dynamic_slice"}
+    assert not names & banned, sorted(names & banned)
+    for n, avals in seen:
+        if n == "mul":
+            assert all(a.dtype != jnp.int8 for a in avals), \
+                "int8 vector multiply: v5e has no int8 VPU multiply"
 
 
 def test_pallas_dynamic_row_bound_skips_blocks(rng):
@@ -189,7 +181,7 @@ def test_pallas_dynamic_row_bound_skips_blocks(rng):
     num_rows carry row_leaf == -1 per the caller contract.)"""
     from lightgbm_tpu.ops import pallas_histogram as PH
     F, B, L = 4, 16, 3
-    blk = PH._plan_chunks(F, B, L)[0]
+    blk = PH._plan(F, B, 3 * L, 4)[0]
     R = 3 * blk                       # three full blocks
     n_live = blk + 7                  # block 0 full + 7 rows of block 1
     bins = rng.randint(0, B, size=(R, F)).astype(np.uint8)
@@ -263,49 +255,33 @@ def test_pallas_tree_with_subtraction_matches_scatter(rng, monkeypatch):
     np.testing.assert_array_equal(out["pallas"][2], out["scatter"][2])
 
 
-def test_auto_impl_pallas_fallback(monkeypatch):
-    """hist_impl='auto' on TPU must survive a Mosaic rejection of the
-    Pallas kernel: the probe fails once, logs, and resolves to matmul
-    (VERDICT r3: first hardware contact must not crash default-params
-    training)."""
+@pytest.mark.parametrize("backend,impl,num_bins,want", [
+    ("tpu", "auto", 63, "pallas"),
+    ("tpu", "auto", 256, "pallas"),
+    ("tpu", "auto", 257, "matmul"),     # by rule, with a named reason
+    ("tpu", "matmul", 63, "matmul"),    # explicit choices pass through
+    ("tpu", "pallas", 1024, "pallas"),  # ... and raise at the kernel
+    ("gpu", "auto", 63, "matmul"),
+    ("cpu", "pallas", 63, "pallas"),
+])
+def test_resolve_impl_rule_table(monkeypatch, backend, impl, num_bins,
+                                 want):
+    """hist_impl=auto resolves from backend and lattice width alone —
+    no probe compile, nothing cached, nothing caught."""
     from lightgbm_tpu.ops import histogram as H
-    from lightgbm_tpu.ops import pallas_histogram as PH
-
-    def boom(*a, **k):
-        raise RuntimeError("Mosaic lowering rejected the kernel")
-
-    monkeypatch.setattr(PH, "build_histograms_pallas", boom)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    H._reset_pallas_probe()
-    try:
-        assert H.resolve_impl("auto") == "matmul"
-        # verdict is cached: a second resolve does not re-probe
-        monkeypatch.setattr(
-            PH, "build_histograms_pallas",
-            lambda *a, **k: (_ for _ in ()).throw(AssertionError("re-probe")))
-        assert H.resolve_impl("auto") == "matmul"
-    finally:
-        H._reset_pallas_probe()
-    # explicit request is honored un-probed (user opted in)
-    assert H.resolve_impl("pallas") == "pallas"
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert H.resolve_impl(impl, num_bins) == want
+    assert bool(H.pallas_shape_reason(num_bins)) == (num_bins > 256)
 
 
-def test_auto_impl_pallas_accepted(monkeypatch):
-    """When the probe compile succeeds, auto->pallas on TPU."""
-    import jax.numpy as jnp_
-    from lightgbm_tpu.ops import histogram as H
-    from lightgbm_tpu.ops import pallas_histogram as PH
-
-    monkeypatch.setattr(
-        PH, "build_histograms_pallas",
-        lambda *a, num_bins, hist_dtype: jnp_.zeros(
-            (2, 2, num_bins, 3), jnp_.float32))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    H._reset_pallas_probe()
-    try:
-        assert H.resolve_impl("auto") == "pallas"
-    finally:
-        H._reset_pallas_probe()
+def test_pallas_unsupported_shape_raises(rng):
+    """An explicit hist_impl=pallas past the kernel's lattice width
+    raises the rule's reason instead of degrading."""
+    bins, gh, row_leaf, leaf_ids = _case(rng, R=256, F=3, B=16, L=2)
+    with pytest.raises(ValueError, match="num_bins=300 > 256"):
+        build_histograms(
+            jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(row_leaf),
+            jnp.asarray(leaf_ids), num_bins=300, impl="pallas")
 
 
 def test_auto_impl_cpu_prefers_native(monkeypatch):
@@ -315,9 +291,9 @@ def test_auto_impl_cpu_prefers_native(monkeypatch):
     from lightgbm_tpu.ops import histogram as H
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     want = "native" if N.hist_lib() is not None else "scatter"
-    assert H.resolve_impl("auto") == want
+    assert H.resolve_impl("auto", 63) == want
     monkeypatch.setattr(N, "hist_lib", lambda: None)
-    assert H.resolve_impl("auto") == "scatter"
+    assert H.resolve_impl("auto", 63) == "scatter"
 
 
 def test_native_matches_scatter(rng):
@@ -493,7 +469,7 @@ def test_native_perm_kernel_threaded_matches_serial(rng, monkeypatch):
         out_dt = jnp.int32 if gh.dtype == np.int8 else jnp.float32
         target = ("lgbtpu_hist_perm_i8" if gh.dtype == np.int8
                   else "lgbtpu_hist_perm_f32")
-        return np.asarray(N.jax_ffi().ffi_call(
+        return np.asarray(jax.ffi.ffi_call(
             target, jax.ShapeDtypeStruct((S, F, B, 3), out_dt))(
             jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(perm),
             jnp.asarray(begin), jnp.asarray(cnt), jnp.asarray(lids),

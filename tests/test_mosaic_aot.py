@@ -1,0 +1,178 @@
+"""Real-Mosaic compile checks with no chip (slow; tier-1 skips them).
+
+libtpu can describe a v5e topology without a TPU attached, and
+``jax.jit(f).trace(*ShapeDtypeStructs).lower().compile()`` against its
+devices runs the real Mosaic + XLA:TPU compilers. The Pallas interpreter
+(what every other test uses) accepts programs Mosaic rejects, so this is
+the only sandbox check that a kernel change still lowers on the chip.
+Recipe for a one-off check: README "Checking a kernel change without a
+chip".
+"""
+
+import functools as ft
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+pytestmark = pytest.mark.slow
+
+F32, I32, I8, U8 = jnp.float32, jnp.int32, jnp.int8, jnp.uint8
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a v5e 2x2 topology (compile targets only)."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — libtpu absent or too old
+        pytest.skip(f"libtpu cannot describe a v5e topology: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return list(topo.devices)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).trace(*args).lower().compile()
+
+
+def _sds(dev):
+    sh = jax.sharding.SingleDeviceSharding(dev)
+    return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+
+def _hist_args(sds, R, F, L, quant):
+    return [sds((R, F), U8), sds((R, 3), I8 if quant else F32),
+            sds((R,), I32), sds((L,), I32)]
+
+
+# (F, B, L): Higgs at 63 and 255 bins with a 21-slot and a 42-slot
+# build, the verify skill's 12-feature default-params flow (leaf_batch
+# 16, both children = 32 slots), MS-LTR and Expo widths
+SHAPES = [(28, 63, 21), (28, 63, 42), (28, 255, 21), (12, 255, 16),
+          (12, 255, 32), (137, 63, 21), (700, 63, 21)]
+
+
+@pytest.mark.parametrize("F,B,L", SHAPES)
+@pytest.mark.parametrize("variant", ["bf16", "f32", "int8", "bf16_rows",
+                                     "int8_rows"])
+def test_histogram_kernel_compiles(v5e, F, B, L, variant):
+    from lightgbm_tpu.ops.pallas_histogram import build_histograms_pallas
+    sds = _sds(v5e[0])
+    quant = variant.startswith("int8")
+    args = _hist_args(sds, 1 << 16, F, L, quant)
+    kw = dict(num_bins=B,
+              hist_dtype="float32" if variant == "f32" else "bfloat16")
+    if variant.endswith("_rows"):
+        _compile(lambda b, g, r, l, n: build_histograms_pallas(
+            b, g, r, l, num_rows=n, **kw), *args, sds((), I32))
+    else:
+        _compile(lambda b, g, r, l: build_histograms_pallas(
+            b, g, r, l, **kw), *args)
+
+
+@pytest.mark.parametrize("bounded", [False, True])
+def test_histogram_kernel_compiles_under_vmap(v5e, bounded):
+    """The class-batched multiclass build vmaps the kernel (and its
+    scalar-prefetch row bound) over the class axis."""
+    from lightgbm_tpu.ops.pallas_histogram import build_histograms_pallas
+    sds = _sds(v5e[0])
+    K, R, F, B, L = 5, 1 << 16, 28, 63, 16
+    args = [sds((R, F), U8), sds((K, R, 3), F32), sds((K, R), I32),
+            sds((K, L), I32)]
+    if bounded:
+        _compile(lambda b, g, r, l, n: jax.vmap(
+            lambda g1, r1, l1, n1: build_histograms_pallas(
+                b, g1, r1, l1, num_bins=B, num_rows=n1))(g, r, l, n),
+            *args, sds((K,), I32))
+    else:
+        _compile(lambda b, g, r, l: jax.vmap(
+            lambda g1, r1, l1: build_histograms_pallas(
+                b, g1, r1, l1, num_bins=B))(g, r, l), *args)
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_class_root_kernel_compiles(v5e, quant):
+    from lightgbm_tpu.ops.pallas_histogram import (
+        build_root_histograms_classes)
+    sds = _sds(v5e[0])
+    K, R, F, B = 10, 1 << 16, 28, 255
+    _compile(lambda b, g, r: build_root_histograms_classes(
+        b, g, r, num_bins=B), sds((R, F), U8),
+        sds((K, R, 3), I8 if quant else F32), sds((R,), I32))
+
+
+def test_fused_split_kernel_is_gated_with_the_compilers_reason(v5e):
+    """fused_build_best_splits cannot lower (its epilogue is
+    eval_split_lattice); gbdt's gate quotes the compiler. If this stops
+    raising, the gate constant is stale: re-evaluate the kernel."""
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    from lightgbm_tpu.ops.split import SplitParams
+    sds = _sds(v5e[0])
+    R, F, B, L = 1 << 16, 28, 63, 21
+
+    def fn(b, g, r, l, nb, nan, cat):
+        return PH.fused_build_best_splits(
+            b, g, r, l, num_bins=B, params=SplitParams(),
+            num_bins_pf=nb, nan_bin_pf=nan, is_cat_pf=cat)[0]["gain"]
+
+    with pytest.raises(NotImplementedError) as ei:
+        _compile(fn, *_hist_args(sds, R, F, L, False), sds((F,), I32),
+                 sds((F,), I32), sds((F,), jnp.bool_))
+    # "Unimplemented primitive in Pallas TPU lowering for
+    # KernelType.TC: cumsum. Please file an issue ..."
+    msg = str(ei.value)
+    assert msg.startswith("Unimplemented primitive in Pallas TPU lowering")
+    assert ": cumsum." in msg and "cumsum" in PH.FUSED_SPLIT_TPU_REASON
+
+
+def _tree_args(make, R, F):
+    return [make((R, F), U8, 2), make((R, 3), F32, 2), make((R,), I32, 1),
+            make((F,), I32, 0), make((F,), I32, 0),
+            make((F,), jnp.bool_, 0), make((F,), jnp.bool_, 0)]
+
+
+_HIGGS = dict(num_leaves=255, leaf_batch=16, max_depth=-1, num_bins=63,
+              hist_dtype="bfloat16", block_rows=1 << 14)
+
+
+def test_serial_tree_build_compiles_at_higgs_width(v5e, monkeypatch):
+    """The whole tree-build program (while_loop, compaction, split
+    search) around the kernel ``auto`` resolves to on a TPU."""
+    from lightgbm_tpu.boosting.tree_builder import _build_tree_jit
+    from lightgbm_tpu.ops.histogram import resolve_impl
+    from lightgbm_tpu.ops.split import SplitParams
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    impl = resolve_impl("auto", _HIGGS["num_bins"])
+    assert impl == "pallas"
+    sds = _sds(v5e[0])
+    _build_tree_jit.trace(
+        *_tree_args(lambda s, d, _: sds(s, d), 1 << 18, 28),
+        split_params=SplitParams(), hist_impl=impl,
+        **_HIGGS).lower().compile()
+
+
+@pytest.mark.parametrize("merge", ["reduce_scatter", "allreduce"])
+def test_data_parallel_tree_build_compiles_on_four_chips(v5e, merge,
+                                                         monkeypatch):
+    """tree_learner=data over the 2x2 mesh: the kernel inside shard_map
+    (check_vma on under allreduce), rows sharded, both merge plans."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.ops.histogram import resolve_impl
+    from lightgbm_tpu.ops.split import SplitParams
+    from lightgbm_tpu.parallel.data_parallel import DataParallelPlan
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan = DataParallelPlan(devices=v5e, hist_merge=merge)
+    assert plan.num_shards == 4
+
+    def make(shape, dt, row_dims):
+        spec = (P(plan.axis_name, *([None] * (row_dims - 1)))
+                if row_dims else P())
+        return jax.ShapeDtypeStruct(
+            shape, dt, sharding=NamedSharding(plan.mesh, spec))
+
+    kw = dict(_HIGGS, block_rows=1 << 12, split_params=SplitParams(),
+              hist_impl=resolve_impl("auto", _HIGGS["num_bins"]))
+    _compile(ft.partial(plan.build_tree, **kw),
+             *_tree_args(make, 1 << 18, 28))

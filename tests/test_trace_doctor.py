@@ -92,8 +92,7 @@ def test_td002_host_callback_fires_unless_allowed():
 
 
 def test_td003_f64_widening_fires_only_under_widening():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) + 1.0)(
                 np.ones(4, np.float32))
