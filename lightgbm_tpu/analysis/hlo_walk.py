@@ -41,11 +41,11 @@ _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _NAME_RE = re.compile(r'op_name="([^"]*)"')
 _CCT_RE = re.compile(r'custom_call_target="([^"]*)"')
 # generic op line: `[ROOT] %instr.N = <out-spec> opcode(...)`; the `%`
-# sigil is optional (newer HLO dumps drop it), the out spec is either
-# one shape or a parenthesized tuple of shapes
-_LINE_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?(?P<name>[A-Za-z_][\w.-]*)\s*=\s*"
-    r"(?P<out>\([^)]*\)|\S+)\s+(?P<op>[\w-]+)\(")
+# sigil is optional (newer HLO dumps drop it). The out spec is one shape
+# or a parenthesized tuple of shapes, see _split_instruction
+_HEAD_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[A-Za-z_][\w.-]*)\s*=\s*")
+_OPCODE_RE = re.compile(r"\s*(?P<op>[\w-]+)\(")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,6 +101,35 @@ def parse_ops(hlo_text: str, opcodes: Sequence[str],
     return ops
 
 
+def _split_instruction(line: str):
+    """(name, out spec, opcode, rest after the opening parenthesis) of an
+    instruction line, or None. The out spec of a tuple holds spaces,
+    ``/*index=5*/`` comments and the parentheses of TPU tiled layouts
+    (``{1,0:T(8,128)}``), so it is skipped by balancing parentheses
+    instead of by a pattern."""
+    m = _HEAD_RE.match(line)
+    if m is None:
+        return None
+    i = m.end()
+    if i < len(line) and line[i] == "(":
+        depth, j = 0, i
+        while j < len(line):
+            depth += {"(": 1, ")": -1}.get(line[j], 0)
+            j += 1
+            if depth == 0:
+                break
+        out = line[i:j]
+    else:
+        j = line.find(" ", i)
+        if j < 0:
+            return None
+        out = line[i:j]
+    mo = _OPCODE_RE.match(line, j)
+    if mo is None:
+        return None
+    return m.group("name"), out, mo.group("op"), line[mo.end():]
+
+
 def parse_all_ops(hlo_text: str) -> List[HloOp]:
     """Every op line of the module (all computations, fusions
     included), with the LHS instruction ``name`` filled — the key the
@@ -108,17 +137,18 @@ def parse_all_ops(hlo_text: str) -> List[HloOp]:
     instruction→phase map (telemetry/costmodel.py) is built from."""
     ops = []
     for line in hlo_text.splitlines():
-        m = _LINE_RE.match(line)
-        if m is None:
+        parts = _split_instruction(line)
+        if parts is None:
             continue
-        shapes, nbytes = shape_bytes(m.group("out"))
+        name, out, opcode, _rest = parts
+        shapes, nbytes = shape_bytes(out)
         nm = _NAME_RE.search(line)
         cct = _CCT_RE.search(line)
-        ops.append(HloOp(opcode=m.group("op"), shapes=shapes,
+        ops.append(HloOp(opcode=opcode, shapes=shapes,
                          out_bytes=nbytes,
                          op_name=nm.group(1) if nm else "",
                          custom_call_target=cct.group(1) if cct else "",
-                         name=m.group("name")))
+                         name=name))
     return ops
 
 

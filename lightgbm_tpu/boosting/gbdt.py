@@ -31,13 +31,16 @@ Structure (TPU-first):
 
 from __future__ import annotations
 
+import collections
 import functools
-from typing import List, Optional, Sequence, Tuple
+import weakref
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import profiler
 from ..config import Config
 from ..dataset import Dataset
 from ..objectives import Objective
@@ -51,6 +54,15 @@ from .tree_builder import build_tree, TreeArrays
 __all__ = ["GBDT"]
 
 kEpsilon = 1e-15
+ROUND_LOG_TREES = 64
+
+
+class RoundRecord(NamedTuple):
+    """One tree's entry of ``GBDT.round_log``."""
+    iteration: int
+    class_index: int
+    rows: np.ndarray     # [rounds] int32 ([n_shards, rounds] when sharded)
+    leaves: np.ndarray   # [rounds] int32
 
 
 def _pad_rows(arr: np.ndarray, r_pad: int, fill=0):
@@ -83,12 +95,14 @@ class _DeviceData:
         bins = _pad_rows(src, self.r_local)
         row_leaf0 = np.where(np.arange(self.r_local) < ds.num_data, 0, -1) \
             .astype(np.int32)
-        if plan is not None:
-            self.bins = plan.shard_bins(bins)
-            self.row_leaf0 = plan.shard_rows(row_leaf0)
-        else:
-            self.bins = jnp.asarray(bins)
-            self.row_leaf0 = jnp.asarray(row_leaf0)
+        with profiler.span("gbdt.to_device"):
+            if plan is not None:
+                self.bins = plan.shard_bins(bins)
+                self.row_leaf0 = plan.shard_rows(row_leaf0)
+            else:
+                self.bins = jnp.asarray(bins)
+                self.row_leaf0 = jnp.asarray(row_leaf0)
+            jax.block_until_ready((self.bins, self.row_leaf0))
 
 
 class _ChunkedDeviceData:
@@ -426,7 +440,9 @@ class GBDT:
                                 and objective.is_ranking)
 
         def _row_put(a):
-            return (self.plan.shard_rows(a) if self.plan is not None
+            with profiler.span("gbdt.to_device"):
+                return jax.block_until_ready(
+                    self.plan.shard_rows(a) if self.plan is not None
                     else jnp.asarray(a))
         self.label_dev = _row_put(
             _pad_rows(np.asarray(lbl, np.float32), R_loc))
@@ -601,6 +617,13 @@ class GBDT:
         self._fused_jit = None
         self._full_mask_cache: Optional[Tuple] = None
         self.host_sync_count = 0
+        # the last ROUND_LOG_TREES trees' per-round counters on the host
+        # (tree_builder.RoundLog as numpy, plus iteration and class):
+        # fetched by the transfer that brings the trees, never by one of
+        # their own
+        self.round_log: collections.deque = collections.deque(
+            maxlen=ROUND_LOG_TREES)
+        GBDT._latest = weakref.ref(self)
 
         # numeric-divergence guard (resilience subsystem): the fused
         # step ALWAYS computes the finiteness flag (one program shape
@@ -1006,8 +1029,10 @@ class GBDT:
     def _build_one_tree(self, gh: jax.Array, fmask: jax.Array, k: int = 0,
                         quant_scales: Optional[jax.Array] = None,
                         it=None, traced: bool = False):
-        """One tree on the current gradients; returns device results.
-        ``it`` overrides the iteration index (the fused step passes a
+        """One tree on the current gradients; returns device results
+        (TreeArrays, row_leaf, valid_row_leafs, RoundLog — None from the
+        chunked builder). ``it`` overrides the iteration index (the
+        fused step passes a
         traced scalar); ``traced`` inlines the builder into an ambient
         trace instead of dispatching its jit."""
         cfg = self.config
@@ -1022,11 +1047,13 @@ class GBDT:
                 kwc["quant_scales"] = quant_scales
             if self._gain_scale is not None:
                 kwc["gain_scale"] = self._gain_scale
+            # host-driven chunk sweeps keep no per-round counters
             return self._chunked_builder.build(
                 self._prefetcher, gh, self.train_dd.row_leaf0, fmask,
                 valid_bins=tuple(dd.bins for dd in self.valid_dd),
                 valid_row_leaf0=tuple(dd.row_leaf0
-                                      for dd in self.valid_dd), **kwc)
+                                      for dd in self.valid_dd), **kwc
+            ) + (None,)
         builder = (self.plan.build_tree if self.plan is not None
                    else functools.partial(build_tree, traced=traced))
         # fold both iteration and class index: multiclass trees of one
@@ -1089,9 +1116,9 @@ class GBDT:
             interaction_groups=self.interaction_groups,
             rng_key=key, feature_fraction_bynode=self._ffbn, **kw)
         if "cegb" in kw:
-            tree_arrays, row_leaf, valid_rls, cegb_state = out
+            tree_arrays, row_leaf, valid_rls, rounds, cegb_state = out
             self._cegb_feat_used, self._cegb_used_rows = cegb_state
-            return tree_arrays, row_leaf, valid_rls
+            return tree_arrays, row_leaf, valid_rls, rounds
         return out
 
     # -- out-of-core chunked training gate (ISSUE 13) ------------------
@@ -1234,7 +1261,8 @@ class GBDT:
         ``gh_k`` is [K, R, 3] (grad/hess/count channels per class);
         ``quant_scales_k`` is [K, 2]. Returns (stacked TreeArrays with
         a leading K axis, row_leaf [K, R], valid_row_leafs tuple of
-        [K, Rv]). Only reachable when :meth:`_class_batch_reason`
+        [K, Rv], RoundLog with a leading K). Only reachable when
+        :meth:`_class_batch_reason`
         cleared, so the forced/CEGB/linear extras of
         :meth:`_build_one_tree` never arise here."""
         cfg = self.config
@@ -1556,19 +1584,20 @@ class GBDT:
         """The traced iteration body. Pure function of its inputs plus
         static self state; numerically identical to the legacy loop
         (same ops, one program). Returns (scores, valid_scores, trees,
-        should_continue flag, finite flag) — all on device. ``trees`` is
+        should_continue flag, finite flag, round logs) — all on
+        device. ``trees`` is
         one stacked TreeArrays (leading K axis) when the class-batched
         build drives the iteration, else the per-class [TreeArrays]*K
-        list; sync() materializes both forms. The finite flag is the
+        list; sync() materializes both forms, and the RoundLog(s) beside
+        them in the same shape. The finite flag is the
         NaN guard's deferred device check (same mechanism as the
         no-split stop): NaN gradients produce -inf gains and a
         no-split tree, so without the explicit g/h check divergence
         would masquerade as a clean early stop."""
-        from .. import profiler
         cfg = self.config
-        with profiler.phase("grads"):
+        with profiler.stage("grads"):
             g, h = self._grads(it, scores)
-        with profiler.phase("sampling"):
+        with profiler.stage("sampling"):
             if self._goss:
                 # GOSS starts after 1/learning_rate iterations
                 # (goss.hpp); a traced-iteration cond replaces the
@@ -1604,8 +1633,8 @@ class GBDT:
             else:
                 gh_k = self._stack_gh_k(g, h, count_mask)
                 qsk_b = None
-            with profiler.phase("build"):
-                trees_k, row_leaf_k, valid_rls_k = \
+            with profiler.stage("build"):
+                trees_k, row_leaf_k, valid_rls_k, rounds_k = \
                     self._build_one_tree_batched(
                         gh_k, fmask, quant_scales_k=qsk_b, it=it,
                         traced=self.plan is None)
@@ -1613,7 +1642,7 @@ class GBDT:
                     trees_k = jax.vmap(self._renew_leaf_impl)(
                         trees_k, row_leaf_k, g_true, h_true)
             grew_k = trees_k.num_leaves > 1                 # [K] bool
-            with profiler.phase("update"):
+            with profiler.stage("update"):
                 # per-class rows are independent, so the batched
                 # where() equals the sequential .at[k].set chain
                 upd = jax.vmap(self._update_score_impl,
@@ -1628,9 +1657,10 @@ class GBDT:
                                               new_valid[vi])
             finite = finite & jnp.all(jnp.isfinite(new_scores))
             return (new_scores, tuple(new_valid), trees_k,
-                    jnp.any(grew_k), finite)
+                    jnp.any(grew_k), finite, rounds_k)
         trees = []
         grews = []
+        rounds = []
         for k in range(self.K):
             if self._quant:
                 gh = jnp.stack([qg[k], qh[k], count_i8], axis=1)
@@ -1638,14 +1668,15 @@ class GBDT:
             else:
                 gh = jnp.stack([g[k], h[k], count_mask], axis=1)
                 qsk = {}
-            with profiler.phase("build"):
-                tree_arrays, row_leaf, valid_rls = self._build_one_tree(
-                    gh, fmask, k, it=it, traced=self.plan is None, **qsk)
+            with profiler.stage("build"):
+                tree_arrays, row_leaf, valid_rls, rounds_1 = \
+                    self._build_one_tree(gh, fmask, k, it=it,
+                                         traced=self.plan is None, **qsk)
                 if self._quant and bool(cfg.quant_train_renew_leaf):
                     tree_arrays = self._renew_leaf_impl(
                         tree_arrays, row_leaf, g_true[k], h_true[k])
             grew = tree_arrays.num_leaves > 1
-            with profiler.phase("update"):
+            with profiler.stage("update"):
                 # score updates apply only when the tree grew — the
                 # device form of the legacy num_leaves>1 host check
                 upd = self._update_score_impl(
@@ -1660,9 +1691,10 @@ class GBDT:
                         jnp.where(grew, vupd, new_valid[vi][k]))
             trees.append(tree_arrays)
             grews.append(grew)
+            rounds.append(rounds_1)
         cont = jnp.any(jnp.stack(grews))
         finite = finite & jnp.all(jnp.isfinite(new_scores))
-        return new_scores, tuple(new_valid), trees, cont, finite
+        return new_scores, tuple(new_valid), trees, cont, finite, rounds
 
     def _fused_data_args(self):
         """The large per-instance device arrays the fused step reads,
@@ -1725,7 +1757,13 @@ class GBDT:
         """Enqueue one fused iteration: a single jit dispatch, no host
         sync. Host-RNG inputs (bagging mask, feature mask) are drawn
         here — pure host computation — so fused and legacy consume the
-        identical RNG streams in the identical order."""
+        identical RNG streams in the identical order. The whole of it
+        is one ``gbdt.dispatch`` span: the driver's busy time a tree."""
+        profiler.recorder.iteration = self.iter_
+        with profiler.span("gbdt.dispatch"):
+            self._fused_dispatch_impl()
+
+    def _fused_dispatch_impl(self):
         it = self.iter_
         mask = self._host_bag_mask(it)
         if mask is None:
@@ -1749,15 +1787,51 @@ class GBDT:
             donate = (0, 1) if jax.default_backend() != "cpu" else ()
             self._fused_jit = jax.jit(self._fused_step_entry,
                                       donate_argnums=donate)
-        scores, valid_scores, trees, cont, ok = self._fused_jit(
+            step = self._step_ready
+        else:
+            step = self._fused_jit
+        scores, valid_scores, trees, cont, ok, rounds = step(
             self.scores, tuple(self.valid_scores), mask, fmask,
             jnp.asarray(it, jnp.int32),
             jnp.asarray(self.shrinkage, jnp.float32),
             self._fused_data_args())
         self.scores = scores
         self.valid_scores = list(valid_scores)
-        self._pending.append((it, float(self.shrinkage), trees, cont, ok))
+        self._pending.append((it, float(self.shrinkage), trees, cont, ok,
+                              rounds))
         self.iter_ += 1
+
+    def _step_ready(self, *args):
+        """The first call of the fused step, as the ``gbdt.step_ready``
+        span: trace, lowering, and the backend compile or the load from
+        the persistent cache. JAX's own monitoring durations of those
+        parts, and its cache hit/miss events, ride on the span as
+        fields (summed over every program the call compiles)."""
+        import jax.monitoring as mon
+        names = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration":
+                     "lowering_s",
+                 "/jax/core/compile/backend_compile_duration":
+                     "backend_compile_s",
+                 "/jax/compilation_cache/cache_hits": "cache_hits",
+                 "/jax/compilation_cache/cache_misses": "cache_misses"}
+        with profiler.span("gbdt.step_ready") as fields:
+            def on_duration(event, duration, **_):
+                if event in names:
+                    key = names[event]
+                    fields[key] = fields.get(key, 0.0) + duration
+
+            def on_event(event, **_):
+                if event in names:
+                    fields[names[event]] = fields.get(names[event], 0) + 1
+
+            mon.register_event_duration_secs_listener(on_duration)
+            mon.register_event_listener(on_event)
+            try:
+                return self._fused_jit(*args)
+            finally:
+                mon.unregister_event_duration_listener(on_duration)
+                mon.unregister_event_listener(on_event)
 
     def sync(self) -> bool:
         """Materialize every deferred iteration's device trees into host
@@ -1771,21 +1845,26 @@ class GBDT:
             return False
         pending, self._pending = self._pending, []
         try:
-            host = jax.device_get([(trees, cont, ok)
-                                   for (_, _, trees, cont, ok)
-                                   in pending])
+            with profiler.span("gbdt.sync.wait"):
+                host = jax.device_get([p[2:] for p in pending])
         except jax.errors.JaxRuntimeError as e:
             # an XLA execution error surfacing at the ring drain means
             # a device (or its collective partner) went away mid-step
             from ..resilience.guards import DeviceLossError
             raise DeviceLossError(pending[0][0], detail=str(e)) from e
         self.host_sync_count += 1
+        with profiler.span("gbdt.sync.trees"):
+            return self._sync_trees(pending, host)
+
+    def _sync_trees(self, pending, host) -> bool:
+        """Host ``Tree`` models (and ``round_log`` entries) from the
+        fetched ring; the deferred stop and divergence checks."""
         bm = self.train_set.bin_mappers
         uf = self.train_set.used_features
         stop = False
         kept = 0
-        for (it, shrink, _, _, _), (trees_h, cont, ok) in zip(pending,
-                                                              host):
+        for (it, shrink, *_), (trees_h, cont, ok, rounds_h) in zip(
+                pending, host):
             if self._nan_guard != "off" and not bool(ok):
                 # divergence check BEFORE the no-split stop: NaN grads
                 # build a no-split tree, which would otherwise read as
@@ -1807,6 +1886,10 @@ class GBDT:
                 # (zero-copy numpy slices)
                 trees_h = [jax.tree.map(lambda a: a[k], trees_h)
                            for k in range(self.K)]
+                rounds_h = [jax.tree.map(lambda a: a[k], rounds_h)
+                            for k in range(self.K)]
+            for k, rl in enumerate(rounds_h):
+                self._log_rounds(it, k, rl)
             for k, tree in enumerate(Tree.from_device_batch(
                     trees_h, bm, uf, shrink)):
                 bias = self._init_scores[k]
@@ -1821,6 +1904,22 @@ class GBDT:
             kept += 1
         self.iter_ = pending[0][0] + kept
         return stop
+
+    _latest: Optional["weakref.ref"] = None
+
+    @classmethod
+    def latest(cls) -> Optional["GBDT"]:
+        """The most recently constructed trainer still alive in this
+        process: how a reader that was handed no booster (a metric of the
+        benchmark, a debugger) finds ``round_log``."""
+        ref = GBDT._latest
+        return ref() if ref is not None else None
+
+    def _log_rounds(self, it: int, k: int, rounds) -> None:
+        if rounds is not None:
+            self.round_log.append(RoundRecord(
+                int(it), int(k), np.asarray(rounds.rows),
+                np.asarray(rounds.leaves)))
 
     def train_one_iter(self, gradients: Optional[np.ndarray] = None,
                        hessians: Optional[np.ndarray] = None, *,
@@ -1913,7 +2012,7 @@ class GBDT:
                                ) -> bool:
         """Per-iteration host loop (~5 dispatches + a per-tree sync);
         returns True when training should stop (no splits possible)."""
-        from .. import profiler
+        profiler.recorder.iteration = self.iter_
         with profiler.phase("grads"):
             if gradients is None or hessians is None:
                 g, h = self._grads(self.iter_)
@@ -1951,17 +2050,19 @@ class GBDT:
                 gh_k = self._stack_gh_k(g, h, count_mask)
                 qsk_b = None
             with profiler.phase("build"):
-                trees_k, row_leaf_k, valid_rls_k = \
+                trees_k, row_leaf_k, valid_rls_k, rounds_k = \
                     self._build_one_tree_batched(gh_k, fmask,
                                                  quant_scales_k=qsk_b)
                 if self._quant and bool(self.config.quant_train_renew_leaf):
                     trees_k = self._renew_batch_jit(trees_k, row_leaf_k,
                                                     g_true, h_true)
-            trees_k_host = jax.tree.map(np.asarray, trees_k)
+            trees_k_host, rounds_k_host = jax.tree.map(
+                np.asarray, (trees_k, rounds_k))
         for k in range(self.K):
             if trees_k is not None:
                 tree_arrays = jax.tree.map(lambda a: a[k], trees_k)
                 host = jax.tree.map(lambda a: a[k], trees_k_host)
+                rounds_host = jax.tree.map(lambda a: a[k], rounds_k_host)
                 row_leaf = row_leaf_k[k]
                 valid_rls = tuple(v[k] for v in valid_rls_k)
             else:
@@ -1972,13 +2073,15 @@ class GBDT:
                     gh = jnp.stack([g[k], h[k], count_mask], axis=1)
                     qsk = {}
                 with profiler.phase("build"):
-                    tree_arrays, row_leaf, valid_rls = \
+                    tree_arrays, row_leaf, valid_rls, rounds_1 = \
                         self._build_one_tree(gh, fmask, k, **qsk)
                     if self._quant and bool(
                             self.config.quant_train_renew_leaf):
                         tree_arrays = self._renew_jit(
                             tree_arrays, row_leaf, g_true[k], h_true[k])
-                host = jax.tree.map(np.asarray, tree_arrays)
+                host, rounds_host = jax.tree.map(
+                    np.asarray, (tree_arrays, rounds_1))
+            self._log_rounds(self.iter_, k, rounds_host)
             num_leaves_trained = int(host.num_leaves)
             shrink = self.shrinkage
             tree = Tree.from_device(host, self.train_set.bin_mappers,

@@ -89,7 +89,8 @@ class RF(GBDT):
         n = float(self.iter_ + self.num_init_iteration)
         for k in range(self.K):
             gh = jnp.stack([g[k], h[k], count_mask], axis=1)
-            tree_arrays, row_leaf, valid_rls = self._build_one_tree(gh, fmask, k)
+            tree_arrays, row_leaf, valid_rls, _ = \
+                self._build_one_tree(gh, fmask, k)
             host = jax.tree.map(np.asarray, tree_arrays)
             bias = float(self._init_scores[k])
             tree = Tree.from_device(host, self.train_set.bin_mappers,

@@ -72,6 +72,8 @@ import jax
 import jax.numpy as jnp
 
 
+from .. import phases as PHS
+from .. import profiler
 from ..ops.histogram import (build_histograms, resolve_impl, HIST_CH,
                              merge_histograms, _pvary)
 # referenced as a module attribute (PH.fused_build_best_splits) so tests
@@ -81,7 +83,7 @@ from ..ops.predict import row_feature_gather
 from ..ops.split import (SplitParams, find_best_splits, leaf_gain,
                          leaf_output, monotone_penalty_factor)
 
-__all__ = ["TreeArrays", "build_tree", "max_rounds_for"]
+__all__ = ["TreeArrays", "RoundLog", "build_tree", "max_rounds_for"]
 
 NEG_INF = -jnp.inf
 F32_MAX = 3.4e38  # monotone bounds start effectively unconstrained
@@ -105,6 +107,16 @@ class TreeArrays(NamedTuple):
     leaf_values: jax.Array     # [L+1] f32 output per leaf slot (unshrunk)
     num_leaves: jax.Array      # scalar int32
     num_nodes: jax.Array       # scalar int32
+
+
+class RoundLog(NamedTuple):
+    """What each round of the grow loop did, returned beside the tree
+    and fetched with it. Arrays sized ``max_rounds_for(L, W)``; rounds
+    the loop never ran stay 0."""
+    rows: jax.Array     # [rounds] int32: live rows the round's histogram
+    #                     stream was bounded by (n_small under
+    #                     compaction; per shard under a row-sharded plan)
+    leaves: jax.Array   # [rounds] int32: splits the round applied
 
 
 def max_rounds_for(num_leaves: int, leaf_batch: int) -> int:
@@ -184,7 +196,9 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                n_shards: int = 1,
                fused_split: bool = False,
                root_hist: Optional[jax.Array] = None):
-    """Grow one tree. Returns (TreeArrays, row_leaf, valid_row_leafs).
+    """Grow one tree. Returns (TreeArrays, row_leaf, valid_row_leafs,
+    RoundLog) — and the CEGB state as a fifth element when ``cegb`` is
+    given.
 
     ``parallel_mode`` (with ``axis_name`` set) selects the distributed
     strategy, mirroring tree_learner=data/feature/voting
@@ -529,9 +543,10 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             (S, mat.shape[1], nb_in, HIST_CH),
             jnp.int32 if q else jnp.float32)
         bf16 = bool((not q) and jnp.dtype(hist_dtype) == jnp.bfloat16)
-        h = jax.ffi.ffi_call(target, out_sds)(
-            mat, g, part[0], part[1], part[2], slots.astype(jnp.int32),
-            bf16_round=bf16)
+        with profiler.stage(PHS.HIST_KERNEL):
+            h = jax.ffi.ffi_call(target, out_sds)(
+                mat, g, part[0], part[1], part[2], slots.astype(jnp.int32),
+                bf16_round=bf16)
         if axis_name is not None:
             h = _pvary(h, axis_name)
             if merge:
@@ -591,8 +606,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         SplitInfo-sized (a handful of [S]-shaped collectives) — tagged
         ``winner_sync`` so the collective auditor (parallel/comms.py)
         separates it from histogram traffic."""
-        from .. import profiler
-        with profiler.phase("winner_sync"):
+        with profiler.stage(PHS.WINNER_SYNC):
             return _sync_best_impl(bs)
 
     def _sync_best_impl(bs):
@@ -982,8 +996,11 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             ride as zeros."""
             pen = (monotone_penalty_factor(depth_s, sp.monotone_penalty)
                    if pen_on else None)
-            mat = (bins if row_gather is None
-                   else jnp.take(bins, row_gather, axis=0))
+            if row_gather is None:
+                mat = bins
+            else:
+                with profiler.stage(PHS.HIST_GATHER):
+                    mat = jnp.take(bins, row_gather, axis=0)
             return PH.fused_build_best_splits(
                 mat, gh if gh_in is None else gh_in, rl, slots,
                 num_bins=B, params=sp, num_bins_pf=num_bins_pf,
@@ -993,7 +1010,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                 quant_scales=quant_scales, hist_dtype=hist_dtype,
                 num_rows=num_rows, emit_hist=emit_hist)
 
-        def fused_children(st, t, row_leaf, sel_s, right_slot, valid,
+        def fused_children(stg, st, t, row_leaf, sel_s, right_slot, valid,
                            slots2w, slots2w_c, depth2w, mid_state, keyr,
                            leaf_lo, leaf_hi):
             """Per-round children splits via the fused kernel. With the
@@ -1006,6 +1023,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             sliced, so bynode/interaction draws match the legacy path
             bit-for-bit."""
             nsh = {}
+            stg(PHS.FIND)
             fmask2w, _ = slot_masks_and_bins(
                 mid_state.get("used_feat"), slots2w_c, keyr)
             lo2w = jnp.take(leaf_lo, slots2w_c) if use_mono else None
@@ -1014,7 +1032,8 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             if not hist_sub:
                 bs, _ = fused_call(slots2w, fmask2w, depth2w, lo2w, hi2w,
                                    po2w, row_leaf, emit_hist=False)
-                return bs, nsh
+                return bs, nsh, jnp.asarray(R, jnp.int32)
+            stg(PHS.COUNT)
             rlc_n = jnp.where(row_leaf < 0, DUMMY_LEAF, row_leaf)
             raw_cnt = jax.ops.segment_sum(
                 jnp.ones((R,), jnp.int32), rlc_n, num_segments=L + 1)
@@ -1031,6 +1050,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
 
             # compacted small-child stream (same lut/cumsum pass as the
             # legacy hist_compact path)
+            stg(PHS.COMPACT)
             is_small = jnp.zeros((L + 2,), bool).at[
                 jnp.clip(small_slots, -1, L) + 1].set(True) \
                 .at[0].set(False)
@@ -1044,12 +1064,14 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                 jnp.arange(R, dtype=jnp.int32) < n_small,
                 jnp.take(row_leaf, c_idx), -1)
             gh_c = jnp.take(gh, c_idx, axis=0)
+            stg(PHS.FIND)
             bs_s, hsmall = fused_call(
                 small_slots, _lane(fmask2w, idx_small),
                 _lane(depth2w, idx_small), _lane(lo2w, idx_small),
                 _lane(hi2w, idx_small), _lane(po2w, idx_small),
                 rl_c, gh_in=gh_c, row_gather=c_idx, num_rows=n_small,
                 emit_hist=True)
+            stg(PHS.SUBTRACT)
             parent_raw = jnp.take(st["hist_cache"],
                                   jnp.clip(sel_s, 0, L), axis=0)
             hbig = parent_raw - hsmall
@@ -1060,6 +1082,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                 .at[jnp.where(valid, sel_s, DUMMY_LEAF)].set(left_raw) \
                 .at[jnp.where(valid, right_slot, DUMMY_LEAF)] \
                 .set(right_raw)
+            stg(PHS.FIND)
             bs_b = find_best_splits(
                 hbig, num_bins_pf, nan_bin_pf, is_cat_pf, sp,
                 feature_mask=_lane(fmask2w, idx_big),
@@ -1075,7 +1098,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                 return jnp.concatenate([jnp.where(s_, ks, kb),
                                         jnp.where(s_, kb, ks)])
             bs = {k: _mix(bs_s[k], bs_b[k]) for k in bs_b}
-            return bs, nsh
+            return bs, nsh, n_small
 
     # ---------------- state ----------------
     tree = TreeArrays(
@@ -1133,116 +1156,128 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             state["cegb_used_rows"] = used_rows0
 
     # ---------------- root ----------------
-    part0 = None
-    if use_native_part:
-        # DataPartition init: live rows (all slot 0 at the root) first,
-        # original order preserved; dead/padded rows trail unused
-        live0 = row_leaf0 >= 0
-        live_i = live0.astype(jnp.int32)
-        n_live0 = live_i.sum()
-        # stable live-first order WITHOUT a sort (XLA's 1M-row sort
-        # costs ~95 ms on one core; this is three cheap passes)
-        dest = jnp.where(live0, jnp.cumsum(live_i) - 1,
-                         n_live0 + jnp.cumsum(1 - live_i) - 1)
-        perm0 = jnp.zeros((R,), jnp.int32).at[dest].set(
-            jnp.arange(R, dtype=jnp.int32))
-        lb0 = jnp.zeros((L + 1,), jnp.int32)
-        lc0 = jnp.zeros((L + 1,), jnp.int32).at[0].set(
-            n_live0.astype(jnp.int32))
-        if axis_name is not None:
-            # the loop-carried partition state is per-shard (varying)
-            perm0 = _pvary(perm0, axis_name)
-            lb0 = _pvary(lb0, axis_name)
-            lc0 = _pvary(lc0, axis_name)
-        part0 = (perm0, lb0, lc0)
-        state["perm"], state["leaf_begin"], state["leaf_cnt"] = part0
-    root_slots = jnp.full((2 * W,), -2, jnp.int32).at[0].set(0)
-    key0 = (jax.random.fold_in(rng_key, 0) if rng_key is not None else None)
-    # path smoothing makes the root split depend on the root OUTPUT
-    # (parent_output), which the fused single launch cannot know yet —
-    # smooth roots keep the two-pass flow (the loop stays fused: there
-    # the parent output is already in the tree)
-    fused_root = use_fused and not use_smooth and root_hist is None
-    bs0 = None
-    if fused_root:
-        # one VMEM-resident pass: root histogram (emitted only when the
-        # subtraction cache needs seeding) AND its best split
-        fmask0, _ = slot_masks_and_bins(state.get("used_feat"),
-                                        root_slots.clip(0), key0)
-        lo0 = (jnp.take(state["leaf_lo"], root_slots.clip(0))
-               if use_mono else None)
-        hi0 = (jnp.take(state["leaf_hi"], root_slots.clip(0))
-               if use_mono else None)
-        bs0, hraw0 = fused_call(
-            root_slots, fmask0, jnp.zeros((2 * W,), jnp.int32), lo0, hi0,
-            None, row_leaf0, emit_hist=hist_sub)
-    elif root_hist is not None:
-        # class-batched root dedupe (ISSUE 14 satellite): the K classes'
-        # root histograms were built pre-vmap by ONE kernel streaming
-        # the bins block once; non-root lattice slots are exact zeros in
-        # both formulations (no row carries the -2 sentinel)
-        hraw0 = jnp.zeros((2 * W,) + root_hist.shape,
-                          root_hist.dtype).at[0].set(root_hist)
-    else:
-        hraw0 = hist_raw_for(root_slots, row_leaf0, part=part0)
-    if fused_root and not hist_sub:
-        # pure fused mode: the root histogram never exists — totals come
-        # from the kernel's per-slot totals record (sum-then-rescale; in
-        # float this can differ from the two-pass scale-then-sum in the
-        # last bits, documented in the fused kernel contract)
-        root_sums = bs0["slot_totals"][0]
-    else:
-        hist0 = hist_finish(hraw0)
-        if hist_sub:
-            # per-leaf RAW histogram cache (HistogramPool analog): slot i
-            # holds leaf i's histogram as of its creation; rows of a leaf
-            # only change when IT is split, so entries stay valid until
-            # popped, when the entry is the subtraction minuend
-            state["hist_cache"] = jnp.zeros(
-                (L + 1,) + hraw0.shape[1:], hraw0.dtype).at[0].set(hraw0[0])
-        root_sums = hist0[0, 0, :, :].sum(axis=0)   # all rows land in f0 bins
-    if mode == "voting":
-        # local hist -> global root sums (the Allreduce of root
-        # (count, sum_g, sum_h), data_parallel_tree_learner.cpp:160-219)
-        root_sums = jax.lax.psum(root_sums, axis_name)
-    elif rs_data:
-        # scattered layout: exactly ONE chip holds global feature 0's
-        # merged column (chip 0 in the plain layout; the owner of
-        # bundle b_gof[0] under EFB — hist0 is zero elsewhere), and its
-        # bin sum is the global root totals. One [3]-sized psum
-        # broadcasts the owner's value.
-        if use_bundle:
-            own0 = rs_own_mask()[0]
+    with profiler.stage(PHS.ROOT_PASS):
+        part0 = None
+        if use_native_part:
+            # DataPartition init: live rows (all slot 0 at the root) first,
+            # original order preserved; dead/padded rows trail unused
+            live0 = row_leaf0 >= 0
+            live_i = live0.astype(jnp.int32)
+            n_live0 = live_i.sum()
+            # stable live-first order WITHOUT a sort (XLA's 1M-row sort
+            # costs ~95 ms on one core; this is three cheap passes)
+            dest = jnp.where(live0, jnp.cumsum(live_i) - 1,
+                             n_live0 + jnp.cumsum(1 - live_i) - 1)
+            perm0 = jnp.zeros((R,), jnp.int32).at[dest].set(
+                jnp.arange(R, dtype=jnp.int32))
+            lb0 = jnp.zeros((L + 1,), jnp.int32)
+            lc0 = jnp.zeros((L + 1,), jnp.int32).at[0].set(
+                n_live0.astype(jnp.int32))
+            if axis_name is not None:
+                # the loop-carried partition state is per-shard (varying)
+                perm0 = _pvary(perm0, axis_name)
+                lb0 = _pvary(lb0, axis_name)
+                lc0 = _pvary(lc0, axis_name)
+            part0 = (perm0, lb0, lc0)
+            state["perm"], state["leaf_begin"], state["leaf_cnt"] = part0
+        root_slots = jnp.full((2 * W,), -2, jnp.int32).at[0].set(0)
+        key0 = (jax.random.fold_in(rng_key, 0) if rng_key is not None
+                else None)
+        # path smoothing makes the root split depend on the root OUTPUT
+        # (parent_output), which the fused single launch cannot know yet —
+        # smooth roots keep the two-pass flow (the loop stays fused: there
+        # the parent output is already in the tree)
+        fused_root = use_fused and not use_smooth and root_hist is None
+        bs0 = None
+        if fused_root:
+            # one VMEM-resident pass: root histogram (emitted only when the
+            # subtraction cache needs seeding) AND its best split
+            fmask0, _ = slot_masks_and_bins(state.get("used_feat"),
+                                            root_slots.clip(0), key0)
+            lo0 = (jnp.take(state["leaf_lo"], root_slots.clip(0))
+                   if use_mono else None)
+            hi0 = (jnp.take(state["leaf_hi"], root_slots.clip(0))
+                   if use_mono else None)
+            bs0, hraw0 = fused_call(
+                root_slots, fmask0, jnp.zeros((2 * W,), jnp.int32), lo0, hi0,
+                None, row_leaf0, emit_hist=hist_sub)
+        elif root_hist is not None:
+            # class-batched root dedupe (ISSUE 14 satellite): the K classes'
+            # root histograms were built pre-vmap by ONE kernel streaming
+            # the bins block once; non-root lattice slots are exact zeros in
+            # both formulations (no row carries the -2 sentinel)
+            hraw0 = jnp.zeros((2 * W,) + root_hist.shape,
+                              root_hist.dtype).at[0].set(root_hist)
         else:
-            own0 = jax.lax.axis_index(axis_name) == 0
-        root_sums = jax.lax.psum(
-            jnp.where(own0, root_sums, jnp.zeros_like(root_sums)),
-            axis_name)
-    root_val = leaf_output(root_sums[0], root_sums[1], sp.lambda_l1,
-                           sp.lambda_l2, sp.max_delta_step)
-    tree = tree._replace(
-        node_value=tree.node_value.at[0].set(root_val),
-        node_count=tree.node_count.at[0].set(root_sums[2]),
-        node_hess=tree.node_hess.at[0].set(root_sums[1]),
-        leaf_values=tree.leaf_values.at[0].set(root_val),
-    )
-    slot_valid0 = jnp.zeros((2 * W,), bool).at[0].set(True)
-    if bs0 is None:
-        bs0 = best_for(hist0, jnp.zeros((2 * W,), jnp.int32), slot_valid0,
-                       root_slots.clip(0), tree, state, key0,
-                       rl=row_leaf0)
-    bs_gain = bs_gain.at[0].set(bs0["gain"][0])
-    bs_feat = bs_feat.at[0].set(bs0["feature"][0])
-    bs_thr = bs_thr.at[0].set(bs0["threshold"][0])
-    bs_dl = bs_dl.at[0].set(bs0["default_left"][0])
-    bs_cat = bs_cat.at[0].set(bs0["is_cat_split"][0])
-    bs_left = bs_left.at[0].set(bs0["left_sum"][0])
-    bs_right = bs_right.at[0].set(bs0["right_sum"][0])
-    bs_bits = bs_bits.at[0].set(bs0["cat_bitset"][0])
-    bs_lout = bs_lout.at[0].set(bs0["left_out"][0])
-    bs_rout = bs_rout.at[0].set(bs0["right_out"][0])
+            hraw0 = hist_raw_for(root_slots, row_leaf0, part=part0)
+        if fused_root and not hist_sub:
+            # pure fused mode: the root histogram never exists — totals come
+            # from the kernel's per-slot totals record (sum-then-rescale; in
+            # float this can differ from the two-pass scale-then-sum in the
+            # last bits, documented in the fused kernel contract)
+            root_sums = bs0["slot_totals"][0]
+        else:
+            hist0 = hist_finish(hraw0)
+            if hist_sub:
+                # per-leaf RAW histogram cache (HistogramPool analog): slot i
+                # holds leaf i's histogram as of its creation; rows of a leaf
+                # only change when IT is split, so entries stay valid until
+                # popped, when the entry is the subtraction minuend
+                state["hist_cache"] = jnp.zeros(
+                    (L + 1,) + hraw0.shape[1:],
+                    hraw0.dtype).at[0].set(hraw0[0])
+            # all rows land in feature 0's bins
+            root_sums = hist0[0, 0, :, :].sum(axis=0)
+        if mode == "voting":
+            # local hist -> global root sums (the Allreduce of root
+            # (count, sum_g, sum_h), data_parallel_tree_learner.cpp:160-219)
+            root_sums = jax.lax.psum(root_sums, axis_name)
+        elif rs_data:
+            # scattered layout: exactly ONE chip holds global feature 0's
+            # merged column (chip 0 in the plain layout; the owner of
+            # bundle b_gof[0] under EFB — hist0 is zero elsewhere), and its
+            # bin sum is the global root totals. One [3]-sized psum
+            # broadcasts the owner's value.
+            if use_bundle:
+                own0 = rs_own_mask()[0]
+            else:
+                own0 = jax.lax.axis_index(axis_name) == 0
+            root_sums = jax.lax.psum(
+                jnp.where(own0, root_sums, jnp.zeros_like(root_sums)),
+                axis_name)
+        root_val = leaf_output(root_sums[0], root_sums[1], sp.lambda_l1,
+                               sp.lambda_l2, sp.max_delta_step)
+        tree = tree._replace(
+            node_value=tree.node_value.at[0].set(root_val),
+            node_count=tree.node_count.at[0].set(root_sums[2]),
+            node_hess=tree.node_hess.at[0].set(root_sums[1]),
+            leaf_values=tree.leaf_values.at[0].set(root_val),
+        )
+        slot_valid0 = jnp.zeros((2 * W,), bool).at[0].set(True)
+        if bs0 is None:
+            bs0 = best_for(hist0, jnp.zeros((2 * W,), jnp.int32), slot_valid0,
+                           root_slots.clip(0), tree, state, key0,
+                           rl=row_leaf0)
+        bs_gain = bs_gain.at[0].set(bs0["gain"][0])
+        bs_feat = bs_feat.at[0].set(bs0["feature"][0])
+        bs_thr = bs_thr.at[0].set(bs0["threshold"][0])
+        bs_dl = bs_dl.at[0].set(bs0["default_left"][0])
+        bs_cat = bs_cat.at[0].set(bs0["is_cat_split"][0])
+        bs_left = bs_left.at[0].set(bs0["left_sum"][0])
+        bs_right = bs_right.at[0].set(bs0["right_sum"][0])
+        bs_bits = bs_bits.at[0].set(bs0["cat_bitset"][0])
+        bs_lout = bs_lout.at[0].set(bs0["left_out"][0])
+        bs_rout = bs_rout.at[0].set(bs0["right_out"][0])
 
     rounds_bound = max_rounds_for(L, W)
+    # per-round counters, fetched with the tree (RoundLog). Row-sharded
+    # plans count each shard's own stream, so the carry varies over the
+    # mesh axis; feature-parallel streams the same rows on every chip.
+    round_rows0 = jnp.zeros((rounds_bound,), jnp.int32)
+    if axis_name is not None and mode != "feature":
+        round_rows0 = _pvary(round_rows0, axis_name)
+    state["round_rows"] = round_rows0
+    state["round_leaves"] = jnp.zeros((rounds_bound,), jnp.int32)
 
     state.update(tree=tree, bs_gain=bs_gain, bs_feat=bs_feat, bs_thr=bs_thr,
                  bs_dl=bs_dl, bs_cat=bs_cat, bs_left=bs_left,
@@ -1260,10 +1295,15 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         return (st["r"] < rounds_bound) & more_budget & has_split
 
     def body(st):
+        with profiler.stage_sequence() as stg:
+            return _round(st, stg)
+
+    def _round(st, stg):
         t: TreeArrays = st["tree"]
         cur = t.num_leaves
         nodes = t.num_nodes
         # -- 1. pop top-W cached splits
+        stg(PHS.POP)
         gains, sel = jax.lax.top_k(st["bs_gain"][:L], W)
         sel = sel.astype(jnp.int32)
         budget = L - cur
@@ -1460,6 +1500,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                              jnp.where(mt_w < 0, lo_pair, rval))
 
         # -- 2. record splits in node arrays
+        stg(PHS.APPLY)
         t = t._replace(
             split_feature=t.split_feature.at[parent].set(sfeat),
             threshold_bin=t.threshold_bin.at[parent].set(sthr),
@@ -1719,9 +1760,11 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         mid_state = dict(leaf_lo=leaf_lo, leaf_hi=leaf_hi,
                          **new_state_extra, **new_state_mono)
         valid2w = jnp.concatenate([valid, valid])
+        # rows this round's histogram stream is bounded by (RoundLog)
+        rows_r = jnp.asarray(R, jnp.int32)
         if use_fused:
-            bs, nsh = fused_children(
-                st, t, row_leaf, sel_s, right_slot, valid, slots2w,
+            bs, nsh, rows_r = fused_children(
+                stg, st, t, row_leaf, sel_s, right_slot, valid, slots2w,
                 slots2w_c, depth2w, mid_state, keyr, leaf_lo, leaf_hi)
             new_state_hist.update(nsh)
             # same gain gating best_for applies after its lattice scan
@@ -1730,12 +1773,14 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                 g = jnp.where(depth2w < max_depth, g, NEG_INF)
             bs["gain"] = jnp.where(valid2w, g, NEG_INF)
         elif hist_sub:
+            stg(PHS.COUNT)
             if use_native_part:
                 raw_cnt = lc_n          # partition maintains the counts
             else:
                 rlc_n = jnp.where(row_leaf < 0, DUMMY_LEAF, row_leaf)
                 raw_cnt = jax.ops.segment_sum(
                     jnp.ones((R,), jnp.int32), rlc_n, num_segments=L + 1)
+            raw_loc = raw_cnt
             if axis_name is not None and mode != "feature":
                 # replicate the small/big choice across row shards: in
                 # data mode the psum inside hist_raw_for sums LOCAL
@@ -1750,6 +1795,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             if hist_compact:
                 # membership via a [L+2] lut gather, not a [R, 2W]
                 # broadcast compare (42x less traffic at W=21)
+                stg(PHS.COMPACT)
                 is_small = jnp.zeros((L + 2,), bool).at[
                     jnp.clip(small_slots, -1, L) + 1].set(True) \
                     .at[0].set(False)           # -1/-2 sentinels
@@ -1763,13 +1809,18 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                     jnp.arange(R, dtype=jnp.int32) < n_small,
                     jnp.take(row_leaf, c_idx), -1)
                 gh_c = jnp.take(gh, c_idx, axis=0)
+                rows_r = n_small
                 hsmall = hist_raw_for(small_slots, rl_c, gh_in=gh_c,
                                       row_gather=c_idx,
                                       num_rows=n_small)
             else:
-                # full masked stream (Pallas), or the partition's exact
-                # row lists (native)
+                # the partition's exact row lists (native): this shard's
+                # rows of the small children
+                rows_r = jnp.where(
+                    valid, jnp.take(raw_loc, jnp.clip(small_slots, 0, L)),
+                    0).sum().astype(jnp.int32)
                 hsmall = hist_raw_for(small_slots, row_leaf, part=part_n)
+            stg(PHS.SUBTRACT)
             parent_raw = jnp.take(st["hist_cache"],
                                   jnp.clip(sel_s, 0, L), axis=0)
             hbig = parent_raw - hsmall
@@ -1783,6 +1834,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             hist2w = hist_finish(jnp.concatenate([left_raw, right_raw]))
         else:
             hist2w = hist_for(slots2w, row_leaf, part=part_n)
+        stg(PHS.FIND)
         if not use_fused:
             bs = best_for(hist2w, depth2w, valid2w,
                           slots2w_c, t, mid_state, keyr, rl=row_leaf)
@@ -1806,18 +1858,23 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                    bs_right=bs_right, bs_bits=bs_bits, bs_lout=bs_lout,
                    bs_rout=bs_rout,
                    leaf_depth=leaf_depth, leaf_lo=leaf_lo, leaf_hi=leaf_hi,
-                   r=st["r"] + 1, **new_state_extra, **new_state_mono,
+                   r=st["r"] + 1,
+                   round_rows=st["round_rows"].at[st["r"]].set(rows_r),
+                   round_leaves=st["round_leaves"].at[st["r"]].set(n_valid),
+                   **new_state_extra, **new_state_mono,
                    **new_state_forced, **new_state_hist,
                    **new_state_part)
         return out
 
     state = jax.lax.while_loop(cond, body, state)
+    rounds = RoundLog(rows=state["round_rows"], leaves=state["round_leaves"])
     if use_cegb:
         cegb_out = (state["cegb_feat_used"],
                     state.get("cegb_used_rows"))
         return (state["tree"], state["row_leaf"],
-                state["valid_row_leaf"], cegb_out)
-    return state["tree"], state["row_leaf"], state["valid_row_leaf"]
+                state["valid_row_leaf"], rounds, cegb_out)
+    return (state["tree"], state["row_leaf"], state["valid_row_leaf"],
+            rounds)
 
 
 _build_tree_jit = functools.partial(
@@ -1864,7 +1921,8 @@ def _build_tree_class_batched(bins, gh, row_leaf0, num_bins_pf,
     bytes-per-class unchanged.
 
     Returns (TreeArrays with a leading K on every field, row_leaf
-    [K, R], valid_row_leafs tuple of [K, Rv] arrays).
+    [K, R], valid_row_leafs tuple of [K, Rv] arrays, RoundLog with a
+    leading K).
 
     Not batchable here (callers gate these to the sequential path):
     forced splits and CEGB (cross-tree host state), and the native FFI

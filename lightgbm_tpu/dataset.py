@@ -25,6 +25,7 @@ import os
 import numpy as np
 from typing import Dict, List, Optional
 
+from . import profiler
 from .binning import BinMapper
 from .config import Config
 
@@ -402,74 +403,77 @@ class Dataset:
                 sample = data
             if sparse:
                 sample = np.asarray(sample.todense(), dtype=np.float64)
-            self._fit_mappers(sample, cat_idx, cfg)
+            with profiler.span("dataset.fit_bins"):
+                self._fit_mappers(sample, cat_idx, cfg)
 
-        F = len(self.used_features)
+        # binning every row with the fitted mappers (and EFB packing)
+        with profiler.span("dataset.apply_bins"):
+            F = len(self.used_features)
 
-        if sparse:
-            # one CSR->CSC conversion; column slices are then O(nnz_col)
-            data_csc = data.tocsc()
-
-        def col_of(f):
             if sparse:
-                return np.asarray(data_csc[:, [f]].todense(),
-                                  dtype=np.float64).ravel()
-            return data[:, f]
+                # one CSR->CSC conversion; column slices are then O(nnz_col)
+                data_csc = data.tocsc()
 
-        # -- EFB: pack mutually-exclusive sparse features (efb.py) ----
-        if self.reference is not None:
-            self.bundle_plan = self.reference.bundle_plan
-        elif self._multi_process():
-            # pre-partitioned multi-host: a bundle plan built from the
-            # LOCAL sample would differ across hosts (different conflict
-            # counts -> different column layouts); skip EFB until the
-            # plan itself is synced like the mappers are
-            self.bundle_plan = None
-        elif cfg.enable_bundle and F > 4:
-            from .efb import plan_bundles
-            uf = self.used_features
-            sample_bins = np.stack(
-                [self.bin_mappers[f].values_to_bins(sample[:, f])
-                 for f in uf], axis=1)
-            plan = plan_bundles(
-                sample_bins,
-                [self.bin_mappers[f].num_bin for f in uf],
-                [self.bin_mappers[f].most_freq_bin for f in uf],
-                max_conflict_rate=cfg.max_conflict_rate,
-                max_bundle_bins=cfg.max_bundle_bins)
-            # bundle only when it genuinely shrinks the matrix
-            self.bundle_plan = (plan if plan.num_bundles <= int(0.75 * F)
-                                else None)
-        else:
-            self.bundle_plan = None
+            def col_of(f):
+                if sparse:
+                    return np.asarray(data_csc[:, [f]].todense(),
+                                      dtype=np.float64).ravel()
+                return data[:, f]
 
-        if self.bundle_plan is not None:
-            from .efb import encode_bundles
-
-            def cols():
-                for j, f in enumerate(self.used_features):
-                    yield j, self.bin_mappers[f].values_to_bins(
-                        col_of(f)).astype(np.int64)
-            self.bins = encode_bundles(self.bundle_plan, cols(),
-                                       self.num_data)
-        else:
-            dtype = np.uint8 if self.max_num_bin <= 256 else np.int32
-            fast = None
-            if not sparse:
-                # accelerator fast path: one jitted searchsorted over the
-                # whole [R, F] matrix (ops/binning_device.py)
-                from .ops.binning_device import (device_bin_dense,
-                                                 want_device_binning)
-                if want_device_binning(self.num_data, F):
-                    fast = device_bin_dense(
-                        data, self.bin_mappers, self.used_features, dtype)
-            if fast is not None:
-                self.bins = fast
+            # -- EFB: pack mutually-exclusive sparse features (efb.py) ----
+            if self.reference is not None:
+                self.bundle_plan = self.reference.bundle_plan
+            elif self._multi_process():
+                # pre-partitioned multi-host: a bundle plan built from the
+                # LOCAL sample would differ across hosts (different conflict
+                # counts -> different column layouts); skip EFB until the
+                # plan itself is synced like the mappers are
+                self.bundle_plan = None
+            elif cfg.enable_bundle and F > 4:
+                from .efb import plan_bundles
+                uf = self.used_features
+                sample_bins = np.stack(
+                    [self.bin_mappers[f].values_to_bins(sample[:, f])
+                     for f in uf], axis=1)
+                plan = plan_bundles(
+                    sample_bins,
+                    [self.bin_mappers[f].num_bin for f in uf],
+                    [self.bin_mappers[f].most_freq_bin for f in uf],
+                    max_conflict_rate=cfg.max_conflict_rate,
+                    max_bundle_bins=cfg.max_bundle_bins)
+                # bundle only when it genuinely shrinks the matrix
+                self.bundle_plan = (plan if plan.num_bundles <= int(0.75 * F)
+                                    else None)
             else:
-                self.bins = np.empty((self.num_data, F), dtype=dtype)
-                for j, f in enumerate(self.used_features):
-                    self.bins[:, j] = self.bin_mappers[f].values_to_bins(
-                        col_of(f)).astype(dtype)
+                self.bundle_plan = None
+
+            if self.bundle_plan is not None:
+                from .efb import encode_bundles
+
+                def cols():
+                    for j, f in enumerate(self.used_features):
+                        yield j, self.bin_mappers[f].values_to_bins(
+                            col_of(f)).astype(np.int64)
+                self.bins = encode_bundles(self.bundle_plan, cols(),
+                                           self.num_data)
+            else:
+                dtype = np.uint8 if self.max_num_bin <= 256 else np.int32
+                fast = None
+                if not sparse:
+                    # accelerator fast path: one jitted searchsorted over the
+                    # whole [R, F] matrix (ops/binning_device.py)
+                    from .ops.binning_device import (device_bin_dense,
+                                                     want_device_binning)
+                    if want_device_binning(self.num_data, F):
+                        fast = device_bin_dense(
+                            data, self.bin_mappers, self.used_features, dtype)
+                if fast is not None:
+                    self.bins = fast
+                else:
+                    self.bins = np.empty((self.num_data, F), dtype=dtype)
+                    for j, f in enumerate(self.used_features):
+                        self.bins[:, j] = self.bin_mappers[f].values_to_bins(
+                            col_of(f)).astype(dtype)
 
         if self.label is None and not self.params.get("_allow_no_label"):
             raise ValueError("Dataset has no label")

@@ -1419,9 +1419,10 @@ def train(params: Dict, train_set: Dataset, num_boost_round: int = 100,
             return None
         path = checkpoint_path(cfg.output_model, iteration)
         try:
-            write_training_checkpoint(
-                path, booster, callbacks, begin_iteration=cadence_base,
-                end_iteration=end_iteration, params=params)
+            with profiler.span("engine.checkpoint"):
+                write_training_checkpoint(
+                    path, booster, callbacks, begin_iteration=cadence_base,
+                    end_iteration=end_iteration, params=params)
         except OSError as e:
             ckpt_fail_streak += 1
             if final or ckpt_fail_streak >= _CKPT_FAIL_LIMIT:
@@ -1571,7 +1572,8 @@ def train(params: Dict, train_set: Dataset, num_boost_round: int = 100,
                 evals = []
                 need_eval = bool(eval_consumers) or cfg.early_stopping_round > 0
                 if need_eval:
-                    with profiler.phase("eval"):
+                    with profiler.span("engine.eval"), \
+                            profiler.stage("eval"):
                         if cfg.is_provide_training_metric and (
                                 train_metric_consumers or not callbacks_after):
                             evals.extend(booster.eval_train(feval))

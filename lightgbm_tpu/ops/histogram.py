@@ -53,6 +53,8 @@ import jax.numpy as jnp
 
 import numpy as np
 
+from .. import phases, profiler
+
 __all__ = ["build_histograms", "resolve_impl", "pallas_shape_reason",
            "merge_histograms", "HIST_CH"]
 
@@ -80,15 +82,14 @@ def merge_histograms(hist: jax.Array, axis_name: Optional[str],
       feature-block merge. Split finding then runs on the local block
       and winners sync SplitInfo-sized (see tree_builder._sync_best).
 
-    The collective is wrapped in the ``hist_merge`` profiler phase, so
-    trace viewers group its device time and the collective-traffic
+    The collective is staged under the ``hist_merge`` named scope, so
+    the stage map attributes its device time and the collective-traffic
     auditor (parallel/comms.py) can attribute histogram collectives by
     the ``hist_merge`` op-name prefix.
     """
     if axis_name is None or merge in (False, "none", None):
         return hist
-    from .. import profiler
-    with profiler.phase("hist_merge"):
+    with profiler.stage(phases.HIST_MERGE):
         if merge == "reduce_scatter":
             F = hist.shape[1]
             F_pad = -(-F // n_shards) * n_shards
@@ -255,22 +256,21 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
     Returns: [L, F, B, 3] float32 (int32 when gh is int8).
     """
     R, F = bins.shape
-    L = leaf_ids.shape[0]
     B = num_bins
-    quant = gh.dtype == jnp.int8
     if block_rows <= 0:
         block_rows = _pick_block_rows(R, F * B)
     if R % block_rows != 0:
         # fall back: single block (caller should pad; keeps jit legal)
         block_rows = R
-    nb = R // block_rows
-    cdt = jnp.dtype(hist_dtype)
     impl = resolve_impl(impl, B)
 
     if impl == "pallas":
         from .pallas_histogram import build_histograms_pallas
-        bins_p = (jnp.take(bins, row_gather, axis=0)
-                  if row_gather is not None else bins)
+        bins_p = bins
+        if row_gather is not None:
+            with profiler.stage(phases.HIST_GATHER):
+                bins_p = jnp.take(bins, row_gather, axis=0)
+        # stages hist_relayout and hist_kernel inside
         hist = build_histograms_pallas(
             bins_p, gh, row_leaf, leaf_ids, num_bins=B,
             hist_dtype=hist_dtype, num_rows=num_rows)
@@ -282,6 +282,25 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
         # double-count for the latter
         return merge_histograms(hist, axis_name, merge, n_shards)
 
+    # every other formulation is one stage: the block loop over the row
+    # stream IS the kernel (its per-block gather keeps its own name)
+    with profiler.stage(phases.HIST_KERNEL):
+        return _build_histograms_xla(
+            bins, gh, row_leaf, leaf_ids, B, impl, block_rows, hist_dtype,
+            axis_name, merge, n_shards, row_gather, num_rows, init)
+
+
+def _build_histograms_xla(bins, gh, row_leaf, leaf_ids, B, impl, block_rows,
+                          hist_dtype, axis_name, merge, n_shards,
+                          row_gather, num_rows, init):
+    """The native, scatter and matmul formulations of
+    :func:`build_histograms` (same contract; ``impl`` resolved and
+    ``block_rows`` dividing R)."""
+    R, F = bins.shape
+    L = leaf_ids.shape[0]
+    quant = gh.dtype == jnp.int8
+    nb = R // block_rows
+    cdt = jnp.dtype(hist_dtype)
     if impl == "native":
         # the C kernel as an XLA FFI custom call (CPU backend): one
         # sequential pass over the row stream at memory speed — the
@@ -335,7 +354,8 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
         s = i * block_rows
         if row_gather is not None:
             idx = jax.lax.dynamic_slice(row_gather, (s,), (block_rows,))
-            bb = jnp.take(bins, idx, axis=0)
+            with profiler.stage(phases.HIST_GATHER):
+                bb = jnp.take(bins, idx, axis=0)
         else:
             bb = jax.lax.dynamic_slice(bins, (s, 0), (block_rows, F))
         ghb = jax.lax.dynamic_slice(gh, (s, 0), (block_rows, HIST_CH))

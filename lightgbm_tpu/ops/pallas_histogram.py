@@ -69,6 +69,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import phases, profiler
 from .histogram import HIST_CH, pallas_shape_reason
 from . import split as _split
 
@@ -82,6 +83,11 @@ __all__ = ["build_histograms_pallas", "fused_build_best_splits",
 FUSED_SPLIT_TPU_REASON = (
     "fused split epilogue does not lower on TPU (Unimplemented primitive "
     "in Pallas TPU lowering for KernelType.TC: cumsum)")
+
+# The histogram kernel's custom call is named after this in the compiled
+# module (``%pallas_hist_kernel.N``), whatever jit wraps it: a trace
+# reader can find the kernel by a name that no refactoring moves.
+HIST_KERNEL_NAME = "pallas_hist_kernel"
 
 _FB_CAP = 2048            # one-hot rows (fc * Bp) per feature chunk
 _VMEM_BUDGET = 24 << 20   # what _plan sizes the row block against
@@ -306,25 +312,31 @@ def build_histograms_pallas(bins: jax.Array, gh: jax.Array,
     r_pad = _ceil_to(R, blk)
     live = _live_rows(num_rows, R)
     fb = fc * Bp
-    out = pl.pallas_call(
-        functools.partial(_accumulate_step, Bp=Bp, cdt=cdt, acc_dt=acc_dt,
-                          blk=blk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_fb, r_pad // blk),
-            in_specs=_stream_specs(fc, blk, lanes),
-            out_specs=pl.BlockSpec((fb, lanes), lambda i, j, s: (i, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (n_fb * fb, lanes), acc_dt,
-            vma=_out_vma(bins, gh, row_leaf, leaf_ids, live)),
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-    )(live, _bins_chunks(bins, r_pad, fc, n_fb),
-      _rows_to_lanes(gh.astype(acc_dt), r_pad), _leaf_lanes(row_leaf, r_pad),
-      _slot_cols(leaf_ids, lanes))
-    return _unpack_hist(out, F=F, B=B, L=L, fc=fc, n_fb=n_fb, Bp=Bp,
-                        lanes=lanes)
+    with profiler.stage(phases.HIST_RELAYOUT):
+        # cast, pad and transpose of the row streams: rows onto lanes
+        operands = (_bins_chunks(bins, r_pad, fc, n_fb),
+                    _rows_to_lanes(gh.astype(acc_dt), r_pad),
+                    _leaf_lanes(row_leaf, r_pad),
+                    _slot_cols(leaf_ids, lanes))
+    with profiler.stage(phases.HIST_KERNEL):
+        out = pl.pallas_call(
+            functools.partial(_accumulate_step, Bp=Bp, cdt=cdt,
+                              acc_dt=acc_dt, blk=blk),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(n_fb, r_pad // blk),
+                in_specs=_stream_specs(fc, blk, lanes),
+                out_specs=pl.BlockSpec((fb, lanes), lambda i, j, s: (i, 0)),
+            ),
+            out_shape=jax.ShapeDtypeStruct(
+                (n_fb * fb, lanes), acc_dt,
+                vma=_out_vma(bins, gh, row_leaf, leaf_ids, live)),
+            compiler_params=_compiler_params(),
+            interpret=interpret,
+            name=HIST_KERNEL_NAME,
+        )(live, *operands)
+        return _unpack_hist(out, F=F, B=B, L=L, fc=fc, n_fb=n_fb, Bp=Bp,
+                            lanes=lanes)
 
 
 # ---------------------------------------------------------------------------
@@ -561,6 +573,7 @@ def fused_build_best_splits(bins: jax.Array, gh: jax.Array,
     cand_o = pl.BlockSpec((l_rec, _REC_LANES), lambda i, j, s: (i, 0))
     outs = pl.pallas_call(
         kern,
+        name="pallas_fused_split_kernel",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_fb, n_rb),

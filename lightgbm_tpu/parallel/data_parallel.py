@@ -59,7 +59,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.split import SplitParams
-from ..boosting.tree_builder import build_tree, TreeArrays
+from ..boosting.tree_builder import RoundLog, build_tree, TreeArrays
 
 __all__ = ["make_mesh", "shard_rows", "replicate", "build_tree_dp",
            "resolve_hist_merge",
@@ -458,7 +458,8 @@ def _build_tree_fp_jit(mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
         in_specs=(mat_spec, fsh2, rep, rep, rep, rep, rep, rep,
                   fsh, fsh, fsh, fsh, fsh, rep, valid_in_specs,
                   extras_specs),
-        out_specs=(tree_specs, rep, tuple([rep] * n_valid)),
+        out_specs=(tree_specs, rep, tuple([rep] * n_valid),
+                   RoundLog(rep, rep)),
         check_vma=False)
     return fn(bins_p, bins_p, gh, row_leaf0, num_bins_p, nan_bin_p,
               is_cat_p, fmask_p, num_bins_p, nan_bin_p, is_cat_p, fmask_p,
@@ -490,7 +491,7 @@ def _build_tree_dp_jit(mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
         vbins = tuple(vflat[:n_valid])
         vrl = tuple(vflat[n_valid:])
         mono, groups, key, bmeta, qs, csm = extra
-        return build_tree(
+        tree, rl_out, vrl_out, rounds = build_tree(
             b, g, rl, nbpf, nanpf, catpf, fmask,
             num_leaves=num_leaves, leaf_batch=leaf_batch,
             max_depth=max_depth, num_bins=num_bins,
@@ -506,6 +507,10 @@ def _build_tree_dp_jit(mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
             cat_sorted_mask=csm, forced=forced, hist_sub=hist_sub,
             hist_merge=hist_merge, n_shards=n_shards,
             class_batched=class_batched)
+        # each shard counts its own row stream: a shard axis of one,
+        # laid out along the mesh axis ([.., n_shards, rounds] outside)
+        return tree, rl_out, vrl_out, rounds._replace(
+            rows=rounds.rows[..., None, :])
 
     tree_specs = jax.tree.map(lambda _: rep, TreeArrays(
         *([0] * len(TreeArrays._fields))))
@@ -527,11 +532,14 @@ def _build_tree_dp_jit(mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
     gh_spec = P(None, axis_name, None) if class_batched else row2
     rl_spec = P(None, axis_name) if class_batched else row
     out_valid_specs = tuple([rl_spec] * n_valid)
+    rounds_specs = RoundLog(
+        rows=P(None, axis_name, None) if class_batched
+        else P(axis_name, None), leaves=rep)
     fn = jax.shard_map(
         step, mesh=mesh,
         in_specs=(row2, gh_spec, row, rep, rep, rep, rep, valid_in_specs,
                   extras_specs),
-        out_specs=(tree_specs, rl_spec, out_valid_specs),
+        out_specs=(tree_specs, rl_spec, out_valid_specs, rounds_specs),
         check_vma=not rs)
     return fn(bins, gh, row_leaf0, num_bins_pf, nan_bin_pf, is_cat_pf,
               feature_mask, valid_flat, extras)
@@ -557,7 +565,9 @@ def build_tree_dp(mesh: Mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
 
     Same contract as :func:`..boosting.tree_builder.build_tree`; the
     returned TreeArrays are replicated (identical on every chip), the
-    returned row→leaf assignments stay row-sharded. ``hist_merge``
+    returned row→leaf assignments stay row-sharded, and the RoundLog's
+    ``rows`` are per shard, ``[n_shards, rounds]`` along the mesh axis
+    (no collective is added for them). ``hist_merge``
     selects the histogram merge collective (module docstring).
 
     ``class_batched``: grow all K per-class trees in one call — ``gh``

@@ -2,9 +2,10 @@
 
 Three layers are coupled through these strings:
 
-1. ``profiler.phase`` emits them as TraceAnnotation spans and
-   ``jax.named_scope`` prefixes, so every XLA op staged under a phase
-   carries ``<name>/`` in its HLO ``op_name`` metadata;
+1. ``profiler.phase`` (around eager dispatches: a host span and a
+   ``jax.named_scope``) and ``profiler.stage`` (inside traced code: the
+   ``jax.named_scope`` alone) emit them, so every XLA op staged under a
+   phase carries ``<name>/`` in its HLO ``op_name`` metadata;
 2. the collective-traffic auditor (``parallel/comms.py``) attributes
    histogram traffic by searching compiled-HLO op names for
    :data:`HIST_MERGE` / :data:`WINNER_SYNC`;
@@ -15,7 +16,7 @@ Three layers are coupled through these strings:
 Before this module the names were retyped string literals in each
 layer, so renaming a phase at an emission site silently broke the
 auditors' attribution (they would just stop matching). Now the emission
-side (``profiler.phase``) asserts membership in :data:`KNOWN_PHASES` at
+side (``profiler.phase`` / ``profiler.stage``) asserts membership in :data:`KNOWN_PHASES` at
 annotation time, and every consumer imports the constant instead of
 retyping it — a rename is a one-line change here or an immediate
 ValueError, never a silent attribution miss.
@@ -25,8 +26,11 @@ from __future__ import annotations
 
 __all__ = ["GRADS", "SAMPLING", "BUILD", "UPDATE", "EVAL",
            "INGEST_SKETCH", "INGEST_WRITE", "PREFETCH",
-           "HIST_MERGE", "WINNER_SYNC", "TRAIN_PHASES",
-           "INGEST_PHASES", "COLLECTIVE_PHASES", "KNOWN_PHASES"]
+           "HIST_MERGE", "WINNER_SYNC", "ROOT_PASS", "POP", "APPLY",
+           "COUNT", "COMPACT", "HIST_GATHER", "HIST_RELAYOUT",
+           "HIST_KERNEL", "SUBTRACT", "FIND", "TRAIN_PHASES",
+           "INGEST_PHASES", "COLLECTIVE_PHASES", "BUILD_STAGES",
+           "KNOWN_PHASES", "HOST_SPANS"]
 
 # training phases (both drivers, boosting/gbdt.py + engine.train's eval)
 GRADS = "grads"
@@ -48,7 +52,40 @@ PREFETCH = "prefetch"
 HIST_MERGE = "hist_merge"
 WINNER_SYNC = "winner_sync"
 
+# stages of one tree build, nested under ``build`` (named after the
+# steps of boosting/tree_builder.py's docstring and of the histogram
+# wrapper in ops/histogram.py). Inside the compiled step they are
+# ``jax.named_scope`` prefixes and nothing else; the deepest one on an
+# instruction's ``op_name`` path is its stage
+# (telemetry/costmodel.instruction_phase_map), which is how a device
+# event inside the grow ``while`` gets a source line.
+ROOT_PASS = "root_pass"          # root histogram, totals, root split
+POP = "pop"                      # top-k over cached gains + their takes
+APPLY = "apply"                  # tree scatter, bounds, row_leaf relabel
+COUNT = "count"                  # segment_sum of raw child counts
+COMPACT = "compact"              # is_small lut, cumsum, c_idx, rl_c, gh
+HIST_GATHER = "hist_gather"      # jnp.take(bins, row_gather)
+HIST_RELAYOUT = "hist_relayout"  # cast, pad, transpose for the kernel
+HIST_KERNEL = "hist_kernel"      # the pallas_call (or the XLA block loop)
+SUBTRACT = "subtract"            # parent minus child, cache scatters
+FIND = "find"                    # best_for / fused split + cache scatter
+
+# host spans of the span record (profiler.span): boundaries that happen
+# once a tree or more rarely. Each is also a TraceAnnotation named
+# ``lgbtpu:<name>`` in a profiler capture.
+HOST_SPANS = frozenset({
+    "dataset.fit_bins", "dataset.apply_bins",      # Dataset.construct
+    "gbdt.to_device",      # H2D of bins, row_leaf0, labels, weights
+    "gbdt.step_ready",     # first call of the fused step: trace..compile
+    "gbdt.dispatch",       # each fused dispatch
+    "gbdt.sync.wait",      # the device_get of the pending ring
+    "gbdt.sync.trees",     # host Trees from the fetched ring
+    "engine.eval", "engine.checkpoint"})
+
 TRAIN_PHASES = frozenset({GRADS, SAMPLING, BUILD, UPDATE, EVAL})
 INGEST_PHASES = frozenset({INGEST_SKETCH, INGEST_WRITE, PREFETCH})
 COLLECTIVE_PHASES = frozenset({HIST_MERGE, WINNER_SYNC})
-KNOWN_PHASES = TRAIN_PHASES | INGEST_PHASES | COLLECTIVE_PHASES
+BUILD_STAGES = frozenset({ROOT_PASS, POP, APPLY, COUNT, COMPACT,
+                          HIST_GATHER, HIST_RELAYOUT, HIST_KERNEL,
+                          SUBTRACT, FIND}) | COLLECTIVE_PHASES
+KNOWN_PHASES = TRAIN_PHASES | INGEST_PHASES | BUILD_STAGES

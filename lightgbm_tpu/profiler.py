@@ -1,4 +1,4 @@
-"""Profiling / tracing hooks.
+"""Profiling / tracing hooks, and the program's one span record.
 
 Analog of the reference timing instrumentation (``Common::Timer`` /
 ``FunctionTimer``, common.h:973,1037, compiled under TIMETAG) — on TPU
@@ -10,62 +10,62 @@ Workflow::
 
     with lightgbm_tpu.profiler.trace("/tmp/tb"):
         lgb.train(params, ds, 100)
-    # then: tensorboard --logdir /tmp/tb  (Profile tab), or pass
-    # create_perfetto_link=True for a one-shot Perfetto URL.
+    # then: python -m lightgbm_tpu monitor --perf /tmp/tb
 
-What the trace attributes, per layer:
+Three kinds of marker, one name set (``phases.py``):
 
-- ``boost_iter`` step markers (engine.train) delimit iterations, so the
-  trace viewer's step table gives ms/tree directly.
-- Training phases — ``grads`` / ``sampling`` / ``build`` / ``update`` /
-  ``eval`` — are emitted through :func:`phase` by BOTH training drivers
-  (boosting/gbdt.py):
+- :func:`stage` — inside traced code (the fused step, the tree builder,
+  the histogram wrapper): a ``jax.named_scope`` and nothing else. It
+  costs nothing at run time and changes no fusion; it puts ``<name>/``
+  on the ``op_name`` path of every instruction staged under it. A device
+  event carries no scope on a TPU (its name is the instruction's text),
+  so the road from an event to its stage is the instruction map built
+  from the compiled module (``telemetry/costmodel.instruction_phase_map``
+  and ``telemetry/xprof.py``).
+- :func:`phase` — around the eager dispatches of the legacy driver,
+  engine eval, ingest and prefetch: the named scope AND a host
+  :func:`span` of the same name, so a per-phase dispatch is timed on
+  the host and its device ops can be attributed by overlap.
+- :func:`span` — a host boundary that happens once a tree or more
+  rarely (``gbdt.dispatch``, ``gbdt.sync.wait``, ``engine.eval``, ...;
+  the list is in PERF.md section 3). Never called from traced code and
+  never per round or per row block.
 
-  * the legacy loop runs one dispatch per phase, so each phase shows up
-    as a host ``TraceAnnotation`` span wrapping its dispatch + wait;
-  * the fused single-dispatch step traces the phases as
-    ``jax.named_scope`` prefixes, so every XLA op inside the one fused
-    program carries its phase in the op name ("grads/...",
-    "build/...") and the trace viewer's op table groups device time by
-    phase even though the host sees a single dispatch.
+Every :func:`span` lands in :data:`recorder`, a bounded in-memory ring
+of ``(name, start_ns, end_ns, parent, iteration)`` that is always on,
+and is also a ``TraceAnnotation`` named ``lgbtpu:<name>``, so it lies
+in the profiler's host plane beside the device's events.
+``start_ns``/``end_ns`` are ``time.time_ns()``: the clock the xplane's
+events are on once ``profile_start_time`` is added to them.
 
-  Metric evaluation at eval-cadence points is wrapped in the ``eval``
-  phase by engine.train.
-
-- Collective phases — ``hist_merge`` wraps the cross-chip histogram
-  merge (psum or psum_scatter, ops/histogram.merge_histograms) and
-  ``winner_sync`` the SplitInfo-sized best-split merge
-  (tree_builder._sync_best). Besides grouping device time in trace
-  viewers, these names reach the compiled HLO as op-name prefixes,
-  which is how the collective-traffic auditor (parallel/comms.py) and
-  the trace doctor (analysis/hlo_lint.py) attribute a program's
-  collectives. The canonical name set lives in ``phases.py``;
-  :func:`phase` asserts membership at annotation time, so a renamed
-  phase is an immediate ValueError instead of a silent attribution
-  miss in the auditors.
-
-- Wall-clock phase TOTALS: :func:`collect_phase_totals` aggregates
-  every :func:`phase` span inside a block into per-phase (total
-  seconds, span count). Span COUNTS are driver- and knob-dependent —
-  the legacy multiclass loop fires ``build`` K times per iteration
-  where the class-batched build fires it once — so comparisons
-  before/after ``class_batch`` (or across drivers) must use the
-  per-iteration totals, which is exactly what
-  :meth:`PhaseTotals.per_iteration` reports.
+:class:`PhaseTotals` (per-name seconds and counts of a stretch of the
+ring) is what ``collect_phase_totals``, the telemetry session's
+``iteration`` event and ``train_phase_seconds_total`` read. Span COUNTS
+are driver- and knob-dependent — the legacy multiclass loop fires
+``build`` K times per iteration where the class-batched build fires it
+once — so comparisons use :meth:`PhaseTotals.per_iteration`. Under the
+fused driver the ring holds ``gbdt.dispatch`` / ``gbdt.sync.*`` and no
+per-phase span: the phases of the one program are device time, read
+from a trace.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Tuple
 
-from .phases import KNOWN_PHASES
+from .phases import HOST_SPANS, KNOWN_PHASES
 
-__all__ = ["trace", "step_annotation", "annotate", "phase",
-           "PhaseTotals", "collect_phase_totals",
-           "add_phase_collector", "remove_phase_collector"]
+__all__ = ["trace", "step_annotation", "annotate", "stage",
+           "stage_sequence", "phase", "span", "Span",
+           "SpanRecorder", "recorder", "PhaseTotals",
+           "collect_phase_totals", "ANNOTATION_PREFIX"]
+
+ANNOTATION_PREFIX = "lgbtpu:"
+RING_SPANS = 8192
 
 
 @contextlib.contextmanager
@@ -94,134 +94,231 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
-@contextlib.contextmanager
-def phase(name: str) -> Iterator[None]:
-    """Training-phase marker usable from BOTH drivers: emits a host
-    ``TraceAnnotation`` span (meaningful around eager dispatches — the
-    legacy loop, engine eval) AND a ``jax.named_scope`` so ops staged
-    inside an ambient trace (the fused step) carry ``name/`` as an op
-    prefix the profiler groups by.
+# ----------------------------------------------------------------------
+# The span record
 
-    ``name`` must be one of the canonical phases (``phases.py``): the
-    collective auditors attribute HLO traffic by these strings, so an
-    unknown name would emit spans nothing downstream can account for.
-    """
+class Span(NamedTuple):
+    name: str
+    start_ns: int        # time.time_ns()
+    end_ns: int
+    parent: str          # name of the enclosing span on this thread, or ""
+    iteration: int       # recorder.iteration when the span closed
+    seq: int             # position in the recorder's whole history
+    fields: Dict[str, Any]
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-9
+
+
+class SpanRecorder:
+    """Bounded ring of closed spans. ``seq`` counts every span ever
+    recorded, so a reader that remembers the last ``seq`` it saw gets
+    exactly the new ones from :meth:`since` (or knows, from a gap, that
+    the ring wrapped under it)."""
+
+    def __init__(self, capacity: int = RING_SPANS):
+        self._ring: collections.deque = collections.deque(maxlen=capacity)
+        self._lock = threading.Lock()
+        self._local = threading.local()
+        self.seq = 0
+        self.iteration = 0       # set by the driver at each dispatch
+
+    def _stack(self) -> List[str]:
+        st = getattr(self._local, "stack", None)
+        if st is None:
+            st = self._local.stack = []
+        return st
+
+    def record(self, name: str, start_ns: int, end_ns: int,
+               parent: str = "", fields: Optional[dict] = None) -> Span:
+        with self._lock:
+            sp = Span(name, int(start_ns), int(end_ns), parent,
+                      self.iteration, self.seq, fields or {})
+            self.seq += 1
+            self._ring.append(sp)
+        return sp
+
+    def since(self, seq: int = 0) -> List[Span]:
+        """Spans with ``span.seq >= seq`` that the ring still holds."""
+        with self._lock:
+            return [s for s in self._ring if s.seq >= seq]
+
+    def spans(self, name: Optional[str] = None) -> List[Span]:
+        with self._lock:
+            return [s for s in self._ring if name is None or s.name == name]
+
+    def __len__(self) -> int:
+        return len(self._ring)
+
+
+recorder = SpanRecorder()
+
+
+@contextlib.contextmanager
+def span(name: str, **fields) -> Iterator[Dict[str, Any]]:
+    """Host span: one record in :data:`recorder` and one
+    ``TraceAnnotation`` named ``lgbtpu:<name>``. Yields the span's
+    ``fields`` dict, which the body may add to. ``name`` is one of
+    ``phases.HOST_SPANS`` or a canonical phase: the span sites are a
+    fixed list, so a reader of the ring knows every name it can meet."""
+    if name not in HOST_SPANS:
+        _check_phase(name)
+    import jax
+    stack = recorder._stack()
+    parent = stack[-1] if stack else ""
+    stack.append(name)
+    t0 = time.time_ns()
+    try:
+        with jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name):
+            yield fields
+    finally:
+        t1 = time.time_ns()
+        stack.pop()
+        recorder.record(name, t0, t1, parent, fields)
+
+
+def _check_phase(name: str) -> None:
     if name not in KNOWN_PHASES:
         raise ValueError(
             f"unknown profiler phase {name!r}; canonical phases are "
             f"{sorted(KNOWN_PHASES)} (lightgbm_tpu/phases.py — add new "
             "phases there so the HLO auditors keep attributing them)")
+
+
+def stage(name: str):
+    """Stage marker for TRACED code: a ``jax.named_scope`` and nothing
+    else. ``name`` must be canonical (``phases.py``): the stage map and
+    the collective auditors attribute instructions by these strings."""
+    _check_phase(name)
     import jax
-    cols = _COLLECTORS
-    t0 = time.perf_counter() if cols else 0.0
-    try:
-        with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
-            yield
-    finally:
-        if cols:
-            dt = time.perf_counter() - t0
-            for col in cols:
-                col._record(name, dt)
+    return jax.named_scope(name)
+
+
+class stage_sequence:
+    """Consecutive stages of one traced function, for code whose steps
+    follow each other in one long body: ``stg(name)`` leaves the stage
+    that is open and enters ``name``; leaving the ``with`` closes the
+    last one (on an exception too, so the thread's name stack is never
+    left extended)::
+
+        with profiler.stage_sequence() as stg:
+            stg(phases.POP); ...
+            stg(phases.APPLY); ...
+    """
+
+    def __init__(self):
+        self._open = None
+
+    def __enter__(self) -> "stage_sequence":
+        return self
+
+    def __call__(self, name: str) -> None:
+        self._close()
+        cm = stage(name)
+        cm.__enter__()
+        self._open = cm
+
+    def _close(self) -> None:
+        cm, self._open = self._open, None
+        if cm is not None:
+            cm.__exit__(None, None, None)
+
+    def __exit__(self, *exc) -> None:
+        self._close()
+
+
+@contextlib.contextmanager
+def phase(name: str) -> Iterator[None]:
+    """Phase marker around EAGER dispatches (the legacy loop, engine
+    eval, ingest, prefetch): a host :func:`span` named ``name`` plus the
+    ``jax.named_scope`` of :func:`stage`, so whatever is traced inside
+    carries the phase too. Inside traced code use :func:`stage`: a host
+    clock there would time the tracing, not the phase."""
+    with stage(name), span(name):
+        yield
 
 
 # ----------------------------------------------------------------------
-# Aggregated per-phase wall-clock totals.
+# Per-phase totals of a stretch of the ring.
 #
 # The raw spans are NOT comparable across drivers or across the
 # class_batch knob: the legacy loop fires ``build``/``update`` once per
-# class per iteration (K spans), the class-batched build exactly once,
-# and the fused step stages phases inside one dispatch (its host spans
-# measure trace/dispatch cost, not device time). Aggregating to
-# per-phase TOTALS per run keeps before/after timings comparable — the
-# sum over K unrolled spans lines up against the one batched span.
-
-# Every active collector sees every span (a tuple, swapped atomically
-# under the GIL): bench's collect_phase_totals() around lgb.train and
-# the telemetry session's collector inside it both need the spans —
-# a single-slot design would make the inner one steal from the outer.
-_COLLECTORS: Tuple["PhaseTotals", ...] = ()
-
-
-def add_phase_collector(col: "PhaseTotals") -> None:
-    """Register an additional live collector (telemetry session)."""
-    global _COLLECTORS
-    _COLLECTORS = _COLLECTORS + (col,)
-
-
-def remove_phase_collector(col: "PhaseTotals") -> None:
-    global _COLLECTORS
-    _COLLECTORS = tuple(c for c in _COLLECTORS if c is not col)
-
+# class per iteration (K spans), the class-batched build exactly once.
+# Aggregating to per-name TOTALS keeps before/after timings comparable —
+# the sum over K unrolled spans lines up against the one batched span.
 
 class PhaseTotals:
-    """Per-phase aggregate of every :func:`phase` span inside a
-    :func:`collect_phase_totals` block: total seconds and span count
-    per phase name, plus the span count of the most-hit phase per
-    ``boost_iter`` when the caller reports iterations."""
+    """Seconds and span counts by name, over the spans the recorder saw
+    from this object's creation on (until :meth:`close`). It reads the
+    ring when asked and remembers the last ``seq`` it folded in, so it
+    stays exact as long as it is asked at least once per ring length of
+    spans (the telemetry session asks at every sync)."""
 
-    def __init__(self):
+    def __init__(self, rec: Optional[SpanRecorder] = None):
+        self._rec = recorder if rec is None else rec
+        self._next = self._rec.seq
+        self._stop: Optional[int] = None
         self._acc: Dict[str, List[float]] = {}
-        # spans arrive from any thread that annotates — the training
-        # loop, serving threads, the telemetry HTTP server. The += on
-        # the accumulator list is a read-modify-write, NOT atomic under
-        # the GIL (the interpreter can switch between the read and the
-        # store), so concurrent spans would silently drop time.
         self._lock = threading.Lock()
 
-    def _record(self, name: str, dt: float) -> None:
+    def _fold(self) -> None:
         with self._lock:
-            ent = self._acc.setdefault(name, [0.0, 0])
-            ent[0] += dt
-            ent[1] += 1
+            for sp in self._rec.since(self._next):
+                if self._stop is not None and sp.seq >= self._stop:
+                    break
+                ent = self._acc.setdefault(sp.name, [0.0, 0])
+                ent[0] += sp.seconds
+                ent[1] += 1
+                self._next = sp.seq + 1
+
+    def close(self) -> None:
+        """Fold what is there and take no later span."""
+        self._fold()
+        self._stop = self._rec.seq
 
     def total_s(self, name: str) -> float:
-        with self._lock:
-            return self._acc.get(name, [0.0, 0])[0]
+        self._fold()
+        return self._acc.get(name, [0.0, 0])[0]
 
     def count(self, name: str) -> int:
-        with self._lock:
-            return int(self._acc.get(name, [0.0, 0])[1])
+        self._fold()
+        return int(self._acc.get(name, [0.0, 0])[1])
 
     def items(self) -> List[Tuple[str, float, int]]:
-        with self._lock:
-            return [(k, v[0], int(v[1]))
-                    for k, v in sorted(self._acc.items())]
+        self._fold()
+        return [(k, v[0], int(v[1])) for k, v in sorted(self._acc.items())]
 
     def per_iteration(self, iterations: int) -> Dict[str, dict]:
-        """{phase: {total_s, count, s_per_iter, spans_per_iter}} —
+        """{name: {total_s, count, s_per_iter, spans_per_iter}} —
         ``s_per_iter`` is the comparable number: the K unrolled
         ``build`` spans of one legacy multiclass iteration and the one
         class-batched span both aggregate to that iteration's build
         seconds."""
         it = max(int(iterations), 1)
-        with self._lock:
-            return {k: {"total_s": v[0], "count": int(v[1]),
-                        "s_per_iter": v[0] / it,
-                        "spans_per_iter": v[1] / it}
-                    for k, v in sorted(self._acc.items())}
+        return {k: {"total_s": tot, "count": cnt, "s_per_iter": tot / it,
+                    "spans_per_iter": cnt / it}
+                for k, tot, cnt in self.items()}
 
     def render(self, iterations: Optional[int] = None) -> str:
         rows = []
         for name, tot, cnt in self.items():
-            line = f"{name:<12} {tot * 1e3:9.2f} ms  x{cnt}"
+            line = f"{name:<16} {tot * 1e3:9.2f} ms  x{cnt}"
             if iterations:
                 line += (f"  ({tot * 1e3 / max(iterations, 1):.2f} "
                          f"ms/iter over {iterations} iter)")
             rows.append(line)
-        return "\n".join(rows) or "(no phase spans recorded)"
+        return "\n".join(rows) or "(no spans recorded)"
 
 
 @contextlib.contextmanager
 def collect_phase_totals() -> Iterator[PhaseTotals]:
-    """Aggregate every :func:`phase` span inside the block into a
-    :class:`PhaseTotals` (opt-in; collectors STACK — a nested block or
-    a live telemetry session each get the same spans). Host-side wall
-    clock: around eager dispatches (legacy driver) the span covers
-    dispatch + device wait; around staged code (inside a trace) it
-    covers trace time only."""
+    """Totals of every span recorded inside the block (any thread).
+    Host-side wall clock: around eager dispatches (legacy driver) a
+    phase span covers dispatch + device wait; the fused driver records
+    ``gbdt.dispatch`` / ``gbdt.sync.*`` and no per-phase span."""
     col = PhaseTotals()
-    add_phase_collector(col)
     try:
         yield col
     finally:
-        remove_phase_collector(col)
+        col.close()

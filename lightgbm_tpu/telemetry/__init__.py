@@ -68,6 +68,8 @@ class TelemetrySession:
         self.device = DeviceWatch(self.registry)
         self.collectives = CollectiveWatch(self.registry,
                                            self._trees_built)
+        # per-name seconds of the span record from begin_run on; read
+        # (and folded forward) at every sync
         self.phase_totals = profiler.PhaseTotals()
         self._booster = None
         self._restore_sig = lambda: None
@@ -92,7 +94,8 @@ class TelemetrySession:
             labels=("data", "metric"))
         self._g_phase = self.registry.gauge(
             "train_phase_seconds_total",
-            "Host wall seconds per training phase (phases.py names)",
+            "Host wall seconds per span of the span record (phases.py: "
+            "legacy-driver phases, gbdt.dispatch, gbdt.sync.*, ...)",
             labels=("phase",))
         self._c_syncs = self.registry.gauge(
             "train_host_syncs_total",
@@ -106,30 +109,13 @@ class TelemetrySession:
         self.registry.gauge("train_uptime_seconds",
                             "Seconds since telemetry start",
                             fn=lambda: time.monotonic() - self._t0)
-        # roofline gauges (ISSUE 11): XLA's cost_analysis price of the
-        # compiled fused step × the measured iteration rate. The cost
-        # report and instruction→phase maps are built ON THE TRAINING
-        # THREAD at the first sync after a scrape asks for them
-        # (lower()-ing the fused jit from the HTTP thread would race a
-        # concurrent dispatch's trace-time attribute rebinding), so the
-        # first scrape reads 0 and arms the want-flag.
+        # instruction→stage maps for /trace captures, built ON THE
+        # TRAINING THREAD at the first sync after a capture asks for
+        # them (lower()-ing the fused jit from the HTTP thread would race
+        # a concurrent dispatch's trace-time attribute rebinding), so the
+        # first capture arms the want-flag and goes without.
         self._perf_want = False
-        self._cost_cache: Any = None      # None | False | CostReport
-        self._phase_maps: Dict[str, Dict[str, str]] = {}
-        self.registry.gauge(
-            "train_fused_flops_per_iter",
-            "XLA cost_analysis flops of one compiled fused step",
-            fn=lambda: self._cost_field("flops"))
-        self.registry.gauge(
-            "train_fused_bytes_per_iter",
-            "XLA cost_analysis bytes accessed of one fused step",
-            fn=lambda: self._cost_field("bytes_accessed"))
-        self._g_tflops = self.registry.gauge(
-            "train_achieved_tflops",
-            "Achieved TFLOP/s: fused-step flops x iteration rate")
-        self._g_mfu = self.registry.gauge(
-            "train_mfu",
-            "Achieved TFLOP/s vs chip peak (known TPU chips only)")
+        self._phase_maps: Optional[Dict[str, Any]] = None
 
     @classmethod
     def from_config(cls, cfg, params: Dict[str, Any]
@@ -167,52 +153,25 @@ class TelemetrySession:
         gb = self._gb()
         return int(getattr(gb, "host_sync_count", 0)) if gb else 0
 
-    # -- cost model / phase maps (built at sync points only) ----------
-    def _cost_field(self, attr: str) -> float:
-        """Gauge fn: read the cached fused-step CostReport, arming the
-        want-flag on a miss (next on_sync builds; scrapes never
-        compile)."""
-        rep = self._cost_cache
-        if rep is None:
+    # -- stage maps (built at sync points only) ------------------------
+    def phase_maps(self) -> Dict[str, Any]:
+        """Instruction→stage maps for trace captures: cached-or-arm,
+        never built off the training thread."""
+        if self._phase_maps is None:
             self._perf_want = True
-        return float(getattr(rep, attr, 0.0) or 0.0) if rep else 0.0
-
-    def phase_maps(self) -> Dict[str, Dict[str, str]]:
-        """Instruction→phase maps for trace captures. Same contract as
-        the gauges: cached-or-arm, never build off the training
-        thread."""
-        if not self._phase_maps:
-            self._perf_want = True
-        return dict(self._phase_maps)
+        return dict(self._phase_maps or {})
 
     def _build_perf(self) -> None:
-        """Build the fused-step CostReport + phase maps (training
-        thread, at a sync point). force=False: uses the driver's
-        already-traced jit, refuses to trigger a fresh trace."""
+        """Build the fused step's stage map (training thread, at a sync
+        point). force=False: uses the driver's already-traced jit,
+        refuses to trigger a fresh trace."""
         from . import costmodel
+        self._phase_maps = {}
         try:
-            compiled = costmodel.fused_compiled(self._booster,
-                                                force=False)
+            self._phase_maps = costmodel.booster_phase_maps(
+                self._booster, force=False)
         except Exception:  # noqa: BLE001 — perf extras never fault a run
-            compiled = None
-        if compiled is None:
-            self._cost_cache = False
-            return
-        try:
-            text = compiled.as_text()
-            self._cost_cache = costmodel.cost_report(
-                compiled, "fused_step", hlo_text=text)
-            mod, table = costmodel.instruction_phase_map(text)
-            if table:
-                self._phase_maps = {mod: table}
-            if self.events is not None:
-                rep = self._cost_cache
-                self.events.append(
-                    "cost_model", label="fused_step",
-                    flops=rep.flops, bytes_accessed=rep.bytes_accessed,
-                    peak_bytes=rep.peak_bytes, n_ops=rep.n_ops)
-        except Exception:  # noqa: BLE001
-            self._cost_cache = False
+            pass
 
     # -- lifecycle (engine.train) --------------------------------------
     def begin_run(self, booster, cfg, params: Dict[str, Any],
@@ -237,7 +196,7 @@ class TelemetrySession:
                 self.events.append("resume", iter=resumed_from[1],
                                    path=resumed_from[0])
             _events.set_active(self.events)
-        profiler.add_phase_collector(self.phase_totals)
+        self.phase_totals = profiler.PhaseTotals()
         self.device.start()
         self.device.sample()
         if self._want_port is not None:
@@ -331,16 +290,8 @@ class TelemetrySession:
         self._g_iter.set(iteration)
         if d_iter > 0:
             self._g_ms_tree.set(ms_tree)
-        if self._perf_want and self._cost_cache is None:
+        if self._perf_want and self._phase_maps is None:
             self._build_perf()
-        rep = self._cost_cache
-        if rep and d_iter > 0 and ms_tree > 0:
-            achieved = rep.flops / (ms_tree / 1e3) / 1e12
-            self._g_tflops.set(achieved)
-            from .costmodel import chip_peaks
-            peaks = chip_peaks()
-            if peaks is not None:
-                self._g_mfu.set(achieved / peaks.bf16_tflops)
         for (name, metric), value in [((n, m), v) for n, m, v, _ in
                                       (evals or [])]:
             self._g_metric.labels(name, metric).set(value)
@@ -411,7 +362,7 @@ class TelemetrySession:
             self.server.stop()
             self.server = None
         if self._started:
-            profiler.remove_phase_collector(self.phase_totals)
+            self.phase_totals.close()
             self.device.stop()
             self._started = False
         if self.events is not None:

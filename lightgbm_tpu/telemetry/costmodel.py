@@ -25,10 +25,10 @@ import re
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 from ..analysis.hlo_walk import parse_all_ops
-from .xprof import UNKNOWN, phase_of_path
+from .xprof import UNKNOWN, stage_of_path
 
 __all__ = ["TPU_PEAKS", "ChipPeaks", "HIST_CH", "CostReport", "cost_report",
-           "instruction_phase_map", "module_name",
+           "instruction_phase_map", "StageMap", "module_name",
            "fused_compiled", "booster_phase_maps",
            "staged_cost_reports", "analytical_hist_counts",
            "analytical_build_split_counts",
@@ -245,7 +245,7 @@ def cost_report(compiled, label: str = "program",
     phase_ops: Dict[str, int] = {}
     phase_bytes: Dict[str, int] = {}
     n_ops = 0
-    for op, _comp, ph in _resolved_phases(hlo_text or ""):
+    for op, _comp, ph in _resolved_phases(hlo_text or "")[0]:
         if op.opcode in _NOOP_OPCODES:
             continue
         n_ops += 1
@@ -269,25 +269,34 @@ def cost_report(compiled, label: str = "program",
 
 
 # ----------------------------------------------------------------------
-# Instruction → phase maps (xprof attribution path 2)
+# Instruction → stage maps (the road from a device event to its source)
 
 def module_name(hlo_text: str) -> str:
     m = _MODULE_RE.search(hlo_text or "")
     return m.group(1) if m else ""
 
 
-def _resolved_phases(hlo_text: str):
-    """[(HloOp, computation, phase-or-None)] with hierarchical phase
-    resolution: an instruction's own ``op_name`` metadata wins; an
-    unannotated fusion/call takes the dominant phase of the computation
-    it calls; remaining compiler-generated plumbing (loop-carry copies,
-    induction arithmetic — XLA strips their metadata) inherits the
-    dominant phase of its enclosing computation. The hierarchy matters
-    on CPU, where while-loop-body micro-ops execute hundreds of
-    thousands of times and would otherwise all land in ``unknown``."""
+class StageMap(NamedTuple):
+    """What ``instruction_phase_map`` knows about one compiled module."""
+    module: str
+    stages: Dict[str, str]    # instruction name -> canonical stage
+    scopes: Dict[str, str]    # instruction name -> its op_name path
+    mixed_fusions: int        # fusions whose fused instructions disagree
+
+
+class _Instr(NamedTuple):
+    op: Any                   # hlo_walk.HloOp
+    comp: str                 # enclosing computation
+    callee: Optional[str]     # computation a fusion/call/while runs
+    operands: Tuple[str, ...]
+
+
+_OPERAND_RE = re.compile(r"%([\w.-]+)")
+
+
+def _instructions(hlo_text: str) -> List[_Instr]:
     comp = ""
-    rows: List[Tuple[Any, str, Optional[str], Optional[str]]] = []
-    votes: Dict[str, Dict[str, int]] = {}
+    rows: List[_Instr] = []
     for line in (hlo_text or "").splitlines():
         mc = _COMP_RE.match(line)
         if mc and "= " not in line.split("{")[0]:
@@ -297,34 +306,95 @@ def _resolved_phases(hlo_text: str):
         if not parsed:
             continue
         op = parsed[0]
-        own = phase_of_path(op.op_name)
-        calls = _CALLS_RE.findall(line)
-        rows.append((op, comp, own, calls[-1] if calls else None))
-        if own is not None:
-            v = votes.setdefault(comp, {})
-            v[own] = v.get(own, 0) + 1
-    dominant = {c: max(v, key=v.get) for c, v in votes.items() if v}
-    out = []
-    for op, c, own, callee in rows:
-        ph = own
-        if ph is None and callee is not None:
-            ph = dominant.get(callee)
-        if ph is None:
-            ph = dominant.get(c)
-        out.append((op, c, ph))
-    return out
+        body = line.split(", metadata=", 1)[0]
+        calls = _CALLS_RE.findall(body)
+        rest = body.partition(" " + op.opcode + "(")[2]
+        rows.append(_Instr(op, comp, calls[-1] if calls else None,
+                           tuple(_OPERAND_RE.findall(rest))))
+    return rows
 
 
-def instruction_phase_map(hlo_text: str
-                          ) -> Tuple[str, Dict[str, str]]:
-    """(module name, {instruction name → phase}) — the lookup table the
-    trace parser uses for executor events that name only ``hlo_op``.
-    Phases resolve hierarchically (see :func:`_resolved_phases`)."""
-    table: Dict[str, str] = {}
-    for op, _comp, ph in _resolved_phases(hlo_text):
-        if ph is not None and op.name:
-            table[op.name] = ph
-    return module_name(hlo_text), table
+def _resolved_phases(hlo_text: str):
+    """[(HloOp, computation, stage-or-None)] and the number of mixed
+    fusions. An instruction's stage is the deepest canonical name on its
+    own ``op_name`` path. A fusion is judged by what it fuses: where its
+    fused instructions agree that is the stage (it is also its root's),
+    where they disagree the stage holding most of them, and such fusions
+    are counted. What the compiler added without metadata (copies for a
+    layout, loop-carry plumbing) takes the stage of the instruction that
+    produces its operand, else of the one that uses its result, else of
+    the instruction that runs its computation (the ``while``, the
+    fusion): so a body op is never worse off than its loop."""
+    rows = _instructions(hlo_text)
+    own: Dict[str, Optional[str]] = {}
+    votes: Dict[str, Dict[str, int]] = {}
+    for r in rows:
+        ph = stage_of_path(r.op.op_name)
+        own[r.op.name] = ph
+        if ph is not None and r.op.opcode not in _NOOP_OPCODES:
+            v = votes.setdefault(r.comp, {})
+            v[ph] = v.get(ph, 0) + 1
+    mixed = 0
+    caller_stage: Dict[str, Optional[str]] = {}
+    for r in rows:
+        if r.op.opcode == "fusion" and r.callee in votes:
+            v = votes[r.callee]
+            if len(v) > 1:
+                mixed += 1
+            top = max(v.values())
+            best = [k for k, n in v.items() if n == top]
+            own[r.op.name] = (own[r.op.name] if own[r.op.name] in best
+                              else sorted(best)[0])
+    # producers, then users, then the caller: two sweeps settle chains
+    # like copy-start -> copy-done -> user
+    users: Dict[str, List[str]] = {}
+    for r in rows:
+        for o in r.operands:
+            users.setdefault(o, []).append(r.op.name)
+    for sweep in (rows, rows[::-1]):
+        for r in sweep:
+            if own[r.op.name] is not None or r.op.opcode in _NOOP_OPCODES:
+                continue
+            near = ([own.get(o) for o in r.operands]
+                    + [own.get(u) for u in users.get(r.op.name, ())])
+            own[r.op.name] = next((p for p in near if p is not None), None)
+    for r in rows:
+        if r.callee is not None:
+            caller_stage.setdefault(r.callee, own[r.op.name])
+    changed = True
+    while changed:      # nested callees inherit through their callers
+        changed = False
+        for r in rows:
+            if own[r.op.name] is None:
+                ph = caller_stage.get(r.comp)
+                if ph is not None:
+                    own[r.op.name] = ph
+                    changed = True
+            if r.callee is not None and caller_stage.get(r.callee) is None \
+                    and own[r.op.name] is not None:
+                caller_stage[r.callee] = own[r.op.name]
+                changed = True
+    return [(r.op, r.comp, own[r.op.name]) for r in rows], mixed
+
+
+def instruction_phase_map(hlo_text: str) -> StageMap:
+    """The lookup table from an instruction of the compiled module —
+    entry computation, ``while`` bodies and fusions alike — to the
+    deepest canonical stage on its ``op_name`` path (see
+    :func:`_resolved_phases` for fusions and unannotated plumbing).
+    A device event names its instruction and nothing else, so this map
+    is the only road from the event to a source scope."""
+    stages: Dict[str, str] = {}
+    scopes: Dict[str, str] = {}
+    resolved, mixed = _resolved_phases(hlo_text)
+    for op, _comp, ph in resolved:
+        if not op.name:
+            continue
+        if ph is not None:
+            stages[op.name] = ph
+        if op.op_name:
+            scopes[op.name] = op.op_name
+    return StageMap(module_name(hlo_text), stages, scopes, mixed)
 
 
 # ----------------------------------------------------------------------
@@ -352,7 +422,7 @@ def fused_compiled(bst, *, force: bool = True):
 
 
 def booster_phase_maps(bst, compiled=None, *,
-                       force: bool = True) -> Dict[str, Dict[str, str]]:
+                       force: bool = True) -> Dict[str, "StageMap"]:
     """Phase maps for a trained booster's staged programs (today: the
     fused step — the one whose CPU executor events need the lookup)."""
     if compiled is None:
@@ -362,8 +432,8 @@ def booster_phase_maps(bst, compiled=None, *,
             compiled = None
     if compiled is None:
         return {}
-    mod, table = instruction_phase_map(compiled.as_text())
-    return {mod: table} if table else {}
+    sm = instruction_phase_map(compiled.as_text())
+    return {sm.module: sm} if sm.stages else {}
 
 
 def staged_cost_reports(bst, *,

@@ -3,8 +3,11 @@
 Three gauge groups, all host-side:
 
 - **HBM/memory watermarks** — ``device.memory_stats()`` where the
-  backend provides it (TPU/GPU runtimes report ``bytes_in_use`` /
-  ``peak_bytes_in_use``); the CPU backend reports nothing, so the
+  backend provides it. On a TPU ``peak_bytes_in_use`` counts live
+  buffers only; a running program's temporaries sit in the allocator's
+  reserved pool (``peak_bytes_reserved``), which the program cannot run
+  without, so the chip's high-water mark is the two together (11x the
+  live buffers at Higgs, PERF.md section 3). The CPU backend reports nothing, so the
   fallback is a live-buffer census over ``jax.live_arrays()``
   (addressable shards summed per device). Both are host bookkeeping —
   neither touches device queues, so sampling at sync points or scrape
@@ -35,8 +38,8 @@ __all__ = ["DeviceWatch", "CollectiveWatch", "device_memory_bytes"]
 
 
 def device_memory_bytes() -> Dict[str, Dict[str, int]]:
-    """{device_label: {"bytes_in_use": n, "peak_bytes_in_use": n}} via
-    ``memory_stats()``, falling back to a live-buffer census (peak not
+    """{device_label: {"bytes_in_use": n, "peak_bytes_in_use": n,
+    "peak_bytes_reserved": n}} via ``memory_stats()``, falling back to a live-buffer census (peak not
     tracked by the census itself — DeviceWatch accumulates it)."""
     import jax
     out: Dict[str, Dict[str, int]] = {}
@@ -53,7 +56,9 @@ def device_memory_bytes() -> Dict[str, Dict[str, int]]:
             out[label] = {
                 "bytes_in_use": int(stats["bytes_in_use"]),
                 "peak_bytes_in_use": int(stats.get("peak_bytes_in_use",
-                                                   0))}
+                                                   0)),
+                "peak_bytes_reserved": int(stats.get(
+                    "peak_bytes_reserved", 0))}
         else:
             census_needed.append((d, label))
     if census_needed:
@@ -71,7 +76,8 @@ def device_memory_bytes() -> Dict[str, Dict[str, int]]:
             pass
         for d, label in census_needed:
             out[label] = {"bytes_in_use": int(by_dev.get(d, 0)),
-                          "peak_bytes_in_use": 0}
+                          "peak_bytes_in_use": 0,
+                          "peak_bytes_reserved": 0}
     return out
 
 
@@ -95,8 +101,15 @@ class DeviceWatch:
             "census)", labels=("device",))
         self._peak = registry.gauge(
             "device_hbm_bytes_peak",
-            "Per-device peak bytes observed (runtime watermark, or max "
-            "over samples)", labels=("device",))
+            "Per-device high-water mark: peak live buffers (runtime "
+            "watermark, or max over samples) PLUS the peak reserved "
+            "pool, where a running program's temporaries sit",
+            labels=("device",))
+        self._reserved = registry.gauge(
+            "device_hbm_bytes_reserved_peak",
+            "Per-device peak of the allocator's reserved pool alone "
+            "(peak_bytes_reserved; 0 where the backend reports none)",
+            labels=("device",))
         registry.gauge("xla_compiles_total",
                        "Backend compiles since telemetry start "
                        "(steady state must hold this flat)",
@@ -133,7 +146,10 @@ class DeviceWatch:
                            stats["bytes_in_use"])
                 self._peaks[label] = peak
                 self._in_use.labels(label).set(stats["bytes_in_use"])
-                self._peak.labels(label).set(peak)
+                self._peak.labels(label).set(
+                    peak + stats["peak_bytes_reserved"])
+                self._reserved.labels(label).set(
+                    stats["peak_bytes_reserved"])
         return mem
 
     @property

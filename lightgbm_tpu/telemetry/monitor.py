@@ -5,8 +5,8 @@ schema.
 The offline half of the telemetry subsystem: the event log
 (telemetry/events.py) is what a run leaves behind; this turns it back
 into the operational picture — what the run was (header), how fast it
-went (ms/tree trajectory, per-phase seconds from
-``PhaseTotals.per_iteration``), and what went wrong (preemptions,
+went (ms/tree trajectory, per-span seconds of the span record), and
+what went wrong (preemptions,
 nan-guard trips, rollbacks, routed warnings). ``--check`` validates
 every record against the schema table (``events.EVENT_TYPES``) and the
 ordering invariants (monotone seq, no duplicate iteration records,
@@ -162,18 +162,19 @@ def find_captures(target: str) -> List[str]:
         caps = [c for c in caps if os.path.isdir(c)]
         if caps:
             return caps
-    # a capture dir itself (holds plugins/profile/... trace files)
-    if glob.glob(os.path.join(target, "**", "*.trace.json*"),
-                 recursive=True):
-        return [target]
-    return []
+    # a capture dir itself (holds plugins/profile/... capture files)
+    from .xprof import find_trace_files
+    return [target] if find_trace_files(target) else []
 
 
 def render_perf(capture: str,
                 records: Optional[List[Dict[str, Any]]] = None) -> str:
-    """``monitor --perf``: device-vs-host phase table of one capture
-    (``xprof.parse_trace`` with the saved ``phase_map.json``), crossed
-    against the event log's measured ms/tree when one is available.
+    """``monitor --perf``: one capture reduced by ``xprof.parse_trace``
+    with the saved ``phase_map.json`` — device seconds by stage (a tree,
+    where the capture holds step markers or dispatch spans), the ten
+    longest instructions with stage and source scope, the longest idle
+    gaps with the program span that covers each — crossed against the
+    event log's measured ms/tree when one is available.
 
     The comparison target is the log's UNPROFILED steady-state ms/tree
     — on CPU the per-event tracing tax inflates the profiled wall
@@ -216,11 +217,12 @@ def monitor_main(argv: Optional[List[str]] = None) -> int:
                          "record and the ordering invariants; rc=1 on "
                          "any problem")
     ap.add_argument("--perf", action="store_true",
-                    help="parse the run's profiler captures "
-                         "(<run_dir>/traces/capture_*) into "
-                         "device-vs-host phase tables and compare the "
-                         "fused phase sum against the event log's "
-                         "measured ms/tree")
+                    help="reduce the run's profiler captures "
+                         "(<run_dir>/traces/capture_*, or a profiler "
+                         "log dir) to device seconds by stage, the "
+                         "longest instructions and idle gaps, and "
+                         "compare the stage sum against the event "
+                         "log's measured ms/tree")
     ns = ap.parse_args(argv)
     paths = find_event_logs(ns.target)
     if ns.perf:

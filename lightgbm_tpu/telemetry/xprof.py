@@ -1,47 +1,49 @@
-"""Device-time phase profiles from ``jax.profiler`` captures.
+"""Device time by stage from ``jax.profiler`` captures.
 
-``collect_phase_totals`` (profiler.py) reports host wall-clock; the
-roadmap's kernel work is judged on *device* time. This module parses
-the trace-event JSON a capture leaves behind (``profiler.trace``, the
-``/trace`` endpoint, ``jax.profiler.start_trace``) and attributes
-device op time to the canonical phase set of ``phases.py``, yielding
-numbers comparable across the fused/legacy drivers and serial/mesh
-modes: per-phase device seconds, device-vs-host overlap, and dispatch
-gaps per iteration.
+The span record (profiler.py) is host wall-clock; the roadmap's kernel
+work is judged on *device* time. This module reduces what a capture
+leaves behind (``profiler.trace``, the ``/trace`` endpoint,
+``jax.profiler.start_trace``) to seconds by canonical stage
+(``phases.py``), the longest instructions with their stage and source
+scope, and the longest idle gaps with the program span that covers each.
 
-Attribution runs three paths, in priority order, per device op event:
+There is ONE reduction (:func:`reduce_capture`), over plain event
+lists, with two loaders in front of it:
 
-1. **Name prefix** — on TPU device tracks the op/``long_name`` carries
-   the ``jax.named_scope`` path ("jit(f)/build/one_hot/dot_general"),
-   so the first path component that is a canonical phase wins. This is
-   the zero-setup path on real device timelines.
-2. **Instruction map** — CPU (and some GPU) executor events carry only
-   ``{hlo_module, hlo_op}`` args, no scope prefix. A *phase map* —
-   ``{module_name: {instruction_name: phase}}`` built from the compiled
+- :func:`load_xplane` — the ``.xplane.pb`` the profiler writes (read
+  with ``jax.profiler.ProfileData``). On a TPU the "XLA Ops" line of a
+  device plane holds one event per executed HLO instruction, named by
+  the instruction's TEXT (``%fusion.1 = pred[...] fusion(...)``) and
+  carrying no scope and no ``op_name``; its module is the event of the
+  "XLA Modules" line that encloses it. On a CPU the executor threads of
+  the host plane stand in for a device and name ``hlo_op`` /
+  ``hlo_module`` in their stats.
+- :func:`load_trace_json` — the trace-event JSON of the same capture
+  (``*.trace.json[.gz]``), kept for captures that have no xplane.
+
+Attribution, per device event:
+
+1. **Stage map** — ``{module: StageMap}`` built from the compiled
    module's ``op_name`` metadata (``costmodel.instruction_phase_map``)
-   — recovers the phase. Captures taken through the telemetry server
-   save it as ``phase_map.json`` next to the trace so offline
-   ``monitor --perf`` gets fused-driver attribution for free.
-3. **Host-span overlap** — the legacy driver dispatches one program per
-   phase under a host ``TraceAnnotation`` span, so a device op's time
-   is attributed to whichever host phase span(s) it overlaps.
+   and looked up by (module, instruction name). It is the only road
+   from a device event to a source scope. Captures taken through the
+   telemetry server save it as ``phase_map.json`` next to the trace so
+   offline ``monitor --perf`` has it.
+2. **Host-span overlap** — the legacy driver dispatches one program per
+   phase under a host phase span, so what the map misses is attributed
+   to the host phase span(s) it overlaps.
 
-Anything all three paths miss lands in the explicit ``unknown`` bucket
-— attribution never silently drops device time.
+Anything both miss lands in the explicit ``unknown`` bucket —
+attribution never silently drops device time.
 
-Timestamps in trace-event JSON are microseconds. Device tracks are
-*mostly* flat (one event per op execution), but the CPU runtime also
-emits container events on the same threads — ``ThunkExecutor::
-Execute`` wrapping a whole dispatch, ``while.N``/``call.N`` thunks
-wrapping every body-op execution — so naive duration sums double-count
-(a while loop's time lands once on the while event and again on its
-276k body events). Each thread is therefore processed as a containment
-stack: only *top-level* events count, events covered by an
-already-counted ancestor are skipped, and pure runtime wrappers
-(``ThunkExecutor``) are transparent — never counted themselves, their
-children visible. Counting the ``while.N`` event rather than its body
-ops also captures the loop's intra-body gaps, which is what makes the
-phase sums comparable to wall-clock ``ms_per_tree``.
+Time is *self* time by nesting: an event's duration less that of the
+events nested directly in it, per track, so a ``while`` container keeps
+only its own overhead and its body's instructions are counted where
+they ran. Pure runtime wrappers of the CPU executor
+(``ThunkExecutor::Execute``) are never a stage of their own: a wrapper
+mostly covered by its own thread's ops contributes its remainder
+(inter-thunk scheduling) through path 2; one mostly empty is a
+dispatcher blocking on worker threads and is dropped.
 """
 
 from __future__ import annotations
@@ -51,70 +53,124 @@ import glob
 import gzip
 import json
 import os
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 from ..phases import KNOWN_PHASES
+from ..profiler import ANNOTATION_PREFIX
 
-__all__ = ["PhaseProfile", "parse_trace", "find_trace_files",
-           "load_trace_events", "save_phase_map", "load_phase_map",
-           "find_phase_map", "PHASE_MAP_NAME", "UNKNOWN"]
+__all__ = ["PhaseProfile", "OpEvent", "HostSpan", "Capture", "parse_trace",
+           "reduce_capture", "load_xplane", "load_trace_json",
+           "find_trace_files", "save_phase_map", "load_phase_map",
+           "find_phase_map", "stage_of_path", "instruction_of",
+           "PHASE_MAP_NAME", "UNKNOWN"]
 
 PHASE_MAP_NAME = "phase_map.json"
 UNKNOWN = "unknown"
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+TOP = 10            # entries of a breakdown list
+TEXT_CHARS = 160    # of an instruction's text kept in such a list
+EPS = 1e-9          # seconds; nesting tolerance, as benchmarks/harness/trace
 
 _STEP_NAME = "boost_iter"
+_DISPATCH_SPAN = "gbdt.dispatch"
 
 Interval = Tuple[float, float]
 
 
+class OpEvent(NamedTuple):
+    """One executed instruction (or runtime container) on a device track."""
+    name: str         # instruction text, or its bare name
+    start: float      # seconds on the capture's clock
+    dur: float
+    module: str = ""  # HLO module it belongs to, '' when unknown
+
+
+class HostSpan(NamedTuple):
+    name: str         # 'gbdt.dispatch', 'build', 'boost_iter', ...
+    start: float
+    dur: float
+
+
+class Capture(NamedTuple):
+    tracks: Dict[str, List[OpEvent]]   # device label -> events
+    host_spans: List[HostSpan]         # lgbtpu: spans, phases, step markers
+    n_events: int
+    sources: List[str]
+    epoch_ns: Optional[int] = None     # time.time_ns() of the clock's zero
+
+
 # ----------------------------------------------------------------------
-# Loading
+# Names
+
+def stage_of_path(name: str) -> Optional[str]:
+    """Deepest canonical stage along a scope path:
+    ``jit(f)/build/while/body/compact/hist_gather/gather`` →
+    ``hist_gather``. Only exact components count (``named_scope`` emits
+    the raw string)."""
+    found = None
+    for part in str(name).replace(":", "/").split("/"):
+        if part in KNOWN_PHASES:
+            found = part
+    return found
+
+
+def instruction_of(name: str) -> str:
+    """``%fusion.1 = pred[...] fusion(...)`` → ``fusion.1``; a bare
+    instruction name is returned as it is."""
+    head = name.split(" = ", 1)[0].strip()
+    return head[1:] if head.startswith("%") else head
+
+
+def _module_of(name: str) -> str:
+    """``jit__fused_step_entry(9155629205207315214)`` → the module name."""
+    return name.split("(", 1)[0]
+
+
+# ----------------------------------------------------------------------
+# Files
 
 def find_trace_files(source: str) -> List[str]:
-    """Trace-event JSON files for a capture. ``source`` may be a trace
-    file itself, a profiler log dir (``<dir>/plugins/profile/<ts>/
-    <host>.trace.json.gz``), or a run dir holding several capture
-    dirs — every ``*.trace.json[.gz]`` below it is returned (one per
-    host; a multi-host capture merges)."""
+    """Capture files under ``source``: a file itself, a profiler log
+    dir (``<dir>/plugins/profile/<ts>/<host>.xplane.pb``) or a run dir
+    holding several. Xplane files are preferred; the trace-event JSON of
+    a capture is used only where no xplane lies beside it."""
     if os.path.isfile(source):
         return [source]
     if not os.path.isdir(source):
         return []
-    hits: List[str] = []
-    for pat in ("**/*.trace.json.gz", "**/*.trace.json"):
-        hits.extend(glob.glob(os.path.join(source, pat), recursive=True))
-    return sorted(set(hits))
+
+    def hits(*pats):
+        out: List[str] = []
+        for pat in pats:
+            out.extend(glob.glob(os.path.join(source, pat), recursive=True))
+        return sorted(set(out))
+
+    planes = hits("**/*.xplane.pb", "**/*.xplane.pb.gz")
+    dirs = {os.path.dirname(p) for p in planes}
+    return planes + [p for p in hits("**/*.trace.json.gz", "**/*.trace.json")
+                     if os.path.dirname(p) not in dirs]
 
 
-def load_trace_events(path: str) -> List[dict]:
-    """The ``traceEvents`` list of one trace-event JSON file
-    (gzipped or plain)."""
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rt", encoding="utf-8") as f:
-        obj = json.load(f)
-    if isinstance(obj, list):
-        return obj
-    return list(obj.get("traceEvents") or [])
-
-
-def save_phase_map(log_dir: str, maps: Dict[str, Dict[str, str]]) -> str:
-    """Write ``{module: {instruction: phase}}`` next to a capture so
-    offline parsers attribute CPU/GPU executor events."""
+def save_phase_map(log_dir: str, maps: Dict[str, Any]) -> str:
+    """Write the stage maps next to a capture so offline parsers can
+    attribute its events."""
+    doc = {m: {"stages": sm.stages, "scopes": sm.scopes,
+               "mixed_fusions": sm.mixed_fusions}
+           if hasattr(sm, "stages") else dict(sm)
+           for m, sm in maps.items()}
     path = os.path.join(log_dir, PHASE_MAP_NAME)
     with open(path, "w", encoding="utf-8") as f:
-        json.dump(maps, f, sort_keys=True)
+        json.dump(doc, f, sort_keys=True)
     return path
 
 
-def load_phase_map(path: str) -> Dict[str, Dict[str, str]]:
+def load_phase_map(path: str) -> Dict[str, Any]:
     with open(path, "r", encoding="utf-8") as f:
-        obj = json.load(f)
-    return {str(m): {str(k): str(v) for k, v in (ops or {}).items()}
-            for m, ops in (obj or {}).items()}
+        return dict(json.load(f) or {})
 
 
-def find_phase_map(trace_file: str,
-                   max_up: int = 4) -> Dict[str, Dict[str, str]]:
+def find_phase_map(trace_file: str, max_up: int = 4) -> Dict[str, Any]:
     """Walk up from a trace file looking for ``phase_map.json`` (the
     capture root is a few levels above ``plugins/profile/<ts>/``)."""
     d = os.path.dirname(os.path.abspath(trace_file))
@@ -132,15 +188,199 @@ def find_phase_map(trace_file: str,
     return {}
 
 
+class _Lookup:
+    """(module, instruction) -> (stage, scope) over maps given as
+    ``costmodel.StageMap``s, as ``phase_map.json`` entries, or as plain
+    ``{instruction: stage}`` tables."""
+
+    def __init__(self, maps: Optional[Dict[str, Any]]):
+        self.tables: Dict[str, Tuple[Dict[str, str], Dict[str, str]]] = {}
+        self.mixed = 0
+        for mod, sm in (maps or {}).items():
+            if hasattr(sm, "stages"):
+                st, sc, mx = sm.stages, sm.scopes, sm.mixed_fusions
+            elif isinstance(sm.get("stages"), dict):
+                st, sc = sm["stages"], sm.get("scopes") or {}
+                mx = int(sm.get("mixed_fusions", 0))
+            else:
+                st, sc, mx = sm, {}, 0
+            self.tables[str(mod)] = (st, sc)
+            self.mixed += mx
+
+    def find(self, module: str, instr: str) -> Tuple[Optional[str], str]:
+        table = self.tables.get(module)
+        if table is None and len(self.tables) == 1:
+            table = next(iter(self.tables.values()))
+        if table is None:
+            return None, ""
+        stage = table[0].get(instr)
+        return (stage if stage in KNOWN_PHASES else None,
+                table[1].get(instr, ""))
+
+
+# ----------------------------------------------------------------------
+# Loaders
+
+def _host_span(name: str, start: float, dur: float) -> Optional[HostSpan]:
+    """Host events the reduction reads: the program's ``lgbtpu:`` spans
+    (prefix dropped), bare canonical phases, and step markers."""
+    if name.startswith(ANNOTATION_PREFIX):
+        return HostSpan(name[len(ANNOTATION_PREFIX):], start, dur)
+    if name in KNOWN_PHASES or name.startswith(_STEP_NAME):
+        return HostSpan(name, start, dur)
+    return None
+
+
+def load_xplane(path: str) -> Capture:
+    """A capture from an ``.xplane.pb`` file (or a gzipped one)."""
+    from jax.profiler import ProfileData
+    if path.endswith(".gz"):
+        with gzip.open(path, "rb") as f:
+            data = ProfileData.from_serialized_xspace(f.read())
+    else:
+        data = ProfileData.from_file(path)
+    tracks: Dict[str, List[OpEvent]] = {}
+    spans: List[HostSpan] = []
+    epoch = None
+    n = 0
+    for plane in data.planes:
+        if plane.name == "Task Environment":
+            epoch = dict(plane.stats).get("profile_start_time")
+        elif plane.name.startswith("/device:"):
+            ops: List[Tuple[str, float, float]] = []
+            mods: List[Tuple[float, float, str]] = []
+            for line in plane.lines:
+                if line.name == OPS_LINE:
+                    ops = [(e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
+                           for e in line.events]
+                elif line.name == MODULES_LINE:
+                    mods = sorted((e.start_ns * 1e-9,
+                                   (e.start_ns + e.duration_ns) * 1e-9,
+                                   _module_of(e.name)) for e in line.events)
+            n += len(ops)
+            if ops:
+                tracks[plane.name.split("/device:", 1)[1]] = \
+                    _with_modules(ops, mods)
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                executor = line.name.startswith("tf_XLA")
+                for e in line.events:
+                    n += 1
+                    if executor:
+                        st = dict(e.stats)
+                        if "hlo_op" in st:
+                            tracks.setdefault("cpu:0", []).append(OpEvent(
+                                str(st["hlo_op"]), e.start_ns * 1e-9,
+                                e.duration_ns * 1e-9,
+                                str(st.get("hlo_module", ""))))
+                        continue
+                    sp = _host_span(e.name, e.start_ns * 1e-9,
+                                    e.duration_ns * 1e-9)
+                    if sp is not None:
+                        spans.append(sp)
+    return Capture(tracks, spans, n, [path],
+                   int(epoch) if epoch is not None else None)
+
+
+def _with_modules(ops: Sequence[Tuple[str, float, float]],
+                  mods: Sequence[Tuple[float, float, str]]) -> List[OpEvent]:
+    """Each op event with the module whose "XLA Modules" event encloses
+    its start (modules run one after another on a device)."""
+    out: List[OpEvent] = []
+    j = 0
+    for name, start, dur in sorted(ops, key=lambda o: o[1]):
+        while j + 1 < len(mods) and mods[j][1] <= start + EPS:
+            j += 1
+        mod = ""
+        if mods and mods[j][0] <= start + EPS and start < mods[j][1] + EPS:
+            mod = mods[j][2]
+        out.append(OpEvent(name, start, dur, mod))
+    return out
+
+
+def load_trace_events(path: str) -> List[dict]:
+    """The ``traceEvents`` list of one trace-event JSON file
+    (gzipped or plain)."""
+    opener = gzip.open if path.endswith(".gz") else open
+    with opener(path, "rt", encoding="utf-8") as f:
+        obj = json.load(f)
+    if isinstance(obj, list):
+        return obj
+    return list(obj.get("traceEvents") or [])
+
+
+def _device_label(pname: str, tname: str) -> Optional[str]:
+    """Device label for a (process, thread) track of a trace-event JSON,
+    or None for host tracks. TPU/GPU device processes are
+    ``/device:TPU:0``-style; their step/module summary lines are
+    excluded (op lines carry the time). On CPU the XLA executor threads
+    (``tf_XLATfrtCpuClient...``) merge into one ``cpu:0`` label, each
+    thread a track of its own."""
+    low_t = tname.lower()
+    if "/device:" in pname:
+        if "step" in low_t or "module" in low_t:
+            return None
+        return pname.split("/device:", 1)[1] or pname
+    if tname.startswith("tf_XLA") and "codegen" not in low_t \
+            and "llvm" not in low_t:
+        return "cpu:0"
+    return None
+
+
+def load_trace_json(path: str) -> Capture:
+    """A capture from trace-event JSON (timestamps in microseconds)."""
+    events = load_trace_events(path)
+    procs: Dict[Any, str] = {}
+    threads: Dict[Tuple[Any, Any], str] = {}
+    for ev in events:
+        if ev.get("ph") == "M":
+            args = ev.get("args") or {}
+            if ev.get("name") == "process_name":
+                procs[ev.get("pid")] = str(args.get("name", ""))
+            elif ev.get("name") == "thread_name":
+                threads[(ev.get("pid"), ev.get("tid"))] = \
+                    str(args.get("name", ""))
+    tracks: Dict[str, List[OpEvent]] = {}
+    spans: List[HostSpan] = []
+    n = 0
+    for ev in events:
+        if ev.get("ph") != "X":
+            continue
+        n += 1
+        try:
+            start = float(ev["ts"]) * 1e-6
+            dur = float(ev.get("dur", 0.0)) * 1e-6
+        except (KeyError, TypeError, ValueError):
+            continue
+        key = (ev.get("pid"), ev.get("tid"))
+        name = str(ev.get("name", ""))
+        dev = _device_label(procs.get(key[0], ""), threads.get(key, ""))
+        if dev is None:
+            sp = _host_span(name, start, dur)
+            if sp is not None:
+                spans.append(sp)
+            continue
+        args = ev.get("args") or {}
+        # a thread is a track: nesting is per thread, labels merge later
+        tracks.setdefault(f"{dev}\t{key[0]}/{key[1]}", []).append(OpEvent(
+            str(args.get("hlo_op") or name), start, dur,
+            str(args.get("hlo_module", ""))))
+    return Capture(tracks, spans, n, [path])
+
+
+def load_capture(path: str) -> Capture:
+    if ".xplane.pb" in os.path.basename(path):
+        return load_xplane(path)
+    return load_trace_json(path)
+
+
 # ----------------------------------------------------------------------
 # Interval helpers (all in seconds)
 
 def _union(intervals: List[Interval]) -> List[Interval]:
-    if not intervals:
-        return []
     out: List[Interval] = []
     for s, e in sorted(intervals):
-        if out and s <= out[-1][1]:
+        if out and s <= out[-1][1] + EPS:
             out[-1] = (out[-1][0], max(out[-1][1], e))
         else:
             out.append((s, e))
@@ -166,45 +406,21 @@ def _intersect(a: List[Interval], b: List[Interval]) -> List[Interval]:
     return out
 
 
-# ----------------------------------------------------------------------
-# Phase attribution
-
-def phase_of_path(name: str) -> Optional[str]:
-    """First canonical phase along a scope path: ``jit(f)/build/dot``
-    → ``build``. Path components may carry trailing disambiguators
-    (``build_1``, ``build.2``) which do not match — named_scope emits
-    the raw phase string, so only exact components count."""
-    for part in str(name).replace(":", "/").split("/"):
-        if part in KNOWN_PHASES:
-            return part
-    return None
-
-
-def _event_phase(name: str, args: Dict[str, Any],
-                 phase_maps: Dict[str, Dict[str, str]]
-                 ) -> Optional[str]:
-    ph = phase_of_path(name)
-    if ph is not None:
-        return ph
-    for key in ("long_name", "tf_op", "name"):
-        v = args.get(key)
-        if v:
-            ph = phase_of_path(v)
-            if ph is not None:
-                return ph
-    if phase_maps and ("hlo_op" in args or "hlo_module" in args):
-        mod = str(args.get("hlo_module", ""))
-        table = phase_maps.get(mod)
-        if table is None and len(phase_maps) == 1:
-            table = next(iter(phase_maps.values()))
-        if table is not None:
-            # executor events name the instruction either in args
-            # (hlo_op) or as the event name itself
-            for key in (args.get("hlo_op"), name):
-                ph = table.get(str(key)) if key else None
-                if ph in KNOWN_PHASES:
-                    return ph
-    return None
+def _covering_span(t: float, spans: Sequence[HostSpan]) -> str:
+    """The innermost program span that holds time ``t``, else the one
+    that ended last before it (``after <name>``)."""
+    best = last = None
+    for s in spans:
+        if s.start <= t <= s.start + s.dur:
+            if best is None or s.dur < best.dur:
+                best = s
+        elif s.start + s.dur < t and (
+                last is None or s.start + s.dur > last.start + last.dur):
+            last = s
+    if best is not None:
+        return best.name
+    # no span holds it: the host had already returned from the one before
+    return f"after {last.name}" if last is not None else "outside-spans"
 
 
 # ----------------------------------------------------------------------
@@ -212,11 +428,11 @@ def _event_phase(name: str, args: Dict[str, Any],
 
 @dataclasses.dataclass
 class PhaseProfile:
-    """Parsed per-phase device/host time of one capture (or of several
-    merged trace files)."""
+    """Per-stage device/host time of one capture (or of several merged
+    capture files)."""
     device_phase_s: Dict[str, float]           # merged across devices
-    per_device: Dict[str, Dict[str, float]]    # device → phase → s
-    host_phase_s: Dict[str, float]             # host TraceAnnotation
+    per_device: Dict[str, Dict[str, float]]    # device → stage → s
+    host_phase_s: Dict[str, float]             # host spans by name
     device_busy_s: float      # union of device-busy time, summed/device
     host_phase_busy_s: float  # union of host phase spans
     overlap_s: float          # device busy ∩ host phase spans
@@ -225,20 +441,35 @@ class PhaseProfile:
     step_span_s: float        # union of the step windows
     n_events: int
     sources: List[str]
+    # (instruction text, stage, source scope, self seconds), longest first
+    top_ops: List[Tuple[str, str, str, float]] = \
+        dataclasses.field(default_factory=list)
+    # (device, covering program span, seconds), longest first
+    idle_gaps: List[Tuple[str, str, float]] = \
+        dataclasses.field(default_factory=list)
+    dispatches: int = 0       # lgbtpu:gbdt.dispatch spans in the capture
+    mixed_fusions: int = 0    # of the stage maps used
+    epoch_ns: Optional[int] = None
 
     def iterations(self) -> int:
-        return self.steps
+        """Trees of the capture: its ``boost_iter`` markers, else its
+        ``gbdt.dispatch`` spans (one fused dispatch is one iteration)."""
+        return self.steps or self.dispatches
 
     def device_s_per_iter(self,
                           iterations: Optional[int] = None
                           ) -> Dict[str, float]:
-        """Per-phase device seconds per boost iteration (the number
-        comparable to ``ms_per_tree``). Uses the capture's own
-        ``boost_iter`` step count unless overridden."""
-        it = int(iterations if iterations is not None else self.steps)
+        """Per-stage device seconds per boost iteration (the number
+        comparable to ``ms_per_tree``)."""
+        it = int(iterations if iterations is not None
+                 else self.iterations())
         if it <= 0:
             return {}
         return {k: v / it for k, v in self.device_phase_s.items()}
+
+    def unknown_share(self) -> float:
+        tot = sum(self.device_phase_s.values())
+        return self.device_phase_s.get(UNKNOWN, 0.0) / tot if tot else 0.0
 
     def summary_dict(self) -> Dict[str, Any]:
         """JSON-ready summary (the ``/trace`` response body)."""
@@ -253,235 +484,210 @@ class PhaseProfile:
             "dispatch_gap_s": round(self.dispatch_gap_s, 6),
             "steps": self.steps,
             "n_events": self.n_events,
+            "top_ops": [[n, st, sc, round(s, 6)]
+                        for n, st, sc, s in self.top_ops],
+            "idle_gaps": [[dv, sp, round(s, 6)]
+                          for dv, sp, s in self.idle_gaps],
         }
         per_iter = self.device_s_per_iter()
         if per_iter:
             d["device_s_per_iter"] = {k: round(v, 6)
                                       for k, v in sorted(per_iter.items())}
             d["dispatch_gap_s_per_iter"] = round(
-                self.dispatch_gap_s / max(self.steps, 1), 6)
+                self.dispatch_gap_s / max(self.iterations(), 1), 6)
         return d
 
     def render(self) -> str:
-        """Device-vs-host per-phase table for ``monitor --perf``."""
+        """Stage table, longest instructions and idle gaps for
+        ``monitor --perf``."""
+        its = self.iterations()
         rows = [f"devices: {', '.join(sorted(self.per_device)) or '-'}"
-                f"  steps: {self.steps}  events: {self.n_events}"]
-        names = sorted(set(self.device_phase_s) | set(self.host_phase_s))
+                f"  steps: {self.steps}  dispatches: {self.dispatches}"
+                f"  events: {self.n_events}"]
+        names = sorted(set(self.device_phase_s) | set(self.host_phase_s),
+                       key=lambda k: -self.device_phase_s.get(k, 0.0))
+        tot = sum(self.device_phase_s.values())
         if names:
-            rows.append(f"  {'phase':<12} {'device ms':>12} "
+            rows.append(f"  {'stage':<16} {'device ms':>12} {'share':>7} "
                         f"{'host ms':>12}"
-                        + (f" {'device ms/iter':>16}" if self.steps
-                           else ""))
+                        + (f" {'device ms/iter':>16}" if its else ""))
             for name in names:
-                dv = self.device_phase_s.get(name, 0.0) * 1e3
+                dv = self.device_phase_s.get(name, 0.0)
                 hv = self.host_phase_s.get(name, 0.0) * 1e3
-                line = f"  {name:<12} {dv:12.3f} {hv:12.3f}"
-                if self.steps:
-                    line += f" {dv / self.steps:16.4f}"
+                line = (f"  {name:<16} {dv * 1e3:12.3f} "
+                        f"{100.0 * dv / tot if tot else 0.0:6.2f}% "
+                        f"{hv:12.3f}")
+                if its:
+                    line += f" {dv * 1e3 / its:16.4f}"
                 rows.append(line)
         rows.append(f"  device busy {self.device_busy_s * 1e3:.3f} ms, "
+                    f"self time by stage {tot * 1e3:.3f} ms "
+                    f"({UNKNOWN} {100.0 * self.unknown_share():.2f}%), "
                     f"host∩device overlap {self.overlap_s * 1e3:.3f} ms, "
                     f"dispatch gap {self.dispatch_gap_s * 1e3:.3f} ms"
                     + (f" ({self.dispatch_gap_s / self.steps * 1e3:.3f}"
                        " ms/iter)" if self.steps else ""))
+        if self.mixed_fusions:
+            rows.append(f"  {self.mixed_fusions} fusion(s) of the stage map "
+                        "mix stages (counted under the stage holding most "
+                        "of their instructions)")
+        if self.top_ops:
+            rows.append("  longest instructions (self time):")
+            for text, stage, scope, s in self.top_ops:
+                rows.append(f"    {s * 1e3:12.3f} ms  [{stage}]  {text}")
+                if scope:
+                    rows.append(f"    {'':>15}  at {scope}")
+        if self.idle_gaps:
+            rows.append("  longest idle gaps (device, covering span):")
+            for dev, sp, s in self.idle_gaps:
+                rows.append(f"    {s * 1e3:12.3f} ms  {dev}  {sp}")
         return "\n".join(rows)
 
 
 def _is_wrapper(name: str) -> bool:
     """Pure runtime wrapper events: they cover whole dispatches on the
-    same thread as the op events, carry no phase of their own, and
+    same thread as the op events, carry no stage of their own, and
     would double-count everything beneath them."""
     return "ThunkExecutor" in name
 
 
-def _is_device_thread(pname: str, tname: str) -> Optional[str]:
-    """Device label for a (process, thread) track, or None for host
-    tracks. TPU/GPU device processes are ``/device:TPU:0``-style; their
-    step/module summary lines are excluded (op lines carry the time).
-    On CPU there is no device process — the XLA executor threads
-    (``tf_XLATfrtCpuClient...``) are the closest thing to a device
-    timeline and merge into one ``cpu:0`` track."""
-    low_t = tname.lower()
-    if "/device:" in pname:
-        if "step" in low_t or "module" in low_t:
-            return None
-        return pname.split("/device:", 1)[1] or pname
-    if tname.startswith("tf_XLA") and "codegen" not in low_t \
-            and "llvm" not in low_t:
-        # the CPU runtime's executor + Eigen pool threads
-        # (tf_XLATfrtCpuClient/..., tf_XLAEigen/...) — compile-time
-        # codegen threads excluded
-        return "cpu:0"
-    return None
-
-
-def parse_trace(source: str,
-                phase_maps: Optional[Dict[str, Dict[str, str]]] = None
-                ) -> PhaseProfile:
-    """Parse one capture (file, log dir, or run dir — every trace file
-    found under ``source`` merges into one profile). ``phase_maps``
-    overrides the per-capture ``phase_map.json`` discovery."""
-    files = find_trace_files(source)
-    if not files:
-        raise FileNotFoundError(f"no trace-event JSON under {source!r}")
-    dev_phase: Dict[str, Dict[str, float]] = {}
-    host_phase: Dict[str, float] = {}
-    host_spans: List[Tuple[float, float, str]] = []
+def reduce_capture(capture: Capture,
+                   maps: Optional[Dict[str, Any]] = None) -> PhaseProfile:
+    """The one reduction: self time by nesting on every track, stage by
+    (module, instruction) lookup, host-span overlap for what the map
+    misses, ``unknown`` for the rest."""
+    lookup = _Lookup(maps)
+    phase_spans = sorted((s.start, s.start + s.dur, s.name)
+                         for s in capture.host_spans
+                         if s.name in KNOWN_PHASES)
+    program_spans = [s for s in capture.host_spans
+                     if not s.name.startswith(_STEP_NAME)]
+    per_device: Dict[str, Dict[str, float]] = {}
     dev_busy: Dict[str, List[Interval]] = {}
-    pending: List[Tuple[str, float, float, float]] = []
-    thread_evs: Dict[Tuple[str, Any, Any],
-                     List[Tuple[float, float, str, Dict[str, Any]]]] = {}
-    step_iv: List[Interval] = []
-    step_count = 0
-    n_events = 0
+    by_text: Dict[Tuple[str, str], List[Any]] = {}
 
-    for path in files:
-        maps = phase_maps if phase_maps is not None \
-            else find_phase_map(path)
-        events = load_trace_events(path)
-        procs: Dict[Any, str] = {}
-        threads: Dict[Tuple[Any, Any], str] = {}
-        for ev in events:
-            if ev.get("ph") == "M":
-                args = ev.get("args") or {}
-                if ev.get("name") == "process_name":
-                    procs[ev.get("pid")] = str(args.get("name", ""))
-                elif ev.get("name") == "thread_name":
-                    threads[(ev.get("pid"), ev.get("tid"))] = \
-                        str(args.get("name", ""))
-        for ev in events:
-            if ev.get("ph") != "X":
-                continue
-            n_events += 1
-            try:
-                ts_us = float(ev["ts"])
-                dur_us = float(ev.get("dur", 0.0))
-            except (KeyError, TypeError, ValueError):
-                continue
-            ts, dur = ts_us / 1e6, dur_us / 1e6
-            pname = procs.get(ev.get("pid"), "")
-            tname = threads.get((ev.get("pid"), ev.get("tid")), "")
-            name = str(ev.get("name", ""))
-            args = ev.get("args") or {}
-            dev = _is_device_thread(pname, tname)
-            if dev is not None:
-                dev_busy.setdefault(dev, []).append((ts, ts + dur))
-                # containment below works on the RAW microsecond
-                # values: integer-tick timestamps are exact there,
-                # while seconds round — back-to-back ops would
-                # float-drift into "covered by the previous op"
-                thread_evs.setdefault(
-                    (dev, ev.get("pid"), ev.get("tid")), []).append(
-                        (ts_us, dur_us, name, args))
-                continue
-            # host track: canonical-phase TraceAnnotation spans and
-            # boost_iter step markers
-            if name in KNOWN_PHASES:
-                host_phase[name] = host_phase.get(name, 0.0) + dur
-                host_spans.append((ts, ts + dur, name))
-            elif name == _STEP_NAME or name.startswith(_STEP_NAME):
-                step_count += 1
-                step_iv.append((ts, ts + dur))
-        # Per-thread containment pass: sort by (start, -dur) so a
-        # container sorts before the events it covers; an event under
-        # an already-counted ancestor is skipped (its time is covered),
-        # runtime wrappers are transparent.
-        for (dev, _pid, _tid), evs in thread_evs.items():
-            evs.sort(key=lambda t: (t[0], -t[1]))
-            bucket = dev_phase.setdefault(dev, {})
-            # (ts, dur, covered-by-children micros) per live wrapper —
-            # the uncovered remainder is thunk-scheduling self-time,
-            # real device time no op event accounts for
-            wrappers: List[List[float]] = []
-            stack: List[Tuple[float, bool, Optional[int]]] = []
-            for ts_us, dur_us, name, args in evs:
-                while stack and stack[-1][0] <= ts_us:
-                    stack.pop()
-                covered = any(counted for _, counted, _ in stack)
-                if _is_wrapper(name):
-                    widx: Optional[int] = None
-                    if not covered:
-                        for _, _, w in reversed(stack):
-                            if w is not None:
-                                # nested wrapper: its whole window is
-                                # covered from the outer one's view
-                                wrappers[w][2] += dur_us
-                                break
-                        widx = len(wrappers)
-                        wrappers.append([ts_us, dur_us, 0.0])
-                    stack.append((ts_us + dur_us, False, widx))
+    for track, events in capture.tracks.items():
+        dev = track.split("\t", 1)[0]
+        bucket = per_device.setdefault(dev, {})
+        evs = sorted(events, key=lambda e: (e.start, -e.dur))
+        self_s = [e.dur for e in evs]
+        stack: List[int] = []
+        for i, e in enumerate(evs):
+            while stack and (evs[stack[-1]].start + evs[stack[-1]].dur
+                             <= e.start + EPS):
+                stack.pop()
+            if stack:
+                self_s[stack[-1]] -= e.dur
+            else:
+                dev_busy.setdefault(dev, []).append(
+                    (e.start, e.start + e.dur))
+            stack.append(i)
+        for e, s in zip(evs, self_s):
+            s = max(s, 0.0)
+            if _is_wrapper(e.name):
+                # see the module docstring: mostly covered -> its
+                # remainder is scheduling time; mostly empty -> dropped
+                if e.dur <= 0 or (e.dur - s) / e.dur < 0.5:
                     continue
-                if covered:
-                    stack.append((ts_us + dur_us, True, None))
-                    continue
-                for _, _, w in reversed(stack):
-                    if w is not None:
-                        wrappers[w][2] += dur_us
+                stage, scope = None, ""
+            else:
+                stage, scope = lookup.find(e.module, instruction_of(e.name))
+            if stage is None and s > 0:
+                # path 2: the host phase span(s) the event overlaps
+                end = e.start + e.dur
+                left = s
+                for a, b, ph in phase_spans:
+                    if b <= e.start:
+                        continue
+                    if a >= end or left <= 0:
                         break
-                stack.append((ts_us + dur_us, True, None))
-                ph = _event_phase(name, args, maps or {})
-                if ph is not None:
-                    bucket[ph] = bucket.get(ph, 0.0) + dur_us / 1e6
-                elif dur_us > 0:
-                    pending.append((dev, ts_us / 1e6, dur_us / 1e6,
-                                    dur_us / 1e6))
-            for wts, wdur, wcov in wrappers:
-                # A wrapper mostly covered by its own thread's ops is a
-                # real execution window — its remainder is inter-thunk
-                # scheduling time. One mostly empty on its own thread
-                # is a dispatcher blocking on worker threads (the CPU
-                # client thread waiting on the Eigen pool): counting
-                # its time would double what the workers already
-                # recorded, so it is dropped.
-                self_us = wdur - wcov
-                if wdur > 0 and wcov / wdur >= 0.5 and self_us > 1e-3:
-                    pending.append((dev, wts / 1e6, wdur / 1e6,
-                                    self_us / 1e6))
-        thread_evs = {}
+                    ov = min(min(b, end) - max(a, e.start), left)
+                    if ov > 0:
+                        bucket[ph] = bucket.get(ph, 0.0) + ov
+                        left -= ov
+                if left > 1e-12:
+                    bucket[UNKNOWN] = bucket.get(UNKNOWN, 0.0) + left
+            elif stage is not None:
+                bucket[stage] = bucket.get(stage, 0.0) + s
+            if not _is_wrapper(e.name):
+                ent = by_text.setdefault((e.module, e.name[:TEXT_CHARS]),
+                                         [stage or UNKNOWN, scope, 0.0])
+                ent[2] += s
 
-    # Path 3: host-span overlap for still-unattributed device events
-    # (the legacy driver dispatches each phase inside its own host
-    # span, so a device op's window picks its phase by time).
-    spans_sorted = sorted(host_spans)
-    for dev, ts, dur, self_dur in pending:
-        end = ts + dur
-        remaining = self_dur
-        bucket = dev_phase.setdefault(dev, {})
-        for s, e, ph in spans_sorted:
-            if e <= ts:
-                continue
-            if s >= end or remaining <= 0:
-                break
-            ov = min(min(e, end) - max(s, ts), remaining)
-            if ov > 0:
-                bucket[ph] = bucket.get(ph, 0.0) + ov
-                remaining -= ov
-        if remaining > 1e-12:
-            bucket[UNKNOWN] = bucket.get(UNKNOWN, 0.0) + remaining
-
-    per_device = {d: dict(sorted(p.items()))
-                  for d, p in sorted(dev_phase.items())}
     merged: Dict[str, float] = {}
     for p in per_device.values():
         for k, v in p.items():
             merged[k] = merged.get(k, 0.0) + v
     busy_unions = {d: _union(iv) for d, iv in dev_busy.items()}
-    busy_total = sum(_total(u) for u in busy_unions.values())
-    host_union = _union([(s, e) for s, e, _ in host_spans])
+    gaps: List[Tuple[float, str, float]] = []
+    for dev, u in busy_unions.items():
+        for (_, a), (b, _) in zip(u, u[1:]):
+            gaps.append((b - a, dev, (a + b) / 2))
+    gaps.sort(key=lambda g: -g[0])
+    host_phase: Dict[str, float] = {}
+    steps: List[Interval] = []
+    dispatches = 0
+    for s in capture.host_spans:
+        if s.name.startswith(_STEP_NAME):
+            steps.append((s.start, s.start + s.dur))
+            continue
+        host_phase[s.name] = host_phase.get(s.name, 0.0) + s.dur
+        dispatches += s.name == _DISPATCH_SPAN
+    host_union = _union([(a, b) for a, b, _ in phase_spans])
     all_busy = _union([iv for u in busy_unions.values() for iv in u])
-    overlap = _total(_intersect(all_busy, host_union))
-    steps_union = _union(step_iv)
-    gap = max(_total(steps_union)
-              - _total(_intersect(all_busy, steps_union)), 0.0)
+    steps_union = _union(steps)
     return PhaseProfile(
         device_phase_s=dict(sorted(merged.items())),
-        per_device=per_device,
+        per_device={d: dict(sorted(p.items()))
+                    for d, p in sorted(per_device.items())},
         host_phase_s=dict(sorted(host_phase.items())),
-        device_busy_s=busy_total,
+        device_busy_s=sum(_total(u) for u in busy_unions.values()),
         host_phase_busy_s=_total(host_union),
-        overlap_s=overlap,
-        dispatch_gap_s=gap,
-        steps=step_count,
+        overlap_s=_total(_intersect(all_busy, host_union)),
+        dispatch_gap_s=max(_total(steps_union) - _total(
+            _intersect(all_busy, steps_union)), 0.0),
+        steps=len(steps),
         step_span_s=_total(steps_union),
-        n_events=n_events,
-        sources=files)
+        n_events=capture.n_events,
+        sources=list(capture.sources),
+        top_ops=[(text, st, sc, s) for (_, text), (st, sc, s) in sorted(
+            by_text.items(), key=lambda kv: -kv[1][2])[:TOP]],
+        idle_gaps=[(dev, _covering_span(mid, program_spans), g)
+                   for g, dev, mid in gaps[:TOP]],
+        dispatches=dispatches,
+        mixed_fusions=lookup.mixed,
+        epoch_ns=capture.epoch_ns)
+
+
+def merge_captures(captures: Sequence[Capture]) -> Capture:
+    """Several files of one capture (one per host) as one. Track labels
+    of later files are kept apart by a file index."""
+    if len(captures) == 1:
+        return captures[0]
+    tracks: Dict[str, List[OpEvent]] = {}
+    for i, c in enumerate(captures):
+        for k, v in c.tracks.items():
+            dev, _, rest = k.partition("\t")
+            tracks[f"{dev}\t{i}:{rest}"] = v
+    return Capture(tracks, [s for c in captures for s in c.host_spans],
+                   sum(c.n_events for c in captures),
+                   [p for c in captures for p in c.sources],
+                   captures[0].epoch_ns)
+
+
+def parse_trace(source: str,
+                phase_maps: Optional[Dict[str, Any]] = None
+                ) -> PhaseProfile:
+    """Parse one capture (file, log dir, or run dir — every capture file
+    found under ``source`` merges into one profile). ``phase_maps``
+    overrides the per-capture ``phase_map.json`` discovery."""
+    files = find_trace_files(source)
+    if not files:
+        raise FileNotFoundError(f"no profiler capture under {source!r}")
+    maps = phase_maps
+    if maps is None:
+        maps = {}
+        for path in files:
+            maps.update(find_phase_map(path))
+    return reduce_capture(merge_captures([load_capture(p) for p in files]),
+                          maps)

@@ -132,7 +132,7 @@ def _seed_class_unroll() -> list:
     def step(gh):                       # gh [K, R]: per-class grads
         outs = []
         for k in range(gh.shape[0]):    # the K-unrolled anti-pattern
-            with profiler.phase("build"):
+            with profiler.stage("build"):
                 outs.append(grow_one(gh[k]))
         return jnp.stack(outs)
     closed = jax.make_jaxpr(step)(jnp.ones((3, 64), jnp.float32))

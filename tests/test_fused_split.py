@@ -158,7 +158,7 @@ def test_builder_fused_matches_two_pass(rng, interp, hist_sub):
                      cat_smooth=10.0, cat_l2=10.0)
     out = {}
     for fused in (True, False):
-        t, rl_out, _ = build_tree(
+        t, rl_out, _, _rounds = build_tree(
             bins, gh, jnp.zeros((1024,), jnp.int32),
             _META["num_bins_pf"], _META["nan_bin_pf"],
             _META["is_cat_pf"], jnp.ones((F,), bool),
@@ -190,10 +190,10 @@ def test_builder_class_batched_fused(rng, interp):
               hist_impl="pallas", block_rows=256, fused_split=True)
     meta = (_META["num_bins_pf"], _META["nan_bin_pf"],
             _META["is_cat_pf"], jnp.ones((F,), bool))
-    tb, rlb, _ = build_tree(bins, gh_k, jnp.zeros((R,), jnp.int32),
+    tb, rlb, _, _rounds = build_tree(bins, gh_k, jnp.zeros((R,), jnp.int32),
                             *meta, class_batched=True, **kw)
     for k in range(K):
-        t, rl_out, _ = build_tree(bins, gh_k[k],
+        t, rl_out, _, _rounds = build_tree(bins, gh_k[k],
                                   jnp.zeros((R,), jnp.int32),
                                   *meta, **kw)
         np.testing.assert_array_equal(np.asarray(tb.split_feature[k]),

@@ -241,7 +241,7 @@ def test_pallas_tree_with_subtraction_matches_scatter(rng, monkeypatch):
     sp = SplitParams(min_data_in_leaf=5, min_sum_hessian_in_leaf=1e-3)
     out = {}
     for impl in ("pallas", "scatter"):
-        t, rl, _ = build_tree(
+        t, rl, _, _rounds = build_tree(
             jnp.asarray(bins), jnp.asarray(gh),
             jnp.zeros((R,), jnp.int32), meta["num_bins_pf"],
             meta["nan_bin_pf"], meta["is_cat_pf"], meta["feature_mask"],
@@ -388,7 +388,7 @@ def test_native_tree_matches_scatter_tree(rng):
         kw = {}
         if impl == "native":
             kw["bins_cm"] = jnp.asarray(bins.T)
-        t, rl, _ = build_tree(
+        t, rl, _, _rounds = build_tree(
             jnp.asarray(bins), jnp.asarray(gh),
             jnp.asarray(rl0), meta["num_bins_pf"],
             meta["nan_bin_pf"], meta["is_cat_pf"], meta["feature_mask"],
@@ -430,7 +430,7 @@ def test_subtraction_tree_matches_direct(rng):
     sp = SplitParams(min_data_in_leaf=5, min_sum_hessian_in_leaf=1e-3)
     trees = {}
     for sub in (True, False):
-        t, rl, _ = build_tree(
+        t, rl, _, _rounds = build_tree(
             jnp.asarray(bins), jnp.asarray(gh),
             jnp.zeros((R,), jnp.int32), meta["num_bins_pf"],
             meta["nan_bin_pf"], meta["is_cat_pf"], meta["feature_mask"],

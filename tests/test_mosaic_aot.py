@@ -13,6 +13,7 @@ import functools as ft
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 pytestmark = pytest.mark.slow
@@ -151,6 +152,71 @@ def test_serial_tree_build_compiles_at_higgs_width(v5e, monkeypatch):
         *_tree_args(lambda s, d, _: sds(s, d), 1 << 18, 28),
         split_params=SplitParams(), hist_impl=impl,
         **_HIGGS).lower().compile()
+
+
+def _bench_shape(name):
+    """(padded rows, cols, builder keywords) of a benchmark
+    configuration, as the trainer lays it out on one chip."""
+    import json
+    import os
+    from lightgbm_tpu.ops.histogram import block_rows_for
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs", name + ".json")
+    with open(path) as f:
+        cfg = json.load(f)
+    rows, cols = cfg["shape"]["rows"], cfg["shape"]["cols"]
+    p = cfg["params"]
+    block = block_rows_for(rows, cols, p["max_bin"])
+    return -(-rows // block) * block, cols, dict(
+        num_leaves=p["num_leaves"], leaf_batch=16, max_depth=-1,
+        num_bins=p["max_bin"], hist_dtype="bfloat16", block_rows=block)
+
+
+@pytest.mark.parametrize("config", ["higgs", "epsilon"])
+def test_stage_map_names_the_grow_loop_at_benchmark_shapes(v5e, config,
+                                                           monkeypatch):
+    """The tree build compiled for the described v5e at a benchmark
+    cell's shape: every instruction of the grow ``while`` that touches a
+    row-sized array has a stage deeper than ``build``, the Pallas custom
+    call is the kernel stage, and no name outside phases.py appears."""
+    import re
+
+    from lightgbm_tpu import phases
+    from lightgbm_tpu.boosting.tree_builder import _build_tree_jit
+    from lightgbm_tpu.ops.pallas_histogram import HIST_KERNEL_NAME
+    from lightgbm_tpu.ops.split import SplitParams
+    from lightgbm_tpu.telemetry import costmodel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    R, F, kw = _bench_shape(config)
+    sds = _sds(v5e[0])
+    text = _build_tree_jit.trace(
+        *_tree_args(lambda s, d, _: sds(s, d), R, F),
+        split_params=SplitParams(min_data_in_leaf=1,
+                                 min_sum_hessian_in_leaf=100.0),
+        hist_impl="pallas", **kw).lower().compile().as_text()
+    sm = costmodel.instruction_phase_map(text)
+    assert set(sm.stages.values()) <= phases.KNOWN_PHASES
+    rows = costmodel._instructions(text)
+    body = next(r.callee for r in rows if r.op.opcode == "while")
+    shape = re.compile(r"\b(?:pred|bf16|[sufc]\d+)\[([0-9,]*)\]")
+    lines = {m.group(1): ln.split(", metadata=")[0]
+             for ln in text.splitlines()
+             for m in [re.match(r"\s*(?:ROOT\s+)?%?([\w.-]+)\s*=", ln)] if m}
+    deep = phases.BUILD_STAGES
+    seen = 0
+    for r in rows:
+        if r.comp != body or r.op.opcode in costmodel._NOOP_OPCODES:
+            continue
+        elems = max((int(np.prod([int(x) for x in d.split(",") if x]))
+                     for d in shape.findall(lines[r.op.name])), default=0)
+        if elems >= R:
+            seen += 1
+            assert sm.stages.get(r.op.name) in deep, (
+                r.op.name, sm.stages.get(r.op.name), r.op.op_name)
+    assert seen > 20
+    kernels = [r for r in rows if r.op.name.startswith(HIST_KERNEL_NAME)]
+    assert len(kernels) == 2      # the root pass and the in-loop call
+    assert all(sm.stages[r.op.name] == phases.HIST_KERNEL for r in kernels)
 
 
 @pytest.mark.parametrize("merge", ["reduce_scatter", "allreduce"])

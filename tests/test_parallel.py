@@ -42,7 +42,7 @@ def test_dp_tree_matches_single_device(rng):
     R = bins.shape[0]
     rl0 = np.zeros(R, np.int32)
 
-    ref_tree, ref_rl, _ = build_tree(
+    ref_tree, ref_rl, _, _rounds = build_tree(
         jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(rl0),
         meta["num_bins_pf"], meta["nan_bin_pf"], meta["is_cat_pf"],
         meta["feature_mask"], block_rows=R, **KW)
@@ -50,7 +50,7 @@ def test_dp_tree_matches_single_device(rng):
     plan = DataParallelPlan()
     nsh = plan.num_shards
     assert nsh == 8
-    got_tree, got_rl, _ = plan.build_tree(
+    got_tree, got_rl, _, _rounds = plan.build_tree(
         plan.shard_rows(bins), plan.shard_rows(gh), plan.shard_rows(rl0),
         meta["num_bins_pf"], meta["nan_bin_pf"], meta["is_cat_pf"],
         meta["feature_mask"], block_rows=R // nsh, **KW)
@@ -73,7 +73,7 @@ def test_dp_valid_copartition(rng):
     rl0 = np.zeros(R, np.int32)
     vrl0 = np.zeros(VR, np.int32)
 
-    _, _, ref_v = build_tree(
+    _, _, ref_v, _rounds = build_tree(
         jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(rl0),
         meta["num_bins_pf"], meta["nan_bin_pf"], meta["is_cat_pf"],
         meta["feature_mask"], block_rows=R,
@@ -82,7 +82,7 @@ def test_dp_valid_copartition(rng):
 
     plan = DataParallelPlan()
     nsh = plan.num_shards
-    _, _, got_v = plan.build_tree(
+    _, _, got_v, _rounds = plan.build_tree(
         plan.shard_rows(bins), plan.shard_rows(gh), plan.shard_rows(rl0),
         meta["num_bins_pf"], meta["nan_bin_pf"], meta["is_cat_pf"],
         meta["feature_mask"], block_rows=R // nsh,
@@ -101,13 +101,13 @@ def test_feature_parallel_matches_single_device(rng):
     R = bins.shape[0]
     rl0 = np.zeros(R, np.int32)
 
-    ref_tree, ref_rl, _ = build_tree(
+    ref_tree, ref_rl, _, _rounds = build_tree(
         jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(rl0),
         meta["num_bins_pf"], meta["nan_bin_pf"], meta["is_cat_pf"],
         meta["feature_mask"], block_rows=R, **KW)
 
     plan = FeatureParallelPlan()
-    got_tree, got_rl, _ = plan.build_tree(
+    got_tree, got_rl, _, _rounds = plan.build_tree(
         plan.shard_rows(bins), plan.shard_rows(gh), plan.shard_rows(rl0),
         meta["num_bins_pf"], meta["nan_bin_pf"], meta["is_cat_pf"],
         meta["feature_mask"], block_rows=R, **KW)
@@ -131,14 +131,14 @@ def test_voting_parallel_full_topk_matches_data_parallel(rng):
     R = bins.shape[0]
     rl0 = np.zeros(R, np.int32)
 
-    ref_tree, ref_rl, _ = build_tree(
+    ref_tree, ref_rl, _, _rounds = build_tree(
         jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(rl0),
         meta["num_bins_pf"], meta["nan_bin_pf"], meta["is_cat_pf"],
         meta["feature_mask"], block_rows=R, **KW)
 
     plan = VotingParallelPlan(top_k=6)
     nsh = plan.num_shards
-    got_tree, got_rl, _ = plan.build_tree(
+    got_tree, got_rl, _, _rounds = plan.build_tree(
         plan.shard_rows(bins), plan.shard_rows(gh), plan.shard_rows(rl0),
         meta["num_bins_pf"], meta["nan_bin_pf"], meta["is_cat_pf"],
         meta["feature_mask"], block_rows=R // nsh, **KW)
@@ -158,7 +158,7 @@ def test_voting_parallel_small_topk_grows_sane_tree(rng):
     rl0 = np.zeros(R, np.int32)
     plan = VotingParallelPlan(top_k=2)
     nsh = plan.num_shards
-    tree, rl, _ = plan.build_tree(
+    tree, rl, _, _rounds = plan.build_tree(
         plan.shard_rows(bins), plan.shard_rows(gh), plan.shard_rows(rl0),
         meta["num_bins_pf"], meta["nan_bin_pf"], meta["is_cat_pf"],
         meta["feature_mask"], block_rows=R // nsh, **KW)

@@ -68,14 +68,14 @@ def test_resolve_hist_merge():
 def test_rs_bit_parity_with_allreduce_and_serial(rng):
     bins, gh, meta = _data(rng)
     R = bins.shape[0]
-    ref_tree, ref_rl, _ = build_tree(
+    ref_tree, ref_rl, _, _rounds = build_tree(
         jnp.asarray(bins), jnp.asarray(gh),
         jnp.asarray(np.zeros(R, np.int32)), *meta, block_rows=R, **KW)
     out = {}
     for hm in ("allreduce", "reduce_scatter"):
         plan = DataParallelPlan(hist_merge=hm)
         assert plan.num_shards == 8
-        t, rl, _ = _dp_tree(plan, bins, gh, meta)
+        t, rl, _, _rounds = _dp_tree(plan, bins, gh, meta)
         out[hm] = (jax.device_get(t), np.asarray(rl))
     ta, rla = out["allreduce"]
     ts, rls = out["reduce_scatter"]
@@ -104,8 +104,8 @@ def test_rs_hist_cache_slot_sharded(rng):
     direct (hist_sub=False) build under reduce_scatter."""
     bins, gh, meta = _data(rng, R=2048)
     plan = DataParallelPlan(hist_merge="reduce_scatter")
-    t_sub, rl_sub, _ = _dp_tree(plan, bins, gh, meta, hist_sub=True)
-    t_dir, rl_dir, _ = _dp_tree(plan, bins, gh, meta, hist_sub=False)
+    t_sub, rl_sub, _, _rounds = _dp_tree(plan, bins, gh, meta, hist_sub=True)
+    t_dir, rl_dir, _, _rounds = _dp_tree(plan, bins, gh, meta, hist_sub=False)
     np.testing.assert_array_equal(np.asarray(t_sub.split_feature),
                                   np.asarray(t_dir.split_feature))
     np.testing.assert_array_equal(np.asarray(t_sub.threshold_bin),
@@ -125,7 +125,7 @@ def test_voting_rs_matches_voting_allreduce(rng):
     out = {}
     for hm in ("allreduce", "reduce_scatter"):
         plan = VotingParallelPlan(top_k=3, hist_merge=hm)
-        t, rl, _ = _dp_tree(plan, bins, gh, meta)
+        t, rl, _, _rounds = _dp_tree(plan, bins, gh, meta)
         out[hm] = (jax.device_get(t), np.asarray(rl))
     ta, rla = out["allreduce"]
     ts, rls = out["reduce_scatter"]

@@ -266,9 +266,11 @@ def test_train_event_log_cadence(tmp_path, monkeypatch):
     assert iters == [2, 4, 6]                 # the eval_period=2 cadence
     it = next(r for r in recs if r["event"] == "iteration")
     assert it["ms_per_tree"] > 0 and "training:auc" in it["metrics"]
-    assert set(it["phase_s"]) <= {"grads", "sampling", "build",
-                                  "update", "eval", "hist_merge",
-                                  "winner_sync"}
+    # the span record's names: canonical phases (legacy driver) and the
+    # fixed list of host spans (fused driver, engine, dataset)
+    from lightgbm_tpu import phases
+    assert set(it["phase_s"]) <= phases.KNOWN_PHASES | phases.HOST_SPANS
+    assert "engine.eval" in it["phase_s"]
     assert recs[-1]["event"] == "train_end"
     assert active_session() is None           # closed after train returns
 
@@ -446,6 +448,93 @@ def test_monitor_cli_report_and_check(tmp_path, capsys):
                 + "\n# force parse of the bogus line\n")
     assert monitor_main(["--check", p]) == 1
     assert monitor_main([str(tmp_path / "missing")]) == 1
+
+
+# ------------------------------------------------------ the span record
+def test_span_ring_is_bounded_and_sequenced():
+    from lightgbm_tpu import profiler
+    rec = profiler.SpanRecorder(capacity=8)
+    for i in range(20):
+        rec.record("gbdt.dispatch", i, i + 1)
+    assert len(rec) == 8 and rec.seq == 20
+    assert [s.seq for s in rec.since(0)] == list(range(12, 20))
+    assert [s.seq for s in rec.since(18)] == [18, 19]
+    # the always-on recorder is a ring too, and span names are a fixed
+    # list: an unknown one is an error at the site, not a silent record
+    assert profiler.recorder._ring.maxlen == profiler.RING_SPANS
+    with pytest.raises(ValueError, match="unknown profiler phase"):
+        with profiler.span("not.a.span"):
+            pass
+
+
+def test_span_parent_iteration_and_fields():
+    from lightgbm_tpu import profiler
+    seq0 = profiler.recorder.seq
+    profiler.recorder.iteration = 41
+    with profiler.span("gbdt.dispatch"):
+        with profiler.span("gbdt.step_ready") as fields:
+            fields["cache_hits"] = 1
+    inner, outer = profiler.recorder.since(seq0)
+    assert (inner.name, inner.parent) == ("gbdt.step_ready",
+                                          "gbdt.dispatch")
+    assert (outer.name, outer.parent) == ("gbdt.dispatch", "")
+    assert inner.fields == {"cache_hits": 1} and inner.iteration == 41
+    assert outer.start_ns <= inner.start_ns <= inner.end_ns <= outer.end_ns
+    # inside traced code a stage is a named scope and records nothing
+    import jax
+    seq1 = profiler.recorder.seq
+
+    @jax.jit
+    def f(x):
+        with profiler.stage("build"), profiler.stage("hist_kernel"):
+            return x + 1
+    f(1)
+    assert profiler.recorder.seq == seq1
+
+
+def test_per_iteration_from_ring_on_legacy_driver():
+    """Legacy driver: per_iteration computed from the ring equals the
+    plain sums over the spans the run recorded (what the old
+    accumulator, fed by phase() itself, used to hold), with one
+    grads/sampling span and K build spans an iteration."""
+    from lightgbm_tpu import profiler
+    seq0 = profiler.recorder.seq
+    rounds = 4
+    with profiler.collect_phase_totals() as col:
+        bst = _train(rounds=rounds, extra={"fused_train": False})
+    assert not bst._gbdt.fused_ok
+    spans = [s for s in profiler.recorder.since(seq0)
+             if s.seq < col._stop]
+    want = {}
+    for sp in spans:
+        tot, cnt = want.get(sp.name, (0.0, 0))
+        want[sp.name] = (tot + sp.seconds, cnt + 1)
+    per = col.per_iteration(rounds)
+    assert set(per) == set(want)
+    for name, (tot, cnt) in want.items():
+        assert per[name]["total_s"] == pytest.approx(tot)
+        assert per[name]["count"] == cnt
+        assert per[name]["s_per_iter"] == pytest.approx(tot / rounds)
+    assert per["grads"]["spans_per_iter"] == 1
+    assert per["build"]["spans_per_iter"] == 1      # binary: K = 1
+    assert "gbdt.dispatch" not in per               # no fused dispatch
+    # the legacy driver fetches one tree a sync and logs its rounds too
+    assert len(bst._gbdt.round_log) == rounds
+
+
+def test_device_reserved_pool_gauge(monkeypatch):
+    """device_hbm_bytes_peak is in-use plus reserved, as the benchmark
+    reckons the chip's high-water mark; the pool alone has a gauge."""
+    from lightgbm_tpu.telemetry import device as tdev
+    monkeypatch.setattr(tdev, "device_memory_bytes", lambda: {
+        "tpu:0": {"bytes_in_use": 100, "peak_bytes_in_use": 500,
+                  "peak_bytes_reserved": 5000}})
+    reg = MetricsRegistry()
+    tdev.DeviceWatch(reg).sample()
+    text = reg.render()
+    assert 'device_hbm_bytes_peak{device="tpu:0"} 5500' in text
+    assert 'device_hbm_bytes_reserved_peak{device="tpu:0"} 5000' in text
+    assert 'device_hbm_bytes_in_use{device="tpu:0"} 100' in text
 
 
 # -------------------------------------------------------- device gauges
