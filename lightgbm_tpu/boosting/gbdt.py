@@ -63,6 +63,7 @@ class RoundRecord(NamedTuple):
     class_index: int
     rows: np.ndarray     # [rounds] int32 ([n_shards, rounds] when sharded)
     leaves: np.ndarray   # [rounds] int32
+    stream_rows: np.ndarray  # like ``rows``: stream positions touched
 
 
 def _pad_rows(arr: np.ndarray, r_pad: int, fill=0):
@@ -1919,7 +1920,7 @@ class GBDT:
         if rounds is not None:
             self.round_log.append(RoundRecord(
                 int(it), int(k), np.asarray(rounds.rows),
-                np.asarray(rounds.leaves)))
+                np.asarray(rounds.leaves), np.asarray(rounds.stream_rows)))
 
     def train_one_iter(self, gradients: Optional[np.ndarray] = None,
                        hessians: Optional[np.ndarray] = None, *,

@@ -25,10 +25,18 @@ Design (TPU-first; not a translation):
        N-dim padding argument only covers the LEAF axis; the row stream
        is the real cost — without subtraction every round re-streams all
        R rows (~13x/tree at 255 leaves, ~254x in leaf_batch=1 modes).
-       With it, each round streams only the smaller children's rows:
-       compaction defers the bins gather to per-block inside
-       ops/histogram.py, and the block loop is bounded by the live row
-       count, so a round over a 1%-sized leaf pays ~1% of a full pass.
+       With it, each round streams only the smaller children's rows.
+       The builder makes the stream's INDEX and nothing else
+       (``compact_small``: lut, cumsum, ``n_small``, the scatter that
+       writes ``c_idx``); ``bins``, ``gh`` and ``row_leaf`` go to
+       ops/histogram.py uncompacted with ``row_gather=c_idx,
+       num_rows=n_small``, and the wrapper gathers, casts and lays them
+       out inside one loop whose trip count is ``ceil(n_small /
+       chunk)`` (a row block a trip for matmul and scatter; for pallas
+       a chunk of ~R/32 rows a trip into the kernel's operand buffers,
+       then ONE kernel call bounded by the same ``n_small``). So a
+       round over a 1%-sized leaf pays about a chunk of a full pass,
+       on every path; RoundLog.stream_rows counts what it touched.
        The cache holds RAW histograms ([L+1, F, B, 3] f32, int32 when
        quantized — subtraction stays exact), ~5 MB at Higgs shape;
        callers disable hist_sub when the cache would not fit
@@ -75,7 +83,8 @@ import jax.numpy as jnp
 from .. import phases as PHS
 from .. import profiler
 from ..ops.histogram import (build_histograms, resolve_impl, HIST_CH,
-                             merge_histograms, _pvary)
+                             merge_histograms, stream_chunk_rows,
+                             stream_trips, _pvary)
 # referenced as a module attribute (PH.fused_build_best_splits) so tests
 # can monkeypatch interpret-mode wrappers in
 from ..ops import pallas_histogram as PH
@@ -117,6 +126,12 @@ class RoundLog(NamedTuple):
     #                     stream was bounded by (n_small under
     #                     compaction; per shard under a row-sharded plan)
     leaves: jax.Array   # [rounds] int32: splits the round applied
+    stream_rows: jax.Array  # [rounds] int32: stream positions the round
+    #                     touched to feed the histogram: trips x chunk
+    #                     of the compacted stream's loop
+    #                     (ops.histogram.stream_chunk_rows), R where the
+    #                     stream is unbounded (native, hist_sub off);
+    #                     per shard like ``rows``
 
 
 def max_rounds_for(num_leaves: int, leaf_batch: int) -> int:
@@ -235,14 +250,15 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
       the split is chosen from those global sub-histograms.
     """
     hist_impl = resolve_impl(hist_impl, bundle_bins or num_bins)
-    # Row compaction redirects the bins stream through a gathered index
-    # order. It pays off when the kernel's per-row cost dominates the
-    # one-time [R, F] gather: the matmul one-hot (R*F*B bf16), the CPU
-    # scatter, AND the Pallas kernel — its dynamic row bound (num_rows
-    # scalar prefetch) skips whole row blocks past the compacted live
-    # prefix, so the VMEM one-hot + MXU dot shrink with the small
-    # child's row fraction (the dense_bin.hpp:105 data_indices saving,
-    # VERDICT r4 #3). Only the native C kernel skips compaction: its
+    # Row compaction redirects the row streams through a gathered index
+    # order, and everything downstream of the index is bounded by the
+    # live rows: the matmul one-hot (R*F*B bf16) and the CPU scatter
+    # gather a block a trip, the Pallas path lays its operands out a
+    # chunk a trip and its kernel's dynamic row bound (num_rows scalar
+    # prefetch) skips whole row blocks past the compacted live prefix,
+    # so gathers, relayout, VMEM one-hot and MXU dot all shrink with
+    # the small child's row fraction (the dense_bin.hpp:105
+    # data_indices saving). Only the native C kernel skips compaction: its
     # partition op already maintains exact per-leaf row lists, so a
     # cumsum + gather pass over R would cost more than it saves.
     hist_compact = hist_sub and hist_impl != "native"
@@ -273,6 +289,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     B = num_bins
     DUMMY_LEAF = L          # scatter sink for masked lanes
     DUMMY_NODE = MAXN
+    R_i32 = jnp.asarray(R, jnp.int32)
     BW = (B + 31) // 32     # cat bitset words
 
     f32 = jnp.float32
@@ -529,14 +546,13 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             return h
         return h.astype(f32) * _dq_vec
 
-    def hist_perm_for(slots, part, gh_in=None):
+    def hist_perm_for(slots, part):
         """Histogram via the partition's ordered row lists (native CPU
         custom call): walks exactly the requested slots' segments."""
         mat = local_bins if mode == "feature" else bins
         nb_in = bundle_bins if use_bundle else B
         merge = mode not in ("feature", "voting")
-        g = gh if gh_in is None else gh_in
-        q = g.dtype == jnp.int8
+        q = gh.dtype == jnp.int8
         target = "lgbtpu_hist_perm_i8" if q else "lgbtpu_hist_perm_f32"
         S = slots.shape[0]
         out_sds = jax.ShapeDtypeStruct(
@@ -545,7 +561,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         bf16 = bool((not q) and jnp.dtype(hist_dtype) == jnp.bfloat16)
         with profiler.stage(PHS.HIST_KERNEL):
             h = jax.ffi.ffi_call(target, out_sds)(
-                mat, g, part[0], part[1], part[2], slots.astype(jnp.int32),
+                mat, gh, part[0], part[1], part[2], slots.astype(jnp.int32),
                 bf16_round=bf16)
         if axis_name is not None:
             h = _pvary(h, axis_name)
@@ -555,8 +571,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                     "reduce_scatter" if rs_data else True, n_shards)
         return h
 
-    def hist_raw_for(slots, rl, gh_in=None, row_gather=None, num_rows=None,
-                     part=None):
+    def hist_raw_for(slots, rl, row_gather=None, num_rows=None, part=None):
         """RAW histogram for the given leaf slots — before dequant and
         EFB unbundling, both of which are LINEAR, so parent-minus-child
         subtraction happens in this space (exactly, int32, when
@@ -574,7 +589,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
           EFB is on: a feature never spans bundles, so whole features
           stay chip-local and the raw cache stays exact)."""
         if use_native_part and part is not None:
-            return hist_perm_for(slots, part, gh_in=gh_in)
+            return hist_perm_for(slots, part)
         mat = local_bins if mode == "feature" else bins
         nb_in = bundle_bins if use_bundle else B
         if mode in ("feature", "voting"):
@@ -584,10 +599,39 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         else:
             merge = True
         return build_histograms(
-            mat, gh if gh_in is None else gh_in, rl, slots,
+            mat, gh, rl, slots,
             num_bins=nb_in, block_rows=block_rows, axis_name=axis_name,
             merge=merge, n_shards=n_shards, hist_dtype=hist_dtype,
             impl=hist_impl, row_gather=row_gather, num_rows=num_rows)
+
+    def compact_small(row_leaf, small_slots):
+        """The compacted stream of the small children's rows:
+        ``(c_idx [R] int32, n_small)`` with stream position ``p <
+        n_small`` reading row ``c_idx[p]`` (row order kept; zeros past
+        the live prefix). Membership is a [L+2] lut gather, not a
+        [R, 2W] broadcast compare (42x less traffic at W=21). The rows
+        themselves are gathered where the stream is consumed
+        (``build_histograms(row_gather=, num_rows=)``), chunk by chunk
+        and only as far as ``n_small``."""
+        is_small = jnp.zeros((L + 2,), bool).at[
+            jnp.clip(small_slots, -1, L) + 1].set(True) \
+            .at[0].set(False)           # -1/-2 sentinels
+        m = jnp.take(is_small, jnp.clip(row_leaf, -1, L) + 1)
+        pos = jnp.cumsum(m.astype(jnp.int32)) - 1
+        n_small = m.astype(jnp.int32).sum()
+        c_idx = jnp.zeros((R,), jnp.int32).at[
+            jnp.where(m, pos, R)].set(
+            jnp.arange(R, dtype=jnp.int32), mode="drop")
+        return c_idx, n_small
+
+    def stream_rows_for(impl, n_live):
+        """Stream positions a compacted round touches for ``n_live``
+        live rows (RoundLog.stream_rows)."""
+        mat = local_bins if mode == "feature" else bins
+        chunk = stream_chunk_rows(
+            impl, R, mat.shape[1], bundle_bins if use_bundle else B, W,
+            gh.dtype, hist_dtype, block_rows)
+        return (stream_trips(n_live, chunk, R) * chunk).astype(jnp.int32)
 
     def hist_finish(hraw):
         """Raw -> per-feature f32 split-finding space. The scattered
@@ -988,27 +1032,22 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         iw = jnp.arange(W, dtype=jnp.int32)
 
         def fused_call(slots, fmask_s, depth_s, lo, hi, po, rl,
-                       gh_in=None, row_gather=None, num_rows=None,
-                       emit_hist=False):
+                       row_gather=None, num_rows=None, emit_hist=False):
             """One fused launch over a leaf-slot lattice. Mirrors the
             metadata prep of best_for's serial arm; the kernel gates
             smoothing/monotone internally on params, so unused operands
             ride as zeros."""
             pen = (monotone_penalty_factor(depth_s, sp.monotone_penalty)
                    if pen_on else None)
-            if row_gather is None:
-                mat = bins
-            else:
-                with profiler.stage(PHS.HIST_GATHER):
-                    mat = jnp.take(bins, row_gather, axis=0)
             return PH.fused_build_best_splits(
-                mat, gh if gh_in is None else gh_in, rl, slots,
+                bins, gh, rl, slots,
                 num_bins=B, params=sp, num_bins_pf=num_bins_pf,
                 nan_bin_pf=nan_bin_pf, is_cat_pf=is_cat_pf,
                 feature_mask=fmask_s, mono_type=mono_type_pf,
                 leaf_lo=lo, leaf_hi=hi, parent_output=po, mono_pen=pen,
                 quant_scales=quant_scales, hist_dtype=hist_dtype,
-                num_rows=num_rows, emit_hist=emit_hist)
+                num_rows=num_rows, row_gather=row_gather,
+                emit_hist=emit_hist)
 
         def fused_children(stg, st, t, row_leaf, sel_s, right_slot, valid,
                            slots2w, slots2w_c, depth2w, mid_state, keyr,
@@ -1032,7 +1071,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             if not hist_sub:
                 bs, _ = fused_call(slots2w, fmask2w, depth2w, lo2w, hi2w,
                                    po2w, row_leaf, emit_hist=False)
-                return bs, nsh, jnp.asarray(R, jnp.int32)
+                return bs, nsh, R_i32, R_i32
             stg(PHS.COUNT)
             rlc_n = jnp.where(row_leaf < 0, DUMMY_LEAF, row_leaf)
             raw_cnt = jax.ops.segment_sum(
@@ -1048,28 +1087,15 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             def _lane(a, idx):
                 return None if a is None else jnp.take(a, idx, axis=0)
 
-            # compacted small-child stream (same lut/cumsum pass as the
-            # legacy hist_compact path)
+            # compacted small-child stream
             stg(PHS.COMPACT)
-            is_small = jnp.zeros((L + 2,), bool).at[
-                jnp.clip(small_slots, -1, L) + 1].set(True) \
-                .at[0].set(False)
-            m = jnp.take(is_small, jnp.clip(row_leaf, -1, L) + 1)
-            pos = jnp.cumsum(m.astype(jnp.int32)) - 1
-            n_small = m.astype(jnp.int32).sum()
-            c_idx = jnp.zeros((R,), jnp.int32).at[
-                jnp.where(m, pos, R)].set(
-                jnp.arange(R, dtype=jnp.int32), mode="drop")
-            rl_c = jnp.where(
-                jnp.arange(R, dtype=jnp.int32) < n_small,
-                jnp.take(row_leaf, c_idx), -1)
-            gh_c = jnp.take(gh, c_idx, axis=0)
+            c_idx, n_small = compact_small(row_leaf, small_slots)
             stg(PHS.FIND)
             bs_s, hsmall = fused_call(
                 small_slots, _lane(fmask2w, idx_small),
                 _lane(depth2w, idx_small), _lane(lo2w, idx_small),
                 _lane(hi2w, idx_small), _lane(po2w, idx_small),
-                rl_c, gh_in=gh_c, row_gather=c_idx, num_rows=n_small,
+                row_leaf, row_gather=c_idx, num_rows=n_small,
                 emit_hist=True)
             stg(PHS.SUBTRACT)
             parent_raw = jnp.take(st["hist_cache"],
@@ -1098,7 +1124,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                 return jnp.concatenate([jnp.where(s_, ks, kb),
                                         jnp.where(s_, kb, ks)])
             bs = {k: _mix(bs_s[k], bs_b[k]) for k in bs_b}
-            return bs, nsh, n_small
+            return bs, nsh, n_small, stream_rows_for("pallas", n_small)
 
     # ---------------- state ----------------
     tree = TreeArrays(
@@ -1277,6 +1303,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     if axis_name is not None and mode != "feature":
         round_rows0 = _pvary(round_rows0, axis_name)
     state["round_rows"] = round_rows0
+    state["round_stream"] = round_rows0
     state["round_leaves"] = jnp.zeros((rounds_bound,), jnp.int32)
 
     state.update(tree=tree, bs_gain=bs_gain, bs_feat=bs_feat, bs_thr=bs_thr,
@@ -1760,10 +1787,11 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         mid_state = dict(leaf_lo=leaf_lo, leaf_hi=leaf_hi,
                          **new_state_extra, **new_state_mono)
         valid2w = jnp.concatenate([valid, valid])
-        # rows this round's histogram stream is bounded by (RoundLog)
-        rows_r = jnp.asarray(R, jnp.int32)
+        # rows this round's histogram stream is bounded by, and the
+        # stream positions it touches (RoundLog)
+        rows_r = stream_r = R_i32
         if use_fused:
-            bs, nsh, rows_r = fused_children(
+            bs, nsh, rows_r, stream_r = fused_children(
                 stg, st, t, row_leaf, sel_s, right_slot, valid, slots2w,
                 slots2w_c, depth2w, mid_state, keyr, leaf_lo, leaf_hi)
             new_state_hist.update(nsh)
@@ -1793,26 +1821,12 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             small_slots = jnp.where(
                 valid, jnp.where(small_is_left, sel_s, right_slot), -2)
             if hist_compact:
-                # membership via a [L+2] lut gather, not a [R, 2W]
-                # broadcast compare (42x less traffic at W=21)
                 stg(PHS.COMPACT)
-                is_small = jnp.zeros((L + 2,), bool).at[
-                    jnp.clip(small_slots, -1, L) + 1].set(True) \
-                    .at[0].set(False)           # -1/-2 sentinels
-                m = jnp.take(is_small, jnp.clip(row_leaf, -1, L) + 1)
-                pos = jnp.cumsum(m.astype(jnp.int32)) - 1
-                n_small = m.astype(jnp.int32).sum()
-                c_idx = jnp.zeros((R,), jnp.int32).at[
-                    jnp.where(m, pos, R)].set(
-                    jnp.arange(R, dtype=jnp.int32), mode="drop")
-                rl_c = jnp.where(
-                    jnp.arange(R, dtype=jnp.int32) < n_small,
-                    jnp.take(row_leaf, c_idx), -1)
-                gh_c = jnp.take(gh, c_idx, axis=0)
+                c_idx, n_small = compact_small(row_leaf, small_slots)
                 rows_r = n_small
-                hsmall = hist_raw_for(small_slots, rl_c, gh_in=gh_c,
-                                      row_gather=c_idx,
-                                      num_rows=n_small)
+                stream_r = stream_rows_for(hist_impl, n_small)
+                hsmall = hist_raw_for(small_slots, row_leaf,
+                                      row_gather=c_idx, num_rows=n_small)
             else:
                 # the partition's exact row lists (native): this shard's
                 # rows of the small children
@@ -1860,6 +1874,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                    leaf_depth=leaf_depth, leaf_lo=leaf_lo, leaf_hi=leaf_hi,
                    r=st["r"] + 1,
                    round_rows=st["round_rows"].at[st["r"]].set(rows_r),
+                   round_stream=st["round_stream"].at[st["r"]].set(stream_r),
                    round_leaves=st["round_leaves"].at[st["r"]].set(n_valid),
                    **new_state_extra, **new_state_mono,
                    **new_state_forced, **new_state_hist,
@@ -1867,7 +1882,8 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         return out
 
     state = jax.lax.while_loop(cond, body, state)
-    rounds = RoundLog(rows=state["round_rows"], leaves=state["round_leaves"])
+    rounds = RoundLog(rows=state["round_rows"], leaves=state["round_leaves"],
+                      stream_rows=state["round_stream"])
     if use_cegb:
         cegb_out = (state["cegb_feat_used"],
                     state.get("cegb_used_rows"))

@@ -56,7 +56,7 @@ import numpy as np
 from .. import phases, profiler
 
 __all__ = ["build_histograms", "resolve_impl", "pallas_shape_reason",
-           "merge_histograms", "HIST_CH"]
+           "merge_histograms", "stream_chunk_rows", "stream_trips", "HIST_CH"]
 
 # channels per histogram cell: (sum_grad, sum_hess, count)
 HIST_CH = 3
@@ -114,6 +114,46 @@ def _pick_block_rows(num_rows: int, fb: int, dtype_bytes: int = 2,
 
 def block_rows_for(num_rows: int, num_features: int, num_bins: int) -> int:
     return _pick_block_rows(num_rows, num_features * num_bins)
+
+
+def _resolve_block_rows(R: int, F: int, B: int, block_rows: int) -> int:
+    """The row block :func:`build_histograms` runs with."""
+    if block_rows <= 0:
+        block_rows = _pick_block_rows(R, F * B)
+    if R % block_rows != 0:
+        # fall back: single block (caller should pad; keeps jit legal)
+        block_rows = R
+    return block_rows
+
+
+def stream_trips(num_rows, chunk: int, R: int):
+    """Trips of a loop that walks a stream of ``num_rows`` live
+    positions (traced) ``chunk`` at a time, of the ``ceil(R / chunk)``
+    the whole stream has."""
+    return jnp.clip((num_rows + chunk - 1) // chunk, 0, -(-R // chunk))
+
+
+def stream_chunk_rows(impl: str, R: int, F: int, num_bins: int,
+                      num_slots: int, gh_dtype, hist_dtype: str,
+                      block_rows: int = 0) -> int:
+    """Rows per trip of the loop that bounds :func:`build_histograms`'
+    compacted stream (``row_gather`` + ``num_rows``) for these static
+    arguments: a round touches ``stream_trips(num_rows, chunk, R) *
+    chunk`` stream positions. The row block for matmul and scatter, the
+    layout loop's chunk for pallas, R for native (the C kernel stops at
+    ``num_rows`` itself; the wrapper's compaction before it does not)."""
+    impl = resolve_impl(impl, num_bins)
+    if impl == "native":
+        from .. import native as _native
+        if _native.hist_lib() is not None:
+            return R
+    if impl == "pallas":
+        from . import pallas_histogram as PH
+        cdt, _ = PH._kernel_dtypes(gh_dtype, hist_dtype)
+        blk = PH._plan(F, num_bins, num_slots * HIST_CH,
+                       jnp.dtype(cdt).itemsize)[0]
+        return PH.stream_chunk(R, blk)
+    return _resolve_block_rows(R, F, num_bins, block_rows)
 
 
 def _pvary(x, axis_name):
@@ -223,17 +263,25 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
 
     Dynamic row stream (the histogram-subtraction companion, VERDICT r3
     #2 — the analog of dense_bin.hpp:105 iterating ``data_indices``
-    only): ``row_gather`` [R] int32 is a compacted row-index order for
-    ``bins`` — ``gh`` and ``row_leaf`` are passed ALREADY compacted by
-    the caller (they are narrow; bins is the wide stream whose gather is
-    deferred to per-block, so unprocessed blocks never touch it).
-    ``num_rows`` (traced scalar) bounds the stream: only
-    ``ceil(num_rows / block_rows)`` blocks are processed via a
-    dynamically-bounded loop — rows past ``num_rows`` must carry
-    ``row_leaf == -1``. Works inside shard_map: each shard bounds its
-    own stream; the psum after the loop re-syncs. The Pallas path
-    honors ``row_gather`` by materializing the gathered bins (correct
-    but not yet a bandwidth win; its grid is static).
+    only): ``row_gather`` [R] int32 is a compacted row-index order.
+    With it set, ``bins``, ``gh`` and ``row_leaf`` all arrive
+    UNCOMPACTED and stream position ``p`` reads row ``row_gather[p]``
+    of each; positions at or past ``num_rows`` (traced scalar) count as
+    dead (leaf -1) whatever ``row_gather`` holds there. The compaction
+    is this wrapper's alone and is bounded by the live rows for every
+    ``impl``: matmul and scatter gather one row block per trip of a
+    ``ceil(num_rows / block_rows)``-trip loop; pallas lays its
+    lane-major operands out chunk by chunk in a
+    ``ceil(num_rows / chunk)``-trip loop (ops/pallas_histogram.py
+    ``_stream_operands``) and calls the kernel once with the same bound
+    as its scalar prefetch; native compacts ``gh`` / ``row_leaf`` ahead
+    of the FFI call, whose own loop stops at ``num_rows``
+    (:func:`stream_chunk_rows` names each granularity). No R-sized
+    gather, cast or transpose runs on the chip's path. ``num_rows``
+    without ``row_gather`` bounds an already-ordered stream: rows past
+    it must then carry ``row_leaf == -1``. Works inside shard_map: each
+    shard bounds its own stream (no collective sits inside the loops);
+    the merge after the kernel re-syncs.
 
     Carried accumulation (out-of-core, data/chunked.py): ``init``
     [L, F, B, CH] seeds the accumulator, so a row stream too large for
@@ -257,23 +305,16 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
     """
     R, F = bins.shape
     B = num_bins
-    if block_rows <= 0:
-        block_rows = _pick_block_rows(R, F * B)
-    if R % block_rows != 0:
-        # fall back: single block (caller should pad; keeps jit legal)
-        block_rows = R
+    block_rows = _resolve_block_rows(R, F, B, block_rows)
     impl = resolve_impl(impl, B)
 
     if impl == "pallas":
         from .pallas_histogram import build_histograms_pallas
-        bins_p = bins
-        if row_gather is not None:
-            with profiler.stage(phases.HIST_GATHER):
-                bins_p = jnp.take(bins, row_gather, axis=0)
-        # stages hist_relayout and hist_kernel inside
+        # stages hist_gather, hist_relayout and hist_kernel inside
         hist = build_histograms_pallas(
-            bins_p, gh, row_leaf, leaf_ids, num_bins=B,
-            hist_dtype=hist_dtype, num_rows=num_rows)
+            bins, gh, row_leaf, leaf_ids, num_bins=B,
+            hist_dtype=hist_dtype, num_rows=num_rows,
+            row_gather=row_gather)
         if init is not None:
             hist = hist + init
         # honor merge=False: feature-parallel slots are feature-disjoint
@@ -288,6 +329,15 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
         return _build_histograms_xla(
             bins, gh, row_leaf, leaf_ids, B, impl, block_rows, hist_dtype,
             axis_name, merge, n_shards, row_gather, num_rows, init)
+
+
+def _gather_rows(gh, row_leaf, idx, start, num_rows):
+    """``gh`` and ``row_leaf`` of the stream positions ``start +
+    arange(len(idx))``, which read rows ``idx``; positions at or past
+    ``num_rows`` are dead (leaf -1)."""
+    pos = start + jnp.arange(idx.shape[0], dtype=jnp.int32)
+    return (jnp.take(gh, idx, axis=0),
+            jnp.where(pos < num_rows, jnp.take(row_leaf, idx), -1))
 
 
 def _build_histograms_xla(bins, gh, row_leaf, leaf_ids, B, impl, block_rows,
@@ -323,6 +373,11 @@ def _build_histograms_xla(bins, gh, row_leaf, leaf_ids, B, impl, block_rows,
             nr_in = (num_rows if num_rows is not None
                      else jnp.asarray(R, jnp.int32))
             nr_in = jnp.asarray(nr_in, jnp.int32).reshape((1,))
+            if has_rg:
+                # the C kernel reads gh and row_leaf by stream position
+                with profiler.stage(phases.HIST_GATHER):
+                    gh, row_leaf = _gather_rows(
+                        gh, row_leaf, row_gather, 0, nr_in[0])
             out_sds = jax.ShapeDtypeStruct((L, F, B, HIST_CH), acc_dt_n)
             target = "lgbtpu_hist_i8" if quant else "lgbtpu_hist_f32"
             hist = jax.ffi.ffi_call(target, out_sds)(
@@ -346,7 +401,7 @@ def _build_histograms_xla(bins, gh, row_leaf, leaf_ids, B, impl, block_rows,
     # rows, via fori_loop; otherwise a full static scan (cheapest trace)
     dyn = (num_rows is not None) or (row_gather is not None)
     if num_rows is not None:
-        nb_used = jnp.clip((num_rows + block_rows - 1) // block_rows, 0, nb)
+        nb_used = stream_trips(num_rows, block_rows, R)
     else:
         nb_used = nb
 
@@ -356,8 +411,10 @@ def _build_histograms_xla(bins, gh, row_leaf, leaf_ids, B, impl, block_rows,
             idx = jax.lax.dynamic_slice(row_gather, (s,), (block_rows,))
             with profiler.stage(phases.HIST_GATHER):
                 bb = jnp.take(bins, idx, axis=0)
-        else:
-            bb = jax.lax.dynamic_slice(bins, (s, 0), (block_rows, F))
+                ghb, lb = _gather_rows(gh, row_leaf, idx, s,
+                                       R if num_rows is None else num_rows)
+            return bb, ghb, lb
+        bb = jax.lax.dynamic_slice(bins, (s, 0), (block_rows, F))
         ghb = jax.lax.dynamic_slice(gh, (s, 0), (block_rows, HIST_CH))
         lb = jax.lax.dynamic_slice(row_leaf, (s,), (block_rows,))
         return bb, ghb, lb

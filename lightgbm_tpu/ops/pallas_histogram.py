@@ -35,6 +35,16 @@ matmul; no reshape, gather, concatenate or narrow-dtype arithmetic):
 - ``hist[fc*Bp, lanes] += onehot @ ghl^T`` (contract the row axis of
   both — the q @ k^T form).
 
+Compacted streams (``row_gather`` + ``num_rows``, what every round of
+the grow loop after the root pass sends): the lane-major operands are
+not made from a gathered copy of the whole matrix. :func:`_stream_operands`
+runs ``ceil(num_rows / chunk)`` trips of gather -> cast -> transpose ->
+``dynamic_update_slice`` into the operand buffers, ``chunk`` a multiple
+of the row block near R / 32, and the kernel is called once on the
+buffers with ``num_rows`` as its scalar-prefetch bound
+(:func:`build_histograms_pallas_lanes` is that call, for operands laid
+out already). What lies past the last chunk written is never read.
+
 Grid: ``(feature_chunks, row_blocks)`` with rows innermost, so each
 feature chunk's accumulator stays pinned in VMEM across the whole row
 stream (TPU grids execute sequentially; revisiting the same out block is
@@ -70,10 +80,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import phases, profiler
-from .histogram import HIST_CH, pallas_shape_reason
+from .histogram import (HIST_CH, _gather_rows, pallas_shape_reason,
+                        stream_trips)
 from . import split as _split
 
-__all__ = ["build_histograms_pallas", "fused_build_best_splits",
+__all__ = ["build_histograms_pallas", "build_histograms_pallas_lanes",
+           "stream_chunk", "fused_build_best_splits",
            "fused_candidate_bytes", "build_root_histograms_classes",
            "FUSED_SPLIT_TPU_REASON"]
 
@@ -211,6 +223,94 @@ def _leaf_lanes(row_leaf, r_pad: int):
                           constant_values=-1)
 
 
+def _lane_operands(bins, gh, row_leaf, r_pad: int, *, fc: int, n_fb: int,
+                   acc_dt):
+    """The kernel's row-stream operands from row-major ones: cast, pad
+    and transpose, rows onto lanes. ``(bins [n_fb, fc, r_pad] int32,
+    gh [3, r_pad] acc_dt, leaf [1, r_pad] int32)``."""
+    return (_bins_chunks(bins, r_pad, fc, n_fb),
+            _rows_to_lanes(gh.astype(acc_dt), r_pad),
+            _leaf_lanes(row_leaf, r_pad))
+
+
+# A compacted stream is laid out in at most this many chunks: the
+# granularity follows the shape (R / 32 rows, rounded up to the row
+# block), so the rows a round touches past its live prefix stay under
+# 1/32 of R whatever the shape.
+_STREAM_CHUNKS = 32
+
+
+def stream_chunk(R: int, blk: int) -> int:
+    """Rows per chunk of the compacted-stream layout loop: the multiple
+    of the kernel's row block next above ``R / 32``."""
+    return _ceil_to(-(-R // _STREAM_CHUNKS), blk)
+
+
+def _vary_like(x, vma):
+    """Mark ``x`` varying over the manual axes in ``vma`` it does not
+    vary over yet (a loop carry must have its body's type)."""
+    missing = tuple(sorted(vma - jax.typeof(x).vma))
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+
+def _stream_operands(bins, gh, row_leaf, row_gather, live, *, chunk: int,
+                     fc: int, n_fb: int, acc_dt):
+    """:func:`_lane_operands` of a COMPACTED stream, bounded by its live
+    rows: stream position ``p`` reads row ``row_gather[p]`` of the
+    uncompacted ``bins`` / ``gh`` / ``row_leaf``, positions at or past
+    ``live`` count as dead (leaf -1). One loop of ``ceil(live / chunk)``
+    trips gathers a chunk's rows, brings them into the lane-major
+    layout and writes them into the operand buffers at lane offset
+    ``i * chunk``; nothing R-sized is gathered, cast or transposed.
+    The buffers start uninitialized and stay so past the last chunk
+    written: the kernel skips those row blocks and clamps their DMAs
+    (``chunk`` is a multiple of its row block). No collective may sit
+    in the loop: under shard_map every shard runs its own trip count.
+
+    The operands' lane extent is ``ceil(R / chunk) * chunk``.
+    """
+    R = bins.shape[0]
+    r_pad = _ceil_to(R, chunk)
+    idx_all = jnp.pad(row_gather.astype(jnp.int32), (0, r_pad - R))
+    vma = _out_vma(bins, gh, row_leaf, row_gather, live)
+    bufs = tuple(
+        _vary_like(jax.lax.empty(shape, dt), vma) for shape, dt in (
+            ((n_fb, fc, r_pad), jnp.int32), ((HIST_CH, r_pad), acc_dt),
+            ((1, r_pad), jnp.int32)))
+    def put_chunk(i, bufs):
+        s = i * chunk
+        with profiler.stage(phases.HIST_GATHER):
+            idx = jax.lax.dynamic_slice(idx_all, (s,), (chunk,))
+            bb = jnp.take(bins, idx, axis=0)
+            ghb, lb = _gather_rows(gh, row_leaf, idx, s, live[0])
+        with profiler.stage(phases.HIST_RELAYOUT):
+            piece = _lane_operands(bb, ghb, lb, chunk, fc=fc, n_fb=n_fb,
+                                   acc_dt=acc_dt)
+            return tuple(
+                jax.lax.dynamic_update_slice(
+                    buf, p, (0,) * (buf.ndim - 1) + (s,))
+                for buf, p in zip(bufs, piece))
+
+    return jax.lax.fori_loop(0, stream_trips(live[0], chunk, R), put_chunk,
+                             bufs)
+
+
+def _row_stream(bins, gh, row_leaf, row_gather, num_rows, *, blk: int,
+                fc: int, n_fb: int, acc_dt):
+    """``(live, operands)`` of a kernel call: the whole matrix re-laid
+    (no ``row_gather``), or the compacted stream's live chunks
+    (:func:`_stream_operands`)."""
+    R = bins.shape[0]
+    live = _live_rows(num_rows, R)
+    if row_gather is None:
+        with profiler.stage(phases.HIST_RELAYOUT):
+            return live, _lane_operands(bins, gh, row_leaf, _ceil_to(R, blk),
+                                        fc=fc, n_fb=n_fb, acc_dt=acc_dt)
+    return live, _stream_operands(
+        bins, gh, row_leaf, row_gather, live, chunk=stream_chunk(R, blk),
+        fc=fc, n_fb=n_fb, acc_dt=acc_dt)
+
+
 def _slot_cols(leaf_ids, lanes: int):
     """[lanes, 2] int32 (slot, channel) map of the channel-major output
     lanes."""
@@ -272,6 +372,15 @@ def _unpack_hist(out, *, F: int, B: int, L: int, fc: int, n_fb: int,
     return hist.reshape(F, B, HIST_CH, L).transpose(3, 0, 1, 2)
 
 
+def _kernel_dtypes(gh_dtype, hist_dtype: str):
+    """(matmul dtype, accumulator dtype) of a build: int8 / int32 for
+    quantized ``gh`` (int8 rows, int32 once laid out), else
+    ``hist_dtype`` / float32."""
+    if gh_dtype in (jnp.int8, jnp.int32):
+        return jnp.int8, jnp.int32
+    return jnp.dtype(hist_dtype), jnp.float32
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("num_bins", "hist_dtype", "interpret"))
@@ -279,7 +388,8 @@ def build_histograms_pallas(bins: jax.Array, gh: jax.Array,
                             row_leaf: jax.Array, leaf_ids: jax.Array, *,
                             num_bins: int, hist_dtype: str = "bfloat16",
                             interpret: bool = False,
-                            num_rows: Optional[jax.Array] = None
+                            num_rows: Optional[jax.Array] = None,
+                            row_gather: Optional[jax.Array] = None
                             ) -> jax.Array:
     """Pallas analog of ops.histogram.build_histograms.
 
@@ -288,36 +398,68 @@ def build_histograms_pallas(bins: jax.Array, gh: jax.Array,
     row block internally (padded rows get leaf -1).
     int8 ``gh`` selects the quantized path (int8 MXU dot, exact int32
     output — see ops/histogram.py docstring).
-    ``num_rows`` (traced int32 scalar): dynamic live-row bound for a
-    COMPACTED stream — it rides in as a scalar-prefetch operand, row
-    blocks at or past ``ceil(num_rows / blk)`` are skipped by
-    ``pl.when`` and their index maps clamp to an already-fetched block
-    (no fresh DMA), so histogram subtraction's row-stream savings
-    survive on the chip. Rows past ``num_rows`` must carry
-    ``row_leaf == -1`` (they are never read when the bound is exact,
-    but the trailing partial block is still masked by leaf ids).
+    ``num_rows`` (traced int32 scalar): dynamic live-row bound — it
+    rides in as a scalar-prefetch operand, row blocks at or past
+    ``ceil(num_rows / blk)`` are skipped by ``pl.when`` and their index
+    maps clamp to an already-fetched block (no fresh DMA), so histogram
+    subtraction's row-stream savings survive on the chip. Without
+    ``row_gather`` the rows past ``num_rows`` must carry
+    ``row_leaf == -1`` (the trailing partial block is masked by leaf
+    ids only).
+    ``row_gather`` [R] int32: the COMPACTED stream — ``bins``, ``gh``
+    and ``row_leaf`` arrive uncompacted, stream position ``p`` reads
+    row ``row_gather[p]`` of each, positions at or past ``num_rows``
+    are dead. The operands are then laid out by a loop over the live
+    chunks only (:func:`_stream_operands`), so everything that feeds
+    the kernel is bounded by ``num_rows`` as the kernel is.
+    Either way the row streams are re-laid rows-onto-lanes here and the
+    kernel is called once, through :func:`build_histograms_pallas_lanes`,
+    the entry for operands that are laid out already.
     ``interpret=True`` runs the kernel in the Pallas interpreter —
     CPU-testable parity with the real TPU lowering.
     Raises ValueError for ``num_bins > 256`` (see module docstring).
     """
-    R, F = bins.shape
+    F = bins.shape[1]
     L = int(leaf_ids.shape[0])
     B = int(num_bins)
     _check_shape(B)
-    quant = gh.dtype == jnp.int8
-    cdt = jnp.int8 if quant else jnp.dtype(hist_dtype)
-    acc_dt = jnp.int32 if quant else jnp.float32
+    cdt, acc_dt = _kernel_dtypes(gh.dtype, hist_dtype)
+    blk, fc, n_fb, _, _ = _plan(F, B, L * HIST_CH, jnp.dtype(cdt).itemsize)
+    live, operands = _row_stream(
+        bins, gh, row_leaf, row_gather, num_rows, blk=blk, fc=fc, n_fb=n_fb,
+        acc_dt=acc_dt)
+    return build_histograms_pallas_lanes(
+        *operands, leaf_ids, live, num_features=F, num_bins=B,
+        hist_dtype=hist_dtype, interpret=interpret)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_features", "num_bins", "hist_dtype", "interpret"))
+def build_histograms_pallas_lanes(bins_t: jax.Array, gh_t: jax.Array,
+                                  leaf_t: jax.Array, leaf_ids: jax.Array,
+                                  live: jax.Array, *, num_features: int,
+                                  num_bins: int,
+                                  hist_dtype: str = "bfloat16",
+                                  interpret: bool = False) -> jax.Array:
+    """:func:`build_histograms_pallas` for operands already in the
+    kernel's layout: ``bins_t`` [n_fb, fc, r_pad] int32, ``gh_t``
+    [3, r_pad] (f32, or int32 grid values: quantized), ``leaf_t``
+    [1, r_pad] int32 with rows on the lane axis, ``r_pad`` a multiple of
+    the plan's row block; ``live`` [1] int32 the live-row bound. Row
+    blocks at or past ``ceil(live / blk)`` are never read, so they may
+    hold anything. One ``pallas_call``; returns [L, F, B, 3]."""
+    F, B = int(num_features), int(num_bins)
+    L = int(leaf_ids.shape[0])
+    _check_shape(B)
+    cdt, acc_dt = _kernel_dtypes(gh_t.dtype, hist_dtype)
     blk, fc, n_fb, Bp, lanes = _plan(F, B, L * HIST_CH,
                                      jnp.dtype(cdt).itemsize)
-    r_pad = _ceil_to(R, blk)
-    live = _live_rows(num_rows, R)
+    r_pad = bins_t.shape[-1]
+    if bins_t.shape != (n_fb, fc, r_pad) or r_pad % blk:
+        raise ValueError(f"bins_t {bins_t.shape} is not the plan's "
+                         f"[{n_fb}, {fc}, k * {blk}] layout")
     fb = fc * Bp
-    with profiler.stage(phases.HIST_RELAYOUT):
-        # cast, pad and transpose of the row streams: rows onto lanes
-        operands = (_bins_chunks(bins, r_pad, fc, n_fb),
-                    _rows_to_lanes(gh.astype(acc_dt), r_pad),
-                    _leaf_lanes(row_leaf, r_pad),
-                    _slot_cols(leaf_ids, lanes))
     with profiler.stage(phases.HIST_KERNEL):
         out = pl.pallas_call(
             functools.partial(_accumulate_step, Bp=Bp, cdt=cdt,
@@ -330,11 +472,11 @@ def build_histograms_pallas(bins: jax.Array, gh: jax.Array,
             ),
             out_shape=jax.ShapeDtypeStruct(
                 (n_fb * fb, lanes), acc_dt,
-                vma=_out_vma(bins, gh, row_leaf, leaf_ids, live)),
+                vma=_out_vma(bins_t, gh_t, leaf_t, leaf_ids, live)),
             compiler_params=_compiler_params(),
             interpret=interpret,
             name=HIST_KERNEL_NAME,
-        )(live, *operands)
+        )(live, bins_t, gh_t, leaf_t, _slot_cols(leaf_ids, lanes))
         return _unpack_hist(out, F=F, B=B, L=L, fc=fc, n_fb=n_fb, Bp=Bp,
                             lanes=lanes)
 
@@ -459,6 +601,7 @@ def fused_build_best_splits(bins: jax.Array, gh: jax.Array,
                             hist_dtype: str = "bfloat16",
                             interpret: bool = False,
                             num_rows: Optional[jax.Array] = None,
+                            row_gather: Optional[jax.Array] = None,
                             emit_hist: bool = False):
     """One VMEM-resident pass: build histograms AND find best splits.
 
@@ -466,7 +609,8 @@ def fused_build_best_splits(bins: jax.Array, gh: jax.Array,
     compiler's own message (``FUSED_SPLIT_TPU_REASON``).
 
     Contract mirrors `build_histograms_pallas` for the row-stream
-    operands plus `ops.split.find_best_splits` for the metadata; returns
+    operands (``row_gather`` / ``num_rows`` included: one compacted
+    stream layout serves both) plus `ops.split.find_best_splits` for the metadata; returns
     ``(best, hist)`` where ``best`` is the find_best_splits dict (gain,
     feature, threshold, default_left, left_sum, right_sum, left_out,
     right_out, is_cat_split, cat_bitset — plus "slot_totals" [L, 3], the
@@ -485,22 +629,23 @@ def fused_build_best_splits(bins: jax.Array, gh: jax.Array,
     sorted-subset categoricals, extra-trees random thresholds,
     gain scale/penalty (feature_contri, CEGB), advanced monotone bounds.
     """
-    R, F = bins.shape
+    F = bins.shape[1]
     L = int(leaf_ids.shape[0])
     B = int(num_bins)
     _check_shape(B)
     quant = gh.dtype == jnp.int8
     if quant and quant_scales is None:
         raise ValueError("int8 gh requires quant_scales")
-    cdt = jnp.int8 if quant else jnp.dtype(hist_dtype)
-    acc_dt = jnp.int32 if quant else jnp.float32
+    cdt, acc_dt = _kernel_dtypes(gh.dtype, hist_dtype)
     blk, fc, n_fb, Bp, lanes = _plan(F, B, L * HIST_CH,
                                      jnp.dtype(cdt).itemsize)
     fb = fc * Bp
     f_pad = n_fb * fc
     l_rec = _ceil_to(L, 8)
-    r_pad = _ceil_to(R, blk)
-    n_rb = r_pad // blk
+    live, operands = _row_stream(
+        bins, gh, row_leaf, row_gather, num_rows, blk=blk, fc=fc, n_fb=n_fb,
+        acc_dt=acc_dt)
+    n_rb = operands[0].shape[-1] // blk
 
     use_mono = mono_type is not None
     use_smooth = params.path_smooth > 0.0
@@ -589,9 +734,7 @@ def fused_build_best_splits(bins: jax.Array, gh: jax.Array,
         out_shape=(hist_shape, cand_shape) if emit_hist else cand_shape,
         compiler_params=_compiler_params(),
         interpret=interpret,
-    )(_live_rows(num_rows, R), _bins_chunks(bins, r_pad, fc, n_fb),
-      _rows_to_lanes(gh.astype(acc_dt), r_pad), _leaf_lanes(row_leaf, r_pad),
-      _slot_cols(leaf_ids, lanes), fmeta, lmeta, fmask)
+    )(live, *operands, _slot_cols(leaf_ids, lanes), fmeta, lmeta, fmask)
 
     if emit_hist:
         hist_raw, cand = outs

@@ -459,7 +459,7 @@ def _build_tree_fp_jit(mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
                   fsh, fsh, fsh, fsh, fsh, rep, valid_in_specs,
                   extras_specs),
         out_specs=(tree_specs, rep, tuple([rep] * n_valid),
-                   RoundLog(rep, rep)),
+                   RoundLog(rep, rep, rep)),
         check_vma=False)
     return fn(bins_p, bins_p, gh, row_leaf0, num_bins_p, nan_bin_p,
               is_cat_p, fmask_p, num_bins_p, nan_bin_p, is_cat_p, fmask_p,
@@ -510,7 +510,8 @@ def _build_tree_dp_jit(mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
         # each shard counts its own row stream: a shard axis of one,
         # laid out along the mesh axis ([.., n_shards, rounds] outside)
         return tree, rl_out, vrl_out, rounds._replace(
-            rows=rounds.rows[..., None, :])
+            rows=rounds.rows[..., None, :],
+            stream_rows=rounds.stream_rows[..., None, :])
 
     tree_specs = jax.tree.map(lambda _: rep, TreeArrays(
         *([0] * len(TreeArrays._fields))))
@@ -532,9 +533,10 @@ def _build_tree_dp_jit(mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
     gh_spec = P(None, axis_name, None) if class_batched else row2
     rl_spec = P(None, axis_name) if class_batched else row
     out_valid_specs = tuple([rl_spec] * n_valid)
-    rounds_specs = RoundLog(
-        rows=P(None, axis_name, None) if class_batched
-        else P(axis_name, None), leaves=rep)
+    per_shard = (P(None, axis_name, None) if class_batched
+                 else P(axis_name, None))
+    rounds_specs = RoundLog(rows=per_shard, leaves=rep,
+                            stream_rows=per_shard)
     fn = jax.shard_map(
         step, mesh=mesh,
         in_specs=(row2, gh_spec, row, rep, rep, rep, rep, valid_in_specs,
@@ -566,7 +568,8 @@ def build_tree_dp(mesh: Mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
     Same contract as :func:`..boosting.tree_builder.build_tree`; the
     returned TreeArrays are replicated (identical on every chip), the
     returned row→leaf assignments stay row-sharded, and the RoundLog's
-    ``rows`` are per shard, ``[n_shards, rounds]`` along the mesh axis
+    ``rows`` and ``stream_rows`` are per shard, ``[n_shards, rounds]``
+    along the mesh axis
     (no collective is added for them). ``hist_merge``
     selects the histogram merge collective (module docstring).
 

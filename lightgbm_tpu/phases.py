@@ -63,9 +63,12 @@ ROOT_PASS = "root_pass"          # root histogram, totals, root split
 POP = "pop"                      # top-k over cached gains + their takes
 APPLY = "apply"                  # tree scatter, bounds, row_leaf relabel
 COUNT = "count"                  # segment_sum of raw child counts
-COMPACT = "compact"              # is_small lut, cumsum, c_idx, rl_c, gh
-HIST_GATHER = "hist_gather"      # jnp.take(bins, row_gather)
-HIST_RELAYOUT = "hist_relayout"  # cast, pad, transpose for the kernel
+COMPACT = "compact"              # is_small lut, cumsum, n_small, c_idx:
+#                                  the making of the index, no row moves
+HIST_GATHER = "hist_gather"      # bins, gh and row_leaf by row_gather: all
+#                                  three, a chunk (pallas) or a block a trip
+HIST_RELAYOUT = "hist_relayout"  # cast, pad, transpose for the kernel; of
+#                                  a compacted stream, chunk by chunk
 HIST_KERNEL = "hist_kernel"      # the pallas_call (or the XLA block loop)
 SUBTRACT = "subtract"            # parent minus child, cache scatters
 FIND = "find"                    # best_for / fused split + cache scatter

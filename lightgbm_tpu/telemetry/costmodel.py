@@ -322,7 +322,8 @@ def _resolved_phases(hlo_text: str):
     where they disagree the stage holding most of them, and such fusions
     are counted. What the compiler added without metadata (copies for a
     layout, loop-carry plumbing) takes the stage of the instruction that
-    produces its operand, else of the one that uses its result, else of
+    produces its operand, else of the one that uses its result (seen
+    through tuples and bitcasts), else of
     the instruction that runs its computation (the ``while``, the
     fusion): so a body op is never worse off than its loop."""
     rows = _instructions(hlo_text)
@@ -348,15 +349,27 @@ def _resolved_phases(hlo_text: str):
     # producers, then users, then the caller: two sweeps settle chains
     # like copy-start -> copy-done -> user
     users: Dict[str, List[str]] = {}
+    noop = {r.op.name for r in rows if r.op.opcode in _NOOP_OPCODES}
     for r in rows:
         for o in r.operands:
             users.setdefault(o, []).append(r.op.name)
+
+    def real_users(name, depth=3):
+        """Users, seen through tuples and bitcasts: what the compiler
+        prefetches for a nested loop reaches it through an operand
+        tuple."""
+        for u in users.get(name, ()):
+            if u in noop and depth:
+                yield from real_users(u, depth - 1)
+            else:
+                yield u
+
     for sweep in (rows, rows[::-1]):
         for r in sweep:
             if own[r.op.name] is not None or r.op.opcode in _NOOP_OPCODES:
                 continue
             near = ([own.get(o) for o in r.operands]
-                    + [own.get(u) for u in users.get(r.op.name, ())])
+                    + [own.get(u) for u in real_users(r.op.name)])
             own[r.op.name] = next((p for p in near if p is not None), None)
     for r in rows:
         if r.callee is not None:

@@ -14,8 +14,11 @@ program span over each. Also checked, and printed as one JSON line
 (``stage_trace:``): that the compiled step's text is the same inside and
 outside a profiler session, the offset between each
 ``lgbtpu:gbdt.dispatch`` annotation in the xplane's host plane and the
-span ring's record of the same span, and the round log's live-row share.
-The capture (xplane and ``phase_map.json``) stays under ``--out``.
+span ring's record of the same span, the round log's live-row share and
+the share of the touched stream positions that were live, and the
+SHA-256 of the model text (tree 0 and the traced trees; two commits that
+grow the same trees print the same one). The capture (xplane and
+``phase_map.json``) and ``model.txt`` stay under ``--out``.
 """
 
 import argparse
@@ -102,6 +105,12 @@ def main(argv=None) -> int:
     log = list(gb.round_log)[-args.trees:]
     rounds = sum(int((r.leaves > 0).sum()) for r in log)
     live = sum(int(r.rows.sum()) for r in log)
+    # None on a commit whose round log has no stream_rows yet
+    touched = sum(int(getattr(r, "stream_rows", r.rows * 0).sum())
+                  for r in log)
+    model_text = bst.model_to_string()
+    with open(os.path.join(args.out, "model.txt"), "w") as f:
+        f.write(model_text)
     print("stage_trace: " + json.dumps({
         "workload": args.workload, "trees": args.trees,
         "device": str(jax.devices()[0].device_kind),
@@ -113,6 +122,8 @@ def main(argv=None) -> int:
         "rounds_per_tree": rounds / max(len(log), 1),
         "live_row_share_pct": 100.0 * live / max(
             rounds * cfg["shape"]["rows"], 1),
+        "stream_row_share_pct": 100.0 * live / touched if touched else None,
+        "model_sha256": hashlib.sha256(model_text.encode()).hexdigest(),
         "host_sync_count": gb.host_sync_count}), flush=True)
     with open(plane, "rb") as f, gzip.open(plane + ".gz", "wb") as g:
         shutil.copyfileobj(f, g)

@@ -300,7 +300,8 @@ def test_native_matches_scatter(rng):
     """The C histogram kernel (native/hist.c) is bit-identical to the
     XLA scatter path: same skip rules, same bf16 addend rounding, exact
     int32 accumulation when quantized, and the compacted dynamic row
-    stream (row_gather + num_rows) honored."""
+    stream (row_gather + num_rows over UNCOMPACTED gh / row_leaf: the
+    wrapper compacts them ahead of the FFI call) honored."""
     pytest.importorskip("ctypes")
     from lightgbm_tpu import native as N
     if N.hist_lib() is None:
@@ -334,10 +335,8 @@ def test_native_matches_scatter(rng):
     pos = np.cumsum(m) - 1
     c_idx = np.zeros(R, np.int32)
     c_idx[pos[m]] = np.arange(R, dtype=np.int32)[m]
-    rl_c = np.where(np.arange(R) < n_small, row_leaf[c_idx],
-                    -1).astype(np.int32)
     got = np.asarray(build_histograms(
-        jnp.asarray(bins), jnp.asarray(gh[c_idx]), jnp.asarray(rl_c),
+        jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(row_leaf),
         jnp.asarray(leaf_ids), hist_dtype="float32", impl="native",
         row_gather=jnp.asarray(c_idx),
         num_rows=jnp.asarray(n_small, jnp.int32), **kw))
@@ -492,3 +491,244 @@ def test_native_perm_kernel_threaded_matches_serial(rng, monkeypatch):
         row_leaf[perm[begin[s]:begin[s] + cnt[s]]] = s
     want = build_histograms_reference(bins, ghf, row_leaf, lids, B)
     np.testing.assert_allclose(f_serial, want, rtol=1e-4, atol=1e-2)
+
+
+# ---------------------------------------------------------------------------
+# The compacted stream's contract (row_gather + num_rows): bins, gh and
+# row_leaf arrive UNCOMPACTED, position p reads row row_gather[p], positions
+# at or past num_rows are dead, and nothing past the live prefix is consumed.
+# ---------------------------------------------------------------------------
+
+_STREAM_F, _STREAM_B, _STREAM_L = 8, 256, 3
+
+
+def _stream_blk(impl):
+    """Row block of ``impl``'s bounded loop at the contract test's
+    shape (the kernel's for pallas; native has none, any will do)."""
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    if impl == "pallas":
+        return PH._plan(_STREAM_F, _STREAM_B, 3 * _STREAM_L, 4)[0]
+    return 256
+
+
+def _stream_call(impl, bins, gh, row_leaf, leaf_ids, row_gather, num_rows):
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    args = (jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(row_leaf),
+            jnp.asarray(leaf_ids))
+    kw = dict(num_bins=_STREAM_B, hist_dtype="float32",
+              row_gather=jnp.asarray(row_gather),
+              num_rows=jnp.asarray(num_rows, jnp.int32))
+    if impl == "pallas":
+        return np.asarray(PH.build_histograms_pallas(
+            *args, interpret=True, **kw))
+    return np.asarray(build_histograms(*args, impl=impl, block_rows=256,
+                                       **kw))
+
+
+@pytest.mark.parametrize("num_rows", ["0", "1", "blk-1", "blk", "chunk",
+                                      "chunk+1", "R"])
+@pytest.mark.parametrize("impl", ["matmul", "scatter", "pallas", "native"])
+def test_compacted_stream_contract(impl, num_rows):
+    """Uncompacted gh / row_leaf + row_gather equals the oracle on the
+    live prefix, for every impl and around every loop boundary. The
+    tail is POISONED: positions past num_rows point at rows whose leaf
+    IS in leaf_ids and whose gh is huge, so a loop that consumes one
+    position too many (or forgets the dead-leaf mask inside the last
+    block) is visibly corrupt."""
+    if impl == "native":
+        from lightgbm_tpu import native as N
+        if N.hist_lib() is None:
+            pytest.skip("native toolchain unavailable")
+    rng = np.random.RandomState(7)
+    F, B, L = _STREAM_F, _STREAM_B, _STREAM_L
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    blk = _stream_blk(impl)
+    R = 5 * blk if impl == "pallas" else 2048      # a multiple of 256
+    # the XLA formulations bound by the row block; pallas lays out in
+    # chunks of whole kernel row blocks
+    chunk = PH.stream_chunk(R, blk) if impl == "pallas" else blk
+    assert chunk + 1 < R
+    n = {"0": 0, "1": 1, "blk-1": blk - 1, "blk": blk, "chunk": chunk,
+         "chunk+1": chunk + 1, "R": R}[num_rows]
+    bins = rng.randint(0, B, size=(R, F)).astype(np.uint8)
+    gh = np.stack([rng.normal(size=R), rng.uniform(0.1, 1, size=R),
+                   np.ones(R)], 1).astype(np.float32)
+    row_leaf = rng.randint(0, L, size=R).astype(np.int32)
+    # the second half of the table is poison: live leaves, huge addends
+    good = np.arange(R // 2, dtype=np.int32)
+    poison = np.arange(R // 2, R, dtype=np.int32)
+    gh[poison] = 1e9
+    row_gather = np.concatenate([rng.choice(good, size=n),
+                                 rng.choice(poison, size=R - n)]
+                                ).astype(np.int32)
+    leaf_ids = np.arange(L, dtype=np.int32)
+    got = _stream_call(impl, bins, gh, row_leaf, leaf_ids, row_gather, n)
+    live = row_gather[:n]
+    want = build_histograms_reference(bins[live], gh[live], row_leaf[live],
+                                      leaf_ids, B)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
+    assert got[..., 2].sum() == n * F
+
+
+def _walk_outside_loops(jaxpr, tainted, whiles, eqns):
+    """Every equation of ``jaxpr`` (through nested jits, not into loop
+    bodies or kernels) into ``eqns``; ``while`` equations, each with
+    whether an operand depends on the ``tainted`` inputs, into
+    ``whiles``. Returns the tainted outvars."""
+    from jax.extend import core as jex_core
+    tainted = set(tainted)
+    for e in jaxpr.eqns:
+        ins = [v for v in e.invars if not isinstance(v, jex_core.Literal)]
+        hit = any(v in tainted for v in ins)
+        sub = e.params.get("jaxpr") if e.primitive.name in (
+            "pjit", "jit", "closed_call", "core_call") else None
+        if sub is not None:
+            inner = getattr(sub, "jaxpr", sub)
+            t_in = {iv for iv, ov in zip(inner.invars, e.invars)
+                    if not isinstance(ov, jex_core.Literal)
+                    and ov in tainted}
+            t_out = _walk_outside_loops(inner, t_in, whiles, eqns)
+            tainted |= {ov for iv, ov in zip(inner.outvars, e.outvars)
+                        if iv in t_out}
+            continue
+        eqns.append(e)
+        if e.primitive.name == "while":
+            whiles.append((e, [v in tainted for v in e.invars
+                               if not isinstance(v, jex_core.Literal)]))
+        if hit:
+            tainted |= set(e.outvars)
+    return tainted
+
+
+def test_pallas_stream_has_no_row_sized_gather_outside_the_chunk_loop():
+    """The pallas branch with row_gather: no gather, convert_element_type
+    or transpose equation outside the chunk loop touches an R-sized
+    array, there is exactly one loop, its trip count derives from
+    num_rows, and the kernel is still called once."""
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    R, F, B, L = 1 << 17, 28, 63, 16
+    blk = PH._plan(F, B, 3 * L, 2)[0]
+    chunk = PH.stream_chunk(R, blk)
+    assert chunk % blk == 0 and chunk < R // 16
+
+    def fn(bins, gh, rl, ids, rg, n):
+        return build_histograms(bins, gh, rl, ids, num_bins=B, impl="pallas",
+                                row_gather=rg, num_rows=n)
+
+    sds = jax.ShapeDtypeStruct
+    closed = jax.make_jaxpr(fn)(
+        sds((R, F), jnp.uint8), sds((R, 3), jnp.float32),
+        sds((R,), jnp.int32), sds((L,), jnp.int32), sds((R,), jnp.int32),
+        sds((), jnp.int32))
+    whiles, eqns = [], []
+    _walk_outside_loops(closed.jaxpr, {closed.jaxpr.invars[5]}, whiles, eqns)
+    names = [e.primitive.name for e in eqns]
+    assert names.count("pallas_call") == 1
+    assert len(whiles) == 1
+    loop, operand_tainted = whiles[0]
+    # fori_loop's carry is (i, upper, *bufs): the bound is an operand
+    # and it is computed from num_rows
+    assert any(operand_tainted)
+    cond = loop.params["cond_jaxpr"].jaxpr
+    assert [e.primitive.name for e in cond.eqns] == ["lt"]
+    for e in eqns:
+        if e.primitive.name in ("gather", "convert_element_type",
+                                "transpose"):
+            sizes = [int(np.prod(v.aval.shape))
+                     for v in list(e.invars) + list(e.outvars)]
+            assert max(sizes) < R, (e.primitive.name, sizes)
+    # and the loop's body does hold them, chunk-sized
+    def flat(jaxpr):
+        for e in jaxpr.eqns:
+            sub = e.params.get("jaxpr")
+            if sub is not None:
+                yield from flat(getattr(sub, "jaxpr", sub))
+            else:
+                yield e
+
+    inner = [e for e in flat(loop.params["body_jaxpr"].jaxpr)
+             if e.primitive.name == "gather"]
+    assert len(inner) == 3
+    assert {e.outvars[0].aval.shape[0] for e in inner} == {chunk}
+
+
+def _exact_tree_case(R=4096, F=8, B=32):
+    """Integer gradients and unit hessians: every histogram sum is exact
+    in float32, so parent-minus-child subtraction is too and two correct
+    builds agree to the bit."""
+    rng = np.random.RandomState(11)
+    bins = rng.randint(0, B, size=(R, F)).astype(np.uint8)
+    g = (bins[:, 0].astype(np.float32) // 4 - 4
+         + (bins[:, 1] > 11) * 3 - (bins[:, 2] > 20) * 2
+         + rng.randint(-2, 3, size=R)).astype(np.float32)
+    gh = np.stack([g, np.ones(R, np.float32), np.ones(R, np.float32)], 1)
+    meta = (jnp.full((F,), B, jnp.int32), jnp.full((F,), -1, jnp.int32),
+            jnp.zeros((F,), bool), jnp.ones((F,), bool))
+    return bins, gh, meta
+
+
+@pytest.mark.parametrize("plan_kind", ["serial", "data8"])
+def test_compacted_tree_equals_uncompacted_tree_bit_for_bit(plan_kind):
+    """A 63-leaf tree grown over the compacted stream (hist_sub) equals,
+    node for node and bit for bit in leaf values, the tree grown with
+    compaction off, serial and on the 8-virtual-device data-parallel
+    mesh; RoundLog.stream_rows covers rows in whole chunks."""
+    from lightgbm_tpu.boosting.tree_builder import build_tree
+    from lightgbm_tpu.ops.histogram import stream_chunk_rows
+    from lightgbm_tpu.ops.split import SplitParams
+    bins, gh, meta = _exact_tree_case()
+    R, F = bins.shape
+    kw = dict(num_leaves=63, leaf_batch=4, max_depth=-1, num_bins=32,
+              split_params=SplitParams(min_data_in_leaf=5,
+                                       min_sum_hessian_in_leaf=1e-3),
+              hist_dtype="float32", hist_impl="scatter")
+    rl0 = np.zeros(R, np.int32)
+    if plan_kind == "serial":
+        r_shard = R
+        block = 256
+
+        def grow(sub):
+            return build_tree(jnp.asarray(bins), jnp.asarray(gh),
+                              jnp.asarray(rl0), *meta, block_rows=block,
+                              hist_sub=sub, **kw)
+    else:
+        from lightgbm_tpu.parallel.data_parallel import DataParallelPlan
+        plan = DataParallelPlan()
+        assert plan.num_shards == 8
+        r_shard = R // 8
+        block = 128
+
+        def grow(sub):
+            return plan.build_tree(
+                plan.shard_rows(bins), plan.shard_rows(gh),
+                plan.shard_rows(rl0), *meta, block_rows=block,
+                hist_sub=sub, **kw)
+
+    t1, rl1, _, log1 = grow(True)
+    t0, rl0_, _, log0 = grow(False)
+    assert int(t1.num_leaves) == 63
+    for name, a, b in zip(t1._fields, t1, t0):
+        if name == "gain":
+            # the two programs fuse the gain formula differently (a few
+            # ulp on the mesh); the sums it is computed from are exact
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4)
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=name)
+    np.testing.assert_array_equal(np.asarray(rl1), np.asarray(rl0_))
+
+    chunk = stream_chunk_rows("scatter", r_shard, F, 32, 4, jnp.float32,
+                              "float32", block)
+    assert chunk == block
+    rows, leaves, stream = (np.asarray(a) for a in log1)
+    ran = leaves > 0
+    assert ran.sum() >= 16
+    assert (stream >= rows).all() and (stream % chunk == 0).all()
+    assert (stream - rows < chunk).all()
+    assert (stream[..., ~ran] == 0).all() and (rows[..., ~ran] == 0).all()
+    # the stream is bounded: well under every round x every row
+    assert stream.sum() < 0.7 * ran.sum() * R
+    # compaction off: every round streams all of the shard's rows
+    s0 = np.asarray(log0.stream_rows)
+    assert (s0[..., np.asarray(log0.leaves) > 0] == r_shard).all()

@@ -57,15 +57,34 @@ SHAPES = [(28, 63, 21), (28, 63, 42), (28, 255, 21), (12, 255, 16),
 
 @pytest.mark.parametrize("F,B,L", SHAPES)
 @pytest.mark.parametrize("variant", ["bf16", "f32", "int8", "bf16_rows",
-                                     "int8_rows"])
+                                     "int8_rows", "bf16_stream",
+                                     "int8_stream", "bf16_lanes",
+                                     "int8_lanes"])
 def test_histogram_kernel_compiles(v5e, F, B, L, variant):
-    from lightgbm_tpu.ops.pallas_histogram import build_histograms_pallas
+    """``_rows``: a live-row bound; ``_stream``: the compacted stream
+    (``row_gather`` + bound: the chunk loop that lays the operands
+    out); ``_lanes``: the entry for operands already laid out."""
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    build_histograms_pallas = PH.build_histograms_pallas
     sds = _sds(v5e[0])
     quant = variant.startswith("int8")
-    args = _hist_args(sds, 1 << 16, F, L, quant)
+    R = 1 << 16
+    args = _hist_args(sds, R, F, L, quant)
     kw = dict(num_bins=B,
               hist_dtype="float32" if variant == "f32" else "bfloat16")
-    if variant.endswith("_rows"):
+    if variant.endswith("_lanes"):
+        blk, fc, n_fb, _, _ = PH._plan(F, B, L * 3, 1 if quant else 2)
+        r_pad = PH._ceil_to(R, PH.stream_chunk(R, blk))
+        _compile(lambda b, g, r, l, n: PH.build_histograms_pallas_lanes(
+            b, g, r, l, n, num_features=F, **kw),
+            sds((n_fb, fc, r_pad), I32),
+            sds((3, r_pad), I32 if quant else F32), sds((1, r_pad), I32),
+            sds((L,), I32), sds((1,), I32))
+    elif variant.endswith("_stream"):
+        _compile(lambda b, g, r, l, n, c: build_histograms_pallas(
+            b, g, r, l, num_rows=n, row_gather=c, **kw), *args,
+            sds((), I32), sds((R,), I32))
+    elif variant.endswith("_rows"):
         _compile(lambda b, g, r, l, n: build_histograms_pallas(
             b, g, r, l, num_rows=n, **kw), *args, sds((), I32))
     else:
@@ -176,9 +195,11 @@ def _bench_shape(name):
 def test_stage_map_names_the_grow_loop_at_benchmark_shapes(v5e, config,
                                                            monkeypatch):
     """The tree build compiled for the described v5e at a benchmark
-    cell's shape: every instruction of the grow ``while`` that touches a
-    row-sized array has a stage deeper than ``build``, the Pallas custom
-    call is the kernel stage, and no name outside phases.py appears."""
+    cell's shape: every instruction of the grow ``while`` (and of the
+    compacted stream's chunk loop nested in it) that touches a
+    row-sized array has a stage deeper than ``build``, none of the chunk
+    loop's is a row-sized gather, the Pallas custom call is the kernel
+    stage, and no name outside phases.py appears."""
     import re
 
     from lightgbm_tpu import phases
@@ -197,23 +218,42 @@ def test_stage_map_names_the_grow_loop_at_benchmark_shapes(v5e, config,
     sm = costmodel.instruction_phase_map(text)
     assert set(sm.stages.values()) <= phases.KNOWN_PHASES
     rows = costmodel._instructions(text)
-    body = next(r.callee for r in rows if r.op.opcode == "while")
+    whiles = {r.callee: r.comp for r in rows if r.op.opcode == "while"}
+    # the chunk loop is the while that runs inside another's body
+    chunk_body = next(c for c, parent in whiles.items() if parent in whiles)
+    body = whiles[chunk_body]
     shape = re.compile(r"\b(?:pred|bf16|[sufc]\d+)\[([0-9,]*)\]")
     lines = {m.group(1): ln.split(", metadata=")[0]
              for ln in text.splitlines()
              for m in [re.match(r"\s*(?:ROOT\s+)?%?([\w.-]+)\s*=", ln)] if m}
     deep = phases.BUILD_STAGES
-    seen = 0
+    seen = {body: 0, chunk_body: 0}
     for r in rows:
-        if r.comp != body or r.op.opcode in costmodel._NOOP_OPCODES:
+        if r.comp not in seen or r.op.opcode in costmodel._NOOP_OPCODES:
             continue
         elems = max((int(np.prod([int(x) for x in d.split(",") if x]))
                      for d in shape.findall(lines[r.op.name])), default=0)
         if elems >= R:
-            seen += 1
+            if (not r.op.op_name and sm.stages.get(r.op.name) is None
+                    and r.op.opcode in ("copy-start", "copy-done")):
+                # the compiler's own prefetch into fast memory for the
+                # NEXT round (its user is the body's root tuple): no
+                # source line to name, bare ``build`` in a stage table
+                continue
+            seen[r.comp] += 1
             assert sm.stages.get(r.op.name) in deep, (
                 r.op.name, sm.stages.get(r.op.name), r.op.op_name)
-    assert seen > 20
+            longest = max(int(x) for d in shape.findall(lines[r.op.name])
+                          for x in d.split(",") if x)
+            if r.comp == chunk_body and longest >= R:
+                # only the operand buffers' in-place updates (and what
+                # the compiler moves between memories) span all rows
+                assert (sm.stages[r.op.name] == phases.HIST_RELAYOUT
+                        or r.op.opcode.startswith("copy")), (
+                    r.op.name, r.op.opcode, sm.stages[r.op.name])
+    assert seen[body] > 15 and seen[chunk_body] >= 3
+    staged = {sm.stages.get(r.op.name) for r in rows if r.comp == chunk_body}
+    assert {phases.HIST_GATHER, phases.HIST_RELAYOUT} <= staged
     kernels = [r for r in rows if r.op.name.startswith(HIST_KERNEL_NAME)]
     assert len(kernels) == 2      # the root pass and the in-loop call
     assert all(sm.stages[r.op.name] == phases.HIST_KERNEL for r in kernels)
