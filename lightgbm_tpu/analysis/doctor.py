@@ -27,8 +27,9 @@ Entry points (per canonical config):
   compiles publish gate).
 
 Canonical configs are the feature matrix the repo actually ships:
-plain / EFB / quantized / categorical, each under serial and (when the
-host exposes a multi-device mesh) data-parallel learners.
+plain / EFB / quantized / categorical / multiclass / lambdarank, each
+under serial and (when the host exposes a multi-device mesh)
+data-parallel learners.
 ``scripts/lint_traces.py`` runs the full battery as the CI gate;
 ``python -m lightgbm_tpu trace-doctor`` is the user-facing form;
 ``tests/test_trace_doctor.py`` runs a tier-1 subset.
@@ -78,6 +79,13 @@ CANONICAL_CONFIGS: Dict[str, Tuple[dict, dict]] = {
     # to a scratch dir by make_booster.
     "telemetry": ({"nan_guard": "rollback", "event_log": "auto",
                    "telemetry_port": 0}, {}),
+    # a ranking objective: its query lattices are device arrays and must
+    # reach the fused step as arguments, not closure constants (TD001;
+    # at MS-LTR's size they are tens of megabytes). Uneven queries, so
+    # several buckets of the layout are in the program.
+    "lambdarank": ({"objective": "lambdarank", "metric": "ndcg",
+                    "min_data_in_leaf": 2},
+                   {"group": [1, 2, 31, 40, 6, 80]}),
 }
 PARALLEL_MODES = ("serial", "data")
 
@@ -111,6 +119,9 @@ def _synth(config: str, *, n: int = 160, f: int = 8, seed: int = 0):
     if config == "multiclass":
         y = (X[:, :3] + 0.5 * rng.normal(size=(n, 3))).argmax(1) \
             .astype(np.float32)
+    elif config == "lambdarank":
+        y = np.clip(np.round(X[:, 1] + 0.5 * X[:, 2] * X[:, 3] + 1.0),
+                    0, 4).astype(np.float32)
     else:
         y = (X[:, 1] + 0.5 * X[:, 2] * X[:, 3]
              + 0.3 * rng.normal(size=n) > 0).astype(np.float32)

@@ -470,6 +470,11 @@ class GBDT:
                     and getattr(self.train_set, "position", None) is not None):
                 okw["position"] = self.train_set.position
             objective.init(lbl, w, self.train_set.query_boundaries(), **okw)
+            if objective.is_ranking:
+                # the query lattices go to the device once, here, and
+                # reach the fused step as an argument (_fused_data_args)
+                with profiler.span("gbdt.to_device"):
+                    jax.block_until_ready(objective.device_state)
             if objective.label is not lbl:
                 # init() may retarget training to a transformed label
                 # space (reg_sqrt trains on sign(y)*sqrt(|y|),
@@ -1712,7 +1717,9 @@ class GBDT:
             weight=self.weight_dev,
             bins_cm=self._bins_cm,
             valid_bins=tuple(dd.bins for dd in self.valid_dd),
-            valid_rl0=tuple(dd.row_leaf0 for dd in self.valid_dd))
+            valid_rl0=tuple(dd.row_leaf0 for dd in self.valid_dd),
+            rank=(self.objective.device_state
+                  if self.objective.is_ranking else None))
 
     def _fused_step_entry(self, scores, valid_scores, bag_mask, fmask,
                           it, lr, data):
@@ -1726,6 +1733,9 @@ class GBDT:
                  self.label_dev, self.weight_dev, self._bins_cm,
                  [dd.bins for dd in self.valid_dd],
                  [dd.row_leaf0 for dd in self.valid_dd])
+        ranking = data["rank"] is not None
+        if ranking:
+            rank_saved = self.objective.bind_device_state(data["rank"])
         try:
             self.train_dd.bins = data["bins"]
             self.train_dd.row_leaf0 = data["row_leaf0"]
@@ -1742,6 +1752,8 @@ class GBDT:
              self.weight_dev, self._bins_cm, vb, vr) = saved
             for dd, b, rl in zip(self.valid_dd, vb, vr):
                 dd.bins, dd.row_leaf0 = b, rl
+            if ranking:
+                self.objective.bind_device_state(rank_saved)
 
     def _full_row_mask(self) -> jax.Array:
         """All-real-rows bagging mask, ``(row_leaf0 >= 0)`` as f32,

@@ -28,9 +28,10 @@ __all__ = ["GRADS", "SAMPLING", "BUILD", "UPDATE", "EVAL",
            "INGEST_SKETCH", "INGEST_WRITE", "PREFETCH",
            "HIST_MERGE", "WINNER_SYNC", "ROOT_PASS", "POP", "APPLY",
            "COUNT", "COMPACT", "HIST_GATHER", "HIST_RELAYOUT",
-           "HIST_KERNEL", "SUBTRACT", "FIND", "TRAIN_PHASES",
+           "HIST_KERNEL", "SUBTRACT", "FIND", "RANK_GATHER", "RANK_SORT",
+           "RANK_PAIRS", "RANK_SCATTER", "TRAIN_PHASES",
            "INGEST_PHASES", "COLLECTIVE_PHASES", "BUILD_STAGES",
-           "KNOWN_PHASES", "HOST_SPANS"]
+           "GRADS_STAGES", "KNOWN_PHASES", "HOST_SPANS"]
 
 # training phases (both drivers, boosting/gbdt.py + engine.train's eval)
 GRADS = "grads"
@@ -73,12 +74,27 @@ HIST_KERNEL = "hist_kernel"      # the pallas_call (or the XLA block loop)
 SUBTRACT = "subtract"            # parent minus child, cache scatters
 FIND = "find"                    # best_for / fused split + cache scatter
 
+# stages of a ranking objective's gradients, nested under ``grads``
+# (ranking.py: queries in buckets by length, one lattice a bucket)
+RANK_GATHER = "rank_gather"      # scores (XE-NDCG: and the draw) by row
+#                                  index into each bucket's [Q_b, W] lattice
+RANK_SORT = "rank_sort"          # each doc's rank in its query's score order
+#                                  (a [Q_b, W, W] count, no sort op) and the
+#                                  pick of the window's docs by rank
+RANK_PAIRS = "rank_pairs"        # the [Q_b, T, W] pair lattice and its two
+#                                  reductions (XE-NDCG: the softmax a query)
+RANK_SCATTER = "rank_scatter"    # every bucket's g, h back to rows, each row
+#                                  reading its slot (an index fixed at init)
+
 # host spans of the span record (profiler.span): boundaries that happen
 # once a tree or more rarely. Each is also a TraceAnnotation named
 # ``lgbtpu:<name>`` in a profiler capture.
 HOST_SPANS = frozenset({
     "dataset.fit_bins", "dataset.apply_bins",      # Dataset.construct
-    "gbdt.to_device",      # H2D of bins, row_leaf0, labels, weights
+    "objective.init",      # a ranking objective's query layout and max-DCG
+    #                        tables; its counters ride on it as fields
+    "gbdt.to_device",      # H2D of bins, row_leaf0, labels, weights and
+    #                        a ranking objective's lattices
     "gbdt.step_ready",     # first call of the fused step: trace..compile
     "gbdt.dispatch",       # each fused dispatch
     "gbdt.sync.wait",      # the device_get of the pending ring
@@ -91,4 +107,7 @@ COLLECTIVE_PHASES = frozenset({HIST_MERGE, WINNER_SYNC})
 BUILD_STAGES = frozenset({ROOT_PASS, POP, APPLY, COUNT, COMPACT,
                           HIST_GATHER, HIST_RELAYOUT, HIST_KERNEL,
                           SUBTRACT, FIND}) | COLLECTIVE_PHASES
-KNOWN_PHASES = TRAIN_PHASES | INGEST_PHASES | BUILD_STAGES
+GRADS_STAGES = frozenset({RANK_GATHER, RANK_SORT, RANK_PAIRS,
+                          RANK_SCATTER})
+KNOWN_PHASES = (TRAIN_PHASES | INGEST_PHASES | BUILD_STAGES
+                | GRADS_STAGES)

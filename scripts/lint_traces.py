@@ -7,8 +7,10 @@ builder, packed-ensemble predict walk, serving micro-batcher, and the
 tensorized compiled-ensemble serving program (no host callbacks
 (TD002), ladder-bounded signatures (TD201)) — for
 every canonical config cell (plain / EFB / quantized / categorical /
-multiclass / nan_guard / telemetry × serial / data-parallel) on the
-8-virtual-device CPU mesh. The telemetry cell trains with the full
+multiclass / nan_guard / telemetry / lambdarank × serial /
+data-parallel) on the 8-virtual-device CPU mesh. The lambdarank cell
+guards the ranking objective's query lattices: they must reach the fused
+step as arguments (TD001; 41 MB at MS-LTR's size). The telemetry cell trains with the full
 observation stack armed (event log + live introspection server) and
 must lint identically — the subsystem's zero-host-callback contract
 (TD002) and the deferred guard flag (TD006) survive being watched.
