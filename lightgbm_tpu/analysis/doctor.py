@@ -12,6 +12,12 @@ Entry points (per canonical config):
 - **tree builder** — the data-parallel plan's ``build_tree`` on the
   local mesh over synthetic inputs (the comms auditor's program);
   collectives must carry the ``hist_merge`` / ``winner_sync`` phases.
+- **round body** — the fused step of a booster trained with
+  ``hist_impl=scatter``, so that the program holds the grow round's XLA
+  formulation (what a TPU runs around its kernel; ``auto`` on a CPU
+  hands relabel and partition to the native custom calls), and the
+  data-parallel ``build_tree`` the same way: no per-row read of a small
+  table in the ``build`` stage (TD008).
 - **predict ensemble** — ``ops.predict_ensemble._walk`` over the packed
   trained ensemble; the serving walk must stage NO collectives and no
   host work at all.
@@ -51,8 +57,9 @@ from .jaxpr_lint import lint_deferred_guard, lint_jaxpr
 from .recompile_guard import cache_size
 from .report import TraceReport, merge_errors
 
-__all__ = ["CANONICAL_CONFIGS", "PARALLEL_MODES", "make_booster",
-           "doctor_fused_step", "doctor_tree_builder", "doctor_predict",
+__all__ = ["CANONICAL_CONFIGS", "PARALLEL_MODES", "ROUND_BODY_CELLS",
+           "make_booster", "doctor_fused_step", "doctor_round_body",
+           "doctor_tree_builder", "doctor_predict",
            "doctor_batcher", "doctor_serving", "doctor_fused_split",
            "run_doctor", "doctor_main"]
 
@@ -88,6 +95,12 @@ CANONICAL_CONFIGS: Dict[str, Tuple[dict, dict]] = {
                    {"group": [1, 2, 31, 40, 6, 80]}),
 }
 PARALLEL_MODES = ("serial", "data")
+# (config, mode) cells whose round body TD008 reads: every way a row
+# reads its pending split (numerical, bitset, bundle decode, per-row
+# gradients around it) and the row mesh
+ROUND_BODY_CELLS = (("plain", "serial"), ("categorical", "serial"),
+                    ("efb", "serial"), ("lambdarank", "serial"),
+                    ("plain", "data"))
 
 _BASE_PARAMS = dict(objective="binary", metric="auc", num_leaves=7,
                     learning_rate=0.2, min_data_in_leaf=5, verbosity=-1)
@@ -130,14 +143,16 @@ def _synth(config: str, *, n: int = 160, f: int = 8, seed: int = 0):
 
 def make_booster(config: str = "plain", mode: str = "serial", *,
                  rounds: int = 2, n: int = 160, f: int = 8,
-                 fused: bool = True):
-    """Train the tiny canonical booster for one (config, mode) cell."""
+                 fused: bool = True, **params_over):
+    """Train the tiny canonical booster for one (config, mode) cell
+    (``params_over``: train params on top of the cell's)."""
     import lightgbm_tpu as lgb
     overrides, ds_kw = CANONICAL_CONFIGS[config]
     X, y = _synth(config, n=n, f=f)
     # explicit even for serial: on a multi-device host the trainer
     # otherwise auto-selects a parallel plan
-    params = dict(_BASE_PARAMS, **overrides, tree_learner=mode)
+    params = dict(_BASE_PARAMS, **overrides, tree_learner=mode,
+                  **params_over)
     if params.get("event_log"):
         # telemetry cell: keep the event log (and auto's output_model
         # anchor) out of the caller's cwd
@@ -164,13 +179,14 @@ def _fused_trace_args(gb):
 
 
 def doctor_fused_step(bst, *, label: str = "fused_step",
-                      compile_hlo: bool = True,
+                      compile_hlo: bool = True, round_body: bool = False,
                       allow: Sequence[Tuple[str, str]] = ()
                       ) -> List[TraceReport]:
     """Lint the fused boosting step of a trained booster. Returns []
     with an info report when the fused gate pins the legacy driver for
     this config (the legacy phases dispatch separate small programs —
-    the builder/predict targets cover them)."""
+    the builder/predict targets cover them). ``round_body`` adds TD008
+    (:func:`doctor_round_body`)."""
     import jax
     gb = bst._gbdt
     reports: List[TraceReport] = []
@@ -187,9 +203,18 @@ def doctor_fused_step(bst, *, label: str = "fused_step",
     # class-batch gate is open; a config the gate excludes (linear /
     # forced / CEGB) legitimately unrolls, so the rule is skipped
     build_budget = 1 if (gb.K == 1 or gb.class_batch_ok) else None
-    reports.append(lint_jaxpr(closed, label=f"{label}/jaxpr",
-                              max_build_programs=build_budget,
-                              allow=allow))
+    build_rows = None
+    if round_body:
+        # TD008 sizes the build stage's gathers by the rows a device
+        # holds, which must exceed every lattice the split search
+        # gathers over (12 slots x 54 bins pass 256 rows a shard in the
+        # categorical cell under a row mesh: not in ROUND_BODY_CELLS)
+        shards = (gb.plan.num_shards
+                  if gb.plan is not None and gb.plan.rows_sharded else 1)
+        build_rows = int(gb.train_dd.row_leaf0.shape[0]) // shards
+    reports.append(lint_jaxpr(
+        closed, label=f"{label}/jaxpr", max_build_programs=build_budget,
+        build_rows=build_rows, allow=allow))
     if getattr(gb, "_nan_guard", "off") != "off":
         # TD006: armed guard — the finite flag must be a deferred
         # program output next to the no-split stop flag
@@ -209,6 +234,19 @@ def doctor_fused_step(bst, *, label: str = "fused_step",
             hlo, label=f"{label}/hlo",
             allowed_phases=COLLECTIVE_PHASES, allow=allow))
     return reports
+
+
+def doctor_round_body(config: str = "plain", mode: str = "serial", *,
+                      label: Optional[str] = None,
+                      allow: Sequence[Tuple[str, str]] = ()
+                      ) -> List[TraceReport]:
+    """The jaxpr rules (TD008 among them) over the fused step of one
+    (config, mode) cell, traced with the grow round's XLA formulation
+    in it (module docstring). Nothing here depends on the backend."""
+    bst = make_booster(config, mode, hist_impl="scatter")
+    return doctor_fused_step(
+        bst, label=label or f"round_body[{config}/{mode}]",
+        compile_hlo=False, round_body=True, allow=allow)
 
 
 def doctor_tree_builder(*, label: str = "tree_builder",
@@ -239,7 +277,15 @@ def doctor_tree_builder(*, label: str = "tree_builder",
                plan.shard_rows(rl0))
     closed = jax.make_jaxpr(fn)(*sharded)
     hlo = lower_hlo(fn, *sharded)
+    # TD008 reads the round's XLA formulation (``auto`` is the native
+    # custom calls on a CPU); traced only, so any R costs the same
+    closed_xla = jax.make_jaxpr(
+        lambda b, g, rl: plan.build_tree(b, g, rl, *meta,
+                                         hist_impl="scatter", **kw)[0])(
+        *sharded)
     return [lint_jaxpr(closed, label=f"{label}/jaxpr", allow=allow),
+            lint_jaxpr(closed_xla, label=f"{label}/round_body",
+                       build_rows=R // plan.num_shards, allow=allow),
             lint_hlo(hlo, label=f"{label}/hlo",
                      allowed_phases=COLLECTIVE_PHASES, allow=allow)]
 
@@ -448,6 +494,9 @@ def run_doctor(configs: Optional[Sequence[str]] = None,
             reports += doctor_fused_step(
                 bst, label=f"fused_step[{cell}]",
                 compile_hlo=compile_hlo, allow=allow)
+    for cfg, mode in ROUND_BODY_CELLS:
+        if cfg in configs and mode in modes:
+            reports += doctor_round_body(cfg, mode, allow=allow)
     reports += doctor_tree_builder(allow=allow)
     if compile_hlo:
         reports += doctor_fused_split(allow=allow)

@@ -47,6 +47,22 @@ hand:
   them and the duplication is no longer countable (verified
   empirically on the CPU backend — the K grow loops lower with
   scatter-expansion metadata, not distinct build tags).
+- **TD008 per-row table read**: inside the ``build`` stage, a
+  ``gather`` of at least R index tuples from operand dimensions that
+  hold fewer than R entries together, or a ``scatter-add`` of at least
+  R updates into fewer than R segments (R = the rows a device holds).
+  On a v5e a gathered element costs 9.7 ns: six ``[L+1]`` tables read
+  by ``row_leaf`` were 12.0 s of an 18.3 s Higgs tree, the
+  ``segment_sum`` of R ones another 1.7 s (PERF.md section 6, PR 29).
+  What a row needs of its leaf is W records, selected by W compares
+  (``tree_builder.select_by_slot``), and a count of the rows in S
+  slots is one compare-and-sum (``slot_counts``). Gathers by the
+  compacted stream's index read R-sized operands and pass, as does the
+  scatter that writes that index (an R-sized result). The histogram
+  itself (``hist_kernel`` scope) is the one sum over rows a tree needs
+  and is exempt: on the chip it is a Pallas kernel with a roofline
+  metric of its own; the XLA scatter formulation that CPU tests use
+  adds rows into ``S*F*B`` bins by design.
 """
 
 from __future__ import annotations
@@ -56,8 +72,8 @@ from typing import Optional, Sequence, Tuple
 from .report import TraceReport
 
 __all__ = ["lint_jaxpr", "lint_deferred_guard", "iter_eqns",
-           "count_build_loops", "CALLBACK_PRIMITIVES",
-           "DEFAULT_CONST_BYTES"]
+           "count_build_loops", "per_row_table_reads",
+           "CALLBACK_PRIMITIVES", "DEFAULT_CONST_BYTES"]
 
 # primitive names that round-trip through the host per dispatch
 CALLBACK_PRIMITIVES = frozenset({
@@ -127,6 +143,52 @@ def count_build_loops(jaxpr, prefix: str = "") -> int:
     return n
 
 
+def _iter_scoped(jaxpr, prefix: str = ""):
+    """``(equation, full name stack)`` depth-first. ``name_stack`` is
+    not inherited by nested call jaxprs (see :func:`count_build_loops`),
+    so the accumulated stack is threaded down."""
+    for eqn in jaxpr.eqns:
+        stack = str(getattr(eqn.source_info, "name_stack", "") or "")
+        full = "/".join(s for s in (prefix, stack) if s)
+        yield eqn, full
+        for sub in _sub_jaxprs(eqn.params):
+            inner = sub.jaxpr if hasattr(sub, "jaxpr") else sub
+            yield from _iter_scoped(inner, full)
+
+
+def per_row_table_reads(jaxpr, rows: int):
+    """TD008's pass: ``(primitive, name stack, index tuples, entries)``
+    of every ``gather`` / ``scatter-add`` under the ``build`` stage and
+    outside ``hist_kernel`` whose indices number at least ``rows``
+    while the operand dimensions they index hold fewer than ``rows``
+    entries."""
+    import math
+    import re
+
+    from ..phases import BUILD, BUILD_STAGES, HIST_KERNEL
+    # a tree build traced on its own (doctor_tree_builder) carries the
+    # stage names without the fused step's ``build`` around them
+    build = re.compile(r"\b(%s)\b" % "|".join(
+        sorted({BUILD} | BUILD_STAGES)))
+    kernel = re.compile(r"\b%s\b" % HIST_KERNEL)
+    out = []
+    for eqn, stack in _iter_scoped(jaxpr):
+        name = eqn.primitive.name
+        if name not in ("gather", "scatter-add"):
+            continue
+        if not build.search(stack) or kernel.search(stack):
+            continue
+        dn = eqn.params["dimension_numbers"]
+        dims = (dn.start_index_map if name == "gather"
+                else dn.scatter_dims_to_operand_dims)
+        operand, indices = eqn.invars[0].aval, eqn.invars[1].aval
+        n_idx = math.prod(indices.shape[:-1])
+        entries = math.prod(operand.shape[d] for d in dims)
+        if n_idx >= rows and entries < rows:
+            out.append((name, stack, n_idx, entries))
+    return out
+
+
 def _const_entries(closed):
     """(index, const) for the top-level consts plus nested pjit consts
     (a closure constant can hide one jit level down)."""
@@ -146,6 +208,7 @@ def lint_jaxpr(closed, *, label: str,
                allow_callbacks: bool = False,
                backend: Optional[str] = None,
                max_build_programs: Optional[int] = None,
+               build_rows: Optional[int] = None,
                allow: Sequence[Tuple[str, str]] = ()) -> TraceReport:
     """Lint one ``ClosedJaxpr``; returns the :class:`TraceReport`.
 
@@ -157,7 +220,9 @@ def lint_jaxpr(closed, *, label: str,
     that many ``build``-phase grow loops (1 for a class-batched or
     single-class trainer; ``None`` skips the rule for programs with a
     legitimate sequential fallback — linear trees, forced splits,
-    CEGB).
+    CEGB). ``build_rows`` enables TD008: the rows one device holds
+    (after padding), against which the ``build`` stage's gathers and
+    scatter-adds are sized.
     """
     import jax
     rep = TraceReport(label=label)
@@ -218,6 +283,18 @@ def lint_jaxpr(closed, *, label: str,
                 "per-class tree builds should batch over the class "
                 "axis into ONE vmapped loop (class_batch=auto), not "
                 "unroll for k in range(num_class)")
+    # TD008 — per-row reads of a small table in the build stage
+    if build_rows is not None:
+        for name, stack, n_idx, entries in per_row_table_reads(
+                closed.jaxpr, build_rows):
+            rep.add(
+                "TD008", "error", f"{stack}/{name}",
+                f"{name} of {n_idx} index tuples (rows a device: "
+                f"{build_rows}) over {entries} table entries in the "
+                "build stage: on the chip each element is a serial "
+                "lookup; select the round's W records by comparing "
+                "row_leaf with its W slots (tree_builder."
+                "select_by_slot), count slots by slot_counts")
     return rep.apply_allowlist(allow)
 
 

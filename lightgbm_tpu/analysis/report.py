@@ -28,6 +28,11 @@ TD007     hlo       full ``[.., F, B, 3]`` histogram lattice staged in
                     the fused build+split program (the VMEM-residency
                     contract of the fused Pallas epilogue: only
                     candidate records may leave the kernel)
+TD008     jaxpr     per-row table read in the ``build`` stage: a
+                    ``gather`` of >= R index tuples from fewer than R
+                    table entries, or a ``scatter-add`` of >= R updates
+                    into fewer than R segments (9.7 ns an element on a
+                    v5e; the round's W records are selected by compares)
 TD101     hlo       oversized dense ``constant`` op in the compiled
                     program
 TD102     hlo       host transfer (infeed/outfeed/send/recv, callback

@@ -16,7 +16,9 @@ Design (TPU-first; not a translation):
        of serial_tree_learner.cpp:226, batched),
     2. applies them with one vectorized pass over ``row_leaf`` (the
        DataPartition::Split analog — no index reordering, just a dense
-       leaf-id relabel),
+       leaf-id relabel; a row finds its leaf's split among the round's
+       W records by W compares, ``select_by_slot``, not by a gather
+       from a per-leaf table),
     3. builds the SMALLER child's histogram over a compacted,
        dynamically-bounded row stream and derives the sibling by
        parent-minus-child subtraction from a per-leaf histogram cache
@@ -27,8 +29,9 @@ Design (TPU-first; not a translation):
        R rows (~13x/tree at 255 leaves, ~254x in leaf_batch=1 modes).
        With it, each round streams only the smaller children's rows.
        The builder makes the stream's INDEX and nothing else
-       (``compact_small``: lut, cumsum, ``n_small``, the scatter that
-       writes ``c_idx``); ``bins``, ``gh`` and ``row_leaf`` go to
+       (``compact_small``: membership, cumsum, ``n_small``, the
+       scatter that writes ``c_idx``); ``bins``, ``gh`` and
+       ``row_leaf`` go to
        ops/histogram.py uncompacted with ``row_gather=c_idx,
        num_rows=n_small``, and the wrapper gathers, casts and lays them
        out inside one loop whose trip count is ``ceil(n_small /
@@ -144,6 +147,76 @@ def max_rounds_for(num_leaves: int, leaf_batch: int) -> int:
 
 def _round_int(x):
     return jnp.floor(x + 0.5)
+
+
+def select_by_slot(row_leaf, slots, lane_ok, records=()):
+    """What each row reads of the round's split records: ``(hit [R]
+    bool, [the record of the row's lane, 0 where none hits])``.
+
+    A round splits at most W leaves, so all a row may need of its
+    leaf's pending split is W records (``records``: [W] arrays, lane
+    ``w`` describing leaf slot ``slots[w]``). A row finds its lane by
+    comparing its leaf with the W slots: a compare a lane and a select
+    a record, elementwise over R, which the vector unit runs as one
+    fused pass. A table of ``L+1`` entries read by ``jnp.take(table,
+    row_leaf)`` costs 9.7 ns a gathered element on a v5e, 1.9 s a table
+    a Higgs tree, against ~1 ms a round for the whole pass (PERF.md
+    section 6, PR 29): the rule ops/predict.py ``row_feature_gather``
+    follows. ``lane_ok`` masks the lanes a round leaves unused: their
+    slots all hold the dummy leaf, so the slot alone does not tell them
+    apart. Slots are distinct where ``lane_ok``, so at most one lane
+    hits a row and the selected value is exactly the table's. Rows with
+    ``row_leaf < 0`` hit nothing."""
+    hit = jnp.zeros(row_leaf.shape, bool)
+    outs = [jnp.zeros(row_leaf.shape, r.dtype) for r in records]
+    for w in range(slots.shape[0]):
+        h = lane_ok[w] & (row_leaf == slots[w])
+        hit = hit | h
+        outs = [jnp.where(h, r[w], o) for r, o in zip(records, outs)]
+    return hit, outs
+
+
+def slot_counts(row_leaf, slots):
+    """[S] int32 rows in each of ``slots``: one compare-and-sum over R
+    (rows on the minor axis), in place of a ``segment_sum`` of R ones
+    into every leaf's segment, of which S entries were read."""
+    return jnp.sum(slots[:, None] == row_leaf[None, :], axis=1,
+                   dtype=jnp.int32)
+
+
+def relabel_rows(bmat, row_leaf, slots, lane_ok, feat, thr, default_left,
+                 is_cat, right, nan_bin, bits, bin_records=(), bin_of=None):
+    """``row_leaf`` after the round's splits (DataPartition::Split as a
+    dense relabel): a row of leaf ``slots[w]`` (where ``lane_ok[w]``)
+    whose bin of feature ``feat[w]`` goes right moves to ``right[w]``.
+
+    Every argument from ``slots`` to ``nan_bin`` is a [W] record of the
+    round's splits (``nan_bin`` = the split feature's NaN bin, -1 for
+    none); ``bits`` [W, BW] is the categorical LEFT subset. A row
+    selects its lane's values (``select_by_slot``) and reads its bin as
+    ``bmat[r, feat[r]]`` (``row_feature_gather``: a one-hot reduce), or
+    as ``bin_of(bmat, active, feat, *selected bin_records)`` where the
+    matrix is not one column a feature: EFB bundles and sharded feature
+    storage pass their own, with the [W] records they decode by."""
+    BW = bits.shape[-1]
+    active, (f_r, thr_r, dl_r, cat_r, right_r, nb_r, *rest) = \
+        select_by_slot(row_leaf, slots, lane_ok,
+                       [feat, thr, default_left, is_cat, right, nan_bin,
+                        *(bits[..., b] for b in range(BW)), *bin_records])
+    binv = (row_feature_gather(bmat, f_r) if bin_of is None
+            else bin_of(bmat, active, f_r, *rest[BW:]))
+    isnan = (binv == nb_r) & (nb_r >= 0)
+    # categorical: bitset membership (CategoricalDecision, tree.h) in
+    # the word of the row's bin, of the row's lane
+    word = binv >> 5
+    wval = jnp.zeros(row_leaf.shape, jnp.uint32)
+    for b in range(BW):
+        wval = jnp.where(word == b, rest[b], wval)
+    in_set = ((wval >> (binv & 31).astype(jnp.uint32))
+              & jnp.uint32(1)) == 1
+    go_left = jnp.where(cat_r, in_set, binv <= thr_r)
+    go_left = jnp.where(isnan & ~cat_r, dl_r, go_left)
+    return jnp.where(active & ~go_left, right_r, row_leaf)
 
 
 def build_tree(*args, hist_impl: str = "auto", traced: bool = False,
@@ -299,6 +372,12 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     # to per-feature space, with the most-frequent bin reconstructed via
     # FixHistogram accounting (dataset.cpp:1488 analog).
     use_bundle = bundle_meta is not None
+    # how the relabel reads a row's bin where the matrix is not one
+    # column a feature (relabel_rows ``bin_of``, ``bin_records``)
+    feature_bin_of = None
+
+    def bin_records(sfeat):
+        return []
     if use_bundle:
         b_gof, b_off, b_mfb = bundle_meta
         G = bins.shape[1]
@@ -329,15 +408,30 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             return jnp.where((mfb_oh & bvalid)[None, :, :, None],
                              mfb_val[:, :, None, :], hf)
 
-        def feature_bin_of(bmat, feat):
+        def bin_records(sfeat):
+            # [W] each: where a split feature's bins lie in the bundled
+            # matrix; a row selects its lane's (relabel_rows)
+            return [jnp.take(b_gof, sfeat), jnp.take(b_off, sfeat),
+                    jnp.take(num_bins_pf, sfeat), jnp.take(b_mfb, sfeat)]
+
+        def feature_bin_of(bmat, active, feat, gof, off, nbf, mfb):
             from ..efb import decode_feature_bins
-            raw = row_feature_gather(bmat, jnp.take(b_gof, feat))
-            return decode_feature_bins(
-                raw, jnp.take(b_off, feat), jnp.take(num_bins_pf, feat),
-                jnp.take(b_mfb, feat), xp=jnp)
-    else:
-        def feature_bin_of(bmat, feat):
-            return row_feature_gather(bmat, feat)
+            return decode_feature_bins(row_feature_gather(bmat, gof),
+                                       off, nbf, mfb, xp=jnp)
+    elif feature_sharded:
+        def feature_bin_of(bmat, active, feat):
+            # each device holds only its [R, F_loc] column shard; the
+            # split feature of a row's leaf is owned by exactly ONE
+            # shard, so a masked local gather + psum over the feature
+            # axis reconstructs the bin value everywhere (one [R] int32
+            # all-reduce per relabel — the sharded analog of the
+            # reference's full-copy re-partition,
+            # feature_parallel_tree_learner.cpp:77)
+            F_m = bmat.shape[1]
+            fl = feat - feat_offset
+            owned = active & (fl >= 0) & (fl < F_m)
+            bl = row_feature_gather(bmat, jnp.clip(fl, 0, F_m - 1))
+            return jax.lax.psum(jnp.where(owned, bl, 0), axis_name)
     sp = split_params
     use_mono = mono_type_pf is not None
     # monotone_constraints_method=intermediate
@@ -608,21 +702,41 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         """The compacted stream of the small children's rows:
         ``(c_idx [R] int32, n_small)`` with stream position ``p <
         n_small`` reading row ``c_idx[p]`` (row order kept; zeros past
-        the live prefix). Membership is a [L+2] lut gather, not a
-        [R, 2W] broadcast compare (42x less traffic at W=21). The rows
-        themselves are gathered where the stream is consumed
+        the live prefix). Membership is W compares a row, fused into
+        the cumsum's input (``select_by_slot``), not a gather from a
+        ``[L+2]`` lut: on the chip that gather was 1.56 s of a Higgs
+        tree, over half of this stage (PERF.md section 6, PR 29). The
+        rows themselves are gathered where the stream is consumed
         (``build_histograms(row_gather=, num_rows=)``), chunk by chunk
         and only as far as ``n_small``."""
-        is_small = jnp.zeros((L + 2,), bool).at[
-            jnp.clip(small_slots, -1, L) + 1].set(True) \
-            .at[0].set(False)           # -1/-2 sentinels
-        m = jnp.take(is_small, jnp.clip(row_leaf, -1, L) + 1)
+        m, _ = select_by_slot(row_leaf, small_slots, small_slots >= 0)
         pos = jnp.cumsum(m.astype(jnp.int32)) - 1
         n_small = m.astype(jnp.int32).sum()
         c_idx = jnp.zeros((R,), jnp.int32).at[
             jnp.where(m, pos, R)].set(
             jnp.arange(R, dtype=jnp.int32), mode="drop")
         return c_idx, n_small
+
+    def small_child(row_leaf, sel_s, right_slot, leaf_cnt=None):
+        """Which child of each lane has fewer rows, ``(small_is_left
+        [W] bool, this shard's rows in that child [W])``. Only the 2W
+        children are counted (``slot_counts``; ``leaf_cnt`` [L+1] where
+        a partition keeps every leaf's count already). Unused lanes
+        hold the dummy leaf on both sides, which no row carries: they
+        tie and read as left. Under a row mesh the counts are summed
+        over the shards, ``[2W]`` integers, so every shard streams the
+        same child: the histogram merge sums LOCAL small-child
+        histograms."""
+        slots = jnp.concatenate([sel_s, right_slot])
+        if leaf_cnt is not None:
+            loc = jnp.take(leaf_cnt, jnp.clip(slots, 0, L))
+        else:
+            loc = slot_counts(row_leaf, slots)
+        cnt = loc
+        if axis_name is not None and mode != "feature":
+            cnt = jax.lax.psum(loc, axis_name)
+        small_is_left = cnt[:W] <= cnt[W:]
+        return small_is_left, jnp.where(small_is_left, loc[:W], loc[W:])
 
     def stream_rows_for(impl, n_live):
         """Stream positions a compacted round touches for ``n_live``
@@ -1073,12 +1187,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                                    po2w, row_leaf, emit_hist=False)
                 return bs, nsh, R_i32, R_i32
             stg(PHS.COUNT)
-            rlc_n = jnp.where(row_leaf < 0, DUMMY_LEAF, row_leaf)
-            raw_cnt = jax.ops.segment_sum(
-                jnp.ones((R,), jnp.int32), rlc_n, num_segments=L + 1)
-            l_raw = jnp.take(raw_cnt, jnp.clip(sel_s, 0, L))
-            r_raw = jnp.take(raw_cnt, jnp.clip(right_slot, 0, L))
-            small_is_left = l_raw <= r_raw
+            small_is_left, _ = small_child(row_leaf, sel_s, right_slot)
             small_slots = jnp.where(
                 valid, jnp.where(small_is_left, sel_s, right_slot), -2)
             idx_small = jnp.where(small_is_left, iw, W + iw)
@@ -1652,32 +1761,38 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                    .at[DUMMY_LEAF].set(False)
             new_state_extra["used_feat"] = uf
 
-        # -- 3. vectorized partition update (DataPartition::Split analog)
-        pend_active = jnp.zeros((L + 1,), bool).at[sel_s].set(valid) \
-            .at[DUMMY_LEAF].set(False)
-        pend_feat = jnp.zeros((L + 1,), jnp.int32).at[sel_s].set(sfeat)
-        pend_thr = jnp.zeros((L + 1,), jnp.int32).at[sel_s].set(sthr)
-        pend_dl = jnp.zeros((L + 1,), bool).at[sel_s].set(sdl)
-        pend_cat = jnp.zeros((L + 1,), bool).at[sel_s].set(scat)
-        pend_right = jnp.zeros((L + 1,), jnp.int32).at[sel_s].set(right_slot)
-        pend_bits = jnp.zeros((L + 1, BW), jnp.uint32).at[sel_s].set(sbits)
-
-        # native CPU path: the relabel runs as the lgbtpu_relabel custom
-        # call — rows whose leaf is not splitting short-circuit after a
-        # 4-byte read instead of streaming the full gather/select chain
-        # (bundled matrices decode bins in feature space, so they keep
-        # the XLA formulation)
-        use_native_relabel = (hist_impl == "native" and not use_bundle
-                              and not feature_sharded)
+        # -- 3. partition update (DataPartition::Split analog): a dense
+        # relabel of row_leaf. What a row reads of its leaf's pending
+        # split is selected from the round's W records by comparing
+        # row_leaf with the W slots (select_by_slot), never gathered
+        # from a per-leaf table: on the chip a gathered element costs
+        # 9.7 ns, a fused compare-select pass 0.13 ns a row (PERF.md
+        # section 6, PR 29); ops/predict.py row_feature_gather reads
+        # the row's bin by the same rule.
+        snan = jnp.take(nan_bin_pf, sfeat)
+        if use_native_part:
+            # the native CPU custom calls (lgbtpu_partition for the
+            # train matrix, lgbtpu_relabel for valid matrices) take the
+            # records as [L+1] tables: a row whose leaf is not
+            # splitting short-circuits after a 4-byte read
+            pend_active = jnp.zeros((L + 1,), bool).at[sel_s].set(valid) \
+                .at[DUMMY_LEAF].set(False)
+            pend_feat = jnp.zeros((L + 1,), jnp.int32).at[sel_s].set(sfeat)
+            pend_thr = jnp.zeros((L + 1,), jnp.int32).at[sel_s].set(sthr)
+            pend_dl = jnp.zeros((L + 1,), bool).at[sel_s].set(sdl)
+            pend_cat = jnp.zeros((L + 1,), bool).at[sel_s].set(scat)
+            pend_right = jnp.zeros((L + 1,), jnp.int32) \
+                .at[sel_s].set(right_slot)
+            pend_bits = jnp.zeros((L + 1, BW), jnp.uint32) \
+                .at[sel_s].set(sbits)
 
         def relabel(bmat, rl):
-            # only VALID matrices reach the native relabel: the train
-            # matrix goes through lgbtpu_partition whenever the native
-            # backend is on (use_native_part == use_native_relabel)
-            if use_native_relabel:
-                # the matrix may be narrower than the padded per-feature
-                # metadata (feature-parallel pads the TRAIN matrix's
-                # feature axis; valid matrices stay unpadded)
+            if use_native_part:
+                # only VALID matrices come here: the train matrix goes
+                # through lgbtpu_partition. The matrix may be narrower
+                # than the padded per-feature metadata
+                # (feature-parallel pads the TRAIN matrix's feature
+                # axis; valid matrices stay unpadded)
                 F_mat = bmat.shape[1]
                 out = jax.ffi.ffi_call(
                     "lgbtpu_relabel",
@@ -1690,41 +1805,9 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                 if axis_name is not None:
                     out = _pvary(out, axis_name)
                 return out
-            rlc = jnp.where(rl < 0, DUMMY_LEAF, rl)
-            active = jnp.take(pend_active, rlc)
-            feat = jnp.take(pend_feat, rlc)
-            if feature_sharded:
-                # each device holds only its [R, F_loc] column shard;
-                # the split feature of a row's leaf is owned by exactly
-                # ONE shard, so a masked local gather + psum over the
-                # feature axis reconstructs the bin value everywhere
-                # (one [R] int32 all-reduce per relabel — the sharded
-                # analog of the reference's full-copy re-partition,
-                # feature_parallel_tree_learner.cpp:77)
-                F_m = bmat.shape[1]
-                fl = feat - feat_offset
-                owned = active & (fl >= 0) & (fl < F_m)
-                bl = row_feature_gather(
-                    bmat, jnp.clip(fl, 0, F_m - 1)).astype(jnp.int32)
-                binv = jax.lax.psum(jnp.where(owned, bl, 0), axis_name)
-            else:
-                binv = feature_bin_of(bmat, feat)
-            thr = jnp.take(pend_thr, rlc)
-            nb = jnp.take(nan_bin_pf, feat)
-            isnan = (binv == nb) & (nb >= 0)
-            cat_row = jnp.take(pend_cat, rlc)
-            # categorical: bitset membership (CategoricalDecision, tree.h)
-            word = binv >> 5
-            rbits = jnp.take(pend_bits, rlc, axis=0)             # [R, BW]
-            wsel = jnp.arange(BW, dtype=jnp.int32)[None, :] == word[:, None]
-            wval = jnp.sum(jnp.where(wsel, rbits, jnp.uint32(0)), axis=1)
-            in_set = ((wval >> (binv & 31).astype(jnp.uint32))
-                      & jnp.uint32(1)) == 1
-            go_left = jnp.where(cat_row, in_set, binv <= thr)
-            go_left = jnp.where(isnan & ~cat_row,
-                                jnp.take(pend_dl, rlc), go_left)
-            return jnp.where(active & ~go_left,
-                             jnp.take(pend_right, rlc), rl)
+            return relabel_rows(
+                bmat, rl, sel_s, valid, sfeat, sthr, sdl, scat, right_slot,
+                snan, sbits, bin_records(sfeat), feature_bin_of)
 
         new_state_part = {}
         part_n = None
@@ -1761,10 +1844,8 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
 
         if use_cegb and cegb_lazy is not None:
             # rows of split leaves have now "paid" for their feature
-            rlc_pre = jnp.where(st["row_leaf"] < 0, DUMMY_LEAF,
-                                st["row_leaf"])
-            act_r = jnp.take(pend_active, rlc_pre)
-            f_r = jnp.take(pend_feat, rlc_pre)
+            act_r, (f_r,) = select_by_slot(st["row_leaf"], sel_s, valid,
+                                           [sfeat])
             ur = st["cegb_used_rows"]
             cur = ur[jnp.arange(R), f_r]
             new_state_extra["cegb_used_rows"] = ur.at[
@@ -1802,22 +1883,10 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             bs["gain"] = jnp.where(valid2w, g, NEG_INF)
         elif hist_sub:
             stg(PHS.COUNT)
-            if use_native_part:
-                raw_cnt = lc_n          # partition maintains the counts
-            else:
-                rlc_n = jnp.where(row_leaf < 0, DUMMY_LEAF, row_leaf)
-                raw_cnt = jax.ops.segment_sum(
-                    jnp.ones((R,), jnp.int32), rlc_n, num_segments=L + 1)
-            raw_loc = raw_cnt
-            if axis_name is not None and mode != "feature":
-                # replicate the small/big choice across row shards: in
-                # data mode the psum inside hist_raw_for sums LOCAL
-                # small-child histograms, so every shard must agree on
-                # which child that is
-                raw_cnt = jax.lax.psum(raw_cnt, axis_name)
-            l_raw = jnp.take(raw_cnt, jnp.clip(sel_s, 0, L))
-            r_raw = jnp.take(raw_cnt, jnp.clip(right_slot, 0, L))
-            small_is_left = l_raw <= r_raw
+            # the native partition maintains the counts
+            small_is_left, small_loc = small_child(
+                row_leaf, sel_s, right_slot,
+                leaf_cnt=lc_n if use_native_part else None)
             small_slots = jnp.where(
                 valid, jnp.where(small_is_left, sel_s, right_slot), -2)
             if hist_compact:
@@ -1830,9 +1899,8 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             else:
                 # the partition's exact row lists (native): this shard's
                 # rows of the small children
-                rows_r = jnp.where(
-                    valid, jnp.take(raw_loc, jnp.clip(small_slots, 0, L)),
-                    0).sum().astype(jnp.int32)
+                rows_r = jnp.where(valid, small_loc, 0).sum() \
+                    .astype(jnp.int32)
                 hsmall = hist_raw_for(small_slots, row_leaf, part=part_n)
             stg(PHS.SUBTRACT)
             parent_raw = jnp.take(st["hist_cache"],

@@ -55,7 +55,6 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.histogram import HIST_CH, build_histograms, resolve_impl
-from ..ops.predict import row_feature_gather
 from ..ops.split import SplitParams, find_best_splits, leaf_output
 
 __all__ = ["ArraySource", "ShardSource", "ChunkedTreeBuilder"]
@@ -195,32 +194,6 @@ class ChunkedTreeBuilder:
             [quant_scales.astype(f32), jnp.ones((1,), f32)])
         return h.astype(f32) * dq
 
-    def _relabel(self, bmat, rl, pend):
-        """The resident builder's vectorized partition update
-        (DataPartition::Split analog) over an arbitrary row window."""
-        (pend_active, pend_feat, pend_thr, pend_dl, pend_cat,
-         pend_right, pend_bits) = pend
-        rlc = jnp.where(rl < 0, self.DUMMY_LEAF, rl)
-        active = jnp.take(pend_active, rlc)
-        feat = jnp.take(pend_feat, rlc)
-        binv = row_feature_gather(bmat, feat)
-        thr = jnp.take(pend_thr, rlc)
-        nb = jnp.take(self.nan_bin_pf, feat)
-        isnan = (binv == nb) & (nb >= 0)
-        cat_row = jnp.take(pend_cat, rlc)
-        word = binv >> 5
-        rbits = jnp.take(pend_bits, rlc, axis=0)
-        wsel = (jnp.arange(self.BW, dtype=jnp.int32)[None, :]
-                == word[:, None])
-        wval = jnp.sum(jnp.where(wsel, rbits, jnp.uint32(0)), axis=1)
-        in_set = ((wval >> (binv & 31).astype(jnp.uint32))
-                  & jnp.uint32(1)) == 1
-        go_left = jnp.where(cat_row, in_set, binv <= thr)
-        go_left = jnp.where(isnan & ~cat_row,
-                            jnp.take(pend_dl, rlc), go_left)
-        return jnp.where(active & ~go_left,
-                         jnp.take(pend_right, rlc), rl)
-
     def _best(self, hist2w, slot_depth, slot_valid, slots_c, tree,
               feature_mask, gain_scale):
         """The resident ``best_for`` simple branch + its gain masks."""
@@ -266,14 +239,13 @@ class ChunkedTreeBuilder:
         return tree._replace(leaf2node=tree.leaf2node.at[0].set(0))
 
     def _zero_pend(self):
-        L, BW = self.L, self.BW
-        return (jnp.zeros((L + 1,), bool),
-                jnp.zeros((L + 1,), jnp.int32),
-                jnp.zeros((L + 1,), jnp.int32),
-                jnp.zeros((L + 1,), bool),
-                jnp.zeros((L + 1,), bool),
-                jnp.zeros((L + 1,), jnp.int32),
-                jnp.zeros((L + 1, BW), jnp.uint32))
+        """A round's split records (``relabel_rows``' [W] arguments)
+        with no lane in use: the relabel is the identity."""
+        W, BW = self.W, self.BW
+        i32 = jnp.zeros((W,), jnp.int32)
+        no = jnp.zeros((W,), bool)
+        return (jnp.full((W,), self.DUMMY_LEAF, jnp.int32), no, i32, i32,
+                no, no, i32, i32, jnp.zeros((W, BW), jnp.uint32))
 
     # -------------------------- jitted programs ------------------------
 
@@ -287,7 +259,8 @@ class ChunkedTreeBuilder:
         rl_c = jax.lax.dynamic_slice(row_leaf, (offset,), (C,))
         gh_c = jax.lax.dynamic_slice(
             gh, (offset, jnp.int32(0)), (C, gh.shape[1]))
-        rl_new = self._relabel(chunk_bins, rl_c, pend)
+        from ..boosting.tree_builder import relabel_rows
+        rl_new = relabel_rows(chunk_bins, rl_c, *pend)
         hist = build_histograms(
             chunk_bins, gh_c, rl_new, slots, num_bins=self.B,
             block_rows=self.block_rows, hist_dtype=self.hist_dtype,
@@ -404,19 +377,14 @@ class ChunkedTreeBuilder:
         leaf_depth = leaf_depth.at[sel_s].set(new_depth) \
                                .at[right_slot].set(new_depth)
 
-        pend = (jnp.zeros((self.L + 1,), bool).at[sel_s].set(valid)
-                .at[DUMMY_LEAF].set(False),
-                jnp.zeros((self.L + 1,), jnp.int32).at[sel_s].set(sfeat),
-                jnp.zeros((self.L + 1,), jnp.int32).at[sel_s].set(sthr),
-                jnp.zeros((self.L + 1,), bool).at[sel_s].set(sdl),
-                jnp.zeros((self.L + 1,), bool).at[sel_s].set(scat),
-                jnp.zeros((self.L + 1,), jnp.int32).at[sel_s]
-                .set(right_slot),
-                jnp.zeros((self.L + 1, self.BW), jnp.uint32).at[sel_s]
-                .set(sbits))
+        # the round's split records, one lane a split: every chunk's
+        # rows (and the valid sets') select from them by their leaf
+        pend = (sel_s, valid, sfeat, sthr, sdl, scat, right_slot,
+                jnp.take(self.nan_bin_pf, sfeat), sbits)
 
+        from ..boosting.tree_builder import relabel_rows
         valid_row_leaf = tuple(
-            self._relabel(vb, vrl, pend)
+            relabel_rows(vb, vrl, *pend)
             for vb, vrl in zip(valid_bins, valid_row_leaf))
 
         slots2w = jnp.concatenate([jnp.where(valid, sel_s, -2),

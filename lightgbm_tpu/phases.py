@@ -63,8 +63,9 @@ WINNER_SYNC = "winner_sync"
 ROOT_PASS = "root_pass"          # root histogram, totals, root split
 POP = "pop"                      # top-k over cached gains + their takes
 APPLY = "apply"                  # tree scatter, bounds, row_leaf relabel
-COUNT = "count"                  # segment_sum of raw child counts
-COMPACT = "compact"              # is_small lut, cumsum, n_small, c_idx:
+COUNT = "count"                  # rows in the round's 2W children: one
+#                                  compare-and-sum over R (slot_counts)
+COMPACT = "compact"              # membership, cumsum, n_small, c_idx:
 #                                  the making of the index, no row moves
 HIST_GATHER = "hist_gather"      # bins, gh and row_leaf by row_gather: all
 #                                  three, a chunk (pallas) or a block a trip

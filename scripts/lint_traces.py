@@ -38,6 +38,10 @@ over it — the gate must exit NON-zero, proving the rule still fires:
                        instead of returning it as a deferred device
                        output (TD006, the resilience PR's
                        host-sync-per-iteration regression class)
+- ``row-table-read`` — a round body that reads a row's pending split by
+                       ``jnp.take`` from an ``[L+1]`` table and counts
+                       children by ``segment_sum`` (TD008, the 12.0 s
+                       of an 18.3 s Higgs tree that PR 29 removed)
 
 Run: python scripts/lint_traces.py [--fast] [--seed CLASS]
 (CPU-only, no hardware needed; ``--fast`` lints one config cell and
@@ -60,7 +64,8 @@ def _load_probe():
 
 
 SEED_CLASSES = ("closure-const", "cpu-donation", "phase-collective",
-                "recompile-blowout", "class-unroll", "nan-guard-sync")
+                "recompile-blowout", "class-unroll", "nan-guard-sync",
+                "row-table-read")
 
 
 def _seed_closure_const() -> list:
@@ -164,6 +169,29 @@ def _seed_nan_guard_sync() -> list:
                                 expect_flags=2)]
 
 
+def _seed_row_table_read() -> list:
+    """Plant the round body as it stood before PR 29: the round's
+    records scattered into per-leaf tables and read back by R rows, the
+    children counted by a ``segment_sum`` of R ones."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu import profiler
+    from lightgbm_tpu.analysis import lint_jaxpr
+    R, L = 4096, 15
+
+    def body(row_leaf, sel, feat):
+        with profiler.stage("build"):
+            pend_feat = jnp.zeros((L + 1,), jnp.int32).at[sel].set(feat)
+            f_r = jnp.take(pend_feat, row_leaf)
+            cnt = jax.ops.segment_sum(jnp.ones((R,), jnp.int32), row_leaf,
+                                      num_segments=L + 1)
+            return f_r, jnp.take(cnt, sel)
+    closed = jax.make_jaxpr(body)(jnp.zeros((R,), jnp.int32),
+                                  jnp.arange(4, dtype=jnp.int32),
+                                  jnp.arange(4, dtype=jnp.int32))
+    return [lint_jaxpr(closed, label="seed/row_table_read", build_rows=R)]
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--seed", choices=SEED_CLASSES,
@@ -190,6 +218,7 @@ def main(argv=None) -> int:
             "recompile-blowout": _seed_recompile_blowout,
             "class-unroll": _seed_class_unroll,
             "nan-guard-sync": _seed_nan_guard_sync,
+            "row-table-read": _seed_row_table_read,
         }[ns.seed]()
         for r in reports:
             print(r.render(verbose=True))

@@ -235,10 +235,13 @@ def test_stage_map_names_the_grow_loop_at_benchmark_shapes(v5e, config,
                      for d in shape.findall(lines[r.op.name])), default=0)
         if elems >= R:
             if (not r.op.op_name and sm.stages.get(r.op.name) is None
-                    and r.op.opcode in ("copy-start", "copy-done")):
+                    and (r.op.opcode in ("copy-start", "copy-done",
+                                         "slice-start", "slice-done")
+                         or r.op.custom_call_target == "ConcatBitcast")):
                 # the compiler's own prefetch into fast memory for the
-                # NEXT round (its user is the body's root tuple): no
-                # source line to name, bare ``build`` in a stage table
+                # NEXT round (its user is the body's root tuple), whole
+                # or in slices that a ConcatBitcast joins: no source
+                # line to name, bare ``build`` in a stage table
                 continue
             seen[r.comp] += 1
             assert sm.stages.get(r.op.name) in deep, (

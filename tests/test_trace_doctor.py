@@ -23,9 +23,13 @@ from lightgbm_tpu.analysis import (Finding, RecompileError,
                                    RecompileGuard, TraceReport,
                                    cache_size, lint_hlo, lint_jaxpr,
                                    lower_hlo, merge_errors)
-from lightgbm_tpu.analysis.doctor import (doctor_batcher,
+from lightgbm_tpu.analysis.doctor import (ROUND_BODY_CELLS,
+                                          doctor_batcher,
                                           doctor_fused_step,
-                                          doctor_predict, make_booster)
+                                          doctor_predict,
+                                          doctor_round_body,
+                                          doctor_tree_builder,
+                                          make_booster)
 
 
 @contextlib.contextmanager
@@ -109,6 +113,113 @@ def test_td004_cpu_donation_fires_on_hlo_and_accelerator_is_exempt():
     rep = lint_hlo(hlo, label="donate", backend="cpu")
     assert any(f.rule == "TD004" for f in rep.errors)
     assert lint_hlo(hlo, label="donate", backend="tpu").ok
+
+
+# ---- TD008: per-row reads of a small table in the build stage (PR 29)
+
+_R, _L, _W = 4096, 15, 4
+
+
+def _round_inputs():
+    rng = np.random.RandomState(0)
+    rl = jnp.asarray(rng.randint(-1, 8, size=_R).astype(np.int32))
+    sel = jnp.asarray(np.array([1, 3, 5, _L], np.int32))
+    ok = jnp.asarray(np.array([True, True, True, False]))
+    feat = jnp.asarray(np.array([2, 0, 1, 3], np.int32))
+    return rl, sel, ok, feat
+
+
+def _table_round(rl, sel, ok, feat):
+    """The round body as it stood at PR 28: the W records scattered
+    into ``[L+1]`` tables, read by R rows; children counted by a
+    ``segment_sum`` of R ones."""
+    with jax.named_scope("build"):
+        with jax.named_scope("apply"):
+            rlc = jnp.where(rl < 0, _L, rl)
+            pend_active = jnp.zeros((_L + 1,), bool).at[sel].set(ok) \
+                .at[_L].set(False)
+            pend_feat = jnp.zeros((_L + 1,), jnp.int32).at[sel].set(feat)
+            active = jnp.take(pend_active, rlc)
+            f_r = jnp.take(pend_feat, rlc)
+        with jax.named_scope("count"):
+            cnt = jax.ops.segment_sum(jnp.ones((_R,), jnp.int32), rlc,
+                                      num_segments=_L + 1)
+        return active, f_r, jnp.take(cnt, sel)
+
+
+def _select_round(rl, sel, ok, feat):
+    from lightgbm_tpu.boosting.tree_builder import (select_by_slot,
+                                                    slot_counts)
+    with jax.named_scope("build"):
+        with jax.named_scope("apply"):
+            active, (f_r,) = select_by_slot(rl, sel, ok, [feat])
+        with jax.named_scope("count"):
+            cnt = slot_counts(rl, sel)
+        with jax.named_scope("compact"):
+            # the stream's index: an R-sized result, R-sized operands
+            pos = jnp.cumsum(active.astype(jnp.int32)) - 1
+            c_idx = jnp.zeros((_R,), jnp.int32).at[
+                jnp.where(active, pos, _R)].set(
+                jnp.arange(_R, dtype=jnp.int32), mode="drop")
+            with jax.named_scope("hist_gather"):
+                rl_c = jnp.take(rl, c_idx)
+            with jax.named_scope("hist_kernel"):
+                # the histogram itself adds rows into few bins
+                hist = jnp.zeros((8,), jnp.int32).at[
+                    jnp.clip(rl_c, 0, 7)].add(1)
+        return active, f_r, cnt, hist
+
+
+def test_td008_fires_on_the_table_round_and_not_on_the_select_round():
+    args = _round_inputs()
+    bad = lint_jaxpr(jax.make_jaxpr(_table_round)(*args), label="tables",
+                     build_rows=_R)
+    assert [f.rule for f in bad.errors] == ["TD008"] * 3
+    assert sorted(f.op_path.rsplit("/", 1)[1] for f in bad.errors) == [
+        "gather", "gather", "scatter-add"]
+    assert all("build" in f.op_path for f in bad.errors)
+    good = lint_jaxpr(jax.make_jaxpr(_select_round)(*args),
+                      label="selects", build_rows=_R)
+    assert good.ok, good.render(verbose=True)
+    # the same reads outside the build stage are another rule's business
+    # (``update`` gathers leaf values by row_leaf), and without
+    # ``build_rows`` the rule is off
+    assert lint_jaxpr(jax.make_jaxpr(_table_round)(*args),
+                      label="tables").ok
+
+    def elsewhere(rl, sel, ok, feat):
+        with jax.named_scope("update"):
+            return jnp.take(jnp.zeros((_L + 1,)), jnp.clip(rl, 0, _L))
+    assert lint_jaxpr(jax.make_jaxpr(elsewhere)(*args), label="update",
+                      build_rows=_R).ok
+    # both rounds give the same answers on the used lanes
+    a0, f0, c0 = _table_round(*args)
+    a1, f1, c1, _ = _select_round(*args)
+    np.testing.assert_array_equal(np.asarray(a0), np.asarray(a1))
+    # (where no lane hits, a table holds what an unused lane wrote)
+    np.testing.assert_array_equal(np.where(a0, f0, 0), np.asarray(f1))
+    np.testing.assert_array_equal(np.asarray(c0)[:3], np.asarray(c1)[:3])
+
+
+@pytest.mark.parametrize("config,mode", ROUND_BODY_CELLS)
+def test_td008_round_body_of_the_fused_step_is_clean(config, mode):
+    """No per-row read of a small table in the ``build`` stage of the
+    fused step, traced with the round's XLA formulation in it."""
+    if mode == "data" and len(jax.devices()) < 2:
+        pytest.skip("needs a multi-device mesh")
+    reports = doctor_round_body(config, mode)
+    assert not merge_errors(reports), "\n".join(
+        r.render(verbose=True) for r in reports)
+    assert not any(f.rule == "TD000" for r in reports for f in r.findings)
+
+
+def test_td008_data_parallel_tree_builder_is_clean():
+    if len(jax.devices()) < 2:
+        pytest.skip("needs a multi-device mesh")
+    reports = doctor_tree_builder()
+    assert any(r.label.endswith("/round_body") for r in reports)
+    assert not merge_errors(reports), "\n".join(
+        r.render(verbose=True) for r in reports)
 
 
 # ------------------------------------------------------------- hlo rules
