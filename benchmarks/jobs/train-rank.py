@@ -255,13 +255,16 @@ def run(env) -> dict:
     addends = str(gb.config.hist_dtype)
     lr = float(params["learning_rate"])
     with spans.span("after.reference"):
-        replays = []
+        replays, upto = [], None    # (k, the scores after k trees)
         for index in sorted({0, trees}):
-            score = ref.replay_scores(model_text, index, ubs, bins_cm, lr)
+            score = ref.replay_scores(model_text, index, ubs, bins_cm, lr,
+                                      start=upto)
+            upto = (index, score)
             g, h = ref.lambdarank_gradients(score, y, bounds, params)
             replays.append(ref.check_tree(model_text, index, ubs, bins_cm,
                                           g, h, params, addends))
-        replayed = ref.replay_scores(model_text, trees + 1, ubs, bins_cm, lr)
+        replayed = ref.replay_scores(model_text, trees + 1, ubs, bins_cm, lr,
+                                     start=upto)
         score_gap = float(np.abs(
             replayed - gb.eval_scores(-1)[:, 0]).max())
     with spans.span("after.gradients"):

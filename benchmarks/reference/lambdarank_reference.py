@@ -199,7 +199,7 @@ def ndcg_at_k(score, label, boundaries, k: int,
 
 def replay_scores(model_text: str, trees: int,
                   upper_bounds: Sequence[np.ndarray], bins_cm: np.ndarray,
-                  learning_rate: float) -> np.ndarray:
+                  learning_rate: float, start=None) -> np.ndarray:
     """[rows] raw score after the first ``trees`` trees of the model text,
     in the score type the configuration states: float32, a tree's leaf
     outputs (float32, what the text holds divided by the rate) times the
@@ -207,10 +207,12 @@ def replay_scores(model_text: str, trees: int,
     documents whose scores differ in the last place decides their ranks,
     so the scores are replayed in the stated type and not in float64;
     the gradients from them are float64. The init score of a ranking
-    objective is 0."""
-    score = np.zeros(bins_cm.shape[1], np.float32)
+    objective is 0. ``start`` = (k, the scores after k trees) goes on from
+    there (the same float32 additions in the same order)."""
+    first, score = (0, np.zeros(bins_cm.shape[1], np.float32)) \
+        if start is None else (start[0], start[1].copy())
     rate = np.float32(learning_rate)
-    for t in range(trees):
+    for t in range(first, trees):
         tree = parse_tree(model_text, t)
         _, leaf_rows = replay(tree, threshold_bins(tree, upper_bounds),
                               bins_cm)

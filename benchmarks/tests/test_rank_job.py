@@ -32,17 +32,17 @@ TINY = {"rows": 6000, "cols": 30, "queries": 90, "min_query": 1,
 
 # -- the manifest ---------------------------------------------------------------
 
-def test_manifest_has_the_cell_its_configuration_and_three_metrics_last():
+def test_manifest_has_the_cell_its_configuration_and_three_metrics():
     man = Manifest(ROOT)
     assert man.problems() == []
     cell = man.cell(CELL)
     assert cell["config"] == "msltr" and cell["traffic"] == "rank-steady"
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
-    assert man.doc["workloads"][-1] is cell
-    assert man.doc["configs"][-1]["name"] == "msltr"
-    assert man.doc["configs"][-1]["reduced"] == ["num_iterations"]
-    assert [m["name"] for m in man.doc["per_layer"][-3:]] == list(NEW)
-    for m in man.doc["per_layer"][-3:]:
+    entry = [c for c in man.doc["configs"] if c["name"] == "msltr"]
+    assert len(entry) == 1 and entry[0]["reduced"] == ["num_iterations"]
+    mine = [m for m in man.doc["per_layer"] if m["name"] in NEW]
+    assert sorted(m["name"] for m in mine) == sorted(NEW)
+    for m in mine:
         assert m["workloads"] == [CELL] and m["layer"] == "objectives"
     cfg = man.config("msltr")
     assert cfg["shape"] == {"rows": 2270296, "cols": 137, "queries": 18919,
@@ -52,7 +52,8 @@ def test_manifest_has_the_cell_its_configuration_and_three_metrics_last():
     assert man.traffic(cell["traffic"])["job"] == "train-rank"
     # every metric without a list reads in the new cell too
     names = {m["name"] for m in man.metrics_for(CELL, "per_layer")}
-    assert len(names) == len(man.doc["per_layer"]) and set(NEW) <= names
+    assert set(NEW) <= names and names >= {
+        m["name"] for m in man.doc["per_layer"] if "workloads" not in m}
     for old in ("higgs-train", "epsilon-train"):
         assert not set(NEW) & {m["name"]
                                for m in man.metrics_for(old, "per_layer")}
@@ -251,3 +252,26 @@ def test_a_pair_lattice_over_half_the_device_is_refused(tiny_root):
     job.refuse_unless_it_fits(lgb, cfg, gen, 2 * need)
     with pytest.raises(job.CannotRunCell, match="over half"):
         job.refuse_unless_it_fits(lgb, cfg, gen, 2 * need - 2)
+
+
+def test_a_replay_that_goes_on_from_a_start_adds_the_same_bits():
+    from reference import lambdarank_reference as ref
+
+    def stump(i, feature, tbin, values):
+        return (f"Tree={i}\nnum_leaves=2\nnum_cat=0\nsplit_feature={feature}\n"
+                f"split_gain=1\nthreshold={tbin + 0.5}\ndecision_type=0\n"
+                "left_child=-1\nright_child=-2\n"
+                f"leaf_value={values[0]!r} {values[1]!r}\nleaf_weight=1 1\n"
+                "leaf_count=1 1\ninternal_value=0\ninternal_weight=0\n"
+                "internal_count=2\nis_linear=0\nshrinkage=0.1\n\n")
+    text = "".join(stump(i, i % 3, 2 + i, [0.013 * (i + 1), -0.021 * (i + 2)])
+                   for i in range(4))
+    bins = np.random.default_rng(0).integers(0, 8, size=(3, 1000),
+                                             dtype=np.uint8)
+    ubs = [np.append(np.arange(7) + 0.5, np.inf)] * 3
+    whole = ref.replay_scores(text, 4, ubs, bins, 0.1)
+    half = ref.replay_scores(text, 2, ubs, bins, 0.1)
+    kept = half.copy()
+    assert np.array_equal(
+        whole, ref.replay_scores(text, 4, ubs, bins, 0.1, start=(2, half)))
+    assert np.array_equal(half, kept) and not np.array_equal(half, whole)

@@ -44,13 +44,13 @@ def _read(run):
     return Manifest(ROOT).metric_reader(NAME).read(run)
 
 
-def test_manifest_has_the_entry_last_and_in_every_cell():
+def test_manifest_has_the_entry_and_in_every_cell():
     man = Manifest(ROOT)
     assert man.problems() == []
-    entry = man.doc["per_layer"][-1]
-    assert entry == {"name": NAME, "unit": "%", "better": "higher",
-                     "source": "program_counter", "layer": "builder",
-                     "moves": "train_row_trees_per_s"}
+    entries = [m for m in man.doc["per_layer"] if m["name"] == NAME]
+    assert entries == [{"name": NAME, "unit": "%", "better": "higher",
+                        "source": "program_counter", "layer": "builder",
+                        "moves": "train_row_trees_per_s"}]
     for w in man.doc["workloads"]:
         assert NAME in {m["name"]
                         for m in man.metrics_for(w["name"], "per_layer")}
