@@ -5,8 +5,8 @@ Drives the main path once, in ONE process, through the entry points a user
 calls (``lgb.Dataset``, ``lgb.train``, ``Booster.update``/``predict``,
 ``serving.PredictionServer`` over HTTP), at the full width of the Higgs
 configuration: 28 features, 255 leaves, 63 bins, default kernel selection.
-Depth is cut (a few trees, not 500); the data is ``bench.make_higgs_like``
-from a seed.
+Depth is cut (a few trees, not 500); the data is ``make_higgs_like`` from
+a seed.
 
 Phases (any failure raises; nothing is caught and carried past):
   a. report jax, the device, the compile cache in force, native helpers;
@@ -66,6 +66,18 @@ def phase(name):
     t0 = time.time()
     yield
     say(f"== {name}: ok ({time.time() - t0:.1f} s)")
+
+
+def make_higgs_like(n_rows: int, n_feat: int = 28, seed: int = 7):
+    """Synthetic stand-in with Higgs-like shape: dense floats, a nonlinear
+    decision surface, balanced classes."""
+    rng = np.random.RandomState(seed)
+    X = rng.normal(size=(n_rows, n_feat)).astype(np.float32)
+    w = rng.normal(size=n_feat) / np.sqrt(n_feat)
+    logit = (X @ w + 0.7 * X[:, 0] * X[:, 1]
+             - 0.4 * X[:, 2] ** 2 + 0.3 * np.abs(X[:, 3]))
+    y = (logit + rng.logistic(size=n_rows) * 0.5 > 0).astype(np.float32)
+    return X, y
 
 
 def cache_entries(path):
@@ -143,7 +155,6 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
 
     import lightgbm_tpu as lgb
-    from bench import make_higgs_like
     from lightgbm_tpu import native
     from lightgbm_tpu.ops.histogram import build_histograms
     from lightgbm_tpu.serving import PredictionServer
@@ -213,7 +224,6 @@ def main(argv=None) -> int:
             "hist_impl": gb.config.hist_impl,
             "hist_impl_reason": gb.hist_impl_reason,
             "fused_reason": gb.fused_reason,
-            "fused_split_reason": gb.fused_split_reason,
             "class_batch_reason": gb.class_batch_reason}
         serial_peak = peak_bytes(devs[0])
         say(f"compile + {warm} warm-up iterations: {t_first:.1f} s; "

@@ -28,7 +28,7 @@ from .hlo_walk import (HloOp, COLLECTIVE_KINDS, parse_ops,  # noqa: F401
 from .recompile_guard import (RecompileGuard,  # noqa: F401
                               RecompileError, cache_size)
 from .doctor import (run_doctor, doctor_main,  # noqa: F401
-                     doctor_fused_split, CANONICAL_CONFIGS)
+                     CANONICAL_CONFIGS)
 
 __all__ = [
     "Finding", "TraceReport", "merge_errors",
@@ -36,6 +36,5 @@ __all__ = [
     "HloOp", "COLLECTIVE_KINDS", "parse_ops", "parse_collective_ops",
     "input_output_aliases", "lower_hlo",
     "RecompileGuard", "RecompileError", "cache_size",
-    "run_doctor", "doctor_main", "doctor_fused_split",
-    "CANONICAL_CONFIGS",
+    "run_doctor", "doctor_main", "CANONICAL_CONFIGS",
 ]

@@ -60,8 +60,8 @@ from .report import TraceReport, merge_errors
 __all__ = ["CANONICAL_CONFIGS", "PARALLEL_MODES", "ROUND_BODY_CELLS",
            "make_booster", "doctor_fused_step", "doctor_round_body",
            "doctor_tree_builder", "doctor_predict",
-           "doctor_batcher", "doctor_serving", "doctor_fused_split",
-           "run_doctor", "doctor_main"]
+           "doctor_batcher", "doctor_serving", "run_doctor",
+           "doctor_main"]
 
 # name -> (train-param overrides, dataset kwargs)
 CANONICAL_CONFIGS: Dict[str, Tuple[dict, dict]] = {
@@ -396,84 +396,6 @@ def doctor_serving(bst, *, label: str = "serving_compiled",
                      allowed_phases=frozenset(), allow=allow)]
 
 
-def doctor_fused_split(*, label: str = "fused_split",
-                       R: int = 256, F: int = 16, B: int = 12,
-                       allow: Sequence[Tuple[str, str]] = ()
-                       ) -> List[TraceReport]:
-    """The fused build+split contract (ISSUE 14): the compiled program
-    must stage NO ``[.., F, B, 3]``-shaped histogram lattice between
-    the hist and split phases — only candidate records reach
-    program-level buffers. Interpret-mode Pallas (the CPU lowering)
-    stages the kernel's VMEM block as ordinary HLO ops, so ``B`` is
-    chosen off the power-of-two grid (``Bp > B``): every in-kernel
-    block carries the padded bin dim and can never alias the exact
-    ``[.., F, B, 3]`` lattice that crosses the phase boundary in the
-    two-pass program. That two-pass program is linted as the negative
-    control: the detector must find the lattice THERE, else the rule
-    itself is broken."""
-    import functools as ft
-    from unittest import mock
-
-    import jax
-    import jax.numpy as jnp
-
-    from ..boosting.tree_builder import build_tree
-    from ..ops import pallas_histogram as PH
-    from ..ops.split import SplitParams
-    from .hlo_walk import parse_all_ops
-
-    rng = np.random.RandomState(2)
-    bins = jnp.asarray(rng.randint(0, B, size=(R, F)).astype(np.uint8))
-    gh = jnp.asarray(rng.normal(size=(R, 3)).astype(np.float32))
-    rl0 = jnp.zeros((R,), jnp.int32)
-    meta = (jnp.full((F,), B, jnp.int32),
-            jnp.full((F,), -1, jnp.int32),
-            jnp.zeros((F,), bool), jnp.ones((F,), bool))
-    kw = dict(num_leaves=7, leaf_batch=2, max_depth=-1, num_bins=B,
-              hist_dtype="float32", block_rows=R, hist_sub=False,
-              split_params=SplitParams(min_data_in_leaf=5,
-                                       min_sum_hessian_in_leaf=1e-3))
-
-    def lattice_hits(hlo: str):
-        hits = []
-        for op in parse_all_ops(hlo):
-            if op.opcode == "parameter":
-                continue
-            for _, shape in op.shapes:
-                if len(shape) >= 3 and tuple(shape[-3:]) == (F, B, 3):
-                    hits.append((op.opcode, shape))
-        return hits
-
-    with contextlib.ExitStack() as ctx:
-        if jax.default_backend() != "tpu":
-            for name in ("fused_build_best_splits",
-                         "build_histograms_pallas"):
-                ctx.enter_context(mock.patch.object(
-                    PH, name,
-                    ft.partial(getattr(PH, name), interpret=True)))
-        hlo_fused = lower_hlo(
-            lambda b, g, r: build_tree(
-                b, g, r, *meta, hist_impl="pallas",
-                fused_split=True, **kw)[0],
-            bins, gh, rl0)
-        hlo_two = lower_hlo(
-            lambda b, g, r: build_tree(
-                b, g, r, *meta, hist_impl="pallas", **kw)[0],
-            bins, gh, rl0)
-    rep = TraceReport(label=label)
-    for opcode, shape in lattice_hits(hlo_fused):
-        rep.add("TD007", "error", opcode,
-                f"histogram lattice {shape} staged in the fused "
-                "build+split program — the fused epilogue must keep "
-                "it VMEM-resident (only candidate records may leave "
-                "the kernel)")
-    if not lattice_hits(hlo_two):
-        rep.add("TD007", "error", "negative_control",
-                "two-pass program shows no histogram lattice — the "
-                "detector is broken, not the kernel")
-    return [rep.apply_allowlist(allow)]
-
-
 def run_doctor(configs: Optional[Sequence[str]] = None,
                modes: Optional[Sequence[str]] = None, *,
                compile_hlo: bool = True,
@@ -498,8 +420,6 @@ def run_doctor(configs: Optional[Sequence[str]] = None,
         if cfg in configs and mode in modes:
             reports += doctor_round_body(cfg, mode, allow=allow)
     reports += doctor_tree_builder(allow=allow)
-    if compile_hlo:
-        reports += doctor_fused_split(allow=allow)
     if first_bst is not None:
         reports += doctor_predict(first_bst, allow=allow)
         reports += doctor_batcher(first_bst, allow=allow)

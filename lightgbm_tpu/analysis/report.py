@@ -24,10 +24,6 @@ TD005     jaxpr     class-unrolled build: more ``build``-phase grow
                     loops staged per program than the caller's budget
                     (a multiclass iteration tracing K sequential tree
                     builds instead of one class-batched build)
-TD007     hlo       full ``[.., F, B, 3]`` histogram lattice staged in
-                    the fused build+split program (the VMEM-residency
-                    contract of the fused Pallas epilogue: only
-                    candidate records may leave the kernel)
 TD008     jaxpr     per-row table read in the ``build`` stage: a
                     ``gather`` of >= R index tuples from fewer than R
                     table entries, or a ``scatter-add`` of >= R updates

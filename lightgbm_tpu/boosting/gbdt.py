@@ -44,7 +44,6 @@ from .. import profiler
 from ..config import Config
 from ..dataset import Dataset
 from ..objectives import Objective
-from ..ops import pallas_histogram as PH
 from ..ops.histogram import (block_rows_for, pallas_shape_reason,
                              resolve_impl)
 from ..ops.split import SplitParams
@@ -617,8 +616,8 @@ class GBDT:
         # fused boosting step state (see train_one_iter): the pending
         # ring of (iteration, shrinkage, device TreeArrays per class,
         # device should_continue flag), materialized in batches by
-        # sync(); host_sync_count instruments the bench's
-        # host_syncs_per_iter field
+        # sync(); host_sync_count is what the benchmark's
+        # driver.host_syncs_per_tree reads
         self._pending: List[Tuple] = []
         self._fused_jit = None
         self._full_mask_cache: Optional[Tuple] = None
@@ -739,12 +738,6 @@ class GBDT:
             # batched build stays bit-identical without it)
             self._hist_sub = _hist_sub_gate(
                 self.K * (-(-_lattice // n_fs)))
-
-        # fused Pallas build+split (ISSUE 14): decided eagerly (the
-        # probe compiles outside any trace) so telemetry can name the
-        # binding gate; both tree builders read the flag
-        self.fused_split_reason = self._fused_split_reason()
-        self.fused_split_ok = not self.fused_split_reason
 
         # decide the iteration driver LAST (the gate reads _cegb/_mp/...)
         self.fused_reason = self._fused_gate_reason()
@@ -1094,8 +1087,6 @@ class GBDT:
             if self._bins_cm is None:
                 self._bins_cm = jnp.asarray(self.train_dd.bins.T)
             kw["bins_cm"] = self._bins_cm
-        if self.fused_split_ok:
-            kw["fused_split"] = True
         mono_method = (cfg.monotone_constraints_method
                        if self.mono_type_pf is not None else "basic")
         leaf_batch = cfg.leaf_batch
@@ -1162,53 +1153,6 @@ class GBDT:
             return "extra-trees thresholds draw inside the builder"
         return ""
 
-    # -- fused Pallas build+split (ISSUE 14) ---------------------------
-
-    def _fused_split_reason(self) -> str:
-        """Why the fused histogram+split-find Pallas kernel cannot
-        drive this run's split search ('' = it can). The kernel's
-        epilogue evaluates the gain lattice on the VMEM-resident
-        accumulator block and emits only per-(leaf, chunk) candidate
-        records, so anything that needs the full [F, B, 3] histogram
-        in HBM — merge collectives, EFB unbundling, sorted-subset
-        categorical reordering, gain rescaling, random thresholds —
-        pins the two-pass kernel + ``find_best_splits`` path. Mirrors
-        tree_builder's trace-time ``use_fused`` gate (which still
-        falls back silently if a traced shape disagrees)."""
-        import os
-        cfg = self.config
-        env = os.environ.get("LIGHTGBM_TPU_FUSED_SPLIT", "")
-        if env == "0":
-            return "LIGHTGBM_TPU_FUSED_SPLIT=0"
-        mode = "on" if env == "1" else str(cfg.fused_split)
-        if mode == "off":
-            return "fused_split=off"
-        if cfg.hist_impl != "pallas":
-            return (f"hist_impl resolves to {cfg.hist_impl} (epilogue is "
-                    "Pallas)")
-        if self.chunked:
-            return "chunked rounds accumulate histograms across chunks"
-        if self.plan is not None:
-            return "parallel plans merge full histograms"
-        if self._bundle_meta is not None:
-            return "EFB bundles unbundle the full histogram"
-        if bool(cfg.extra_trees):
-            return "extra-trees thresholds sample the full lattice"
-        if self._forced_splits is not None:
-            return "forced splits gather arbitrary (feature, bin) cells"
-        if self._cegb is not None:
-            return "CEGB rescales gains outside the kernel"
-        if self._gain_scale is not None:
-            return "feature_contri rescales gains outside the kernel"
-        if self._cat_sorted_mask is not None:
-            return "sorted-subset categoricals reorder histogram bins"
-        if (self.mono_type_pf is not None
-                and cfg.monotone_constraints_method == "advanced"):
-            return "advanced monotone re-reads sibling histograms"
-        if mode != "on":
-            return PH.FUSED_SPLIT_TPU_REASON
-        return ""
-
     # -- class-batched multiclass build (ISSUE 8) ----------------------
 
     def _class_batch_reason(self) -> str:
@@ -1232,8 +1176,8 @@ class GBDT:
             return "class_batch=off"
         if self.K <= 1 and mode != "on":
             # one model per iteration: nothing to batch (class_batch=on
-            # still exercises the K=1 batched path — the bench ablation
-            # and parity tests rely on that)
+            # still exercises the K=1 batched path — the parity tests
+            # rely on that)
             return "single model per iteration"
         if type(self) is not GBDT:
             return "boosting mode overrides the iteration loop"
@@ -1290,8 +1234,6 @@ class GBDT:
             kw["bundle_bins"] = self._bundle_bins
         if self.plan is None and self._gain_scale is not None:
             kw["gain_scale"] = self._gain_scale
-        if self.fused_split_ok:
-            kw["fused_split"] = True
         mono_method = (cfg.monotone_constraints_method
                        if self.mono_type_pf is not None else "basic")
         leaf_batch = cfg.leaf_batch

@@ -88,12 +88,12 @@ from .. import profiler
 from ..ops.histogram import (build_histograms, resolve_impl, HIST_CH,
                              merge_histograms, stream_chunk_rows,
                              stream_trips, _pvary)
-# referenced as a module attribute (PH.fused_build_best_splits) so tests
-# can monkeypatch interpret-mode wrappers in
+# referenced as a module attribute (PH.build_root_histograms_classes) so
+# tests can monkeypatch interpret-mode wrappers in
 from ..ops import pallas_histogram as PH
 from ..ops.predict import row_feature_gather
 from ..ops.split import (SplitParams, find_best_splits, leaf_gain,
-                         leaf_output, monotone_penalty_factor)
+                         leaf_output)
 
 __all__ = ["TreeArrays", "RoundLog", "build_tree", "max_rounds_for"]
 
@@ -282,7 +282,6 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                feature_sharded: bool = False,
                hist_merge: str = "allreduce",
                n_shards: int = 1,
-               fused_split: bool = False,
                root_hist: Optional[jax.Array] = None):
     """Grow one tree. Returns (TreeArrays, row_leaf, valid_row_leafs,
     RoundLog) — and the CEGB state as a fifth element when ``cegb`` is
@@ -605,26 +604,6 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         F_loc = loc_nbpf.shape[0]
     if feature_sharded and mode != "feature":
         raise ValueError("feature_sharded requires parallel_mode='feature'")
-
-    # Fused Pallas build+split (ISSUE 14): one VMEM-resident pass builds
-    # a leaf batch's histograms AND runs the split-find epilogue on the
-    # still-resident accumulator block, emitting only per-(leaf, chunk)
-    # candidate records to HBM — the [F, B, 3] histogram round-trip
-    # between the hist and split phases disappears. Gates (fall back to
-    # histogram kernel + find_best_splits) are the lattice features the
-    # epilogue can't express: sorted-subset categoricals, extra-trees
-    # random thresholds, gain scale/penalty (feature_contri, CEGB),
-    # advanced monotone bounds, forced-split gathers, every parallel /
-    # EFB / feature-sharded plan (they need the full histogram for the
-    # merge collective or subtraction).
-    use_smooth = split_params.path_smooth > 0.0
-    pen_on = use_mono and split_params.monotone_penalty > 0.0
-    use_fused = bool(
-        fused_split and hist_impl == "pallas" and axis_name is None
-        and not use_bundle and not use_rand and not use_cegb
-        and not use_forced and not use_mono_adv
-        and gain_scale is None and cat_sorted_mask is None
-        and not feature_sharded)
 
     # quantized training: histograms come back int32 (exact); descale to
     # (sum_g, sum_h, count) f32 once per build — the single-pass analog of
@@ -1142,99 +1121,6 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             bs = _sync_best(bs)
         return bs
 
-    if use_fused:
-        iw = jnp.arange(W, dtype=jnp.int32)
-
-        def fused_call(slots, fmask_s, depth_s, lo, hi, po, rl,
-                       row_gather=None, num_rows=None, emit_hist=False):
-            """One fused launch over a leaf-slot lattice. Mirrors the
-            metadata prep of best_for's serial arm; the kernel gates
-            smoothing/monotone internally on params, so unused operands
-            ride as zeros."""
-            pen = (monotone_penalty_factor(depth_s, sp.monotone_penalty)
-                   if pen_on else None)
-            return PH.fused_build_best_splits(
-                bins, gh, rl, slots,
-                num_bins=B, params=sp, num_bins_pf=num_bins_pf,
-                nan_bin_pf=nan_bin_pf, is_cat_pf=is_cat_pf,
-                feature_mask=fmask_s, mono_type=mono_type_pf,
-                leaf_lo=lo, leaf_hi=hi, parent_output=po, mono_pen=pen,
-                quant_scales=quant_scales, hist_dtype=hist_dtype,
-                num_rows=num_rows, row_gather=row_gather,
-                emit_hist=emit_hist)
-
-        def fused_children(stg, st, t, row_leaf, sel_s, right_slot, valid,
-                           slots2w, slots2w_c, depth2w, mid_state, keyr,
-                           leaf_lo, leaf_hi):
-            """Per-round children splits via the fused kernel. With the
-            subtraction cache on, only the SMALLER child is streamed
-            (fused, emitting its histogram for the cache); the sibling
-            is parent-minus-child from the cache and scanned directly —
-            the raw difference is already in split-finding space (f32
-            serial; exact int32 + in-scan rescale when quantized). The
-            per-slot masks are computed ONCE on the 2W lattice and
-            sliced, so bynode/interaction draws match the legacy path
-            bit-for-bit."""
-            nsh = {}
-            stg(PHS.FIND)
-            fmask2w, _ = slot_masks_and_bins(
-                mid_state.get("used_feat"), slots2w_c, keyr)
-            lo2w = jnp.take(leaf_lo, slots2w_c) if use_mono else None
-            hi2w = jnp.take(leaf_hi, slots2w_c) if use_mono else None
-            po2w = jnp.take(t.node_value, jnp.take(t.leaf2node, slots2w_c))
-            if not hist_sub:
-                bs, _ = fused_call(slots2w, fmask2w, depth2w, lo2w, hi2w,
-                                   po2w, row_leaf, emit_hist=False)
-                return bs, nsh, R_i32, R_i32
-            stg(PHS.COUNT)
-            small_is_left, _ = small_child(row_leaf, sel_s, right_slot)
-            small_slots = jnp.where(
-                valid, jnp.where(small_is_left, sel_s, right_slot), -2)
-            idx_small = jnp.where(small_is_left, iw, W + iw)
-            idx_big = jnp.where(small_is_left, W + iw, iw)
-
-            def _lane(a, idx):
-                return None if a is None else jnp.take(a, idx, axis=0)
-
-            # compacted small-child stream
-            stg(PHS.COMPACT)
-            c_idx, n_small = compact_small(row_leaf, small_slots)
-            stg(PHS.FIND)
-            bs_s, hsmall = fused_call(
-                small_slots, _lane(fmask2w, idx_small),
-                _lane(depth2w, idx_small), _lane(lo2w, idx_small),
-                _lane(hi2w, idx_small), _lane(po2w, idx_small),
-                row_leaf, row_gather=c_idx, num_rows=n_small,
-                emit_hist=True)
-            stg(PHS.SUBTRACT)
-            parent_raw = jnp.take(st["hist_cache"],
-                                  jnp.clip(sel_s, 0, L), axis=0)
-            hbig = parent_raw - hsmall
-            sil = small_is_left.reshape((W,) + (1,) * (hsmall.ndim - 1))
-            left_raw = jnp.where(sil, hsmall, hbig)
-            right_raw = jnp.where(sil, hbig, hsmall)
-            nsh["hist_cache"] = st["hist_cache"] \
-                .at[jnp.where(valid, sel_s, DUMMY_LEAF)].set(left_raw) \
-                .at[jnp.where(valid, right_slot, DUMMY_LEAF)] \
-                .set(right_raw)
-            stg(PHS.FIND)
-            bs_b = find_best_splits(
-                hbig, num_bins_pf, nan_bin_pf, is_cat_pf, sp,
-                feature_mask=_lane(fmask2w, idx_big),
-                mono_type=mono_type_pf,
-                leaf_lo=_lane(lo2w, idx_big),
-                leaf_hi=_lane(hi2w, idx_big),
-                parent_output=_lane(po2w, idx_big),
-                slot_depth=_lane(depth2w, idx_big),
-                quant_scales=quant_scales)
-
-            def _mix(ks, kb):
-                s_ = small_is_left.reshape((W,) + (1,) * (ks.ndim - 1))
-                return jnp.concatenate([jnp.where(s_, ks, kb),
-                                        jnp.where(s_, kb, ks)])
-            bs = {k: _mix(bs_s[k], bs_b[k]) for k in bs_b}
-            return bs, nsh, n_small, stream_rows_for("pallas", n_small)
-
     # ---------------- state ----------------
     tree = TreeArrays(
         split_feature=jnp.full((MAXN + 1,), -1, jnp.int32),
@@ -1318,25 +1204,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         root_slots = jnp.full((2 * W,), -2, jnp.int32).at[0].set(0)
         key0 = (jax.random.fold_in(rng_key, 0) if rng_key is not None
                 else None)
-        # path smoothing makes the root split depend on the root OUTPUT
-        # (parent_output), which the fused single launch cannot know yet —
-        # smooth roots keep the two-pass flow (the loop stays fused: there
-        # the parent output is already in the tree)
-        fused_root = use_fused and not use_smooth and root_hist is None
-        bs0 = None
-        if fused_root:
-            # one VMEM-resident pass: root histogram (emitted only when the
-            # subtraction cache needs seeding) AND its best split
-            fmask0, _ = slot_masks_and_bins(state.get("used_feat"),
-                                            root_slots.clip(0), key0)
-            lo0 = (jnp.take(state["leaf_lo"], root_slots.clip(0))
-                   if use_mono else None)
-            hi0 = (jnp.take(state["leaf_hi"], root_slots.clip(0))
-                   if use_mono else None)
-            bs0, hraw0 = fused_call(
-                root_slots, fmask0, jnp.zeros((2 * W,), jnp.int32), lo0, hi0,
-                None, row_leaf0, emit_hist=hist_sub)
-        elif root_hist is not None:
+        if root_hist is not None:
             # class-batched root dedupe (ISSUE 14 satellite): the K classes'
             # root histograms were built pre-vmap by ONE kernel streaming
             # the bins block once; non-root lattice slots are exact zeros in
@@ -1345,24 +1213,17 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                               root_hist.dtype).at[0].set(root_hist)
         else:
             hraw0 = hist_raw_for(root_slots, row_leaf0, part=part0)
-        if fused_root and not hist_sub:
-            # pure fused mode: the root histogram never exists — totals come
-            # from the kernel's per-slot totals record (sum-then-rescale; in
-            # float this can differ from the two-pass scale-then-sum in the
-            # last bits, documented in the fused kernel contract)
-            root_sums = bs0["slot_totals"][0]
-        else:
-            hist0 = hist_finish(hraw0)
-            if hist_sub:
-                # per-leaf RAW histogram cache (HistogramPool analog): slot i
-                # holds leaf i's histogram as of its creation; rows of a leaf
-                # only change when IT is split, so entries stay valid until
-                # popped, when the entry is the subtraction minuend
-                state["hist_cache"] = jnp.zeros(
-                    (L + 1,) + hraw0.shape[1:],
-                    hraw0.dtype).at[0].set(hraw0[0])
-            # all rows land in feature 0's bins
-            root_sums = hist0[0, 0, :, :].sum(axis=0)
+        hist0 = hist_finish(hraw0)
+        if hist_sub:
+            # per-leaf RAW histogram cache (HistogramPool analog): slot i
+            # holds leaf i's histogram as of its creation; rows of a leaf
+            # only change when IT is split, so entries stay valid until
+            # popped, when the entry is the subtraction minuend
+            state["hist_cache"] = jnp.zeros(
+                (L + 1,) + hraw0.shape[1:],
+                hraw0.dtype).at[0].set(hraw0[0])
+        # all rows land in feature 0's bins
+        root_sums = hist0[0, 0, :, :].sum(axis=0)
         if mode == "voting":
             # local hist -> global root sums (the Allreduce of root
             # (count, sum_g, sum_h), data_parallel_tree_learner.cpp:160-219)
@@ -1389,10 +1250,9 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             leaf_values=tree.leaf_values.at[0].set(root_val),
         )
         slot_valid0 = jnp.zeros((2 * W,), bool).at[0].set(True)
-        if bs0 is None:
-            bs0 = best_for(hist0, jnp.zeros((2 * W,), jnp.int32), slot_valid0,
-                           root_slots.clip(0), tree, state, key0,
-                           rl=row_leaf0)
+        bs0 = best_for(hist0, jnp.zeros((2 * W,), jnp.int32), slot_valid0,
+                       root_slots.clip(0), tree, state, key0,
+                       rl=row_leaf0)
         bs_gain = bs_gain.at[0].set(bs0["gain"][0])
         bs_feat = bs_feat.at[0].set(bs0["feature"][0])
         bs_thr = bs_thr.at[0].set(bs0["threshold"][0])
@@ -1871,17 +1731,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         # rows this round's histogram stream is bounded by, and the
         # stream positions it touches (RoundLog)
         rows_r = stream_r = R_i32
-        if use_fused:
-            bs, nsh, rows_r, stream_r = fused_children(
-                stg, st, t, row_leaf, sel_s, right_slot, valid, slots2w,
-                slots2w_c, depth2w, mid_state, keyr, leaf_lo, leaf_hi)
-            new_state_hist.update(nsh)
-            # same gain gating best_for applies after its lattice scan
-            g = bs["gain"]
-            if max_depth > 0:
-                g = jnp.where(depth2w < max_depth, g, NEG_INF)
-            bs["gain"] = jnp.where(valid2w, g, NEG_INF)
-        elif hist_sub:
+        if hist_sub:
             stg(PHS.COUNT)
             # the native partition maintains the counts
             small_is_left, small_loc = small_child(
@@ -1917,9 +1767,8 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         else:
             hist2w = hist_for(slots2w, row_leaf, part=part_n)
         stg(PHS.FIND)
-        if not use_fused:
-            bs = best_for(hist2w, depth2w, valid2w,
-                          slots2w_c, t, mid_state, keyr, rl=row_leaf)
+        bs = best_for(hist2w, depth2w, valid2w,
+                      slots2w_c, t, mid_state, keyr, rl=row_leaf)
 
         scatter_slots = slots2w_c
         bs_gain = st["bs_gain"].at[scatter_slots].set(bs["gain"]) \
@@ -1968,7 +1817,7 @@ _build_tree_jit = functools.partial(
                      "block_rows", "feature_fraction_bynode",
                      "parallel_mode", "top_k", "bundle_bins", "mono_method",
                      "forced", "hist_sub", "feature_sharded",
-                     "hist_merge", "n_shards", "fused_split"))(
+                     "hist_merge", "n_shards"))(
     _build_tree_impl)
 
 
@@ -2064,5 +1913,5 @@ _build_tree_cb_jit = functools.partial(
                      "block_rows", "feature_fraction_bynode",
                      "parallel_mode", "top_k", "bundle_bins", "mono_method",
                      "forced", "hist_sub", "feature_sharded",
-                     "hist_merge", "n_shards", "fused_split"))(
+                     "hist_merge", "n_shards"))(
     _build_tree_class_batched)

@@ -269,10 +269,8 @@ def main(argv: Optional[List[str]] = None) -> int:
               "       python -m lightgbm_tpu chaos [--fast] [--cell ...]\n"
               "       python -m lightgbm_tpu monitor <run_dir|events."
               "jsonl> [--check] [--perf]\n"
-              "       python -m lightgbm_tpu perf-gate [--update] "
-              "[--skip-timing]\n"
               "tasks: train | predict | refit | save_binary | serve | "
-              "ingest | trace-doctor | chaos | monitor | perf-gate")
+              "ingest | trace-doctor | chaos | monitor")
         return 0
     # `python -m lightgbm_tpu serve model=...` — subcommand spelling of
     # task=serve (the reference CLI is key=value only; serve is ours)
@@ -310,21 +308,18 @@ def main(argv: Optional[List[str]] = None) -> int:
     if argv[0] == "monitor":
         from .telemetry.monitor import monitor_main
         return monitor_main(argv[1:])
-    # `chaos` / `perf-gate` — repo-checkout harnesses under scripts/:
-    # chaos_train.py (fault injection + bit-identical recovery) and
-    # perf_gate.py (cost-model + timing vs PERF_BASELINE.json)
-    if argv[0] in ("chaos", "perf-gate", "perf_gate"):
+    # `chaos` — the repo-checkout harness scripts/chaos_train.py
+    # (fault injection + bit-identical recovery)
+    if argv[0] == "chaos":
         import importlib.util
-        fname = ("chaos_train.py" if argv[0] == "chaos"
-                 else "perf_gate.py")
         here = os.path.dirname(os.path.abspath(__file__))
-        path = os.path.join(os.path.dirname(here), "scripts", fname)
+        path = os.path.join(os.path.dirname(here), "scripts",
+                            "chaos_train.py")
         if not os.path.exists(path):
             raise SystemExit(
-                f"{argv[0]} harness not found (scripts/{fname} ships "
+                "chaos harness not found (scripts/chaos_train.py ships "
                 "with the repo checkout, not the installed package)")
-        spec = importlib.util.spec_from_file_location(
-            fname[:-3], path)
+        spec = importlib.util.spec_from_file_location("chaos_train", path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         return mod.main(argv[1:])

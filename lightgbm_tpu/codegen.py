@@ -509,8 +509,8 @@ class CompiledEnsemble:
         return cache_size(self._jit_leaves)
 
     def lower_serving(self, rows: int = 256):
-        """AOT-compile the serving walk at one shape (cost model /
-        trace doctor hook)."""
+        """AOT-compile the serving walk at one shape (the trace
+        doctor's hook)."""
         import jax
         tb = self.tables_for(None)
         X = self._as_f32_matrix(

@@ -197,23 +197,6 @@ PARAMS: Dict[str, ParamSpec] = {
                "CEGB, feature-parallel learners) fall back "
                "automatically; results are bit-identical either way. "
                "LIGHTGBM_TPU_CLASS_BATCH=0/1 pins from the env"),
-        _p("fused_split", "auto", str,
-           check=lambda v: v in ("auto", "on", "off"),
-           doc="fused histogram+split-find Pallas kernel: auto/on run "
-               "the per-(leaf, feature-chunk) gain epilogue inside the "
-               "histogram kernel's VMEM-resident accumulator and emit "
-               "only best-split candidate records, eliminating the "
-               "[F,B,3] HBM histogram round-trip between the hist and "
-               "split phases; off pins the two-pass histogram-only "
-               "kernel + find_best_splits scan. Configs the epilogue "
-               "cannot express fall back automatically (non-pallas "
-               "hist_impl, categorical sorted-subset, extra-trees "
-               "random thresholds, forced splits, CEGB, advanced "
-               "monotone, EFB bundles, feature/data-parallel plans, "
-               "chunked out-of-core, unaligned chunk plans); auto "
-               "additionally requires the fused probe to compile on "
-               "this backend. LIGHTGBM_TPU_FUSED_SPLIT=0/1 pins from "
-               "the env"),
         _p("dp_hist_merge", "auto", str,
            check=lambda v: v in ("auto", "allreduce", "reduce_scatter"),
            doc="histogram merge collective for tree_learner=data/voting "

@@ -17,8 +17,7 @@ for a staged chunk (``wait_s``) against the staging thread's total
 work time (``stage_s``); ``overlap_fraction = 1 - wait_s / stage_s``.
 1.0 means every read+copy hid completely behind compute; 0.0 means
 fully serialized (the first chunk of every sweep always serializes —
-there is nothing to hide it behind). The ``ingest_bench`` probe
-(bench.py) reports this number.
+there is nothing to hide it behind).
 """
 
 from __future__ import annotations

@@ -63,10 +63,7 @@ the (class-shared) bins once for all K classes.
 Compile verdicts on v5e (tests/test_mosaic_aot.py compiles every
 variant against a v5e topology without a chip): the histogram kernel
 (f32/bf16/int8, with and without ``num_rows``, under vmap and under
-shard_map with check_vma) and the class-root kernel compile;
-:func:`fused_build_best_splits` does NOT — its epilogue is
-``ops.split.eval_split_lattice``, whose ``cumsum`` has no Pallas TPU
-lowering (``FUSED_SPLIT_TPU_REASON``) — and runs in interpret mode only.
+shard_map with check_vma) and the class-root kernel compile.
 """
 
 from __future__ import annotations
@@ -82,19 +79,9 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import phases, profiler
 from .histogram import (HIST_CH, _gather_rows, pallas_shape_reason,
                         stream_trips)
-from . import split as _split
 
 __all__ = ["build_histograms_pallas", "build_histograms_pallas_lanes",
-           "stream_chunk", "fused_build_best_splits",
-           "fused_candidate_bytes", "build_root_histograms_classes",
-           "FUSED_SPLIT_TPU_REASON"]
-
-# Why gbdt's fused-split gate stays closed unless forced: the compiler's
-# own words (jax 0.9.0 Pallas TPU lowering; tests/test_mosaic_aot.py
-# asserts the message still holds, so a jax that learns cumsum trips it).
-FUSED_SPLIT_TPU_REASON = (
-    "fused split epilogue does not lower on TPU (Unimplemented primitive "
-    "in Pallas TPU lowering for KernelType.TC: cumsum)")
+           "stream_chunk", "build_root_histograms_classes"]
 
 # The histogram kernel's custom call is named after this in the compiled
 # module (``%pallas_hist_kernel.N``), whatever jit wraps it: a trace
@@ -479,294 +466,6 @@ def build_histograms_pallas_lanes(bins_t: jax.Array, gh_t: jax.Array,
         )(live, bins_t, gh_t, leaf_t, _slot_cols(leaf_ids, lanes))
         return _unpack_hist(out, F=F, B=B, L=L, fc=fc, n_fb=n_fb, Bp=Bp,
                             lanes=lanes)
-
-
-# ---------------------------------------------------------------------------
-# Fused histogram → split-find kernel (ISSUE 14). INTERPRET MODE ONLY:
-# see FUSED_SPLIT_TPU_REASON.
-#
-# Same accumulation step as the histogram kernel; on the LAST row step
-# of each feature chunk an epilogue runs ops/split.py's dense gain lattice
-# (`eval_split_lattice`) on the VMEM-resident accumulator and emits one
-# [l_rec, 128] candidate record block per chunk — gain, global feature,
-# bin, missing-direction, winner left/right (G, H, count), constrained
-# outputs, and the chunk's leaf totals. A tiny XLA argmax over chunks
-# (`fused_build_best_splits` postlude) then replaces the full-lattice
-# scan: the [L, F, B, 3] histogram never round-trips through HBM unless
-# the caller asks for it (`emit_hist=True`, which feeds the histogram
-# subtraction cache).
-#
-# Candidate record lanes (f32):
-#   0 gain   1 feature(global)  2 bin  3 dir(1=missing-left)
-#   4..6 left (G, H, count)     7..9 right (G, H, count)
-#   10 left_out  11 right_out   12..14 leaf totals (G, H, count)
-#
-# Quantized path: int8 gh → int32 accumulators, scanned EXACTLY in the
-# epilogue with the grid-value rescale applied at gain time
-# (`eval_split_lattice(quant_scales=...)`) — no dequantized histogram is
-# ever materialized.
-# ---------------------------------------------------------------------------
-
-_REC_LANES = 128
-
-
-def fused_candidate_bytes(F: int, B: int, L: int) -> int:
-    """HBM bytes of the fused kernel's candidate-record output stream.
-
-    This is the only lattice-sized traffic the fused build pass writes:
-    one [l_rec, _REC_LANES] f32 record block per feature chunk, in place
-    of the two-pass path's [F, B, L, 3] histogram write + re-read. Used
-    by the telemetry cost model's analytical byte counts."""
-    _, _, n_fb, _, _ = _plan(F, B, L * HIST_CH, 2)
-    return n_fb * _ceil_to(L, 8) * _REC_LANES * 4
-
-
-def _split_epilogue(acc, chunk_idx, fmeta, lmeta, fmask, *, params,
-                    fc: int, Bp: int, L: int, use_mono: bool,
-                    use_smooth: bool, pen_on: bool, quant: bool):
-    """Gain lattice + per-chunk argmax over the VMEM-resident accumulator.
-
-    acc:   [fc*Bp, lanes] (f32, or int32 quantized), channel-major lanes
-    fmeta: [8, fc] int32 — rows 0 num_bins_pf, 1 nan_bin, 2 is_cat,
-           3 mono_type (this chunk's feature slice)
-    lmeta: [8, l_rec] f32 — rows 0 parent_output, 1 leaf_lo, 2 leaf_hi,
-           3 mono_pen, 4 g_scale, 5 h_scale
-    fmask: [l_rec, fc] int32 candidate-feature mask
-    Returns the [l_rec, _REC_LANES] candidate record block.
-    """
-    l_rec = lmeta.shape[1]
-    hist = acc[:, :L * HIST_CH].reshape(fc, Bp, HIST_CH, L)
-    hist = jnp.pad(hist.transpose(3, 0, 1, 2),
-                   ((0, l_rec - L), (0, 0), (0, 0), (0, 0)))
-    lat = _split.eval_split_lattice(
-        hist, fmeta[0], fmeta[1], fmeta[2] != 0, params,
-        feature_mask=(fmask != 0),
-        mono_type=fmeta[3] if use_mono else None,
-        leaf_lo=lmeta[1] if use_mono else None,
-        leaf_hi=lmeta[2] if use_mono else None,
-        parent_output=lmeta[0] if use_smooth else None,
-        mono_pen=lmeta[3] if pen_on else None,
-        quant_scales=(jnp.stack([lmeta[4], lmeta[5]], axis=1)
-                      if quant else None))
-    N = fc * Bp * 2
-    flat = lat["net"].reshape(l_rec, N)
-    best = jnp.argmax(flat, axis=1)
-    # gather-free winner select: one-hot the argmax and reduce. where()
-    # keeps -inf/0 products out of the sum.
-    sel = (jax.lax.broadcasted_iota(jnp.int32, (l_rec, N), 1)
-           == best[:, None])
-
-    def pick1(a):
-        return jnp.sum(jnp.where(sel, a.reshape(l_rec, N), 0.0), axis=1)
-
-    def pick3(a):
-        return jnp.sum(jnp.where(sel[:, :, None], a.reshape(l_rec, N, 3),
-                                 0.0), axis=1)
-
-    gain = pick1(flat)
-    lsum = pick3(lat["left"])
-    rsum = pick3(lat["right"])
-    f_loc = (best // (Bp * 2)).astype(jnp.int32)
-    feat_g = chunk_idx * fc + f_loc
-    thr = ((best // 2) % Bp).astype(jnp.int32)
-    opt = (best % 2).astype(jnp.int32)
-    tot0 = lat["totals"][:, 0, :]          # any feature's totals = leaf's
-    rec = jnp.stack([
-        gain, feat_g.astype(jnp.float32), thr.astype(jnp.float32),
-        opt.astype(jnp.float32),
-        lsum[:, 0], lsum[:, 1], lsum[:, 2],
-        rsum[:, 0], rsum[:, 1], rsum[:, 2],
-        pick1(lat["out_l"]), pick1(lat["out_r"]),
-        tot0[:, 0], tot0[:, 1], tot0[:, 2],
-    ], axis=1)                              # [l_rec, 15]
-    return jnp.pad(rec, ((0, 0), (0, _REC_LANES - rec.shape[1])))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_bins", "params", "hist_dtype", "interpret",
-                     "emit_hist"))
-def fused_build_best_splits(bins: jax.Array, gh: jax.Array,
-                            row_leaf: jax.Array, leaf_ids: jax.Array, *,
-                            num_bins: int, params,
-                            num_bins_pf: jax.Array, nan_bin_pf: jax.Array,
-                            is_cat_pf: jax.Array,
-                            feature_mask: Optional[jax.Array] = None,
-                            mono_type: Optional[jax.Array] = None,
-                            leaf_lo: Optional[jax.Array] = None,
-                            leaf_hi: Optional[jax.Array] = None,
-                            parent_output: Optional[jax.Array] = None,
-                            mono_pen: Optional[jax.Array] = None,
-                            quant_scales: Optional[jax.Array] = None,
-                            hist_dtype: str = "bfloat16",
-                            interpret: bool = False,
-                            num_rows: Optional[jax.Array] = None,
-                            row_gather: Optional[jax.Array] = None,
-                            emit_hist: bool = False):
-    """One VMEM-resident pass: build histograms AND find best splits.
-
-    Runs in interpret mode only — on a TPU the pallas_call raises the
-    compiler's own message (``FUSED_SPLIT_TPU_REASON``).
-
-    Contract mirrors `build_histograms_pallas` for the row-stream
-    operands (``row_gather`` / ``num_rows`` included: one compacted
-    stream layout serves both) plus `ops.split.find_best_splits` for the metadata; returns
-    ``(best, hist)`` where ``best`` is the find_best_splits dict (gain,
-    feature, threshold, default_left, left_sum, right_sum, left_out,
-    right_out, is_cat_split, cat_bitset — plus "slot_totals" [L, 3], the
-    per-leaf (G, H, count) totals for root-sum bootstrapping) and
-    ``hist`` is the [L, F, B, 3] histogram when ``emit_hist=True``
-    (feeds the subtraction cache) or ``None`` (pure mode — the histogram
-    never touches HBM; only [n_chunks * l_rec, 128] candidate records do).
-
-    Winners are bit-equal to ``find_best_splits`` over the scatter-path
-    histogram: the epilogue runs the identical `eval_split_lattice` ops
-    on the identical accumulator block, per-chunk/within-chunk first-max
-    argmaxes compose to the same global first-max tie-break, and the
-    postlude's cross-chunk argmax runs over feature-contiguous chunks.
-
-    Gates the caller must respect (`find_best_splits` fallback):
-    sorted-subset categoricals, extra-trees random thresholds,
-    gain scale/penalty (feature_contri, CEGB), advanced monotone bounds.
-    """
-    F = bins.shape[1]
-    L = int(leaf_ids.shape[0])
-    B = int(num_bins)
-    _check_shape(B)
-    quant = gh.dtype == jnp.int8
-    if quant and quant_scales is None:
-        raise ValueError("int8 gh requires quant_scales")
-    cdt, acc_dt = _kernel_dtypes(gh.dtype, hist_dtype)
-    blk, fc, n_fb, Bp, lanes = _plan(F, B, L * HIST_CH,
-                                     jnp.dtype(cdt).itemsize)
-    fb = fc * Bp
-    f_pad = n_fb * fc
-    l_rec = _ceil_to(L, 8)
-    live, operands = _row_stream(
-        bins, gh, row_leaf, row_gather, num_rows, blk=blk, fc=fc, n_fb=n_fb,
-        acc_dt=acc_dt)
-    n_rb = operands[0].shape[-1] // blk
-
-    use_mono = mono_type is not None
-    use_smooth = params.path_smooth > 0.0
-    pen_on = use_mono and params.monotone_penalty > 0.0
-
-    def _frow(a, fill):
-        # pad features (F..f_pad) are trivial: one bin, masked out
-        return jnp.pad(a.astype(jnp.int32), (0, f_pad - F),
-                       constant_values=fill)
-
-    zi = jnp.zeros((f_pad,), jnp.int32)
-    fmeta = jnp.stack([
-        _frow(num_bins_pf, 1), _frow(nan_bin_pf, -1), _frow(is_cat_pf, 0),
-        _frow(mono_type, 0) if use_mono else zi,
-        zi, zi, zi, zi], axis=0)                          # [8, f_pad]
-    fmeta = fmeta.reshape(8, n_fb, fc).transpose(1, 0, 2)
-
-    zf = jnp.zeros((l_rec,), jnp.float32)
-
-    def _lrow(a, fill=0.0):
-        if a is None:
-            return zf
-        return jnp.pad(a.astype(jnp.float32), (0, l_rec - L),
-                       constant_values=fill)
-
-    if quant:
-        qsf = quant_scales.astype(jnp.float32)
-        srow_g = jnp.broadcast_to(qsf[0], (l_rec,))
-        srow_h = jnp.broadcast_to(qsf[1], (l_rec,))
-    else:
-        srow_g = srow_h = zf
-    lmeta = jnp.stack([
-        _lrow(parent_output), _lrow(leaf_lo), _lrow(leaf_hi),
-        _lrow(mono_pen, fill=1.0), srow_g, srow_h, zf, zf],
-        axis=0)                                           # [8, l_rec]
-
-    if feature_mask is None:
-        fm2 = jnp.ones((L, F), jnp.int32)
-    else:
-        fm2 = (feature_mask if feature_mask.ndim == 2
-               else jnp.broadcast_to(feature_mask[None, :], (L, F)))
-    fmask = jnp.pad(fm2.astype(jnp.int32), ((0, l_rec - L), (0, 0)),
-                    constant_values=1)
-    fmask = jnp.pad(fmask, ((0, 0), (0, f_pad - F)))
-    fmask = fmask.reshape(l_rec, n_fb, fc).transpose(1, 0, 2)
-
-    def kern(nr_ref, *refs):
-        """Accumulation step + last-row-step split epilogue. Output
-        refs: emit_hist → (hist_out, cand_out) with the histogram block
-        doubling as the accumulator; else (cand_out, acc_scratch) — the
-        histogram never leaves the chip."""
-        *stream, fmeta_ref, lmeta_ref, fmask_ref, out0, out1 = refs
-        acc_ref, cand_ref = (out0, out1) if emit_hist else (out1, out0)
-        _accumulate_step(nr_ref, *stream, acc_ref, Bp=Bp, cdt=cdt,
-                         acc_dt=acc_dt, blk=blk)
-        i = pl.program_id(0)
-        j = pl.program_id(1)
-
-        @pl.when(j == n_rb - 1)
-        def _():
-            cand_ref[:] = _split_epilogue(
-                acc_ref[:], i, fmeta_ref[:], lmeta_ref[:], fmask_ref[:],
-                params=params, fc=fc, Bp=Bp, L=L, use_mono=use_mono,
-                use_smooth=use_smooth, pen_on=pen_on, quant=quant)
-
-    cand_shape = jax.ShapeDtypeStruct((n_fb * l_rec, _REC_LANES),
-                                      jnp.float32)
-    hist_shape = jax.ShapeDtypeStruct((n_fb * fb, lanes), acc_dt)
-    hist_o = pl.BlockSpec((fb, lanes), lambda i, j, s: (i, 0))
-    cand_o = pl.BlockSpec((l_rec, _REC_LANES), lambda i, j, s: (i, 0))
-    outs = pl.pallas_call(
-        kern,
-        name="pallas_fused_split_kernel",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_fb, n_rb),
-            in_specs=_stream_specs(fc, blk, lanes) + [
-                pl.BlockSpec((None, 8, fc), lambda i, j, s: (i, 0, 0)),
-                pl.BlockSpec((8, l_rec), lambda i, j, s: (0, 0)),
-                pl.BlockSpec((None, l_rec, fc), lambda i, j, s: (i, 0, 0)),
-            ],
-            out_specs=(hist_o, cand_o) if emit_hist else cand_o,
-            scratch_shapes=(() if emit_hist
-                            else (pltpu.VMEM((fb, lanes), acc_dt),)),
-        ),
-        out_shape=(hist_shape, cand_shape) if emit_hist else cand_shape,
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-    )(live, *operands, _slot_cols(leaf_ids, lanes), fmeta, lmeta, fmask)
-
-    if emit_hist:
-        hist_raw, cand = outs
-        hist = _unpack_hist(hist_raw, F=F, B=B, L=L, fc=fc, n_fb=n_fb,
-                            Bp=Bp, lanes=lanes)
-    else:
-        hist, cand = None, outs
-
-    # ---- XLA postlude: tiny argmax over chunks replaces the full scan
-    cand = cand.reshape(n_fb, l_rec, _REC_LANES)[:, :L, :]
-    bc = jnp.argmax(cand[:, :, 0], axis=0)                # [L] first-max
-    rec = jnp.take_along_axis(cand, bc[None, :, None], axis=0)[0]
-    gain = rec[:, 0]
-    feat = rec[:, 1].astype(jnp.int32)
-    thr = rec[:, 2].astype(jnp.int32)
-    is_cat_split = jnp.take(is_cat_pf.astype(bool), feat)
-    member = ((jnp.arange(B, dtype=jnp.int32)[None, :] == thr[:, None])
-              & is_cat_split[:, None] & jnp.isfinite(gain)[:, None])
-    best = {
-        "gain": gain,
-        "feature": feat,
-        "threshold": thr,
-        "default_left": rec[:, 3] == 1.0,
-        "left_sum": rec[:, 4:7],
-        "right_sum": rec[:, 7:10],
-        "left_out": rec[:, 10],
-        "right_out": rec[:, 11],
-        "is_cat_split": is_cat_split,
-        "cat_bitset": _split.pack_member_bitset(member),
-        "slot_totals": rec[:, 12:15],
-    }
-    return best, hist
 
 
 # ---------------------------------------------------------------------------

@@ -121,8 +121,8 @@ def monotone_penalty_factor(depth, penalization):
 
 def pack_member_bitset(member: jax.Array) -> jax.Array:
     """Pack a [L, B] bin-membership mask into uint32 words (tree.h cat
-    bitset layout). Shared by `find_best_splits` and the fused-kernel
-    postlude in ops/pallas_histogram.py."""
+    bitset layout), as `find_best_splits` returns a categorical
+    winner's left-side bins."""
     L, B = member.shape
     BW = (B + 31) // 32
     pad = BW * 32 - B
@@ -149,22 +149,22 @@ def eval_split_lattice(hist: jax.Array, num_bins_per_feat: jax.Array,
                        adv_bounds: Optional[tuple] = None,
                        quant_scales: Optional[jax.Array] = None
                        ) -> Dict[str, jax.Array]:
-    """Dense gain-lattice evaluation shared by `find_best_splits` and the
-    fused Pallas epilogue (ops/pallas_histogram.py) — everything up to but
-    excluding the argmax, so a per-chunk kernel invocation can run the
-    same math on a VMEM-resident histogram block.
+    """Dense gain-lattice evaluation of `find_best_splits`, its one
+    caller — everything up to but excluding the argmax, so the same
+    math can run on any block of a histogram (a kernel that keeps the
+    block in VMEM would need a prefix sum Mosaic can lower: `cumsum`
+    has no Pallas TPU lowering).
 
     Same operands/semantics as `find_best_splits` except:
       mono_pen: optional [L] f32 — precomputed
         `monotone_penalty_factor(slot_depth, params.monotone_penalty)`
-        (the depth→penalty map is the caller's job here since the kernel
-        epilogue streams depths in as a metadata row).
+        (the depth→penalty map is the caller's job here).
       quant_scales: optional [2] or [L, 2] f32 — (g_scale, h_scale) for
         int8-quantized training. When given, `hist` holds raw int32
         accumulator sums; prefix scans run EXACTLY in integers and the
         cumulative sums are rescaled to f32 grid values only at gain
-        time (the ISSUE-14 exact-scan path; contrast the legacy two-pass
-        flow which dequantizes the full histogram first).
+        time. The builder does not pass it: it dequantizes the whole
+        histogram once a build (`tree_builder._dequant`; ROADMAP D9).
 
     Returns dict: net [L,F,B,2] (NEG_INF where invalid), left/right
     [L,F,B,2,3] (f32 grid values), out_l/out_r [L,F,B,2], pg [L,F],
@@ -221,8 +221,8 @@ def eval_split_lattice(hist: jax.Array, num_bins_per_feat: jax.Array,
     cat_ok = ((bins_iota[None, None, :] < nnb[:, :, None])
               & onehot_f[:, :, None])                          # [M, F, B]
     # option-0 selector built from an iota (not a literal [True, False]
-    # constant) so the Pallas kernel epilogue can trace this body —
-    # pallas_call rejects captured array constants
+    # constant): the body then captures no array constant, which a
+    # pallas_call tracing it would reject
     opt0 = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, 2), 3) == 0
     cat_valid = cat_ok[:, :, :, None] & opt0
 

@@ -13,9 +13,8 @@ tree_builder._sync_best).
 
 Used by ``scripts/audit_collectives.py`` (CI gate: the reduce-scatter
 program must emit no full-histogram all-reduce and move <= (1/n + eps) x
-the allreduce baseline's histogram bytes), by ``tests/test_comm_audit.py``
-(the fast in-suite form), and by ``bench.py``'s merge-mode ablation
-(``dp_comm_bytes_per_tree``).
+the allreduce baseline's histogram bytes) and by
+``tests/test_comm_audit.py`` (the fast in-suite form).
 """
 
 from __future__ import annotations

@@ -168,8 +168,8 @@ class ModelRegistry:
                 mv.compiled = CompiledEnsemble(booster,
                                                **session_kwargs)
             except (ValueError, TypeError) as e:
-                # named fallback, same discipline as fused_split=auto:
-                # the session path serves, /models says why
+                # named fallback: the session path serves, /models
+                # says why
                 mv.compiled_fallback = str(e)
         if mv.compiled is not None:
             if self.replicas > 0:

@@ -1,37 +1,30 @@
-"""Compiled-program cost model: what XLA actually built, priced.
+"""What a compiled program is made of, read from the program itself.
 
-The bench's roofline numbers (``hist_tflops``/``hist_hbm_gbps``) were
-hand-derived FLOP/byte formulas; this module prices the *compiled*
-programs instead, via ``Compiled.cost_analysis()`` /
-``memory_analysis()``, and cross-checks the analytical counts against
-XLA's. It covers the staged programs the trace doctor already builds —
-the fused boosting step, the data-parallel tree builder, the packed
-ensemble predict, the serving batcher rungs — and attributes a
-program's ops/result-bytes to the canonical phases of ``phases.py``
-through the ``op_name`` metadata (``jax.named_scope`` prefixes) that
-``analysis/hlo_walk.py`` parses.
+The instruction→stage map (`instruction_phase_map`): every instruction
+of a compiled module under the canonical stage of ``phases.py`` whose
+``jax.named_scope`` is deepest on its ``op_name`` metadata (parsed by
+``analysis/hlo_walk.py``). A device event carries only
+``{hlo_module, hlo_op}``, so this map is the road from an event to a
+source line; the trace parser (``xprof.py``) and the benchmark's stage
+readers attribute device time through it.
 
-Also owns the chip peak table (``TPU_PEAKS``, moved out of bench.py)
-so live runs — not just the bench — can state MFU / bandwidth
-utilization, and the instruction→phase map (`instruction_phase_map`)
-the trace parser (``xprof.py``) uses to attribute CPU executor events
-that carry only ``{hlo_module, hlo_op}``.
+Also the analytical FLOP/byte count of one histogram build
+(`analytical_hist_counts`) with XLA's own price of the same work to
+hold it to (`hist_xla_cost`, within 2x), and the chip peak table
+(``TPU_PEAKS``) a roofline share is stated against.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import re
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 from ..analysis.hlo_walk import parse_all_ops
-from .xprof import UNKNOWN, stage_of_path
+from .xprof import stage_of_path
 
-__all__ = ["TPU_PEAKS", "ChipPeaks", "HIST_CH", "CostReport", "cost_report",
+__all__ = ["TPU_PEAKS", "ChipPeaks", "HIST_CH",
            "instruction_phase_map", "StageMap", "module_name",
-           "fused_compiled", "booster_phase_maps",
-           "staged_cost_reports", "analytical_hist_counts",
-           "analytical_build_split_counts",
+           "fused_compiled", "booster_phase_maps", "analytical_hist_counts",
            "kernel_roofline_fields", "roofline_utilization",
            "hist_xla_cost", "chip_peaks"]
 
@@ -86,7 +79,7 @@ def chip_peaks() -> Optional[ChipPeaks]:
 
 
 # ----------------------------------------------------------------------
-# Analytical histogram-kernel counts (the formulas bench.py carried)
+# Analytical histogram-kernel counts
 
 def analytical_hist_counts(R: int, F: int, B: int,
                            L: int) -> Tuple[float, float]:
@@ -96,36 +89,6 @@ def analytical_hist_counts(R: int, F: int, B: int,
     uint8 + gh f32 in, hist f32 out)."""
     flops = 2.0 * R * (F * B) * (L * HIST_CH)
     bytes_ = R * F + R * HIST_CH * 4 + F * B * L * HIST_CH * 4
-    return flops, bytes_
-
-
-def analytical_build_split_counts(R: int, F: int, B: int, L: int, *,
-                                  fused: bool,
-                                  emit_hist: bool = False
-                                  ) -> Tuple[float, float]:
-    """(flops, bytes) of one full BUILD+SPLIT pass — histogram plus the
-    best-split gain scan, the quantity the fused kernel optimizes.
-
-    Two-pass: the [F, B, L, CH] f32 histogram goes to HBM once
-    (`analytical_hist_counts` already prices the write) and the split
-    scan reads it back — one extra lattice-sized stream. Fused: the
-    epilogue scans the VMEM-resident block, so the lattice never
-    round-trips; the only extra HBM traffic is the per-(feature-chunk,
-    leaf) candidate-record stream (`fused_candidate_bytes`), with the
-    lattice write retained only in `emit_hist` mode (subtraction-cache
-    feeding). The scan's flops (a few prefix-sum passes over the
-    lattice) are identical either way and negligible next to the
-    one-hot matmul; counted once as 8 ops/cell so the ratio stays a
-    pure bytes story."""
-    flops, hist_bytes = analytical_hist_counts(R, F, B, L)
-    lattice = F * B * L * HIST_CH * 4
-    flops += 8.0 * F * B * L * HIST_CH
-    if not fused:
-        return flops, hist_bytes + lattice
-    from ..ops.pallas_histogram import fused_candidate_bytes
-    bytes_ = (hist_bytes - lattice) + fused_candidate_bytes(F, B, L)
-    if emit_hist:
-        bytes_ += lattice
     return flops, bytes_
 
 
@@ -153,6 +116,16 @@ def kernel_roofline_fields(platform: str, t_hist_s: float,
     return out
 
 
+def _cost_dict(compiled) -> Dict[str, float]:
+    try:
+        ca = compiled.cost_analysis()
+    except Exception:  # noqa: BLE001 — backend may not implement it
+        return {}
+    if isinstance(ca, (list, tuple)):
+        ca = ca[0] if ca else {}
+    return dict(ca) if isinstance(ca, dict) else {}
+
+
 def hist_xla_cost(R: int, F: int, B: int, L: int, *,
                   impl: str = "matmul",
                   hist_dtype: str = "bfloat16") -> Dict[str, float]:
@@ -160,7 +133,7 @@ def hist_xla_cost(R: int, F: int, B: int, L: int, *,
     ``ops.histogram.build_histograms`` at the given lattice and read
     ``cost_analysis``. ``impl='matmul'`` is the formulation the
     analytical count models (one-hot MXU matmul), so these two must
-    agree within 2x — the perf gate asserts it.
+    agree within 2x (``tests/test_perf_observability.py`` asserts it).
 
     Compiled with ``block_rows=R`` (one block): ``cost_analysis``
     prices a while-loop body ONCE regardless of trip count, so the
@@ -185,87 +158,6 @@ def hist_xla_cost(R: int, F: int, B: int, L: int, *,
     ca = _cost_dict(compiled)
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
-
-
-# ----------------------------------------------------------------------
-# CostReport over one compiled program
-
-def _cost_dict(compiled) -> Dict[str, float]:
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:  # noqa: BLE001 — backend may not implement it
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if isinstance(ca, dict) else {}
-
-
-@dataclasses.dataclass
-class CostReport:
-    """One compiled program, priced: XLA flop/byte totals, the memory
-    footprint, and per-phase attribution from op_name metadata."""
-    label: str
-    flops: float
-    transcendentals: float
-    bytes_accessed: float
-    argument_bytes: int
-    output_bytes: int
-    temp_bytes: int
-    peak_bytes: int                 # argument + output + temp
-    generated_code_bytes: int
-    n_ops: int
-    phase_ops: Dict[str, int]       # phase → executable op count
-    phase_bytes: Dict[str, int]     # phase → result bytes of those ops
-
-    def as_dict(self) -> Dict[str, Any]:
-        d = dataclasses.asdict(self)
-        for k in ("flops", "transcendentals", "bytes_accessed"):
-            d[k] = round(float(d[k]), 1)
-        return d
-
-
-def cost_report(compiled, label: str = "program",
-                hlo_text: Optional[str] = None) -> CostReport:
-    """Price one ``Compiled`` (jax ``.lower(...).compile()`` result)."""
-    ca = _cost_dict(compiled)
-    mem = None
-    try:
-        mem = compiled.memory_analysis()
-    except Exception:  # noqa: BLE001
-        pass
-
-    def _m(attr: str) -> int:
-        return int(getattr(mem, attr, 0) or 0) if mem is not None else 0
-
-    if hlo_text is None:
-        try:
-            hlo_text = compiled.as_text()
-        except Exception:  # noqa: BLE001
-            hlo_text = ""
-    phase_ops: Dict[str, int] = {}
-    phase_bytes: Dict[str, int] = {}
-    n_ops = 0
-    for op, _comp, ph in _resolved_phases(hlo_text or "")[0]:
-        if op.opcode in _NOOP_OPCODES:
-            continue
-        n_ops += 1
-        ph = ph or UNKNOWN
-        phase_ops[ph] = phase_ops.get(ph, 0) + 1
-        phase_bytes[ph] = phase_bytes.get(ph, 0) + op.out_bytes
-    arg_b, out_b, tmp_b = (_m("argument_size_in_bytes"),
-                           _m("output_size_in_bytes"),
-                           _m("temp_size_in_bytes"))
-    return CostReport(
-        label=label,
-        flops=float(ca.get("flops", 0.0)),
-        transcendentals=float(ca.get("transcendentals", 0.0)),
-        bytes_accessed=float(ca.get("bytes accessed", 0.0)),
-        argument_bytes=arg_b, output_bytes=out_b, temp_bytes=tmp_b,
-        peak_bytes=arg_b + out_b + tmp_b,
-        generated_code_bytes=_m("generated_code_size_in_bytes"),
-        n_ops=n_ops,
-        phase_ops=dict(sorted(phase_ops.items())),
-        phase_bytes=dict(sorted(phase_bytes.items())))
 
 
 # ----------------------------------------------------------------------
@@ -411,7 +303,7 @@ def instruction_phase_map(hlo_text: str) -> StageMap:
 
 
 # ----------------------------------------------------------------------
-# The staged programs (same set the trace doctor lints)
+# The trainer's own compiled step, and its map
 
 def fused_compiled(bst, *, force: bool = True):
     """The trainer's own compiled fused step (donation flags and all),
@@ -447,63 +339,3 @@ def booster_phase_maps(bst, compiled=None, *,
         return {}
     sm = instruction_phase_map(compiled.as_text())
     return {sm.module: sm} if sm.stages else {}
-
-
-def staged_cost_reports(bst, *,
-                        batcher_rows: int = 16) -> Dict[str, CostReport]:
-    """CostReports over the staged programs of one trained booster:
-    the fused step (when the gate allows), the packed-ensemble predict,
-    one serving-batcher rung, and — on a multi-device host — the
-    data-parallel tree builder."""
-    import jax
-    import jax.numpy as jnp
-    reports: Dict[str, CostReport] = {}
-    compiled = fused_compiled(bst)
-    if compiled is not None:
-        reports["fused_step"] = cost_report(compiled, "fused_step")
-    from ..ops.predict_ensemble import _walk, pack_ensemble
-    ens = pack_ensemble(bst._trees)
-    F = bst.num_feature()
-    for label, rows in (("predict", 256), (f"batcher_b{batcher_rows}",
-                                           batcher_rows)):
-        X = jnp.zeros((rows, F), jnp.float32)
-        c = jax.jit(_walk).lower(ens, X).compile()
-        reports[label] = cost_report(c, label)
-    # the tensorized serving program (ISSUE 15): same 256-row shape as
-    # the packed walk above, so the two predict paths gate against the
-    # same lattice
-    try:
-        from ..codegen import CompiledEnsemble
-        ce = CompiledEnsemble(bst)
-        reports["compiled_predict"] = cost_report(
-            ce.lower_serving(rows=256), "compiled_predict")
-    except (ValueError, TypeError):   # non-tensorizable model: skip
-        pass
-    if len(jax.devices()) >= 2:
-        try:
-            reports["tree_builder"] = _tree_builder_report()
-        except Exception:  # noqa: BLE001 — mesh probe is best-effort
-            pass
-    return reports
-
-
-def _tree_builder_report(R: int = 256, F: int = 8,
-                         B: int = 16) -> CostReport:
-    import jax
-
-    from ..ops.split import SplitParams
-    from ..parallel.comms import _synthetic_inputs
-    from ..parallel.data_parallel import DataParallelPlan
-    plan = DataParallelPlan(hist_merge="reduce_scatter")
-    bins, gh, rl0, meta = _synthetic_inputs(R, F, B)
-    kw = dict(num_leaves=7, leaf_batch=4, max_depth=-1, num_bins=B,
-              hist_dtype="float32", block_rows=R // plan.num_shards,
-              split_params=SplitParams(min_data_in_leaf=2,
-                                       min_sum_hessian_in_leaf=1e-3))
-
-    def fn(b, g, rl):
-        return plan.build_tree(b, g, rl, *meta, **kw)[0]
-    sharded = (plan.shard_bins(bins), plan.shard_rows(gh),
-               plan.shard_rows(rl0))
-    c = jax.jit(fn).lower(*sharded).compile()
-    return cost_report(c, "tree_builder")

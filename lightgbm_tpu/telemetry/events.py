@@ -58,7 +58,6 @@ EVENT_TYPES: Dict[str, tuple] = {
     "serving": ("action", "model"),
     "train_end": ("iter", "trees", "wall_s"),
     "cost_model": ("label", "flops", "bytes_accessed"),
-    "perf_gate": ("status", "checked", "failed"),
     # out-of-core ingest (data/ingest.py): one record per completed
     # pass; shard writes are individually atomic so the log is
     # observability, not recovery state

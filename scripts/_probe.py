@@ -2,8 +2,7 @@
 
 Every tool that measures or audits jax programs out-of-process —
 ``scripts/lint_traces.py``, ``scripts/audit_collectives.py``,
-``bench.py``'s dp-comm and compile-cache probes — needs the same three
-things, previously reimplemented in each:
+``scripts/chaos_train.py`` — needs the same three things:
 
 1. **env pinning**: the virtual-device count must be in ``XLA_FLAGS``
    and ``JAX_PLATFORMS=cpu`` set BEFORE jax initializes, so mesh-shaped

@@ -1,10 +1,8 @@
 #!/usr/bin/env bash
-# Static-analysis gate: source lint + trace lint + perf gate.
+# Static-analysis gate: source lint + trace lint + chaos harnesses.
 #
 #   scripts/lint_static.sh          # full: ruff + trace-doctor battery
-#                                   # + perf gate (incl. self-test)
 #   scripts/lint_static.sh --fast   # pre-push smoke: ruff + one cell
-#                                   # + static-only perf gate
 #
 # Source lint runs ruff when available (version pinned via the [lint]
 # extra: pip install -e '.[lint]'; rules scoped in [tool.ruff.lint] to
@@ -43,23 +41,6 @@ python scripts/chaos_train.py --elastic $fast || rc=1
 
 echo "== chaos ingest (out-of-core crash safety) =="
 python scripts/chaos_train.py --ingest $fast || rc=1
-
-# Perf gate: static cost-model metrics vs PERF_BASELINE.json (timing
-# compares only when the host is quiet — the gate decides via loadavg),
-# then the self-test: a seeded 2x regression MUST trip the gate.
-echo "== perf gate =="
-if [ -n "$fast" ]; then
-    python scripts/perf_gate.py --skip-timing || rc=1
-else
-    python scripts/perf_gate.py || rc=1
-    if python scripts/perf_gate.py --seed-regression --skip-timing \
-            >/dev/null 2>&1; then
-        echo "perf gate self-test FAILED: seeded regression passed" >&2
-        rc=1
-    else
-        echo "perf gate self-test OK (seeded regression trips)"
-    fi
-fi
 
 if [ "$rc" -ne 0 ]; then
     echo "LINT FAILED" >&2

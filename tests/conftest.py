@@ -56,6 +56,19 @@ def rng():
     return np.random.RandomState(42)
 
 
+@pytest.fixture
+def interp(monkeypatch):
+    """Route every Pallas entry point through the interpreter, so that
+    ``hist_impl=pallas`` trains on the CPU with the chip's kernels."""
+    import functools
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    for name in ("build_histograms_pallas", "build_histograms_pallas_lanes",
+                 "build_root_histograms_classes"):
+        monkeypatch.setattr(
+            PH, name, functools.partial(getattr(PH, name), interpret=True))
+    return PH
+
+
 def _map_count() -> int:
     try:
         with open("/proc/self/maps", "rb") as f:

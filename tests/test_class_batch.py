@@ -252,9 +252,9 @@ def test_one_build_loop_and_k_independent_trace():
     step stages exactly ONE build-phase grow loop, and its equation
     count does not scale with num_class (the unrolled shape is both
     K loops and ~K x the equations). Trace sizes being within a few
-    percent across K is the compile-time bound in static form — the
-    wall-clock ratio itself is asserted in the bench, not a unit test
-    on a shared host."""
+    percent across K is the compile-time bound in static form — a
+    wall-clock ratio is a chip measurement, not a unit test on a
+    shared host."""
     import jax
     from lightgbm_tpu.analysis.doctor import _fused_trace_args
     from lightgbm_tpu.analysis.jaxpr_lint import (count_build_loops,

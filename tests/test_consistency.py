@@ -173,8 +173,7 @@ def test_leaf_batch_auc_delta_bounded():
     """VERDICT r4 #6: leaf_batch>1 changes split ORDER (the one
     TPU-first liberty without a measured bound); quantify it. At a
     Higgs-like shape the valid-AUC spread across leaf_batch in
-    {1, 4, 16} must stay within noise (<0.003 at this scale; bench.py
-    records the 1M-row spread every run)."""
+    {1, 4, 16} must stay within noise (<0.003 at this scale)."""
     rng = np.random.RandomState(11)
     n, f = 200_000, 20
     X = rng.normal(size=(n, f)).astype(np.float32)

@@ -203,6 +203,28 @@ def test_chunked_quantized_bagging_parity(rng):
     np.testing.assert_array_equal(p_res, p_chk)
 
 
+@pytest.mark.parametrize("quant", [False, True])
+def test_chunked_subtraction_cache_parity(rng, quant):
+    """Chunked out-of-core rounds with the parent-minus-child
+    subtraction cache == full per-child rebuilds: exact in int32
+    quantized mode and for the f32 serial accumulator."""
+    X = rng.normal(size=(900, 6)).astype(np.float32)
+    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
+    p = dict(objective="binary", num_leaves=15, min_data_in_leaf=5,
+             verbosity=-1, hist_impl="scatter", deterministic=True,
+             tree_learner="serial",  # chunked driver needs a host plan
+             out_of_core="on", chunk_budget_mb=0.05)
+    if quant:
+        p["use_quantized_grad"] = True
+    preds = {}
+    for sub in (True, False):
+        ds = lgb.Dataset(X, label=y, params=dict(p))
+        bst = lgb.train(dict(p, hist_subtraction=sub), ds,
+                        num_boost_round=3)
+        preds[sub] = bst.predict(X)
+    np.testing.assert_array_equal(preds[True], preds[False])
+
+
 def test_chunked_gate_raises_reasoned(rng):
     X, y = _parity_data(rng, R=400)
     bad = dict(_PARITY, out_of_core="on", linear_tree=True)

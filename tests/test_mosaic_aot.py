@@ -123,30 +123,6 @@ def test_class_root_kernel_compiles(v5e, quant):
         sds((K, R, 3), I8 if quant else F32), sds((R,), I32))
 
 
-def test_fused_split_kernel_is_gated_with_the_compilers_reason(v5e):
-    """fused_build_best_splits cannot lower (its epilogue is
-    eval_split_lattice); gbdt's gate quotes the compiler. If this stops
-    raising, the gate constant is stale: re-evaluate the kernel."""
-    from lightgbm_tpu.ops import pallas_histogram as PH
-    from lightgbm_tpu.ops.split import SplitParams
-    sds = _sds(v5e[0])
-    R, F, B, L = 1 << 16, 28, 63, 21
-
-    def fn(b, g, r, l, nb, nan, cat):
-        return PH.fused_build_best_splits(
-            b, g, r, l, num_bins=B, params=SplitParams(),
-            num_bins_pf=nb, nan_bin_pf=nan, is_cat_pf=cat)[0]["gain"]
-
-    with pytest.raises(NotImplementedError) as ei:
-        _compile(fn, *_hist_args(sds, R, F, L, False), sds((F,), I32),
-                 sds((F,), I32), sds((F,), jnp.bool_))
-    # "Unimplemented primitive in Pallas TPU lowering for
-    # KernelType.TC: cumsum. Please file an issue ..."
-    msg = str(ei.value)
-    assert msg.startswith("Unimplemented primitive in Pallas TPU lowering")
-    assert ": cumsum." in msg and "cumsum" in PH.FUSED_SPLIT_TPU_REASON
-
-
 def _tree_args(make, R, F):
     return [make((R, F), U8, 2), make((R, 3), F32, 2), make((R,), I32, 1),
             make((F,), I32, 0), make((F,), I32, 0),

@@ -1,6 +1,6 @@
 """Performance-observability subsystem: xprof trace parsing against the
-golden fixture, phase-totals thread safety, capture retention, the
-cost-model cross-check, and the perf-gate tolerance semantics."""
+golden fixture, phase-totals thread safety, capture retention and the
+cost-model cross-check."""
 
 import json
 import os
@@ -12,7 +12,7 @@ import urllib.request
 import pytest
 
 from lightgbm_tpu import profiler
-from lightgbm_tpu.telemetry import costmodel, perf, xprof
+from lightgbm_tpu.telemetry import costmodel, xprof
 from lightgbm_tpu.telemetry.core import MetricsRegistry
 from lightgbm_tpu.telemetry.exporter import (CaptureError,
                                              IntrospectionServer)
@@ -436,68 +436,6 @@ def test_trace_endpoint_500_on_capture_error(monkeypatch, tmp_path):
 
 
 # ----------------------------------------------------------------------
-# perf gate: tolerance semantics + baseline round trip
-
-
-def test_tolerance_kinds():
-    t = perf.Tolerance("time", 1.5)
-    assert t.check(1.4, 1.0)[0] and not t.check(1.6, 1.0)[0]
-    assert t.check(0.1, 1.0)[0]  # faster never regresses
-    t = perf.Tolerance("throughput", 1.5)
-    assert t.check(0.7, 1.0)[0] and not t.check(0.6, 1.0)[0]
-    assert t.check(99.0, 1.0)[0]
-    t = perf.Tolerance("static", 2.0)
-    assert t.check(1.9, 1.0)[0] and t.check(0.51, 1.0)[0]
-    assert not t.check(2.1, 1.0)[0] and not t.check(0.4, 1.0)[0]
-    with pytest.raises(ValueError):
-        perf.Tolerance("speed", 1.5)
-    with pytest.raises(ValueError):
-        perf.Tolerance("time", 0.5)
-
-
-def test_compare_pass_fail_missing_new_skip():
-    base = {"ms_per_tree": 10.0, "cost_fused_step_flops": 1000.0,
-            "gone": 5.0, "timing_skipped": 3.0}
-    cur = {"ms_per_tree": 11.0, "cost_fused_step_flops": 2000.0,
-           "fresh": 1.0}
-    res = perf.compare(cur, base, skipped=["timing_skipped"])
-    by = {c.metric: c for c in res.checks}
-    assert by["ms_per_tree"].status == "pass"          # within 1.6x
-    assert by["cost_fused_step_flops"].status == "fail"  # 2x static
-    assert by["gone"].status == "missing"
-    assert by["timing_skipped"].status == "skip"
-    assert by["fresh"].status == "new"
-    assert not res.ok
-    assert set(res.failed) == {"cost_fused_step_flops", "gone"}
-    assert "FAIL" in res.render()
-
-
-def test_compare_all_green():
-    base = {"a": 1.0, "b": 2.0}
-    res = perf.compare({"a": 1.0, "b": 2.0}, base)
-    assert res.ok and res.failed == []
-    assert "PASS" in res.render()
-
-
-def test_baseline_round_trip(tmp_path):
-    path = str(tmp_path / "PERF_BASELINE.json")
-    metrics = {"ms_per_tree": 12.5, "cost_fused_step_flops": 7e7}
-    perf.save_baseline(path, metrics, meta={"note": "test"})
-    obj = perf.load_baseline(path)
-    assert obj["metrics"] == metrics
-    assert obj["meta"]["note"] == "test"
-    assert obj["host"]["cpu_count"] == os.cpu_count()
-    assert perf.compare(metrics, obj["metrics"]).ok
-
-
-def test_load_baseline_rejects_garbage(tmp_path):
-    path = tmp_path / "bad.json"
-    path.write_text(json.dumps({"not_metrics": 1}))
-    with pytest.raises(ValueError):
-        perf.load_baseline(str(path))
-
-
-# ----------------------------------------------------------------------
 # cost model: the XLA-vs-analytical histogram cross-check
 
 
@@ -598,37 +536,3 @@ def test_monitor_perf_cli(tmp_path, capsys):
     bare = tmp_path / "empty"
     bare.mkdir()
     assert monitor_main(["--perf", str(bare)]) == 1
-
-
-# ----------------------------------------------------------------------
-# perf-gate end to end (trains the canonical booster: slow lane)
-
-
-def _gate_main():
-    import importlib.util
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "scripts", "perf_gate.py")
-    spec = importlib.util.spec_from_file_location("perf_gate", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.main
-
-
-@pytest.mark.slow
-def test_perf_gate_update_then_green_then_seeded(tmp_path, capsys):
-    main = _gate_main()
-    baseline = str(tmp_path / "PERF_BASELINE.json")
-    events = str(tmp_path / "gate.events.jsonl")
-    assert main(["--update", "--baseline", baseline,
-                 "--skip-timing"]) == 0
-    assert main(["--baseline", baseline, "--skip-timing",
-                 "--event-log", events]) == 0
-    assert main(["--baseline", baseline, "--skip-timing",
-                 "--seed-regression"]) == 1
-    recs = [json.loads(ln) for ln in open(events)]
-    assert recs[-1]["event"] == "perf_gate"
-    assert recs[-1]["status"] == "pass"
-    # a missing baseline is its own exit code (2): "create one", not
-    # "regression"
-    assert main(["--baseline", str(tmp_path / "nope.json"),
-                 "--skip-timing"]) == 2

@@ -93,18 +93,40 @@ def test_quantized_int32_histogram_exactness(rng):
 
 
 def test_quantized_matches_on_data_parallel_mesh(rng):
-    """Quantized training under tree_learner=data must equal the serial
-    result bit-for-bit: int32 psum of integer histograms is exact."""
+    """Quantized training under tree_learner=data equals the serial
+    result bit for bit: every real row draws its stochastic rounding as
+    the serial run does (the draw is shaped by the logical rows, not by
+    a layout's padding), and the int32 psum of integer histograms is
+    exact. Held where every chip searches the whole merged histogram
+    (``dp_hist_merge=allreduce``). Under ``reduce_scatter`` each chip
+    searches its own feature block in a program of another shape, whose
+    gains differ from the serial program's in the last place: with four
+    quantization bins two features that cut the same rows tie exactly,
+    and the tie may fall the other way (at 255 feature bins, tree 2 of
+    this data: feature 0 against feature 4 at gain 1.7977309; the test
+    failed on it from the seed on). The sums are exact there too:
+    tests/test_reduce_scatter.py::test_rs_quantized_renew holds the
+    scattered merge to this one bit for bit."""
     X, y = _data(rng, n=1024)
     base = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
             "use_quantized_grad": True, "num_grad_quant_bins": 4,
-            "min_data_in_leaf": 5, "deterministic": True}
+            "min_data_in_leaf": 5, "deterministic": True,
+            # few bins, so a bin's integer sums reach the hundreds: a
+            # merge in bfloat16 (exact to 256) fails this test
+            "max_bin": 15}
     serial = lgb.train(dict(base, tree_learner="serial"),
                        lgb.Dataset(X, label=y, free_raw_data=False), 5)
-    dist = lgb.train(dict(base, tree_learner="data"),
+    dist = lgb.train(dict(base, tree_learner="data",
+                          dp_hist_merge="allreduce"),
                      lgb.Dataset(X, label=y, free_raw_data=False), 5)
-    np.testing.assert_allclose(serial.predict(X), dist.predict(X),
-                               rtol=1e-6, atol=1e-7)
+    # the layouts pad differently, or the draws' shape is not under test
+    assert serial._gbdt.train_dd.r_pad != dist._gbdt.train_dd.r_pad
+    for ts, td in zip(serial._all_trees(), dist._all_trees()):
+        for field in ("split_feature", "threshold_bin", "leaf_count",
+                      "leaf_value", "split_gain"):
+            np.testing.assert_array_equal(getattr(ts, field),
+                                          getattr(td, field), err_msg=field)
+    np.testing.assert_array_equal(serial.predict(X), dist.predict(X))
 
 
 @pytest.mark.slow
