@@ -57,12 +57,13 @@ hand:
   What a row needs of its leaf is W records, selected by W compares
   (``tree_builder.select_by_slot``), and a count of the rows in S
   slots is one compare-and-sum (``slot_counts``). Gathers by the
-  compacted stream's index read R-sized operands and pass, as does the
-  scatter that writes that index (an R-sized result). The histogram
-  itself (``hist_kernel`` scope) is the one sum over rows a tree needs
-  and is exempt: on the chip it is a Pallas kernel with a roofline
-  metric of its own; the XLA scatter formulation that CPU tests use
-  adds rows into ``S*F*B`` bins by design.
+  compacted stream's index read R-sized operands and pass; the index
+  itself is a sort of the row numbers
+  (``tree_builder.stream_index``), neither a gather nor a scatter.
+  The histogram itself (``hist_kernel`` scope) is the one sum over rows
+  a tree needs and is exempt: on the chip it is a Pallas kernel with a
+  roofline metric of its own; the XLA scatter formulation that CPU
+  tests use adds rows into ``S*F*B`` bins by design.
 """
 
 from __future__ import annotations

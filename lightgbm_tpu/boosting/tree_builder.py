@@ -29,9 +29,9 @@ Design (TPU-first; not a translation):
        R rows (~13x/tree at 255 leaves, ~254x in leaf_batch=1 modes).
        With it, each round streams only the smaller children's rows.
        The builder makes the stream's INDEX and nothing else
-       (``compact_small``: membership, cumsum, ``n_small``, the
-       scatter that writes ``c_idx``); ``bins``, ``gh`` and
-       ``row_leaf`` go to
+       (``compact_small``: membership, ``n_small``, and ``c_idx`` as
+       one sort of the row numbers, ``stream_index``);
+       ``bins``, ``gh`` and ``row_leaf`` go to
        ops/histogram.py uncompacted with ``row_gather=c_idx,
        num_rows=n_small``, and the wrapper gathers, casts and lays them
        out inside one loop whose trip count is ``ceil(n_small /
@@ -174,6 +174,32 @@ def select_by_slot(row_leaf, slots, lane_ok, records=()):
         hit = hit | h
         outs = [jnp.where(h, r[w], o) for r, o in zip(records, outs)]
     return hit, outs
+
+
+def stream_index(m):
+    """The compacted stream's index of a row mask: ``(c_idx [R] int32,
+    n [] int32)`` with the ``n = m.sum()`` rows of ``m`` first, in row
+    order, then the dead rows in no promised order.
+
+    One ``lax.sort`` of one ``s32[R]``: a live row's key is its own
+    number, a dead row's its number plus R, so the sorted keys ARE the
+    index (keys are distinct, so an unstable sort keeps the live rows
+    in row order) and nothing rides along; the dead tail gives its R
+    back so that every entry names a row. The positions are also a
+    cumsum, and ``zeros(R).at[where(m, pos, R)].set(arange(R))`` says
+    so; but XLA:TPU expands that scatter into a sort of (position, row
+    number) pairs and then a scatter that copies the sorted payload's
+    prefix onto itself at 4.9 ns a row: 0.97 + 0.41 s of a 3.36 s Higgs
+    tree, with another 0.05 s for the cumsum that fed them, where this
+    sort is 0.16 s (PERF.md section 6, PR 33). Rows last, so under
+    ``vmap`` (``class_batch``) the sort is batched over the class axis;
+    under a row mesh each shard sorts its own rows and no collective
+    enters."""
+    R = m.shape[-1]
+    assert R < 2 ** 30, R     # R + row fits int32
+    iota = jnp.arange(R, dtype=jnp.int32)
+    key = jax.lax.sort(jnp.where(m, iota, iota + R), is_stable=False)
+    return jnp.where(key < R, key, key - R), m.astype(jnp.int32).sum()
 
 
 def slot_counts(row_leaf, slots):
@@ -332,7 +358,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     # the small child's row fraction (the dense_bin.hpp:105
     # data_indices saving). Only the native C kernel skips compaction: its
     # partition op already maintains exact per-leaf row lists, so a
-    # cumsum + gather pass over R would cost more than it saves.
+    # sort + gather pass over R would cost more than it saves.
     hist_compact = hist_sub and hist_impl != "native"
     # native CPU backend: maintain the DataPartition analog — `perm`
     # holds row indices grouped by leaf (leaf_begin/leaf_cnt segments,
@@ -680,21 +706,17 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     def compact_small(row_leaf, small_slots):
         """The compacted stream of the small children's rows:
         ``(c_idx [R] int32, n_small)`` with stream position ``p <
-        n_small`` reading row ``c_idx[p]`` (row order kept; zeros past
-        the live prefix). Membership is W compares a row, fused into
-        the cumsum's input (``select_by_slot``), not a gather from a
-        ``[L+2]`` lut: on the chip that gather was 1.56 s of a Higgs
-        tree, over half of this stage (PERF.md section 6, PR 29). The
-        rows themselves are gathered where the stream is consumed
+        n_small`` reading row ``c_idx[p]`` (row order kept; dead rows,
+        in no promised order, past the live prefix). Membership is W
+        compares a row, fused into the sort's key (``select_by_slot``),
+        not a gather from a ``[L+2]`` lut: on the chip that gather was
+        1.56 s of a Higgs tree (PERF.md section 6, PR 29). The index is
+        one sort of the row numbers (``stream_index``). The rows
+        themselves are gathered where the stream is consumed
         (``build_histograms(row_gather=, num_rows=)``), chunk by chunk
         and only as far as ``n_small``."""
         m, _ = select_by_slot(row_leaf, small_slots, small_slots >= 0)
-        pos = jnp.cumsum(m.astype(jnp.int32)) - 1
-        n_small = m.astype(jnp.int32).sum()
-        c_idx = jnp.zeros((R,), jnp.int32).at[
-            jnp.where(m, pos, R)].set(
-            jnp.arange(R, dtype=jnp.int32), mode="drop")
-        return c_idx, n_small
+        return stream_index(m)
 
     def small_child(row_leaf, sel_s, right_slot, leaf_cnt=None):
         """Which child of each lane has fewer rows, ``(small_is_left

@@ -65,8 +65,9 @@ POP = "pop"                      # top-k over cached gains + their takes
 APPLY = "apply"                  # tree scatter, bounds, row_leaf relabel
 COUNT = "count"                  # rows in the round's 2W children: one
 #                                  compare-and-sum over R (slot_counts)
-COMPACT = "compact"              # membership, cumsum, n_small, c_idx:
-#                                  the making of the index, no row moves
+COMPACT = "compact"              # membership, n_small, and c_idx as one
+#                                  sort of the row numbers: the making
+#                                  of the index, no row moves
 HIST_GATHER = "hist_gather"      # bins, gh and row_leaf by row_gather: all
 #                                  three, a chunk (pallas) or a block a trip
 HIST_RELAYOUT = "hist_relayout"  # cast, pad, transpose for the kernel; of

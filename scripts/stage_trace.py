@@ -18,7 +18,9 @@ span ring's record of the same span, the round log's live-row share and
 the share of the touched stream positions that were live, and the
 SHA-256 of the model text (tree 0 and the traced trees; two commits that
 grow the same trees print the same one). The capture (xplane and
-``phase_map.json``) and ``model.txt`` stay under ``--out``.
+``phase_map.json``), ``model.txt`` and the compiled step's text
+(``step.hlo.txt.gz``: which instruction a device op of the capture is,
+its operands and their memory-space marks) stay under ``--out``.
 """
 
 import argparse
@@ -125,6 +127,8 @@ def main(argv=None) -> int:
         "stream_row_share_pct": 100.0 * live / touched if touched else None,
         "model_sha256": hashlib.sha256(model_text.encode()).hexdigest(),
         "host_sync_count": gb.host_sync_count}), flush=True)
+    with gzip.open(os.path.join(args.out, "step.hlo.txt.gz"), "wt") as g:
+        g.write(text)
     with open(plane, "rb") as f, gzip.open(plane + ".gz", "wb") as g:
         shutil.copyfileobj(f, g)
     for p in glob.glob(os.path.join(os.path.dirname(plane), "*")):

@@ -230,7 +230,9 @@ def test_stage_map_names_the_grow_loop_at_benchmark_shapes(v5e, config,
                 assert (sm.stages[r.op.name] == phases.HIST_RELAYOUT
                         or r.op.opcode.startswith("copy")), (
                     r.op.name, r.op.opcode, sm.stages[r.op.name])
-    assert seen[body] > 15 and seen[chunk_body] >= 3
+    # (the walk found the round: 12 row-sized instructions at Higgs since
+    # PR 33 made the stream's index one sort, 18 before)
+    assert seen[body] > 10 and seen[chunk_body] >= 3
     staged = {sm.stages.get(r.op.name) for r in rows if r.comp == chunk_body}
     assert {phases.HIST_GATHER, phases.HIST_RELAYOUT} <= staged
     kernels = [r for r in rows if r.op.name.startswith(HIST_KERNEL_NAME)]

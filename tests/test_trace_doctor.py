@@ -149,18 +149,17 @@ def _table_round(rl, sel, ok, feat):
 
 def _select_round(rl, sel, ok, feat):
     from lightgbm_tpu.boosting.tree_builder import (select_by_slot,
-                                                    slot_counts)
+                                                    slot_counts,
+                                                    stream_index)
     with jax.named_scope("build"):
         with jax.named_scope("apply"):
             active, (f_r,) = select_by_slot(rl, sel, ok, [feat])
         with jax.named_scope("count"):
             cnt = slot_counts(rl, sel)
         with jax.named_scope("compact"):
-            # the stream's index: an R-sized result, R-sized operands
-            pos = jnp.cumsum(active.astype(jnp.int32)) - 1
-            c_idx = jnp.zeros((_R,), jnp.int32).at[
-                jnp.where(active, pos, _R)].set(
-                jnp.arange(_R, dtype=jnp.int32), mode="drop")
+            # the stream's index: one sort over R, no gather and no
+            # scatter at all
+            c_idx, _ = stream_index(active)
             with jax.named_scope("hist_gather"):
                 rl_c = jnp.take(rl, c_idx)
             with jax.named_scope("hist_kernel"):
