@@ -23,7 +23,8 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["HloOp", "DTYPE_BYTES", "COLLECTIVE_KINDS", "parse_ops",
+__all__ = ["HloOp", "DTYPE_BYTES", "COLLECTIVE_KINDS", "base_opcode",
+           "parse_ops",
            "parse_all_ops", "parse_collective_ops",
            "input_output_aliases", "lower_hlo"]
 
@@ -59,11 +60,9 @@ class HloOp:
     name: str = ""                  # LHS instruction name (%name = ...)
 
 
-def _op_re(opcodes: Sequence[str]) -> re.Pattern:
-    return re.compile(
-        r"=\s*(?P<out>\([^)]*\)|[\w\[\],{}]+?)\s+"
-        r"(?P<op>" + "|".join(re.escape(o) for o in opcodes)
-        + r")(?:-start)?\(")
+def base_opcode(opcode: str) -> str:
+    """An async ``-start`` form's opcode without the suffix."""
+    return opcode[:-6] if opcode.endswith("-start") else opcode
 
 
 def shape_bytes(text: str):
@@ -84,18 +83,26 @@ def shape_bytes(text: str):
 def parse_ops(hlo_text: str, opcodes: Sequence[str],
               skip_done: bool = True) -> List[HloOp]:
     """Extract every op whose opcode is in ``opcodes`` from compiled-HLO
-    text (async ``-start`` forms included, ``-done`` halves skipped)."""
-    rx = _op_re(opcodes)
+    text (async ``-start`` forms included, ``-done`` halves skipped).
+    Lines are split by :func:`_split_instruction`, so the tiled layouts
+    of a TPU's text (``{1,0:T(8,128)S(1)}``) are read as well as a
+    CPU's."""
+    want = set(opcodes)
     ops = []
     for line in hlo_text.splitlines():
-        m = rx.search(line)
-        if m is None or (skip_done and "-done(" in line):
+        parts = _split_instruction(line)
+        if parts is None:
             continue
-        shapes, nbytes = shape_bytes(m.group("out"))
+        _name, out, opcode, _rest = parts
+        if opcode.endswith("-done") and skip_done:
+            continue
+        kind = base_opcode(opcode)
+        if kind not in want:
+            continue
+        shapes, nbytes = shape_bytes(out)
         nm = _NAME_RE.search(line)
         cct = _CCT_RE.search(line)
-        ops.append(HloOp(opcode=m.group("op"), shapes=shapes,
-                         out_bytes=nbytes,
+        ops.append(HloOp(opcode=kind, shapes=shapes, out_bytes=nbytes,
                          op_name=nm.group(1) if nm else "",
                          custom_call_target=cct.group(1) if cct else ""))
     return ops

@@ -1569,7 +1569,9 @@ class GBDT:
                 qg, qh, q_gs, q_hs = self._quantize_impl(
                     g, h, jax.random.fold_in(self._quant_key, it))
                 count_i8 = count_mask.astype(jnp.int8)
-        finite = jnp.all(jnp.isfinite(g)) & jnp.all(jnp.isfinite(h))
+            # the NaN guard's reductions are collectives under a
+            # row-sharded plan: staged, so no collective is nameless
+            finite = jnp.all(jnp.isfinite(g)) & jnp.all(jnp.isfinite(h))
         new_scores = scores
         new_valid = list(valid_scores)
         if self.class_batch_ok:
@@ -1605,7 +1607,7 @@ class GBDT:
                         new_valid[vi], trees_k.leaf_values, vrl_k, lr)
                     new_valid[vi] = jnp.where(grew_k[:, None], vupd,
                                               new_valid[vi])
-            finite = finite & jnp.all(jnp.isfinite(new_scores))
+                finite = finite & jnp.all(jnp.isfinite(new_scores))
             return (new_scores, tuple(new_valid), trees_k,
                     jnp.any(grew_k), finite, rounds_k)
         trees = []
@@ -1643,7 +1645,8 @@ class GBDT:
             grews.append(grew)
             rounds.append(rounds_1)
         cont = jnp.any(jnp.stack(grews))
-        finite = finite & jnp.all(jnp.isfinite(new_scores))
+        with profiler.stage("update"):
+            finite = finite & jnp.all(jnp.isfinite(new_scores))
         return new_scores, tuple(new_valid), trees, cont, finite, rounds
 
     def _fused_data_args(self):
@@ -1785,6 +1788,17 @@ class GBDT:
             mon.register_event_duration_secs_listener(on_duration)
             mon.register_event_listener(on_event)
             try:
+                if self.plan is not None:
+                    # the plan's counters, from the text of the very
+                    # executable the call below runs: lowering is
+                    # cached on the arguments' types, so this is the
+                    # step's one compile and the call finds it made
+                    from ..parallel.comms import plan_counters
+                    n = self.plan.num_shards
+                    fields.update(plan_counters(
+                        self._fused_jit.lower(*args).compile(), n,
+                        self.train_dd.r_pad // n
+                        if self.plan.rows_sharded else self.train_dd.r_pad))
                 return self._fused_jit(*args)
             finally:
                 mon.unregister_event_duration_listener(on_duration)

@@ -24,12 +24,14 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..analysis.hlo_walk import (COLLECTIVE_KINDS,
+from ..analysis.hlo_walk import (COLLECTIVE_KINDS, base_opcode,
                                  lower_hlo as _walk_lower_hlo,
                                  parse_collective_ops)
+from .. import phases
 from ..phases import HIST_MERGE, WINNER_SYNC
 
 __all__ = ["CollectiveOp", "CommReport", "parse_collectives",
+           "plan_counters",
            "lower_hlo", "audit_fn", "audit_tree_program", "audit_plans",
            "hist_bytes_per_tree", "render_table", "COLLECTIVE_KINDS"]
 
@@ -75,6 +77,52 @@ def parse_collectives(hlo_text: str) -> List[CollectiveOp]:
     return [CollectiveOp(kind=o.opcode, shapes=o.shapes,
                          out_bytes=o.out_bytes, op_name=o.op_name)
             for o in parse_collective_ops(hlo_text)]
+
+
+# The TPU compiler turns a small reduce-scatter into a fusion named
+# ``all-reduce-scatter`` that holds an all-reduce of the whole buffer and
+# the slice of this chip's part: on the wire it is a reduce-scatter
+_FUSED_SCATTER = "all-reduce-scatter"
+
+
+def plan_counters(compiled, num_shards: int,
+                  rows_per_shard: int) -> Dict[str, object]:
+    """The counters of a parallel plan (``phases.PLAN_COUNTERS``) from
+    its compiled fused step, or the step's HLO text: every collective
+    instruction of the module (the ops :func:`parse_collectives` finds),
+    told apart by whether it runs inside a ``while`` (the grow loop is
+    the one loop of the step that holds collectives: once a round) or
+    outside any (once a tree), and by its stage as the stage map
+    resolves it (``costmodel.staged_ops``: the deepest canonical name on
+    its op_name path, or for an instruction the compiler left without
+    one, its neighbours'; a device event is attributed the same way);
+    '' where neither gives one."""
+    from ..telemetry.costmodel import staged_ops
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    kinds: Dict[str, int] = {}
+    per_round: Dict[str, int] = {}
+    per_tree: Dict[str, int] = {}
+    for op, comp, stage, in_round in staged_ops(text):
+        kind = base_opcode(op.opcode)
+        if kind not in COLLECTIVE_KINDS:
+            continue
+        out_bytes = op.out_bytes
+        if kind == "all-reduce" and comp.startswith(_FUSED_SCATTER):
+            kind, out_bytes = "reduce-scatter", out_bytes // num_shards
+        wire = CollectiveOp(kind, op.shapes, out_bytes,
+                            op.op_name).wire_bytes(num_shards)
+        by_stage = per_tree
+        if in_round:
+            by_stage = per_round
+            kinds[kind] = kinds.get(kind, 0) + 1
+        by_stage[stage or ""] = by_stage.get(stage or "", 0) + wire
+    return {
+        phases.PLAN_SHARDS: int(num_shards),
+        phases.PLAN_ROWS_PER_SHARD: int(rows_per_shard),
+        phases.PLAN_COLLECTIVES_PER_ROUND: dict(sorted(kinds.items())),
+        phases.PLAN_ROUND_BYTES_BY_STAGE: dict(sorted(per_round.items())),
+        phases.PLAN_TREE_BYTES_BY_STAGE: dict(sorted(per_tree.items())),
+    }
 
 
 @dataclasses.dataclass

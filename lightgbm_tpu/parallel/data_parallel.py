@@ -189,12 +189,15 @@ class DataParallelPlan:
         return self.shard_rows(arr)
 
     def shard_scores(self, local_kr):
-        """[K, local_rows] host block -> [K, r_pad] global, row axis 1."""
+        """[K, local_rows] host block -> [K, r_pad] global, row axis 1.
+        Placed as the fused step hands the scores back (rows over the
+        mesh, committed), so the step that tree 0 compiles is the step
+        every later tree runs."""
+        sh = NamedSharding(self.mesh, P(None, self.axis_name))
         if not self.multi_process:
-            return jnp.asarray(local_kr)
-        spec = P(None, self.axis_name)
+            return jax.device_put(local_kr, sh)
         return jax.make_array_from_process_local_data(
-            NamedSharding(self.mesh, spec), np.asarray(local_kr))
+            sh, np.asarray(local_kr))
 
     def host_local_cols(self, arr, num_valid: int):
         """[K, r_pad] global -> this process's [K, num_valid] host block
@@ -321,11 +324,10 @@ class FeatureParallelPlan:
             arr, NamedSharding(self.mesh, P(None, self.axis_name)))
 
     def shard_scores(self, local_kr):
-        # every worker holds the full score block; multi-controller runs
-        # need it assembled into a GLOBAL replicated array
-        if self.multi_process:
-            return replicate(self.mesh, np.asarray(local_kr))
-        return jnp.asarray(local_kr)
+        # every worker holds the full score block, placed as the fused
+        # step hands it back (replicated, committed: one compile);
+        # multi-controller runs assemble it into a GLOBAL array
+        return replicate(self.mesh, np.asarray(local_kr))
 
     def host_local_cols(self, arr, num_valid: int):
         return np.asarray(arr)[:, :num_valid]

@@ -31,7 +31,10 @@ __all__ = ["GRADS", "SAMPLING", "BUILD", "UPDATE", "EVAL",
            "HIST_KERNEL", "SUBTRACT", "FIND", "RANK_GATHER", "RANK_SORT",
            "RANK_PAIRS", "RANK_SCATTER", "TRAIN_PHASES",
            "INGEST_PHASES", "COLLECTIVE_PHASES", "BUILD_STAGES",
-           "GRADS_STAGES", "KNOWN_PHASES", "HOST_SPANS"]
+           "GRADS_STAGES", "KNOWN_PHASES", "HOST_SPANS",
+           "PLAN_SHARDS", "PLAN_ROWS_PER_SHARD",
+           "PLAN_COLLECTIVES_PER_ROUND", "PLAN_ROUND_BYTES_BY_STAGE",
+           "PLAN_TREE_BYTES_BY_STAGE", "PLAN_COUNTERS"]
 
 # training phases (both drivers, boosting/gbdt.py + engine.train's eval)
 GRADS = "grads"
@@ -97,11 +100,29 @@ HOST_SPANS = frozenset({
     #                        tables; its counters ride on it as fields
     "gbdt.to_device",      # H2D of bins, row_leaf0, labels, weights and
     #                        a ranking objective's lattices
-    "gbdt.step_ready",     # first call of the fused step: trace..compile
+    "gbdt.step_ready",     # first call of the fused step: trace..compile;
+    #                        under a parallel plan its counters
+    #                        (PLAN_COUNTERS) ride on it as fields
     "gbdt.dispatch",       # each fused dispatch
     "gbdt.sync.wait",      # the device_get of the pending ring
     "gbdt.sync.trees",     # host Trees from the fetched ring
     "engine.eval", "engine.checkpoint"})
+
+# counters of a parallel plan's compiled fused step
+# (parallel/comms.plan_counters), read once from the step's own text and
+# kept as fields of the ``gbdt.step_ready`` span. Bytes are what one chip
+# puts on the wire under ring algorithms (CollectiveOp.wire_bytes); a
+# "round" is the body of the grow loop, a "tree" what runs once outside it
+# (the root pass, the step's guards). The merge's and the winner sync's
+# bytes a round are the entries HIST_MERGE and WINNER_SYNC of the round's.
+PLAN_SHARDS = "plan_shards"
+PLAN_ROWS_PER_SHARD = "plan_rows_per_shard"
+PLAN_COLLECTIVES_PER_ROUND = "plan_collectives_per_round"    # {kind: n}
+PLAN_ROUND_BYTES_BY_STAGE = "plan_round_bytes_by_stage"      # {stage: B}
+PLAN_TREE_BYTES_BY_STAGE = "plan_tree_bytes_by_stage"        # {stage: B}
+PLAN_COUNTERS = (PLAN_SHARDS, PLAN_ROWS_PER_SHARD,
+                 PLAN_COLLECTIVES_PER_ROUND, PLAN_ROUND_BYTES_BY_STAGE,
+                 PLAN_TREE_BYTES_BY_STAGE)
 
 TRAIN_PHASES = frozenset({GRADS, SAMPLING, BUILD, UPDATE, EVAL})
 INGEST_PHASES = frozenset({INGEST_SKETCH, INGEST_WRITE, PREFETCH})

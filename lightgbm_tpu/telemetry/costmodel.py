@@ -206,7 +206,7 @@ def _instructions(hlo_text: str) -> List[_Instr]:
     return rows
 
 
-def _resolved_phases(hlo_text: str):
+def _resolved_phases(hlo_text: str, rows: Optional[List[_Instr]] = None):
     """[(HloOp, computation, stage-or-None)] and the number of mixed
     fusions. An instruction's stage is the deepest canonical name on its
     own ``op_name`` path. A fusion is judged by what it fuses: where its
@@ -218,7 +218,8 @@ def _resolved_phases(hlo_text: str):
     through tuples and bitcasts), else of
     the instruction that runs its computation (the ``while``, the
     fusion): so a body op is never worse off than its loop."""
-    rows = _instructions(hlo_text)
+    if rows is None:
+        rows = _instructions(hlo_text)
     own: Dict[str, Optional[str]] = {}
     votes: Dict[str, Dict[str, int]] = {}
     for r in rows:
@@ -280,6 +281,41 @@ def _resolved_phases(hlo_text: str):
                 caller_stage[r.callee] = own[r.op.name]
                 changed = True
     return [(r.op, r.comp, own[r.op.name]) for r in rows], mixed
+
+
+class StagedOp(NamedTuple):
+    """One instruction of a compiled module as :func:`staged_ops` reads it."""
+    op: Any                   # hlo_walk.HloOp
+    computation: str
+    stage: Optional[str]      # canonical stage, resolved as the stage map's
+    in_loop: bool             # its computation runs inside a ``while``
+
+
+def staged_ops(hlo_text: str) -> List[StagedOp]:
+    """Every instruction of the module with its computation, its stage (as
+    :func:`instruction_phase_map` resolves it) and whether it runs inside
+    a ``while`` (directly, or in a computation a loop body calls): one
+    parse of the text, for readers that count instructions by stage and
+    by how often they run (``parallel/comms.plan_counters``)."""
+    rows = _instructions(hlo_text)
+    loops = {r.callee for r in rows if r.op.opcode == "while"}
+    called_from: Dict[str, str] = {}
+    for r in rows:
+        if r.callee is not None:
+            called_from.setdefault(r.callee, r.comp)
+
+    def in_loop(comp):
+        seen = set()
+        while comp is not None and comp not in seen:
+            if comp in loops:
+                return True
+            seen.add(comp)
+            comp = called_from.get(comp)
+        return False
+
+    inside = {c: in_loop(c) for c in {r.comp for r in rows}}
+    return [StagedOp(op, comp, stage, inside[comp])
+            for op, comp, stage in _resolved_phases(hlo_text, rows)[0]]
 
 
 def instruction_phase_map(hlo_text: str) -> StageMap:
