@@ -164,8 +164,9 @@ def _pvary(x, axis_name):
     return jax.lax.pcast(x, axis_name, to="varying")
 
 
-# The Pallas kernel expands bin ids on the MXU in bf16, which is exact
-# up to 256 (ops/pallas_histogram.py module docstring).
+# The Pallas kernel's plan pads a feature's one-hot rows to a power of
+# two up to 256, the range of the uint8 bin ids the trainer stores
+# (ops/pallas_histogram.py ``_plan`` and module docstring).
 PALLAS_MAX_BINS = 256
 
 
@@ -174,8 +175,9 @@ def pallas_shape_reason(num_bins: int) -> str:
     ('' = it can). The rule ``resolve_impl`` applies under ``auto`` and
     the message an explicit ``hist_impl=pallas`` raises with."""
     if num_bins > PALLAS_MAX_BINS:
-        return (f"num_bins={num_bins} > {PALLAS_MAX_BINS} (bin ids are "
-                "expanded in bf16, exact only up to 256)")
+        return (f"num_bins={num_bins} > {PALLAS_MAX_BINS} (the kernel's "
+                "plan holds uint8 bin ids: at most 256 one-hot rows a "
+                "feature)")
     return ""
 
 

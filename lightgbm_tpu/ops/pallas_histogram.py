@@ -10,8 +10,9 @@ row-block) grid step, multiplies on the MXU, and accumulates into a
 VMEM-resident output block — the one-hot never touches HBM.
 
 Layout (what real Mosaic on a v5e accepts — every op in the histogram
-kernel body is a 2-D elementwise op, a 2-D broadcast, an iota or a
-matmul; no reshape, gather, concatenate or narrow-dtype arithmetic):
+kernel body is a 2-D elementwise op, a 2-D broadcast, an iota, one
+sublane-aligned join or the one matmul; no reshape, gather or
+narrow-dtype arithmetic):
 
 - **rows ride the lane axis.** Operands arrive transposed — bins
   ``[n_chunks, fc, R]``, gh ``[3, R]``, leaf ``[1, R]`` — so every HBM
@@ -20,12 +21,17 @@ matmul; no reshape, gather, concatenate or narrow-dtype arithmetic):
   second-to-last the array's full extent. Any feature-chunk width is a
   legal block; F is padded up to ``n_chunks * fc`` with zero columns
   whose histogram rows are sliced away.
-- **one-hot by a 0/1 expansion matmul.** ``expand[fc*Bp, fc] @
-  bins[fc, blk]`` copies each feature row to its ``Bp`` one-hot rows on
-  the MXU (exact: bin ids <= 255 are exact in bf16, one nonzero term per
-  output); comparing with the row's own bin id gives ``onehot[fc*Bp,
-  blk]`` with no 3-D broadcast + reshape. This is why the kernel takes
-  ``num_bins <= 256`` only (``ops.histogram.pallas_shape_reason``).
+- **one-hot on the VPU.** Each of the chunk's ``fc`` feature rows
+  ``bins[f:f+1, blk]`` is broadcast along sublanes and compared in int32
+  with the bin index of its ``Bp`` one-hot rows; the ``fc`` pieces of
+  ``[Bp, blk]`` are joined at sublane offsets ``f * Bp`` (``Bp`` is a
+  multiple of 8) into ``onehot[fc*Bp, blk]`` in 32 bits and narrowed to
+  the matmul dtype once — no 3-D broadcast + reshape, and no MXU pass:
+  copying bin ids to their one-hot rows with a 0/1 matmul costs as much
+  as the histogram's own (a contraction of ``fc`` pads to the array's
+  128), so the MXU does the accumulation alone. ``_plan`` sizes ``Bp``
+  up to 256, the range of the uint8 bin ids, which is why the kernel
+  takes ``num_bins <= 256`` only (``ops.histogram.pallas_shape_reason``).
 - **addends channel-major.** Output lane ``c = channel * L + slot``;
   ``ghl[c, r] = (leaf[r] == slot_of[c]) ? gh[channel_of[c], r] : 0`` is
   two selects over a sublane-broadcast row and a lane-broadcast column,
@@ -102,12 +108,12 @@ def _plan(F: int, B: int, n_cols: int, cdt_bytes: int):
     """(row_block, feature_chunk, n_chunks, padded_bins, lanes).
 
     ``Bp`` is the power of two >= max(B, 8) (bins >= B never match), so
-    a one-hot row's feature and bin are a shift and a mask of its index
-    and ``fc * Bp`` is a sublane multiple. Features split into the
-    fewest balanced chunks with ``fc * Bp <= _FB_CAP``. The row block
-    is sized so the step's 32-bit intermediates (expanded bins, compare
-    mask, selected addends — Mosaic need not materialize them all, the
-    estimate assumes it does), the narrow matmul operands, the
+    a feature's one-hot rows start at a sublane multiple ``f * Bp`` and
+    ``fc * Bp`` is one. Features split into the fewest balanced chunks
+    with ``fc * Bp <= _FB_CAP``. The row block is sized so the step's
+    32-bit intermediates (the one-hot before it is narrowed, its compare
+    mask, the selected addends — Mosaic need not materialize them all,
+    the estimate assumes it does), the narrow matmul operands, the
     double-buffered inputs and the resident accumulator fit
     ``_VMEM_BUDGET``."""
     Bp = max(8, 1 << (max(B, 2) - 1).bit_length())
@@ -124,19 +130,19 @@ def _plan(F: int, B: int, n_cols: int, cdt_bytes: int):
 
 def _onehot_t(bins_ref, *, Bp: int, cdt):
     """[fc*Bp, blk] one-hot of a [fc, blk] int32 bin block (row
-    ``f*Bp + b`` is 1 where ``bins[f, r] == b``)."""
-    fc, _ = bins_ref.shape
-    fb = fc * Bp
-    shift = Bp.bit_length() - 1
-    row = jax.lax.broadcasted_iota(jnp.int32, (fb, fc), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (fb, fc), 1)
-    expand = ((row >> shift) == col).astype(jnp.bfloat16)
-    bins_x = jnp.dot(
-        expand, bins_ref[:].astype(jnp.float32).astype(jnp.bfloat16),
-        preferred_element_type=jnp.float32)               # [fb, blk]
-    bin_of_row = (jax.lax.broadcasted_iota(jnp.int32, (fb, 1), 0)
-                  & (Bp - 1)).astype(jnp.float32)
-    return jnp.where(bins_x == bin_of_row, 1, 0).astype(cdt)
+    ``f*Bp + b`` is 1 where ``bins[f, r] == b``), made on the VPU: each
+    feature row, broadcast along sublanes, is compared in int32 with the
+    bin index of its ``Bp`` one-hot rows, the ``fc`` pieces are joined
+    at sublane offsets ``f*Bp`` in 32 bits and the whole is narrowed to
+    the matmul dtype once (a piece narrowed before the join makes Mosaic
+    repack every register)."""
+    fc, blk = bins_ref.shape
+    wide = jnp.int32 if cdt == jnp.int8 else jnp.float32
+    bin_of_row = jax.lax.broadcasted_iota(jnp.int32, (Bp, blk), 0)
+    one, zero = jnp.ones((), wide), jnp.zeros((), wide)
+    pieces = [jnp.where(bins_ref[f:f + 1, :] == bin_of_row, one, zero)
+              for f in range(fc)]
+    return jnp.concatenate(pieces, axis=0).astype(cdt)
 
 
 def _accumulate(out_ref, onehot, addends, *, cdt, acc_dt):

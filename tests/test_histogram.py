@@ -125,28 +125,45 @@ def test_pallas_interpret_matches_oracle(rng):
     np.testing.assert_allclose(pls, xla, rtol=1e-5, atol=1e-5)
 
 
+class _Ref:
+    """An array behind the slice of a kernel ref's surface the
+    histogram kernel's helpers use."""
+
+    def __init__(self, a):
+        self.a = a
+        self.shape = a.shape
+
+    def __getitem__(self, idx):
+        return self.a[idx]
+
+
+def _prims(jp):
+    """(primitive name, equation) of a jaxpr and every jaxpr under it
+    (a ``pallas_call``'s body, a ``pl.when``'s branches)."""
+    for e in jp.eqns:
+        yield e.primitive.name, e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _prims(sub)
+
+
 def test_pallas_kernel_body_uses_only_mosaic_safe_ops():
     """What real Mosaic on a v5e rejected in this kernel, in order of
     discovery: a lax.gather from a mixed newaxis+slice index, a 2-D ->
-    3-D reshape of the addends, int8 vector multiplies, and cumsum. The
-    body is now 2-D selects/compares/iotas/casts and two matmuls; this
-    keeps it so on every CPU run (tests/test_mosaic_aot.py, slow, runs
-    the real compiler)."""
-    import functools
-
+    3-D reshape of the addends, int8 vector multiplies, cumsum, and
+    (PR 36) a stride-0 sublane load of a bin row ("the last dim size is
+    not 128 in original base memref"). The body is now 2-D
+    selects/compares/iotas/casts, ONE matmul, and one join: the one-hot's
+    ``fc`` pieces of ``[Bp, blk]`` are concatenated along sublanes at
+    offsets ``f * Bp`` in 32 bits, which Mosaic takes (``Bp`` is a
+    multiple of 8, the 32-bit sublane tile; the same pieces narrowed
+    first compile too but every register is repacked). This keeps it so
+    on every CPU run (tests/test_mosaic_aot.py, slow, runs the real
+    compiler)."""
     from lightgbm_tpu.ops import pallas_histogram as PH
 
     F, B, L = 28, 255, 21
     blk, fc, n_fb, Bp, lanes = PH._plan(F, B, 3 * L, 1)
     assert fc < F, "exercise a chunked plan"
-
-    class _Ref:
-        def __init__(self, a):
-            self.a = a
-            self.shape = a.shape
-
-        def __getitem__(self, idx):
-            return self.a[idx]
 
     def body(bins, gh, leaf, cols):
         onehot = PH._onehot_t(_Ref(bins), Bp=Bp, cdt=jnp.int8)
@@ -157,21 +174,114 @@ def test_pallas_kernel_body_uses_only_mosaic_safe_ops():
         jnp.zeros((fc, blk), jnp.int32), jnp.zeros((3, blk), jnp.int32),
         jnp.zeros((1, blk), jnp.int32), jnp.zeros((lanes, 2), jnp.int32))
 
-    def prims(jp):
-        for e in jp.eqns:
-            yield e.primitive.name, [v.aval for v in e.invars]
-            for sub in jax.core.jaxprs_in_params(e.params):
-                yield from prims(sub)
-
-    seen = list(prims(jaxpr.jaxpr))
+    seen = list(_prims(jaxpr.jaxpr))
     names = {n for n, _ in seen}
-    banned = {"gather", "reshape", "cumsum", "concatenate", "scatter",
-              "scatter-add", "dynamic_slice"}
+    banned = {"gather", "reshape", "cumsum", "scatter", "scatter-add",
+              "dynamic_slice", "dot_general"}
     assert not names & banned, sorted(names & banned)
-    for n, avals in seen:
+    joins = [e for n, e in seen if n == "concatenate"]
+    assert len(joins) == 1
+    assert joins[0].params["dimension"] == 0
+    for v in joins[0].invars:
+        assert v.aval.shape == (Bp, blk) and v.aval.dtype.itemsize == 4, \
+            "a join that is not sublane-aligned in 32 bits"
+    for n, e in seen:
         if n == "mul":
-            assert all(a.dtype != jnp.int8 for a in avals), \
+            assert all(v.aval.dtype != jnp.int8 for v in e.invars), \
                 "int8 vector multiply: v5e has no int8 VPU multiply"
+
+    # the whole grid step holds one matmul: the 0/1 expansion that made
+    # the one-hot on the MXU until PR 36 cannot come back unnoticed
+    for quant in (False, True):
+        blk_q, fc_q, n_fb_q, _, _ = PH._plan(F, B, 3 * L, 1 if quant else 2)
+        r_pad = 2 * blk_q
+        call = jax.make_jaxpr(
+            lambda b, g, r, l, n: PH.build_histograms_pallas_lanes(
+                b, g, r, l, n, num_features=F, num_bins=B))(
+            jnp.zeros((n_fb_q, fc_q, r_pad), jnp.int32),
+            jnp.zeros((3, r_pad), jnp.int32 if quant else jnp.float32),
+            jnp.zeros((1, r_pad), jnp.int32), jnp.zeros((L,), jnp.int32),
+            jnp.zeros((1,), jnp.int32))
+        kernels = [e for n, e in _prims(call.jaxpr) if n == "pallas_call"]
+        assert len(kernels) == 1
+        inside = [n for n, _ in _prims(kernels[0].params["jaxpr"])]
+        assert inside.count("dot_general") == 1, inside.count("dot_general")
+
+
+# (F, B, slots, live slots, quantized): the four benchmark cells' plans
+# (Epsilon's 2,000 columns cut to two of its 63 feature chunks), the
+# root's lattice (2W slots, one live), int8 addends, and the least Bp
+_PLAN_CASES = {
+    "higgs": (28, 63, 16, 16, False),
+    "epsilon": (64, 63, 16, 16, False),
+    "msltr": (137, 63, 16, 16, False),
+    "criteo": (67, 255, 16, 16, False),
+    "root": (28, 63, 32, 1, False),
+    "int8": (28, 63, 16, 16, True),
+    "bp8": (3, 5, 2, 2, False),
+}
+# what _plan gave these shapes at the parent of PR 36 (bfloat16)
+_CELL_PLANS = {
+    "higgs": (28, (1152, 28, 1, 64, 128)),
+    "epsilon": (2000, (1024, 32, 63, 64, 128)),
+    "msltr": (137, (1152, 28, 5, 64, 128)),
+    "criteo": (67, (1024, 8, 9, 256, 128)),
+}
+
+
+@pytest.mark.parametrize("case", list(_PLAN_CASES))
+def test_pallas_onehot_and_histogram_are_exact_counts(case):
+    """The one-hot is made on the VPU (PR 36). (1) The helper alone, at
+    the case's plan: row ``f * Bp + b`` equals ``bins[f, r] == b`` at
+    every (f, b, r), the padded bins ``B..Bp-1`` included (all zero).
+    (2) The kernel in the interpreter with whole-number addends equals
+    exact integer counts from numpy bit for bit: nothing is rounded, so
+    any element of the one-hot that is not exactly 0 or 1, or sits in
+    another row, shows."""
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    F, B, L, n_live, quant = _PLAN_CASES[case]
+    cdt = jnp.int8 if quant else jnp.bfloat16
+    blk, fc, n_fb, Bp, lanes = PH._plan(F, B, 3 * L, 1 if quant else 2)
+    if case in _CELL_PLANS:
+        F_cell, plan = _CELL_PLANS[case]
+        assert PH._plan(F_cell, B, 3 * L, 2) == plan
+        assert (blk, fc, Bp, lanes) == plan[:2] + plan[3:]
+    if case == "epsilon":
+        assert n_fb == 2
+    if case == "bp8":
+        assert Bp == 8
+    rng = np.random.RandomState(36)
+    R = 2 * blk + 77
+    bins = rng.randint(0, B, size=(R, F)).astype(np.uint8)
+    bins[0, :] = B - 1
+    bins[1, :] = 0
+
+    block = np.zeros((fc, 256), np.int32)
+    block[:min(fc, F)] = bins[:256, :fc].T
+    onehot = np.asarray(PH._onehot_t(_Ref(jnp.asarray(block)), Bp=Bp,
+                                     cdt=cdt))
+    assert onehot.dtype == cdt and onehot.shape == (fc * Bp, 256)
+    want = block[:, None, :] == np.arange(Bp)[None, :, None]
+    assert np.array_equal(onehot.astype(np.int32).reshape(fc, Bp, 256),
+                          want.astype(np.int32))
+    assert not onehot.reshape(fc, Bp, 256)[:, B:].any()
+
+    g = rng.randint(-100, 101, size=R)
+    h = rng.randint(0, 101, size=R)
+    gh = np.stack([g, h, np.ones(R, np.int64)], axis=1)
+    leaf_ids = np.full(L, -2, np.int32)
+    leaf_ids[:n_live] = np.arange(n_live)
+    row_leaf = rng.randint(-1, n_live, size=R).astype(np.int32)
+    got = np.asarray(PH.build_histograms_pallas(
+        jnp.asarray(bins), jnp.asarray(gh.astype(np.int8 if quant
+                                                 else np.float32)),
+        jnp.asarray(row_leaf), jnp.asarray(leaf_ids), num_bins=B,
+        interpret=True))
+    want = build_histograms_reference(bins, gh, row_leaf, leaf_ids, B)
+    want = want.astype(got.dtype)       # whole numbers under 2^24: exact
+    assert got.dtype == (np.int32 if quant else np.float32)
+    assert got.tobytes() == want.tobytes()
+    assert not got[n_live:].any()
 
 
 def test_pallas_dynamic_row_bound_skips_blocks(rng):

@@ -50,9 +50,11 @@ def _hist_args(sds, R, F, L, quant):
 
 # (F, B, L): Higgs at 63 and 255 bins with a 21-slot and a 42-slot
 # build, the verify skill's 12-feature default-params flow (leaf_batch
-# 16, both children = 32 slots), MS-LTR and Expo widths
+# 16, both children = 32 slots), MS-LTR and Expo widths, and the
+# `criteo` cell's plan (67 columns at 255 bins: 8 features of 256
+# one-hot rows a chunk)
 SHAPES = [(28, 63, 21), (28, 63, 42), (28, 255, 21), (12, 255, 16),
-          (12, 255, 32), (137, 63, 21), (700, 63, 21)]
+          (12, 255, 32), (137, 63, 21), (700, 63, 21), (67, 255, 16)]
 
 
 @pytest.mark.parametrize("F,B,L", SHAPES)
