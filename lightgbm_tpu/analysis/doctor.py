@@ -125,10 +125,13 @@ def _synth(config: str, *, n: int = 160, f: int = 8, seed: int = 0):
     if config == "categorical":
         X[:, 0] = rng.randint(0, 5, size=n)
     if config == "efb":
-        # mutually-exclusive sparse pair so a bundle actually forms
-        on = rng.rand(n) < 0.5
-        X[:, -2] = np.where(on, X[:, -2], 0.0)
-        X[:, -1] = np.where(on, 0.0, X[:, -1])
+        # four mutually-exclusive sparse columns so a bundle actually
+        # forms AND the plan is kept (it has to shrink the matrix to 3/4:
+        # an exclusive pair among 8 columns made 7 bundles and was dropped,
+        # so this cell ran unbundled until PR 37)
+        which = rng.randint(0, 4, size=n)
+        for k in range(4):
+            X[:, f - 4 + k] = np.where(which == k, X[:, f - 4 + k], 0.0)
     if config == "multiclass":
         y = (X[:, :3] + 0.5 * rng.normal(size=(n, 3))).argmax(1) \
             .astype(np.float32)

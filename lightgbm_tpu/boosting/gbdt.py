@@ -374,10 +374,15 @@ class GBDT:
             # column slice of the (padded) matrix
             cap_width = -(-cap_width // n_fs)
             try:
+                # the search scans feature space (a column-sharded plan:
+                # its own features) whatever the stored columns are
+                w = max(1, min(int(config.leaf_batch),
+                               int(config.num_leaves) - 1))
                 check_device_capacity(
                     self.train_set.num_data, cap_width, cap_itemsize,
                     config.num_leaves, self._bundle_bins or self.B,
-                    self._hist_sub, n_row_shards=n_row_shards)
+                    self._hist_sub, n_row_shards=n_row_shards,
+                    search_lattice=(2 * w, -(-F // n_fs), self.B))
             except MemoryError:
                 if oc == "off" or chunk_reason:
                     raise
@@ -1877,6 +1882,14 @@ class GBDT:
         return stop
 
     _latest: Optional["weakref.ref"] = None
+
+    @property
+    def ingest_counters(self) -> dict:
+        """The training Dataset's layout counters
+        (``Dataset.ingest_counters``): stored columns, bundle bins used and
+        offered, valid feature bins and the positions the search scans,
+        ``efb.conflict_rows``."""
+        return self.train_set.ingest_counters
 
     @classmethod
     def latest(cls) -> Optional["GBDT"]:

@@ -755,7 +755,8 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         h = _dequant(hraw)
         if not use_bundle:
             return h
-        return unbundle_shard(h) if rs_data else unbundle(h)
+        with profiler.stage(PHS.UNBUNDLE):
+            return unbundle_shard(h) if rs_data else unbundle(h)
 
     def hist_for(slots, rl, part=None):
         return hist_finish(hist_raw_for(slots, rl, part=part))

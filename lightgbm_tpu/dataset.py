@@ -22,6 +22,7 @@ Design differences (TPU-first):
 from __future__ import annotations
 
 import os
+from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 from typing import Dict, List, Optional
 
@@ -32,10 +33,22 @@ from .config import Config
 __all__ = ["Dataset", "Sequence", "estimate_device_bytes",
            "check_device_capacity"]
 
+_APPLY_BLOCK_ROWS = 1 << 18    # rows of a block of Dataset._apply_blocks
+
+
+# float32 arrays of the search lattice's size the compiled round holds at
+# once: the unbundled histogram, the gather it came from, the two scan
+# directions' prefix sums and their gains. Set from the chip's reading at
+# 13,184,290 x 79 stored columns, F 4,228, B 255, 32 slots (PERF.md section
+# 4): 7.55 GB of temporaries at the peak, 4.03 GB of them the kernel's
+# int32 copy of the bin matrix, so ~3.5 GB = 8.5 lattices of 414 MB
+SEARCH_LATTICE_COPIES = 8
+
 
 def estimate_device_bytes(num_rows: int, width: int, itemsize: int,
                           num_leaves: int, max_bin: int,
-                          hist_cache: bool, n_row_shards: int = 1) -> int:
+                          hist_cache: bool, n_row_shards: int = 1,
+                          search_lattice: Optional[tuple] = None) -> int:
     """Per-device bytes of the training working set (capacity model,
     VERDICT r4 #5). Device storage is the DENSE bundled bin matrix
     sharded over data-parallel rows — the reference instead has
@@ -45,19 +58,28 @@ def estimate_device_bytes(num_rows: int, width: int, itemsize: int,
       bins [R/shards, width] itemsize   (the matrix itself)
       gh/scores/row_leaf ~ 4 x [R/shards] f32
       hist cache [(L+1), width*B', 3] f32 when hist_subtraction is on
+      the split search's lattice of a round, ``search_lattice`` =
+      (slots, features, bins): [slots, F, B, 3] f32 in FEATURE space
+      however the matrix is stored (a bundled matrix is unbundled into
+      it every round), times SEARCH_LATTICE_COPIES for its temporaries
     """
     r_local = -(-num_rows // max(1, n_row_shards))
     bins_b = r_local * width * itemsize
     per_row = 4 * 4 * r_local                    # gh(3) + scores/row_leaf
     cache_b = ((num_leaves + 1) * width * max_bin * 3 * 4
                if hist_cache else 0)
-    return int(bins_b + per_row + cache_b)
+    search_b = 0
+    if search_lattice is not None:
+        slots, feats, fbins = search_lattice
+        search_b = SEARCH_LATTICE_COPIES * slots * feats * fbins * 3 * 4
+    return int(bins_b + per_row + cache_b + search_b)
 
 
 def check_device_capacity(num_rows: int, width: int, itemsize: int,
                           num_leaves: int, max_bin: int,
                           hist_cache: bool, n_row_shards: int = 1,
-                          headroom: float = 0.85) -> None:
+                          headroom: float = 0.85,
+                          search_lattice: Optional[tuple] = None) -> None:
     """Raise MemoryError with sized guidance when the dense working set
     cannot fit a device (instead of an opaque device OOM mid-training).
 
@@ -81,15 +103,27 @@ def check_device_capacity(num_rows: int, width: int, itemsize: int,
     if not budget:
         return
     need = estimate_device_bytes(num_rows, width, itemsize, num_leaves,
-                                 max_bin, hist_cache, n_row_shards)
+                                 max_bin, hist_cache, n_row_shards,
+                                 search_lattice)
     if need <= budget * headroom:
         return
     gib = 1 << 30
+    search = ""
+    if search_lattice is not None:
+        slots, feats, fbins = search_lattice
+        lattice = slots * feats * fbins * 3 * 4
+        search = (
+            f" Of that, {SEARCH_LATTICE_COPIES * lattice / gib:.1f} GiB is "
+            f"the split search: a lattice of {slots} slots x {feats:,} "
+            f"features x {fbins} bins x 3 sums in float32 "
+            f"({lattice / gib:.2f} GiB) and its temporaries, in FEATURE "
+            "space whatever the stored columns are (a smaller leaf_batch "
+            "or max_bin shrinks it).")
     raise MemoryError(
         f"training working set ~{need / gib:.1f} GiB per device exceeds "
         f"{budget * headroom / gib:.1f} GiB available "
         f"({num_rows:,} rows x {width:,} stored columns x {itemsize} B "
-        f"over {n_row_shards} row shard(s)). Device storage is the "
+        f"over {n_row_shards} row shard(s)).{search} Device storage is the "
         "DENSE bundled bin matrix — wide sparse data fits only when its "
         "columns are mutually exclusive enough to bundle (EFB). "
         "Options: enable_bundle=true with a larger max_conflict_rate; "
@@ -201,6 +235,62 @@ def _to_2d_float(data) -> np.ndarray:
     return np.ascontiguousarray(arr, dtype=np.float64)
 
 
+class _Columns:
+    """The columns of a block of rows, dense ``[n, F]`` or scipy CSC,
+    behind one reading. A sparse column is read as its stored values and
+    the COUNT of its implied zeros, so fitting, planning and encoding
+    cost the stored values and no ``[n, F]`` array is ever made (what the
+    reference's ``BinMapper::FindBin`` takes: the non-zero sample values
+    and ``total_sample_cnt``)."""
+
+    def __init__(self, data):
+        self.sparse = _is_sparse(data)
+        if self.sparse:
+            # duplicate entries add up, as a dense conversion adds them
+            # (in a copy where the matrix is the caller's own)
+            if data.format != "csc":
+                data = data.tocsc()
+            elif not data.has_canonical_format:
+                data = data.copy()
+            data.sum_duplicates()
+        self.data = data
+        self.num_rows = data.shape[0]
+
+    def stored(self, f: int):
+        """(rows, float64 values): ``rows`` ascending row numbers of the
+        stored values, or None where the column is dense."""
+        if not self.sparse:
+            return None, self.data[:, f]
+        lo, hi = self.data.indptr[f], self.data.indptr[f + 1]
+        return (self.data.indices[lo:hi],
+                self.data.data[lo:hi].astype(np.float64))
+
+    def summary(self, f: int):
+        """(sorted distinct non-NaN values, counts, NaN count) of column
+        ``f``, implied zeros counted: ``BinMapper.from_distinct``'s
+        arguments."""
+        rows, v = self.stored(f)
+        v = np.asarray(v, np.float64)
+        nan = np.isnan(v)
+        dv, cnts = np.unique(v[~nan], return_counts=True)
+        zeros = 0 if rows is None else self.num_rows - len(v)
+        if zeros:
+            i = int(np.searchsorted(dv, 0.0))
+            if i < len(dv) and dv[i] == 0.0:
+                cnts[i] += zeros
+            else:
+                dv, cnts = np.insert(dv, i, 0.0), np.insert(cnts, i, zeros)
+        return dv, cnts, int(nan.sum())
+
+    def binned(self, f: int, mapper: BinMapper):
+        """(rows, bins at those rows, the bin every other row holds: that
+        of 0.0, which need not be the column's most frequent)."""
+        rows, v = self.stored(f)
+        zero_bin = (0 if rows is None
+                    else int(mapper.values_to_bins(np.zeros(1))[0]))
+        return rows, mapper.values_to_bins(v), zero_bin
+
+
 class Dataset:
     """Binned training data.
 
@@ -237,6 +327,8 @@ class Dataset:
         self.pandas_categorical = None   # per-cat-column category lists
         self.raw_values: Optional[np.ndarray] = None  # kept for linear_tree
         self.bundle_plan = None                     # EFB layout (efb.py)
+        self.efb_conflict_rows: Optional[int] = None  # rows of the encode
+        # that lost a value to a later member of their bundle
         self.bins = None                            # [num_data, F|G] int
         self.chunk_source = None   # shard-backed row stream (data/)
         self.num_data: int = 0
@@ -326,11 +418,11 @@ class Dataset:
         sparse = _is_sparse(self._raw_data)
         pd_cat_idx = None
         if sparse:
-            # scipy CSR/CSC input: binning samples densify per-row, full
-            # extraction streams per-column — the dense [R, F] matrix
-            # never materializes (SparseBin/CSR ingestion analog)
+            # scipy CSR/CSC input: rows are sampled from CSR, everything
+            # else reads CSC columns as stored values + a count of implied
+            # zeros (_Columns) — no dense [R, F] or [sample, F] array is
+            # ever made (SparseBin/CSR ingestion analog)
             data = self._raw_data.tocsr()
-            data_csc = None
         elif _is_pandas_df(self._raw_data):
             # a valid set aligns to its train set's category lists; a
             # train set trained WITHOUT pandas gets [] so a categorical
@@ -401,61 +493,41 @@ class Dataset:
                 sample = data[sample_idx]
             else:
                 sample = data
-            if sparse:
-                sample = np.asarray(sample.todense(), dtype=np.float64)
-            with profiler.span("dataset.fit_bins"):
+            with profiler.span("dataset.fit_bins") as fields:
+                sample = _Columns(sample)
                 self._fit_mappers(sample, cat_idx, cfg)
+                fields.update(sample_rows=sample.num_rows,
+                              features_used=len(self.used_features))
+
+        F = len(self.used_features)
+        # -- EFB: pack mutually-exclusive sparse features (efb.py) ----
+        if self.reference is not None:
+            self.bundle_plan = self.reference.bundle_plan
+        elif self._multi_process():
+            # pre-partitioned multi-host: a bundle plan built from the
+            # LOCAL sample would differ across hosts (different conflict
+            # counts -> different column layouts); skip EFB until the
+            # plan itself is synced like the mappers are
+            self.bundle_plan = None
+        elif cfg.enable_bundle and F > 4:
+            with profiler.span("dataset.plan_bundles") as fields:
+                self.bundle_plan = self._plan_bundles(sample, cfg)
+                fields.update(self._plan_counters())
+        else:
+            self.bundle_plan = None
 
         # binning every row with the fitted mappers (and EFB packing)
-        with profiler.span("dataset.apply_bins"):
-            F = len(self.used_features)
-
+        with profiler.span("dataset.apply_bins") as fields:
             if sparse:
-                # one CSR->CSC conversion; column slices are then O(nnz_col)
-                data_csc = data.tocsc()
-
-            def col_of(f):
-                if sparse:
-                    return np.asarray(data_csc[:, [f]].todense(),
-                                      dtype=np.float64).ravel()
-                return data[:, f]
-
-            # -- EFB: pack mutually-exclusive sparse features (efb.py) ----
-            if self.reference is not None:
-                self.bundle_plan = self.reference.bundle_plan
-            elif self._multi_process():
-                # pre-partitioned multi-host: a bundle plan built from the
-                # LOCAL sample would differ across hosts (different conflict
-                # counts -> different column layouts); skip EFB until the
-                # plan itself is synced like the mappers are
-                self.bundle_plan = None
-            elif cfg.enable_bundle and F > 4:
-                from .efb import plan_bundles
-                uf = self.used_features
-                sample_bins = np.stack(
-                    [self.bin_mappers[f].values_to_bins(sample[:, f])
-                     for f in uf], axis=1)
-                plan = plan_bundles(
-                    sample_bins,
-                    [self.bin_mappers[f].num_bin for f in uf],
-                    [self.bin_mappers[f].most_freq_bin for f in uf],
-                    max_conflict_rate=cfg.max_conflict_rate,
-                    max_bundle_bins=cfg.max_bundle_bins)
-                # bundle only when it genuinely shrinks the matrix
-                self.bundle_plan = (plan if plan.num_bundles <= int(0.75 * F)
-                                    else None)
-            else:
-                self.bundle_plan = None
-
-            if self.bundle_plan is not None:
-                from .efb import encode_bundles
-
-                def cols():
-                    for j, f in enumerate(self.used_features):
-                        yield j, self.bin_mappers[f].values_to_bins(
-                            col_of(f)).astype(np.int64)
-                self.bins = encode_bundles(self.bundle_plan, cols(),
-                                           self.num_data)
+                fields["stored_values"] = int(data.nnz)
+            bp = self.bundle_plan
+            if bp is not None:
+                dtype = np.uint8 if bp.max_bundle_bins <= 256 else np.int32
+                with profiler.span("dataset.encode_bundles") as enc:
+                    self.bins = np.zeros((self.num_data, bp.num_bundles),
+                                         dtype)
+                    self.efb_conflict_rows = enc["conflict_rows"] = \
+                        self._apply_blocks(data)
             else:
                 dtype = np.uint8 if self.max_num_bin <= 256 else np.int32
                 fast = None
@@ -471,9 +543,7 @@ class Dataset:
                     self.bins = fast
                 else:
                     self.bins = np.empty((self.num_data, F), dtype=dtype)
-                    for j, f in enumerate(self.used_features):
-                        self.bins[:, j] = self.bin_mappers[f].values_to_bins(
-                            col_of(f)).astype(dtype)
+                    self._apply_blocks(data)
 
         if self.label is None and not self.params.get("_allow_no_label"):
             raise ValueError("Dataset has no label")
@@ -573,7 +643,7 @@ class Dataset:
             sample_idx = np.sort(rng.choice(self.num_data, sample_cnt,
                                             replace=False))
             sample = reader.read_rows_at(sample_idx)
-            self._fit_mappers(sample, cat_idx, cfg)
+            self._fit_mappers(_Columns(sample), cat_idx, cfg)
             self.bundle_plan = None  # streaming path stays unbundled
 
         F = len(self.used_features)
@@ -617,8 +687,8 @@ class Dataset:
         self._constructed = True
         return self
 
-    def _fit_mappers(self, sample: np.ndarray, cat_idx: set, cfg) -> None:
-        """Fit per-feature BinMappers from a row sample
+    def _fit_mappers(self, sample: "_Columns", cat_idx: set, cfg) -> None:
+        """Fit per-feature BinMappers from a row sample's columns
         (ConstructBinMappersFromTextData / ConstructFromSampleData
         analog), honoring max_bin_by_feature and forcedbins_filename
         (dataset_loader.cpp:619-653)."""
@@ -651,8 +721,8 @@ class Dataset:
                 self.bin_mappers.append(BinMapper())  # filled by sync
                 continue
             bt = "categorical" if f in cat_idx else "numerical"
-            m = BinMapper.from_values(
-                sample[:, f],
+            m = BinMapper.from_distinct(
+                *sample.summary(f),
                 max_bin=int(mbf[f]) if mbf else cfg.max_bin,
                 min_data_in_bin=cfg.min_data_in_bin, bin_type=bt,
                 use_missing=cfg.use_missing,
@@ -675,6 +745,186 @@ class Dataset:
                              "trivial (single value)")
         self.max_num_bin = max(
             self.bin_mappers[f].num_bin for f in self.used_features)
+
+    def _apply_blocks(self, data) -> int:
+        """Fill ``self.bins`` from ``data`` (dense rows or CSR), a block of
+        ``_APPLY_BLOCK_ROWS`` rows at a time on a few threads. A dense
+        block goes column by column through ``encode_bundles`` (or one
+        column a feature); a CSR block through :meth:`_apply_sparse_block`,
+        which costs its stored values. Returns the rows that lost a value
+        to a later member of their bundle."""
+        from .efb import encode_bundles
+        bp = self.bundle_plan
+        tables = self._sparse_tables() if _is_sparse(data) else None
+
+        def one(lo) -> int:
+            view = self.bins[lo:lo + _APPLY_BLOCK_ROWS]
+            block = data[lo:lo + _APPLY_BLOCK_ROWS]
+            if tables is not None:
+                return self._apply_sparse_block(block.tocsc(), view, tables)
+            cols = ((j, self.bin_mappers[f].values_to_bins(block[:, f]))
+                    for j, f in enumerate(self.used_features))
+            if bp is None:
+                for j, col in cols:
+                    view[:, j] = col
+                return 0
+            mine: dict = {}
+            encode_bundles(bp, cols, len(view), counters=mine, out=view)
+            return mine["conflict_rows"]
+        starts = range(0, self.num_data, _APPLY_BLOCK_ROWS)
+        with ThreadPoolExecutor(max(1, min(8, os.cpu_count() or 1,
+                                           len(starts)))) as ex:
+            return sum(ex.map(one, starts))
+
+    def _sparse_tables(self) -> dict:
+        """By raw feature, what :meth:`_apply_sparse_block` needs of a
+        stored value's column: whether it is used, its bin bounds (None:
+        categorical), the bin of NaN and of an implied zero, and where the
+        feature's bins go in ``self.bins``: stored column, offset (0 = the
+        column holds raw bins) and ``default`` = offset + most frequent
+        bin, the value a member of a bundle does not write (-1 where the
+        column holds raw bins: every value is written)."""
+        n, uf, bp = self.num_total_features, self.used_features, self.bundle_plan
+        ms = [self.bin_mappers[f] for f in uf]
+        t = {k: np.zeros(n, np.int32)
+             for k in ("column", "offset", "zero_bin", "nan_bin")}
+        t["used"] = np.zeros(n, bool)
+        t["used"][uf] = True
+        t["column"][uf] = np.arange(len(uf)) if bp is None else bp.feat_bundle
+        t["default"] = np.full(n, -1, np.int32)
+        if bp is not None:
+            t["offset"][uf] = bp.feat_offset
+            t["default"][uf] = np.where(bp.feat_offset == 0, -1,
+                                        bp.feat_offset + bp.feat_mfb)
+        t["zero_bin"][uf] = [int(m.values_to_bins(np.zeros(1))[0])
+                             for m in ms]
+        t["nan_bin"][uf] = [m.num_bin - 1 if m.nan_bin >= 0
+                            else m.default_bin for m in ms]
+        t["bounds"] = [None] * n
+        for f, m in zip(uf, ms):
+            if m.bin_type != "categorical":
+                t["bounds"][f] = m.bin_upper_bound
+        return t
+
+    def _apply_sparse_block(self, csc, view: np.ndarray, t: dict) -> int:
+        """Write a block's rows (``csc``: its CSC form) into ``view``, its
+        rows of ``self.bins`` (zeroed), in O(stored values): a column's
+        stored values are binned by one search, a column that holds raw
+        bins is filled with the bin of 0.0 first (which need not be its
+        most frequent bin), and a member of a bundle writes ``offset +
+        bin`` where it is not at its most frequent bin. Where two members
+        of a bundle meet in a row the higher feature wins; returns the
+        number of rows that lost a value so."""
+        if not csc.has_canonical_format:
+            csc.sum_duplicates()        # as a dense conversion adds them
+        n, width = view.shape
+        ptr, rows = csc.indptr, csc.indices
+        counts = np.diff(ptr)
+        v = csc.data.astype(np.float64)
+        nan = np.isnan(v)
+        if nan.any():       # ValueToBin: searched as 0.0, then the NaN bin
+            v[nan] = 0.0
+        value = np.zeros(len(v), np.int32)      # offset + bin
+        for f in np.flatnonzero(t["used"] & (counts > 0)):
+            seg, ub = slice(ptr[f], ptr[f + 1]), t["bounds"][f]
+            value[seg] = (ub.searchsorted(v[seg]) if ub is not None else
+                          self.bin_mappers[f].values_to_bins(csc.data[seg]))
+        if nan.any():
+            value[nan] = np.repeat(t["nan_bin"], counts)[nan]
+        value += np.repeat(t["offset"], counts)
+        col = np.repeat(t["column"], counts)
+        default = np.repeat(t["default"], counts)
+        raw = t["default"] < 0
+        filled = raw & t["used"]
+        view[:, t["column"][filled]] = t["zero_bin"][filled][None, :]
+        # a member whose implied zeros are not its most frequent bin is
+        # non-default in every row that stores nothing: name those rows,
+        # and keep the arrays in feature order (a stable sort)
+        feat = None
+        for f in np.flatnonzero(t["used"] & ~raw & (
+                t["offset"] + t["zero_bin"] != t["default"])):
+            rest = np.setdiff1d(np.arange(n, dtype=rows.dtype),
+                                rows[ptr[f]:ptr[f + 1]], assume_unique=True)
+            if feat is None:
+                feat = np.repeat(np.arange(len(counts)), counts)
+            feat = np.concatenate([feat, np.full(len(rest), f)])
+            rows = np.concatenate([rows, rest])
+            value, col, default = (
+                np.concatenate([a, np.full(len(rest), x, a.dtype)])
+                for a, x in ((value, t["offset"][f] + t["zero_bin"][f]),
+                             (col, t["column"][f]),
+                             (default, t["default"][f])))
+        keep = value != default
+        if not t["used"].all():
+            keep &= (np.repeat(t["used"], counts) if feat is None
+                     else t["used"][feat])
+        if feat is not None:
+            order = np.argsort(feat, kind="stable")
+            rows, value, col, default, keep = (
+                a[order] for a in (rows, value, col, default, keep))
+        if not keep.all():
+            rows, value, col, default = (
+                a[keep] for a in (rows, value, col, default))
+        wide = np.int64 if n * width >= 2 ** 31 else np.int32
+        cell = rows.astype(wide) * wide(width) + col
+        value = value.astype(view.dtype)
+        flat = view.reshape(-1)
+        flat[cell] = value
+        shared = np.flatnonzero(np.bincount(
+            t["column"][t["used"] & ~raw], minlength=width))
+        if np.count_nonzero(view[:, shared]) == np.count_nonzero(default >= 0):
+            return 0
+        # some cell was written twice: the cell's last writer (features
+        # ascend along the arrays) is the one that stays
+        _, first = np.unique(cell[::-1], return_index=True)
+        last = len(cell) - 1 - first
+        flat[cell[last]] = value[last]
+        lost = np.ones(len(cell), bool)
+        lost[last] = False
+        return len(np.unique(rows[lost]))
+
+    def _plan_bundles(self, sample: "_Columns", cfg):
+        """The EFB plan (efb.py) from the sample's columns: each used
+        feature's set of non-default sample rows as bits, from its stored
+        values; kept only where it genuinely shrinks the matrix."""
+        from .efb import pack_nondefault, plan_from_masks
+        mappers = [self.bin_mappers[f] for f in self.used_features]
+        masks = [pack_nondefault(sample.num_rows, *sample.binned(f, m),
+                                 m.most_freq_bin)
+                 for f, m in zip(self.used_features, mappers)]
+        plan = plan_from_masks(
+            [m for m, _ in masks], [c for _, c in masks], sample.num_rows,
+            [m.num_bin for m in mappers], [m.most_freq_bin for m in mappers],
+            max_conflict_rate=cfg.max_conflict_rate,
+            max_bundle_bins=cfg.max_bundle_bins)
+        return plan if plan.num_bundles <= int(0.75 * len(mappers)) else None
+
+    def _plan_counters(self) -> dict:
+        """What the stored layout holds and what the split search scans:
+        fields of the ``dataset.plan_bundles`` span, and
+        ``ingest_counters``."""
+        nb = self.per_feature_num_bins()
+        out = {"features_used": len(nb),
+               "valid_feature_bins": int(nb.sum()),
+               "scanned_positions": int(len(nb) * self.max_num_bin)}
+        bp = self.bundle_plan
+        if bp is not None:
+            out.update(stored_columns=int(bp.num_bundles),
+                       bundle_bins_used=int(bp.bundle_num_bins.sum()),
+                       bundle_bins_offered=int(bp.num_bundles
+                                               * bp.max_bundle_bins),
+                       sample_conflicts=int(bp.sample_conflicts))
+        return out
+
+    @property
+    def ingest_counters(self) -> dict:
+        """:meth:`_plan_counters` and, of a bundled Dataset, the rows of
+        the encode that lost a value to a later member of their bundle
+        (``efb.conflict_rows``)."""
+        out = self._plan_counters()
+        if self.bundle_plan is not None:
+            out["efb.conflict_rows"] = self.efb_conflict_rows
+        return out
 
     def _resolve_categoricals(self, names) -> set:
         cat = self.categorical_feature

@@ -28,7 +28,8 @@ __all__ = ["GRADS", "SAMPLING", "BUILD", "UPDATE", "EVAL",
            "INGEST_SKETCH", "INGEST_WRITE", "PREFETCH",
            "HIST_MERGE", "WINNER_SYNC", "ROOT_PASS", "POP", "APPLY",
            "COUNT", "COMPACT", "HIST_GATHER", "HIST_RELAYOUT",
-           "HIST_KERNEL", "SUBTRACT", "FIND", "RANK_GATHER", "RANK_SORT",
+           "HIST_KERNEL", "UNBUNDLE", "SUBTRACT", "FIND", "RANK_GATHER",
+           "RANK_SORT",
            "RANK_PAIRS", "RANK_SCATTER", "TRAIN_PHASES",
            "INGEST_PHASES", "COLLECTIVE_PHASES", "BUILD_STAGES",
            "GRADS_STAGES", "KNOWN_PHASES", "HOST_SPANS",
@@ -76,6 +77,10 @@ HIST_GATHER = "hist_gather"      # bins, gh and row_leaf by row_gather: all
 HIST_RELAYOUT = "hist_relayout"  # cast, pad, transpose for the kernel; of
 #                                  a compacted stream, chunk by chunk
 HIST_KERNEL = "hist_kernel"      # the pallas_call (or the XLA block loop)
+UNBUNDLE = "unbundle"            # an EFB matrix only: the bundle-space
+#                                  histogram gathered to the feature-space
+#                                  lattice [slots, F, B, 3] the search scans,
+#                                  most frequent bins restored (hist_finish)
 SUBTRACT = "subtract"            # parent minus child, cache scatters
 FIND = "find"                    # best_for / fused split + cache scatter
 
@@ -96,6 +101,11 @@ RANK_SCATTER = "rank_scatter"    # every bucket's g, h back to rows, each row
 # ``lgbtpu:<name>`` in a profiler capture.
 HOST_SPANS = frozenset({
     "dataset.fit_bins", "dataset.apply_bins",      # Dataset.construct
+    "dataset.plan_bundles",    # the EFB plan from the sample's columns;
+    #                            the layout's counters ride on it as fields
+    #                            (Dataset._plan_counters)
+    "dataset.encode_bundles",  # inside apply_bins: the [R, G] matrix from
+    #                            the columns; field ``conflict_rows``
     "objective.init",      # a ranking objective's query layout and max-DCG
     #                        tables; its counters ride on it as fields
     "gbdt.to_device",      # H2D of bins, row_leaf0, labels, weights and
@@ -129,7 +139,7 @@ INGEST_PHASES = frozenset({INGEST_SKETCH, INGEST_WRITE, PREFETCH})
 COLLECTIVE_PHASES = frozenset({HIST_MERGE, WINNER_SYNC})
 BUILD_STAGES = frozenset({ROOT_PASS, POP, APPLY, COUNT, COMPACT,
                           HIST_GATHER, HIST_RELAYOUT, HIST_KERNEL,
-                          SUBTRACT, FIND}) | COLLECTIVE_PHASES
+                          UNBUNDLE, SUBTRACT, FIND}) | COLLECTIVE_PHASES
 GRADS_STAGES = frozenset({RANK_GATHER, RANK_SORT, RANK_PAIRS,
                           RANK_SCATTER})
 KNOWN_PHASES = (TRAIN_PHASES | INGEST_PHASES | BUILD_STAGES

@@ -478,10 +478,12 @@ def test_feature_shard_storage_capacity_width(rng, monkeypatch):
     X = rng.normal(size=(n, f))
     y = (X[:, 0] > 0).astype(float)
     # budget sized so the REPLICATED working set (bins 32 KB + 4x[R]
-    # f32 per-row state 8 KB = 40 KB) fails but the column-sharded one
-    # (bins 4 KB + 8 KB = 12 KB) fits under 0.85 * 20 KB = 17 KB
+    # f32 per-row state 8 KB + the split search's lattice, 6 slots x 64
+    # features x 16 bins x 12 B x 8 copies = 590 KB: 630 KB) fails but
+    # the column-sharded one (bins 4 KB + 8 KB + the search over its own
+    # 8 features 74 KB = 86 KB) fits under 0.85 * 200 KB = 170 KB
     monkeypatch.setenv("LIGHTGBM_TPU_DEVICE_MEM_GB",
-                       str(20e3 / (1 << 30)))  # ~20 KB
+                       str(200e3 / (1 << 30)))  # ~200 KB
     common = {"objective": "binary", "num_leaves": 4, "verbosity": -1,
               "max_bin": 16, "hist_subtraction": False}
     with pytest.raises(MemoryError):
