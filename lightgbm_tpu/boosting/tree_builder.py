@@ -33,10 +33,12 @@ Design (TPU-first; not a translation):
        one sort of the row numbers, ``stream_index``);
        ``bins``, ``gh`` and ``row_leaf`` go to
        ops/histogram.py uncompacted with ``row_gather=c_idx,
-       num_rows=n_small``, and the wrapper gathers, casts and lays them
-       out inside one loop whose trip count is ``ceil(n_small /
-       chunk)`` (a row block a trip for matmul and scatter; for pallas
-       a chunk of ~R/32 rows a trip into the kernel's operand buffers,
+       num_rows=n_small``, and the wrapper gathers (two gathers a trip:
+       the bin rows, and one table row holding ``gh`` and the leaf),
+       casts and lays them out inside one loop whose trip count is
+       ``ceil(n_small / chunk)`` (a row block a trip for matmul and
+       scatter; for pallas a chunk of ~R/32 rows a trip into the
+       kernel's operand buffers,
        then ONE kernel call bounded by the same ``n_small``). So a
        round over a 1%-sized leaf pays about a chunk of a full pass,
        on every path; RoundLog.stream_rows counts what it touched.
@@ -714,7 +716,11 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         one sort of the row numbers (``stream_index``). The rows
         themselves are gathered where the stream is consumed
         (``build_histograms(row_gather=, num_rows=)``), chunk by chunk
-        and only as far as ``n_small``."""
+        and only as far as ``n_small``: a bin row and one row of the
+        ``[R, 4]`` table of ``gh`` and ``row_leaf`` a position, so
+        ``row_leaf`` goes there uncompacted and is never gathered alone
+        (a 1-D ``s32[R]`` gather was the dearest of three, PERF.md
+        section 6, PR 38)."""
         m, _ = select_by_slot(row_leaf, small_slots, small_slots >= 0)
         return stream_index(m)
 

@@ -278,8 +278,12 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
     ``_stream_operands``) and calls the kernel once with the same bound
     as its scalar prefetch; native compacts ``gh`` / ``row_leaf`` ahead
     of the FFI call, whose own loop stops at ``num_rows``
-    (:func:`stream_chunk_rows` names each granularity). No R-sized
-    gather, cast or transpose runs on the chip's path. ``num_rows``
+    (:func:`stream_chunk_rows` names each granularity). ``gh`` and
+    ``row_leaf`` travel in ONE per-row table (:func:`_row_table`,
+    assembled once a call: the only R-sized write), so a trip gathers
+    twice, the bin rows and the table's rows, for every ``impl``. No
+    R-sized gather or transpose runs on the chip's path (and no R-sized
+    cast with float ``gh``). ``num_rows``
     without ``row_gather`` bounds an already-ordered stream: rows past
     it must then carry ``row_leaf == -1``. Works inside shard_map: each
     shard bounds its own stream (no collective sits inside the loops);
@@ -333,13 +337,35 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
             axis_name, merge, n_shards, row_gather, num_rows, init)
 
 
-def _gather_rows(gh, row_leaf, idx, start, num_rows):
-    """``gh`` and ``row_leaf`` of the stream positions ``start +
-    arange(len(idx))``, which read rows ``idx``; positions at or past
+def _row_table(gh, row_leaf, acc_dt):
+    """``[R, HIST_CH + 1]`` int32 per-row table of a compacted stream:
+    channels 0..2 are the 32-bit words of ``gh.astype(acc_dt)`` (the
+    accumulator's dtype, the cast the kernel's addends get anyway: it
+    commutes with a gather), channel 3 is the row's leaf. One index
+    selects both (:func:`_gather_rows`), so a stream position costs one
+    gather for them and not two. The words travel as integers because
+    XLA:TPU writes a concatenate as ``maximum`` over padded operands:
+    exact on any int32, not on the float a leaf id's bits would spell
+    (a denormal or a NaN). Assembled once a call, outside every chunk /
+    block loop: the one R-sized write a round the stream makes."""
+    words = gh.astype(acc_dt)
+    if acc_dt != jnp.int32:
+        words = jax.lax.bitcast_convert_type(words, jnp.int32)
+    return jnp.concatenate([words, row_leaf.astype(jnp.int32)[:, None]],
+                           axis=1)
+
+
+def _gather_rows(table, idx, start, num_rows, acc_dt):
+    """``gh`` (as ``acc_dt``) and ``row_leaf`` of the stream positions
+    ``start + arange(len(idx))``, which read rows ``idx`` of
+    :func:`_row_table`'s table in ONE ``take``; positions at or past
     ``num_rows`` are dead (leaf -1)."""
     pos = start + jnp.arange(idx.shape[0], dtype=jnp.int32)
-    return (jnp.take(gh, idx, axis=0),
-            jnp.where(pos < num_rows, jnp.take(row_leaf, idx), -1))
+    piece = jnp.take(table, idx, axis=0)
+    ghb = piece[:, :HIST_CH]
+    if acc_dt != jnp.int32:
+        ghb = jax.lax.bitcast_convert_type(ghb, acc_dt)
+    return ghb, jnp.where(pos < num_rows, piece[:, HIST_CH], -1)
 
 
 def _build_histograms_xla(bins, gh, row_leaf, leaf_ids, B, impl, block_rows,
@@ -378,8 +404,10 @@ def _build_histograms_xla(bins, gh, row_leaf, leaf_ids, B, impl, block_rows,
             if has_rg:
                 # the C kernel reads gh and row_leaf by stream position
                 with profiler.stage(phases.HIST_GATHER):
-                    gh, row_leaf = _gather_rows(
-                        gh, row_leaf, row_gather, 0, nr_in[0])
+                    ghs, row_leaf = _gather_rows(
+                        _row_table(gh, row_leaf, acc_dt_n), row_gather, 0,
+                        nr_in[0], acc_dt_n)
+                    gh = ghs.astype(gh.dtype)
             out_sds = jax.ShapeDtypeStruct((L, F, B, HIST_CH), acc_dt_n)
             target = "lgbtpu_hist_i8" if quant else "lgbtpu_hist_f32"
             hist = jax.ffi.ffi_call(target, out_sds)(
@@ -407,14 +435,19 @@ def _build_histograms_xla(bins, gh, row_leaf, leaf_ids, B, impl, block_rows,
     else:
         nb_used = nb
 
+    if row_gather is not None:
+        with profiler.stage(phases.HIST_GATHER):
+            table = _row_table(gh, row_leaf, acc_dt)
+
     def _block(i):
         s = i * block_rows
         if row_gather is not None:
             idx = jax.lax.dynamic_slice(row_gather, (s,), (block_rows,))
             with profiler.stage(phases.HIST_GATHER):
                 bb = jnp.take(bins, idx, axis=0)
-                ghb, lb = _gather_rows(gh, row_leaf, idx, s,
-                                       R if num_rows is None else num_rows)
+                ghb, lb = _gather_rows(
+                    table, idx, s, R if num_rows is None else num_rows,
+                    acc_dt)
             return bb, ghb, lb
         bb = jax.lax.dynamic_slice(bins, (s, 0), (block_rows, F))
         ghb = jax.lax.dynamic_slice(gh, (s, 0), (block_rows, HIST_CH))

@@ -44,10 +44,17 @@ narrow-dtype arithmetic):
 Compacted streams (``row_gather`` + ``num_rows``, what every round of
 the grow loop after the root pass sends): the lane-major operands are
 not made from a gathered copy of the whole matrix. :func:`_stream_operands`
-runs ``ceil(num_rows / chunk)`` trips of gather -> cast -> transpose ->
+runs ``ceil(num_rows / chunk)`` trips of gather -> transpose ->
 ``dynamic_update_slice`` into the operand buffers, ``chunk`` a multiple
-of the row block near R / 32, and the kernel is called once on the
-buffers with ``num_rows`` as its scalar-prefetch bound
+of the row block near R / 32. A trip gathers twice by the chunk's index:
+the bin rows, and the rows of one ``[R, 4]`` int32 table that holds
+``gh``'s three addends (cast already, as 32-bit words) and the row's
+leaf (``ops.histogram._row_table``, assembled once a call outside the
+loop: on the chip ``gh`` lies channel-major in tiles of four sublanes,
+so the leaf fills the sublane that was fetched and thrown away, and the
+1-D ``s32[R]`` gather, the dearest of the three there were, is gone).
+The kernel is called once on the buffers with ``num_rows`` as its
+scalar-prefetch bound
 (:func:`build_histograms_pallas_lanes` is that call, for operands laid
 out already). What lies past the last chunk written is never read.
 
@@ -83,8 +90,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import phases, profiler
-from .histogram import (HIST_CH, _gather_rows, pallas_shape_reason,
-                        stream_trips)
+from .histogram import (HIST_CH, _gather_rows, _row_table,
+                        pallas_shape_reason, stream_trips)
 
 __all__ = ["build_histograms_pallas", "build_histograms_pallas_lanes",
            "stream_chunk", "build_root_histograms_classes"]
@@ -252,9 +259,11 @@ def _stream_operands(bins, gh, row_leaf, row_gather, live, *, chunk: int,
     rows: stream position ``p`` reads row ``row_gather[p]`` of the
     uncompacted ``bins`` / ``gh`` / ``row_leaf``, positions at or past
     ``live`` count as dead (leaf -1). One loop of ``ceil(live / chunk)``
-    trips gathers a chunk's rows, brings them into the lane-major
-    layout and writes them into the operand buffers at lane offset
-    ``i * chunk``; nothing R-sized is gathered, cast or transposed.
+    trips gathers a chunk's rows (two gathers: ``bins``, and the
+    ``[R, 4]`` table of ``gh`` and ``row_leaf``, which is assembled
+    before the loop: the one R-sized write), brings them into the
+    lane-major layout and writes them into the operand buffers at lane
+    offset ``i * chunk``; nothing R-sized is gathered or transposed.
     The buffers start uninitialized and stay so past the last chunk
     written: the kernel skips those row blocks and clamps their DMAs
     (``chunk`` is a multiple of its row block). No collective may sit
@@ -270,12 +279,15 @@ def _stream_operands(bins, gh, row_leaf, row_gather, live, *, chunk: int,
         _vary_like(jax.lax.empty(shape, dt), vma) for shape, dt in (
             ((n_fb, fc, r_pad), jnp.int32), ((HIST_CH, r_pad), acc_dt),
             ((1, r_pad), jnp.int32)))
+    with profiler.stage(phases.HIST_GATHER):
+        table = _row_table(gh, row_leaf, acc_dt)
+
     def put_chunk(i, bufs):
         s = i * chunk
         with profiler.stage(phases.HIST_GATHER):
             idx = jax.lax.dynamic_slice(idx_all, (s,), (chunk,))
             bb = jnp.take(bins, idx, axis=0)
-            ghb, lb = _gather_rows(gh, row_leaf, idx, s, live[0])
+            ghb, lb = _gather_rows(table, idx, s, live[0], acc_dt)
         with profiler.stage(phases.HIST_RELAYOUT):
             piece = _lane_operands(bb, ghb, lb, chunk, fc=fc, n_fb=n_fb,
                                    acc_dt=acc_dt)

@@ -72,8 +72,11 @@ COUNT = "count"                  # rows in the round's 2W children: one
 COMPACT = "compact"              # membership, n_small, and c_idx as one
 #                                  sort of the row numbers: the making
 #                                  of the index, no row moves
-HIST_GATHER = "hist_gather"      # bins, gh and row_leaf by row_gather: all
-#                                  three, a chunk (pallas) or a block a trip
+HIST_GATHER = "hist_gather"      # a chunk (pallas) or a block a trip, two
+#                                  gathers by row_gather: the bin rows, and
+#                                  the per-row table [R, 4] that carries gh
+#                                  and row_leaf together (assembled once a
+#                                  call, outside the loop, in this stage too)
 HIST_RELAYOUT = "hist_relayout"  # cast, pad, transpose for the kernel; of
 #                                  a compacted stream, chunk by chunk
 HIST_KERNEL = "hist_kernel"      # the pallas_call (or the XLA block loop)

@@ -15,10 +15,14 @@ program span over each. Also checked, and printed as one JSON line
 outside a profiler session, the offset between each
 ``lgbtpu:gbdt.dispatch`` annotation in the xplane's host plane and the
 span ring's record of the same span, the round log's live-row share and
-the share of the touched stream positions that were live, and the
-SHA-256 of the model text (tree 0 and the traced trees; two commits that
-grow the same trees print the same one). The capture (xplane and
-``phase_map.json``), ``model.txt`` and the compiled step's text
+the share of the touched stream positions that were live, how many
+``gather`` instructions the stage map puts under ``hist_gather``
+(``hist_gather_ops``: what a trip of the compacted stream's chunk loop
+fetches by the chunk's index; 3 before the row's leaf rode ``gh``'s
+table, 2 since), and the SHA-256 of the model text (tree 0 and the
+traced trees; two commits that grow the same trees print the same
+one). The capture (xplane and ``phase_map.json``), ``model.txt`` and the
+compiled step's text
 (``step.hlo.txt.gz``: which instruction a device op of the capture is,
 its operands and their memory-space marks) stay under ``--out``.
 """
@@ -55,7 +59,7 @@ def main(argv=None) -> int:
 
     import jax
     import lightgbm_tpu as lgb
-    from lightgbm_tpu import profiler
+    from lightgbm_tpu import phases, profiler
     from lightgbm_tpu.telemetry import costmodel, xprof
     from lightgbm_tpu.telemetry.monitor import render_perf
     job._compile_cache(lgb, jax)
@@ -118,6 +122,9 @@ def main(argv=None) -> int:
         "device": str(jax.devices()[0].device_kind),
         "module": sm.module, "map_instructions": len(sm.stages),
         "mixed_fusions": sm.mixed_fusions,
+        "hist_gather_ops": sum(
+            o.op.opcode == "gather" and o.stage == phases.HIST_GATHER
+            for o in costmodel.staged_ops(text)),
         "step_text_same_under_profiler": same_text,
         "annotations": len(ann), "ring_dispatches": len(ring),
         "annotation_minus_ring": offsets[:4],

@@ -86,22 +86,31 @@ def test_under_shard_map_each_shard_indexes_its_own_rows():
         assert op not in text, op
 
 
-def test_fused_step_makes_the_index_with_one_sort_and_nothing_else():
+@pytest.fixture(scope="module")
+def fused_step_eqns():
+    """``stage -> [equation]`` of the fused step of a small binary
+    booster (traced with ``hist_impl=scatter``: no kernel to lower)."""
+    from lightgbm_tpu.analysis.doctor import (_fused_trace_args,
+                                              make_booster)
+    from lightgbm_tpu.analysis.jaxpr_lint import _iter_scoped
+    from lightgbm_tpu.telemetry.xprof import stage_of_path
+    gb = make_booster("plain", "serial", hist_impl="scatter")._gbdt
+    closed = jax.make_jaxpr(gb._fused_step_entry)(*_fused_trace_args(gb))
+    by_stage = {}
+    for eqn, stack in _iter_scoped(closed.jaxpr):
+        by_stage.setdefault(stage_of_path(stack), []).append(eqn)
+    return by_stage
+
+
+def test_fused_step_makes_the_index_with_one_sort_and_nothing_else(
+        fused_step_eqns):
     """Under the ``compact`` scope of the fused step of a small binary
     booster: one ``sort``, and no scatter, cumsum or ``reduce_window``
     (the formulation PR 33 deleted made the positions by a cumsum and
     wrote the index by an R-sized scatter, which XLA:TPU lowers through
     a sort of its own)."""
-    from lightgbm_tpu.analysis.doctor import (_fused_trace_args,
-                                              make_booster)
-    from lightgbm_tpu.analysis.jaxpr_lint import _iter_scoped
     from lightgbm_tpu.phases import COMPACT
-    from lightgbm_tpu.telemetry.xprof import stage_of_path
-    bst = make_booster("plain", "serial", hist_impl="scatter")
-    gb = bst._gbdt
-    closed = jax.make_jaxpr(gb._fused_step_entry)(*_fused_trace_args(gb))
-    prims = [eqn.primitive.name for eqn, stack in _iter_scoped(closed.jaxpr)
-             if stage_of_path(stack) == COMPACT]
+    prims = [e.primitive.name for e in fused_step_eqns[COMPACT]]
     assert prims.count("sort") == 1, prims
     banned = [p for p in prims
               if p.startswith(("scatter", "cumsum", "cumlogsumexp",
@@ -109,3 +118,46 @@ def test_fused_step_makes_the_index_with_one_sort_and_nothing_else():
     assert not banned, banned
     # the stage is the membership compares, the sort and the count
     assert "reduce_sum" in prims and "eq" in prims, prims
+
+
+def test_fused_step_gathers_twice_a_trip_under_hist_gather(fused_step_eqns):
+    """Under the ``hist_gather`` scope of the same fused step: exactly
+    two ``gather`` equations (the bin rows, and the per-row table that
+    carries ``gh`` and the row's leaf together), none of them reading a
+    1-D row-sized operand, and the table's one concatenate."""
+    from lightgbm_tpu.phases import HIST_GATHER
+    eqns = fused_step_eqns[HIST_GATHER]
+    gathers = [e for e in eqns if e.primitive.name == "gather"]
+    assert len(gathers) == 2, gathers
+    assert [e.invars[0].aval.ndim for e in gathers] == [2, 2]
+    assert {e.outvars[0].aval.shape[1] for e in gathers} >= {4}
+    prims = [e.primitive.name for e in eqns]
+    assert prims.count("concatenate") == 1, prims
+
+
+def test_chunk_loop_under_shard_map_holds_no_collective():
+    """``tree_learner=data``: every shard assembles its table from its
+    own rows and runs its own trip count, so the lowered text of the
+    stream's layout loop names no collective."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four virtual devices")
+    n_sh, R, F, chunk = 4, 2048, 5, 256
+    mesh = Mesh(np.array(jax.devices()[:n_sh]), ("d",))
+
+    def f(bins, gh, rl, rg, n):
+        return PH._stream_operands(bins, gh, rl, rg, n, chunk=chunk, fc=8,
+                                   n_fb=1, acc_dt=jnp.float32)
+    g = jax.jit(jax.shard_map(
+        f, mesh=mesh, in_specs=(P("d"), P("d"), P("d"), P("d"), P("d")),
+        out_specs=(P(None, None, "d"), P(None, "d"), P(None, "d"))))
+    sds = jax.ShapeDtypeStruct
+    text = g.lower(sds((n_sh * R, F), jnp.uint8),
+                   sds((n_sh * R, 3), jnp.float32),
+                   sds((n_sh * R,), jnp.int32), sds((n_sh * R,), jnp.int32),
+                   sds((n_sh,), jnp.int32)).as_text()
+    assert "stablehlo.while" in text and "stablehlo.gather" in text
+    for op in ("all_reduce", "all_gather", "all_to_all",
+               "collective_permute", "reduce_scatter"):
+        assert op not in text, op

@@ -859,7 +859,10 @@ def test_pallas_stream_has_no_row_sized_gather_outside_the_chunk_loop():
     """The pallas branch with row_gather: no gather, convert_element_type
     or transpose equation outside the chunk loop touches an R-sized
     array, there is exactly one loop, its trip count derives from
-    num_rows, and the kernel is still called once."""
+    num_rows, and the kernel is still called once. The one R-sized
+    thing made outside the loop is the per-row table (gh's words and
+    the leaf, one concatenate and one bitcast), and a trip gathers
+    twice: the bin rows and the table's rows."""
     from lightgbm_tpu.ops import pallas_histogram as PH
     R, F, B, L = 1 << 17, 28, 63, 16
     blk = PH._plan(F, B, 3 * L, 2)[0]
@@ -892,6 +895,14 @@ def test_pallas_stream_has_no_row_sized_gather_outside_the_chunk_loop():
             sizes = [int(np.prod(v.aval.shape))
                      for v in list(e.invars) + list(e.outvars)]
             assert max(sizes) < R, (e.primitive.name, sizes)
+    # the table's assembly: gh's float32 words as int32, the leaf beside
+    # them, once
+    cats = [e for e in eqns if e.primitive.name == "concatenate"
+            and e.outvars[0].aval.shape[0] == R]
+    assert [(c.outvars[0].aval.shape, c.outvars[0].aval.dtype)
+            for c in cats] == [((R, 4), jnp.int32)]
+    casts = [e for e in eqns if e.primitive.name == "bitcast_convert_type"]
+    assert [c.invars[0].aval.shape for c in casts] == [(R, 3)]
     # and the loop's body does hold them, chunk-sized
     def flat(jaxpr):
         for e in jaxpr.eqns:
@@ -903,8 +914,50 @@ def test_pallas_stream_has_no_row_sized_gather_outside_the_chunk_loop():
 
     inner = [e for e in flat(loop.params["body_jaxpr"].jaxpr)
              if e.primitive.name == "gather"]
-    assert len(inner) == 3
-    assert {e.outvars[0].aval.shape[0] for e in inner} == {chunk}
+    assert len(inner) == 2
+    assert {e.outvars[0].aval.shape for e in inner} == {(chunk, F),
+                                                        (chunk, 4)}
+    assert not any(v.aval.shape == (R,) for e in inner for v in e.invars)
+
+
+@pytest.mark.parametrize("start", ["0", "chunk-1", "chunk", "chunk+1"])
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+def test_row_table_gather_equals_the_two_takes_it_replaces(quant, start):
+    """``_gather_rows`` over ``_row_table`` against a ``take`` of ``gh``
+    and one of ``row_leaf``: float32 and int8 addends, every 32-bit
+    pattern of the addends (NaN, inf, -0.0, a denormal) and the leaf ids
+    -1, -2, 0 and 131,071 bit for bit, a poisoned dead tail masked to
+    -1, positions counted from ``start`` around a chunk boundary."""
+    from lightgbm_tpu.ops.histogram import _gather_rows, _row_table
+    rng = np.random.RandomState(3)
+    R, chunk = 4096, 256
+    s = {"0": 0, "chunk-1": chunk - 1, "chunk": chunk,
+         "chunk+1": chunk + 1}[start]
+    if quant:
+        gh = rng.randint(-128, 128, size=(R, 3)).astype(np.int8)
+        acc_dt = jnp.int32
+    else:
+        gh = rng.normal(size=(R, 3)).astype(np.float32)
+        gh[:6, 0] = [np.nan, np.inf, -np.inf, -0.0, 1e-45, 3e38]
+        acc_dt = jnp.float32
+    row_leaf = rng.randint(0, 255, size=R).astype(np.int32)
+    row_leaf[:4] = [-1, -2, 0, 131071]
+    idx = np.concatenate([np.arange(8), rng.randint(0, R, size=chunk - 8)]
+                         ).astype(np.int32)
+    num_rows = s + chunk - 5          # the chunk's last five are dead
+    table = jax.jit(_row_table, static_argnums=2)(
+        jnp.asarray(gh), jnp.asarray(row_leaf), acc_dt)
+    assert table.shape == (R, 4) and table.dtype == jnp.int32
+    ghb, lb = jax.jit(_gather_rows, static_argnums=4)(
+        table, jnp.asarray(idx), jnp.int32(s), jnp.int32(num_rows), acc_dt)
+    want_gh = gh[idx].astype(np.dtype(acc_dt))
+    assert ghb.dtype == acc_dt and lb.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(ghb).view(np.int32),
+                                  want_gh.view(np.int32))
+    want_leaf = np.where(s + np.arange(chunk) < num_rows, row_leaf[idx], -1)
+    assert list(want_leaf[:4]) == [-1, -2, 0, 131071]
+    assert list(want_leaf[-5:]) == [-1] * 5
+    np.testing.assert_array_equal(np.asarray(lb), want_leaf)
 
 
 def _exact_tree_case(R=4096, F=8, B=32):
