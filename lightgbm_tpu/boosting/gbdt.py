@@ -34,13 +34,14 @@ from __future__ import annotations
 import collections
 import functools
 import weakref
-from typing import List, NamedTuple, Optional, Sequence, Tuple
+from typing import (Any, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import profiler
+from .. import phases, profiler
 from ..config import Config
 from ..dataset import Dataset
 from ..objectives import Objective
@@ -48,7 +49,8 @@ from ..ops.histogram import (block_rows_for, pallas_shape_reason,
                              resolve_impl)
 from ..ops.split import SplitParams
 from ..tree import Tree
-from .tree_builder import build_tree, TreeArrays
+from .tree_builder import (StepShape, TreeArrays, build_impl, build_tree,
+                           feature_block, step_shape)
 
 __all__ = ["GBDT"]
 
@@ -636,6 +638,11 @@ class GBDT:
         self.round_log: collections.deque = collections.deque(
             maxlen=ROUND_LOG_TREES)
         GBDT._latest = weakref.ref(self)
+        # the fused step's shape (phases.STEP_SHAPE) and, under a
+        # parallel plan, the plan's counters (phases.PLAN_COUNTERS): set
+        # where the step is made (_step_ready), fields of its span too
+        self.step_shape: Optional[Dict[str, int]] = None
+        self.plan_counters: Dict[str, Any] = {}
 
         # numeric-divergence guard (resilience subsystem): the fused
         # step ALWAYS computes the finiteness flag (one program shape
@@ -1094,18 +1101,10 @@ class GBDT:
             if self._bins_cm is None:
                 self._bins_cm = jnp.asarray(self.train_dd.bins.T)
             kw["bins_cm"] = self._bins_cm
-        mono_method = (cfg.monotone_constraints_method
-                       if self.mono_type_pf is not None else "basic")
-        leaf_batch = cfg.leaf_batch
-        if mono_method in ("intermediate", "advanced"):
-            # cross-leaf bound propagation is only sound one split at a
-            # time (see tree_builder.py); the reference learner is
-            # sequential here anyway
-            leaf_batch = 1
-        kw["mono_method"] = mono_method
+        kw["mono_method"] = self._mono_method()
         if self._forced_splits is not None:
             kw["forced"] = self._forced_splits
-            leaf_batch = 1
+        leaf_batch = self._leaf_batch()
         out = builder(
             self.train_dd.bins, gh, self.train_dd.row_leaf0,
             self.num_bins_pf, self.nan_bin_pf, self.is_cat_pf, fmask,
@@ -1124,6 +1123,73 @@ class GBDT:
             self._cegb_feat_used, self._cegb_used_rows = cegb_state
             return tree_arrays, row_leaf, valid_rls, rounds
         return out
+
+    def _mono_method(self) -> str:
+        return (self.config.monotone_constraints_method
+                if self.mono_type_pf is not None else "basic")
+
+    def _leaf_batch(self) -> int:
+        """The ``leaf_batch`` a build runs with: 1 where splits must
+        apply one at a time. Cross-leaf bound propagation is only sound
+        so (see tree_builder.py; the reference learner is sequential
+        there anyway), and forced splits assign node slots in order
+        (the class-batched build never carries them)."""
+        if (self._mono_method() in ("intermediate", "advanced")
+                or self._forced_splits is not None):
+            return 1
+        return self.config.leaf_batch
+
+    def _step_shape(self) -> StepShape:
+        """``tree_builder.step_shape`` of what :meth:`_build_one_tree`
+        (or its class-batched twin) hands the builder: the sizes the
+        traced build takes for itself, for one device. Host integers
+        only."""
+        cfg, plan = self.config, self.plan
+        n = plan.num_shards if plan is not None else 1
+        mode = plan.parallel_mode if plan is not None else None
+        rows = self.train_dd.r_pad
+        columns = self.train_dd.bins.shape[1]
+        features = int(self.num_bins_pf.shape[0])
+        if mode == "feature":
+            # rows replicated, each chip histograms and searches its
+            # slice of the columns (F padded to a multiple of the chips)
+            columns = features = feature_block(features, n)
+        elif plan is not None and plan.rows_sharded:
+            rows //= n
+        bundled = self._bundle_meta is not None
+        return step_shape(
+            rows=rows, stored_columns=columns, features=features,
+            num_bins=self.B,
+            bundle_bins=self._bundle_bins if bundled else 0,
+            num_leaves=cfg.num_leaves, leaf_batch=self._leaf_batch(),
+            hist_impl=build_impl(
+                cfg.hist_impl, (self._bundle_bins if bundled else 0)
+                or self.B, self.class_batch_ok),
+            gh_dtype=jnp.int8 if self._quant else jnp.float32,
+            hist_dtype=cfg.hist_dtype, block_rows=self.block,
+            hist_sub=self._hist_sub, parallel_mode=mode,
+            hist_merge=getattr(plan, "hist_merge", "allreduce"),
+            n_shards=n)
+
+    def stage_work(self, n: Optional[int] = None, *,
+                   fullest: bool = False) -> Dict[str, Tuple[float, str]]:
+        """``{stage: (count, unit)}`` of the last ``n`` trees of
+        ``round_log`` (all it holds by default): what each stage of the
+        fused step worked through, in the units of ``phases.STAGE_WORK``
+        (``telemetry/costmodel.stage_work`` has the counting). One
+        device's: under a row-sharded plan the mean over the shards, or
+        the fullest shard's with ``fullest``. Empty before the first
+        fused step has been made or the first tree fetched."""
+        from ..telemetry.costmodel import stage_work
+        log = list(self.round_log)
+        log = log[-n:] if n else log
+        if self.step_shape is None or not log:
+            return {}
+        return stage_work(
+            self.step_shape, log, plan_bytes=self.plan_counters,
+            pair_slots=(getattr(self.objective, "counters", None)
+                        or {}).get("pair_slots", 0),
+            fullest=fullest)
 
     # -- out-of-core chunked training gate (ISSUE 13) ------------------
 
@@ -1241,16 +1307,11 @@ class GBDT:
             kw["bundle_bins"] = self._bundle_bins
         if self.plan is None and self._gain_scale is not None:
             kw["gain_scale"] = self._gain_scale
-        mono_method = (cfg.monotone_constraints_method
-                       if self.mono_type_pf is not None else "basic")
-        leaf_batch = cfg.leaf_batch
-        if mono_method in ("intermediate", "advanced"):
-            leaf_batch = 1
-        kw["mono_method"] = mono_method
+        kw["mono_method"] = self._mono_method()
         return builder(
             self.train_dd.bins, gh_k, self.train_dd.row_leaf0,
             self.num_bins_pf, self.nan_bin_pf, self.is_cat_pf, fmask,
-            num_leaves=cfg.num_leaves, leaf_batch=leaf_batch,
+            num_leaves=cfg.num_leaves, leaf_batch=self._leaf_batch(),
             max_depth=cfg.max_depth, num_bins=self.B,
             split_params=self.split_params,
             hist_dtype=cfg.hist_dtype, hist_impl=cfg.hist_impl,
@@ -1781,6 +1842,9 @@ class GBDT:
                  "/jax/compilation_cache/cache_hits": "cache_hits",
                  "/jax/compilation_cache/cache_misses": "cache_misses"}
         with profiler.span("gbdt.step_ready") as fields:
+            self.step_shape = self._step_shape().fields()
+            fields.update(self.step_shape)
+
             def on_duration(event, duration, **_):
                 if event in names:
                     key = names[event]
@@ -1799,11 +1863,11 @@ class GBDT:
                     # cached on the arguments' types, so this is the
                     # step's one compile and the call finds it made
                     from ..parallel.comms import plan_counters
-                    n = self.plan.num_shards
-                    fields.update(plan_counters(
-                        self._fused_jit.lower(*args).compile(), n,
-                        self.train_dd.r_pad // n
-                        if self.plan.rows_sharded else self.train_dd.r_pad))
+                    self.plan_counters = plan_counters(
+                        self._fused_jit.lower(*args).compile(),
+                        self.plan.num_shards,
+                        self.step_shape[phases.SHAPE_ROWS])
+                    fields.update(self.plan_counters)
                 return self._fused_jit(*args)
             finally:
                 mon.unregister_event_duration_listener(on_duration)

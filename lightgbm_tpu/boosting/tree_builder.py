@@ -87,9 +87,9 @@ import jax.numpy as jnp
 
 from .. import phases as PHS
 from .. import profiler
-from ..ops.histogram import (build_histograms, resolve_impl, HIST_CH,
-                             merge_histograms, stream_chunk_rows,
-                             stream_trips, _pvary)
+from ..ops.histogram import (build_histograms, effective_impl, HIST_CH,
+                             kernel_plan, merge_histograms,
+                             stream_chunk_rows, stream_trips, _pvary)
 # referenced as a module attribute (PH.build_root_histograms_classes) so
 # tests can monkeypatch interpret-mode wrappers in
 from ..ops import pallas_histogram as PH
@@ -97,7 +97,8 @@ from ..ops.predict import row_feature_gather
 from ..ops.split import (SplitParams, find_best_splits, leaf_gain,
                          leaf_output)
 
-__all__ = ["TreeArrays", "RoundLog", "build_tree", "max_rounds_for"]
+__all__ = ["TreeArrays", "RoundLog", "StepShape", "build_tree",
+           "build_impl", "max_rounds_for", "step_shape"]
 
 NEG_INF = -jnp.inf
 F32_MAX = 3.4e38  # monotone bounds start effectively unconstrained
@@ -145,6 +146,78 @@ def max_rounds_for(num_leaves: int, leaf_batch: int) -> int:
         cur += min(leaf_batch, cur, num_leaves - cur)
         r += 1
     return r
+
+
+class StepShape(NamedTuple):
+    """What one build is sized by, on one device: the values of
+    ``phases.STEP_SHAPE``, in that order (:func:`step_shape`)."""
+    rows: int
+    stored_columns: int
+    stored_bins: int
+    search_positions: int
+    slots: int
+    stream_chunk_rows: int
+    stream_compacted: int
+    kernel_row_block: int
+    kernel_root_row_block: int
+    kernel_feature_chunk: int
+    kernel_chunks: int
+    kernel_padded_bins: int
+    kernel_lanes: int
+    rounds_bound: int
+
+    def fields(self) -> dict:
+        """``{phases.STEP_SHAPE name: int}``: the span's fields."""
+        return dict(zip(PHS.STEP_SHAPE, (int(v) for v in self)))
+
+
+def feature_block(num_features: int, n_shards: int) -> int:
+    """Features a chip searches where the merge is a reduce-scatter along
+    the feature axis: its block of F padded to a multiple of the chips."""
+    return -(-num_features // n_shards)
+
+
+def step_shape(*, rows: int, stored_columns: int, features: int,
+               num_bins: int, bundle_bins: int, num_leaves: int,
+               leaf_batch: int, hist_impl: str, gh_dtype, hist_dtype: str,
+               block_rows: int, hist_sub: bool,
+               parallel_mode: Optional[str] = None,
+               hist_merge: str = "allreduce",
+               n_shards: int = 1) -> StepShape:
+    """The sizes :func:`_build_tree_impl` builds with, from its static
+    arguments alone: host integers, no trace, no device. The builder
+    calls this for its own W, chunk, compaction and loop bound, and the
+    driver calls it with what it hands the builder to put the same
+    numbers on ``gbdt.step_ready`` (``phases.STEP_SHAPE``).
+
+    ``rows`` and ``stored_columns`` are the bin matrix's a device
+    histograms (a shard's rows; bundles under EFB; a chip's column slice
+    under ``parallel_mode='feature'``), ``features`` what its search runs
+    over before any reduce-scatter block (a chip's slice under
+    ``feature``), ``parallel_mode`` None off a mesh."""
+    nb_in = bundle_bins or num_bins
+    impl = effective_impl(hist_impl, nb_in)
+    W = max(1, min(leaf_batch, num_leaves - 1))
+    plan_args = (impl, rows, stored_columns, nb_in)
+    dtypes = (gh_dtype, hist_dtype, block_rows)
+    blk, fc, n_fb, bp, lanes = kernel_plan(*plan_args, W, *dtypes)
+    root_blk = kernel_plan(*plan_args, 2 * W, *dtypes)[0]
+    searched = features
+    if (parallel_mode == "data" and hist_merge == "reduce_scatter"
+            and n_shards > 1 and not bundle_bins):
+        # a bundled matrix scatters bundle columns and searches the
+        # whole feature lattice, zeros outside the bundles it owns
+        searched = feature_block(features, n_shards)
+    return StepShape(
+        rows=rows, stored_columns=stored_columns, stored_bins=nb_in,
+        search_positions=searched * num_bins, slots=2 * W,
+        stream_chunk_rows=stream_chunk_rows(*plan_args, W, *dtypes),
+        # only the native C kernel skips compaction: its partition op
+        # already keeps exact per-leaf row lists
+        stream_compacted=int(bool(hist_sub) and impl != "native"),
+        kernel_row_block=blk, kernel_root_row_block=root_blk,
+        kernel_feature_chunk=fc, kernel_chunks=n_fb, kernel_padded_bins=bp,
+        kernel_lanes=lanes, rounds_bound=max_rounds_for(num_leaves, W))
 
 
 def _round_int(x):
@@ -247,11 +320,21 @@ def relabel_rows(bmat, row_leaf, slots, lane_ok, feat, thr, default_left,
     return jnp.where(active & ~go_left, right_r, row_leaf)
 
 
+def build_impl(hist_impl: str, lattice_bins: int,
+               class_batched: bool = False) -> str:
+    """The histogram formulation a build runs with
+    (``ops.histogram.effective_impl``'s rule over the lattice's width);
+    the native FFI kernels carry no vmap batching rule, so a
+    class-batched build remaps native -> scatter (bit-identical;
+    tests/test_histogram.py native parity)."""
+    impl = effective_impl(hist_impl, lattice_bins)
+    return "scatter" if class_batched and impl == "native" else impl
+
+
 def build_tree(*args, hist_impl: str = "auto", traced: bool = False,
                class_batched: bool = False, **kwargs):
-    """Unjitted entry: resolves ``hist_impl='auto'`` by
-    ``ops.histogram.resolve_impl``'s rule and dispatches to the jitted
-    core. Same contract as :func:`_build_tree_impl` below.
+    """Unjitted entry: resolves ``hist_impl='auto'`` (:func:`build_impl`)
+    and dispatches to the jitted core. Same contract as :func:`_build_tree_impl` below.
 
     ``traced=True`` runs the plain (unjitted) core for callers that are
     ALREADY inside a trace — the fused boosting step of gbdt.py — so the
@@ -262,14 +345,11 @@ def build_tree(*args, hist_impl: str = "auto", traced: bool = False,
     iteration in one program (ISSUE 8): ``gh`` arrives [K, R, 3] (plus
     per-class ``rng_key``/``quant_scales`` when present) and the core is
     vmapped over the class axis — see
-    :func:`_build_tree_class_batched`. The native FFI kernels carry no
-    vmap batching rule, so the batched build remaps native -> scatter
-    (bit-identical; tests/test_histogram.py native parity)."""
-    impl = resolve_impl(hist_impl, kwargs.get("bundle_bins")
-                        or kwargs["num_bins"])
+    :func:`_build_tree_class_batched` (and :func:`build_impl` for the
+    kernel it then runs)."""
+    impl = build_impl(hist_impl, kwargs.get("bundle_bins")
+                      or kwargs["num_bins"], class_batched)
     if class_batched:
-        if impl == "native":
-            impl = "scatter"
         if traced:
             return _build_tree_class_batched(*args, hist_impl=impl,
                                              **kwargs)
@@ -349,7 +429,23 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
       are gathered and psum'd (communication O(top_k·B), not O(F·B));
       the split is chosen from those global sub-histograms.
     """
-    hist_impl = resolve_impl(hist_impl, bundle_bins or num_bins)
+    # trace-time availability check of the native C kernel (a missing
+    # toolchain degrades it to scatter); the call also compiles and
+    # REGISTERS the FFI targets
+    hist_impl = effective_impl(hist_impl, bundle_bins or num_bins)
+    mode = parallel_mode if axis_name is not None else "data"
+    _loc = mode == "feature" and local_bins is not None \
+        and local_meta is not None
+    shape = step_shape(
+        rows=bins.shape[0],
+        stored_columns=(local_bins if _loc else bins).shape[1],
+        features=(local_meta[0] if _loc else num_bins_pf).shape[0],
+        num_bins=num_bins, bundle_bins=bundle_bins if bundle_meta is not None
+        else 0, num_leaves=num_leaves, leaf_batch=leaf_batch,
+        hist_impl=hist_impl, gh_dtype=gh.dtype, hist_dtype=hist_dtype,
+        block_rows=block_rows, hist_sub=hist_sub,
+        parallel_mode=mode if axis_name is not None else None,
+        hist_merge=hist_merge, n_shards=n_shards)
     # Row compaction redirects the row streams through a gathered index
     # order, and everything downstream of the index is bounded by the
     # live rows: the matmul one-hot (R*F*B bf16) and the CPU scatter
@@ -361,7 +457,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     # data_indices saving). Only the native C kernel skips compaction: its
     # partition op already maintains exact per-leaf row lists, so a
     # sort + gather pass over R would cost more than it saves.
-    hist_compact = hist_sub and hist_impl != "native"
+    hist_compact = bool(shape.stream_compacted)
     # native CPU backend: maintain the DataPartition analog — `perm`
     # holds row indices grouped by leaf (leaf_begin/leaf_cnt segments,
     # data_partition.hpp:116 Split semantics) as loop-carried state, so
@@ -369,14 +465,6 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     # histogram op walks exactly the requested children's rows (no scan
     # over R, no per-row branch). Bundled matrices decode bins in
     # feature space and keep the XLA formulation.
-    if hist_impl == "native":
-        # trace-time availability check; the call also compiles and
-        # REGISTERS the FFI targets (build_histograms degrades to
-        # scatter on its own when the toolchain is missing)
-        from .. import native as _native
-        if _native.hist_lib() is None:
-            hist_impl = "scatter"
-            hist_compact = hist_sub
     # sharded feature storage: no device holds the full matrix, so the
     # native CPU partition/relabel (which walk every column) cannot run
     use_native_part = (hist_impl == "native" and bundle_meta is None
@@ -384,7 +472,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     R = bins.shape[0]
     F = num_bins_pf.shape[0]   # per-FEATURE count (bins may be bundled)
     L = num_leaves
-    W = max(1, min(leaf_batch, L - 1))
+    W = shape.slots // 2
     MAXN = 2 * L - 1
     B = num_bins
     DUMMY_LEAF = L          # scatter sink for masked lanes
@@ -538,7 +626,6 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                 "CEGB is single-device only (the reference ties it to "
                 "the serial tree learner too)")
 
-    mode = parallel_mode if axis_name is not None else "data"
     # reduce-scatter merge layouts (ISSUE 4): only meaningful on a mesh
     rs = (axis_name is not None and hist_merge == "reduce_scatter"
           and n_shards > 1)
@@ -553,8 +640,8 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     if rs_data and not use_bundle:
         # feature-slot shard geometry: F padded so it splits evenly;
         # pad features are trivial (1 bin, masked out), never selected
-        F_pad_rs = -(-F // n_shards) * n_shards
-        F_loc_rs = F_pad_rs // n_shards
+        F_loc_rs = feature_block(F, n_shards)
+        F_pad_rs = F_loc_rs * n_shards
         pf_rs = F_pad_rs - F
         nb_rs = jnp.pad(num_bins_pf, (0, pf_rs), constant_values=1)
         nan_rs = jnp.pad(nan_bin_pf, (0, pf_rs), constant_values=-1)
@@ -745,13 +832,10 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         small_is_left = cnt[:W] <= cnt[W:]
         return small_is_left, jnp.where(small_is_left, loc[:W], loc[W:])
 
-    def stream_rows_for(impl, n_live):
+    def stream_rows_for(n_live):
         """Stream positions a compacted round touches for ``n_live``
         live rows (RoundLog.stream_rows)."""
-        mat = local_bins if mode == "feature" else bins
-        chunk = stream_chunk_rows(
-            impl, R, mat.shape[1], bundle_bins if use_bundle else B, W,
-            gh.dtype, hist_dtype, block_rows)
+        chunk = shape.stream_chunk_rows
         return (stream_trips(n_live, chunk, R) * chunk).astype(jnp.int32)
 
     def hist_finish(hraw):
@@ -1293,7 +1377,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         bs_lout = bs_lout.at[0].set(bs0["left_out"][0])
         bs_rout = bs_rout.at[0].set(bs0["right_out"][0])
 
-    rounds_bound = max_rounds_for(L, W)
+    rounds_bound = shape.rounds_bound
     # per-round counters, fetched with the tree (RoundLog). Row-sharded
     # plans count each shard's own stream, so the carry varies over the
     # mesh axis; feature-parallel streams the same rows on every chip.
@@ -1772,7 +1856,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                 stg(PHS.COMPACT)
                 c_idx, n_small = compact_small(row_leaf, small_slots)
                 rows_r = n_small
-                stream_r = stream_rows_for(hist_impl, n_small)
+                stream_r = stream_rows_for(n_small)
                 hsmall = hist_raw_for(small_slots, row_leaf,
                                       row_gather=c_idx, num_rows=n_small)
             else:

@@ -56,7 +56,8 @@ import numpy as np
 from .. import phases, profiler
 
 __all__ = ["build_histograms", "resolve_impl", "pallas_shape_reason",
-           "merge_histograms", "stream_chunk_rows", "stream_trips", "HIST_CH"]
+           "merge_histograms", "stream_chunk_rows", "stream_trips",
+           "kernel_plan", "effective_impl", "HIST_CH"]
 
 # channels per histogram cell: (sum_grad, sum_hess, count)
 HIST_CH = 3
@@ -133,6 +134,39 @@ def stream_trips(num_rows, chunk: int, R: int):
     return jnp.clip((num_rows + chunk - 1) // chunk, 0, -(-R // chunk))
 
 
+def effective_impl(impl: str, num_bins: int) -> str:
+    """:func:`resolve_impl`, and ``scatter`` for a ``native`` whose C
+    toolchain is missing (what every ``native`` path degrades to). The
+    call also compiles and REGISTERS the FFI targets."""
+    impl = resolve_impl(impl, num_bins)
+    if impl == "native":
+        from .. import native as _native
+        if _native.hist_lib() is None:
+            return "scatter"
+    return impl
+
+
+def kernel_plan(impl: str, R: int, F: int, num_bins: int, num_slots: int,
+                gh_dtype, hist_dtype: str, block_rows: int = 0):
+    """``(row_block, feature_chunk, n_chunks, padded_bins, lanes)`` of a
+    :func:`build_histograms` call with these static arguments: the Pallas
+    kernel's own plan (``pallas_histogram._plan``); for matmul and scatter
+    the block loop's row block over one chunk of all ``F`` columns at
+    ``num_bins`` wide; for native a row block of 1 (the C loop stops at
+    its last row). A call multiplies (or adds into) ``rows covered x
+    n_chunks x feature_chunk x padded_bins`` one-hot elements, rows
+    covered being its live rows rounded up to the row block."""
+    impl = effective_impl(impl, num_bins)
+    if impl == "pallas":
+        from . import pallas_histogram as PH
+        cdt, _ = PH._kernel_dtypes(gh_dtype, hist_dtype)
+        return PH._plan(F, num_bins, num_slots * HIST_CH,
+                        jnp.dtype(cdt).itemsize)
+    blk = 1 if impl == "native" else _resolve_block_rows(
+        R, F, num_bins, block_rows)
+    return blk, F, 1, num_bins, num_slots * HIST_CH
+
+
 def stream_chunk_rows(impl: str, R: int, F: int, num_bins: int,
                       num_slots: int, gh_dtype, hist_dtype: str,
                       block_rows: int = 0) -> int:
@@ -142,18 +176,15 @@ def stream_chunk_rows(impl: str, R: int, F: int, num_bins: int,
     chunk`` stream positions. The row block for matmul and scatter, the
     layout loop's chunk for pallas, R for native (the C kernel stops at
     ``num_rows`` itself; the wrapper's compaction before it does not)."""
-    impl = resolve_impl(impl, num_bins)
+    impl = effective_impl(impl, num_bins)
     if impl == "native":
-        from .. import native as _native
-        if _native.hist_lib() is not None:
-            return R
+        return R
+    blk = kernel_plan(impl, R, F, num_bins, num_slots, gh_dtype,
+                      hist_dtype, block_rows)[0]
     if impl == "pallas":
         from . import pallas_histogram as PH
-        cdt, _ = PH._kernel_dtypes(gh_dtype, hist_dtype)
-        blk = PH._plan(F, num_bins, num_slots * HIST_CH,
-                       jnp.dtype(cdt).itemsize)[0]
         return PH.stream_chunk(R, blk)
-    return _resolve_block_rows(R, F, num_bins, block_rows)
+    return blk
 
 
 def _pvary(x, axis_name):

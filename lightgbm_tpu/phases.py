@@ -35,7 +35,17 @@ __all__ = ["GRADS", "SAMPLING", "BUILD", "UPDATE", "EVAL",
            "GRADS_STAGES", "KNOWN_PHASES", "HOST_SPANS",
            "PLAN_SHARDS", "PLAN_ROWS_PER_SHARD",
            "PLAN_COLLECTIVES_PER_ROUND", "PLAN_ROUND_BYTES_BY_STAGE",
-           "PLAN_TREE_BYTES_BY_STAGE", "PLAN_COUNTERS"]
+           "PLAN_TREE_BYTES_BY_STAGE", "PLAN_COUNTERS",
+           "SHAPE_ROWS", "SHAPE_STORED_COLUMNS", "SHAPE_STORED_BINS",
+           "SHAPE_SEARCH_POSITIONS", "SHAPE_SLOTS",
+           "SHAPE_STREAM_CHUNK_ROWS", "SHAPE_STREAM_COMPACTED",
+           "SHAPE_KERNEL_ROW_BLOCK", "SHAPE_KERNEL_ROOT_ROW_BLOCK",
+           "SHAPE_KERNEL_FEATURE_CHUNK", "SHAPE_KERNEL_CHUNKS",
+           "SHAPE_KERNEL_PADDED_BINS", "SHAPE_KERNEL_LANES",
+           "SHAPE_ROUNDS_BOUND", "STEP_SHAPE", "STAGE_WORK",
+           "UNIT_POSITIONS", "UNIT_ELEMENTS", "UNIT_ONEHOT",
+           "UNIT_SORTED", "UNIT_ROW_PASSES", "UNIT_LATTICE", "UNIT_ROWS",
+           "UNIT_PAIR_SLOTS", "UNIT_BYTES"]
 
 # training phases (both drivers, boosting/gbdt.py + engine.train's eval)
 GRADS = "grads"
@@ -114,8 +124,9 @@ HOST_SPANS = frozenset({
     "gbdt.to_device",      # H2D of bins, row_leaf0, labels, weights and
     #                        a ranking objective's lattices
     "gbdt.step_ready",     # first call of the fused step: trace..compile;
-    #                        under a parallel plan its counters
-    #                        (PLAN_COUNTERS) ride on it as fields
+    #                        the step's shape (STEP_SHAPE) rides on it as
+    #                        fields, and under a parallel plan the plan's
+    #                        counters (PLAN_COUNTERS)
     "gbdt.dispatch",       # each fused dispatch
     "gbdt.sync.wait",      # the device_get of the pending ring
     "gbdt.sync.trees",     # host Trees from the fetched ring
@@ -136,6 +147,64 @@ PLAN_TREE_BYTES_BY_STAGE = "plan_tree_bytes_by_stage"        # {stage: B}
 PLAN_COUNTERS = (PLAN_SHARDS, PLAN_ROWS_PER_SHARD,
                  PLAN_COLLECTIVES_PER_ROUND, PLAN_ROUND_BYTES_BY_STAGE,
                  PLAN_TREE_BYTES_BY_STAGE)
+
+# the step's shape: integer fields of the ``gbdt.step_ready`` span under
+# every plan and on one device alike, each computed on the host by the
+# function the traced builder sizes itself with
+# (boosting/tree_builder.step_shape). All are ONE device's.
+SHAPE_ROWS = "shape_rows"                    # rows a device holds, padded
+SHAPE_STORED_COLUMNS = "shape_stored_columns"  # columns of the bin matrix
+#                            the stream gathers and the kernel reads
+#                            (bundles where the matrix is bundled)
+SHAPE_STORED_BINS = "shape_stored_bins"      # bins of that lattice
+SHAPE_SEARCH_POSITIONS = "shape_search_positions"  # positions a slot the
+#                            split search scans: features x bins of the
+#                            widest feature; a chip's own block of the
+#                            features where each chip searches its block
+SHAPE_SLOTS = "shape_slots"                  # lattice slots a round, 2 W
+SHAPE_STREAM_CHUNK_ROWS = "shape_stream_chunk_rows"  # rows a trip of the
+#                            compacted stream's loop
+#                            (ops/histogram.stream_chunk_rows)
+SHAPE_STREAM_COMPACTED = "shape_stream_compacted"  # 1 where a round sorts
+#                            an index and streams the small children's
+#                            rows through it, else 0
+# the histogram kernel's plan (ops/histogram.kernel_plan) of a round's
+# call, and the row block of the root's call (its lanes may differ)
+SHAPE_KERNEL_ROW_BLOCK = "shape_kernel_row_block"
+SHAPE_KERNEL_ROOT_ROW_BLOCK = "shape_kernel_root_row_block"
+SHAPE_KERNEL_FEATURE_CHUNK = "shape_kernel_feature_chunk"
+SHAPE_KERNEL_CHUNKS = "shape_kernel_chunks"
+SHAPE_KERNEL_PADDED_BINS = "shape_kernel_padded_bins"
+SHAPE_KERNEL_LANES = "shape_kernel_lanes"
+SHAPE_ROUNDS_BOUND = "shape_rounds_bound"    # the grow loop's bound
+STEP_SHAPE = (SHAPE_ROWS, SHAPE_STORED_COLUMNS, SHAPE_STORED_BINS,
+              SHAPE_SEARCH_POSITIONS, SHAPE_SLOTS, SHAPE_STREAM_CHUNK_ROWS,
+              SHAPE_STREAM_COMPACTED, SHAPE_KERNEL_ROW_BLOCK,
+              SHAPE_KERNEL_ROOT_ROW_BLOCK, SHAPE_KERNEL_FEATURE_CHUNK,
+              SHAPE_KERNEL_CHUNKS, SHAPE_KERNEL_PADDED_BINS,
+              SHAPE_KERNEL_LANES, SHAPE_ROUNDS_BOUND)
+
+# the unit a stage's work is counted in (telemetry/costmodel.stage_work:
+# the counts come from the round log and the step's shape, at the
+# boundaries of the stage scopes, so that seconds over count is what one
+# unit costs). A stage that is not here has no count.
+UNIT_POSITIONS = "stream_positions"    # gathered through the index
+UNIT_ELEMENTS = "relaid_elements"      # positions x stored columns
+UNIT_ONEHOT = "onehot_elements"        # sent through the MXU, padding too
+UNIT_SORTED = "sorted_elements"        # of the one sort a round
+UNIT_ROW_PASSES = "row_passes"         # one elementwise pass over a row
+UNIT_LATTICE = "lattice_positions"     # slots x positions a slot
+UNIT_ROWS = "rows"                     # rows x trees
+UNIT_PAIR_SLOTS = "pair_slots"         # a ranking objective's layout
+UNIT_BYTES = "wire_bytes"              # one chip's, ring estimates
+STAGE_WORK = {
+    HIST_GATHER: UNIT_POSITIONS, HIST_RELAYOUT: UNIT_ELEMENTS,
+    HIST_KERNEL: UNIT_ONEHOT, COMPACT: UNIT_SORTED,
+    APPLY: UNIT_ROW_PASSES, COUNT: UNIT_ROW_PASSES,
+    FIND: UNIT_LATTICE, SUBTRACT: UNIT_LATTICE, UNBUNDLE: UNIT_LATTICE,
+    ROOT_PASS: UNIT_LATTICE, UPDATE: UNIT_ROWS, GRADS: UNIT_ROWS,
+    RANK_PAIRS: UNIT_PAIR_SLOTS, HIST_MERGE: UNIT_BYTES,
+    WINNER_SYNC: UNIT_BYTES}
 
 TRAIN_PHASES = frozenset({GRADS, SAMPLING, BUILD, UPDATE, EVAL})
 INGEST_PHASES = frozenset({INGEST_SKETCH, INGEST_WRITE, PREFETCH})

@@ -59,7 +59,7 @@ from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 from .phases import HOST_SPANS, KNOWN_PHASES
 
-__all__ = ["trace", "step_annotation", "annotate", "stage",
+__all__ = ["trace", "step_annotation", "stage",
            "stage_sequence", "phase", "span", "Span",
            "SpanRecorder", "recorder", "PhaseTotals",
            "collect_phase_totals", "ANNOTATION_PREFIX"]
@@ -86,12 +86,6 @@ def step_annotation(name: str, step_num: Optional[int] = None):
     import jax
     kwargs = {} if step_num is None else {"step_num": step_num}
     return jax.profiler.StepTraceAnnotation(name, **kwargs)
-
-
-def annotate(name: str):
-    """Named sub-scope inside a step (global_timer sections analog)."""
-    import jax
-    return jax.profiler.TraceAnnotation(name)
 
 
 # ----------------------------------------------------------------------

@@ -161,6 +161,19 @@ class TelemetrySession:
             self._perf_want = True
         return dict(self._phase_maps or {})
 
+    def step_work(self, iterations: int) -> Optional[Dict[str, Any]]:
+        """A capture's ``step_work`` sidecar entry
+        (``xprof.step_work_of``) for ``iterations`` captured
+        iterations: the step's shape and the work of as many of the
+        newest logged trees. Trees still in the pending ring are not in
+        the round log yet; the newest ones that are stand in for them.
+        Reads host state only, so it is safe off the training thread."""
+        from . import xprof
+        gb = self._gb()
+        if gb is None:
+            return None
+        return xprof.step_work_of(gb, iterations * max(int(gb.K), 1))
+
     def _build_perf(self) -> None:
         """Build the fused step's stage map (training thread, at a sync
         point). force=False: uses the driver's already-traced jit,
@@ -210,7 +223,8 @@ class TelemetrySession:
                 health_fn=self._health,
                 port=int(self._want_port),
                 capture_root=capture_root,
-                phase_map_fn=self.phase_maps)
+                phase_map_fn=self.phase_maps,
+                step_work_fn=self.step_work)
             try:
                 self.port = self.server.start()
             except OSError as e:

@@ -8,45 +8,27 @@ of a compiled module under the canonical stage of ``phases.py`` whose
 source line; the trace parser (``xprof.py``) and the benchmark's stage
 readers attribute device time through it.
 
-Also the analytical FLOP/byte count of one histogram build
-(`analytical_hist_counts`) with XLA's own price of the same work to
-hold it to (`hist_xla_cost`, within 2x), and the chip peak table
-(``TPU_PEAKS``) a roofline share is stated against.
+Beside it a stage's work (`stage_work`): what each stage worked through
+over some trees, counted from the round log and the step's shape in the
+units of ``phases.STAGE_WORK``, so that a stage's device seconds have a
+count to be divided by.
 """
 
 from __future__ import annotations
 
 import re
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
+import numpy as np
+
+from .. import phases as PHS
 from ..analysis.hlo_walk import parse_all_ops
 from .xprof import stage_of_path
 
-__all__ = ["TPU_PEAKS", "ChipPeaks", "HIST_CH",
-           "instruction_phase_map", "StageMap", "module_name",
-           "fused_compiled", "booster_phase_maps", "analytical_hist_counts",
-           "kernel_roofline_fields", "roofline_utilization",
-           "hist_xla_cost", "chip_peaks"]
+__all__ = ["instruction_phase_map", "StageMap", "module_name",
+           "fused_compiled", "booster_phase_maps", "staged_ops",
+           "stage_work"]
 
-
-class ChipPeaks(NamedTuple):
-    kind: str            # jax.devices()[0].device_kind, verbatim
-    bf16_tflops: float
-    int8_tops: float
-    hbm_gbps: float
-
-
-# Published per-chip peaks keyed by the EXACT ``device_kind`` the
-# installed runtime reports (jax 0.9.0 / libtpu 0.0.34 name a v5e chip
-# "TPU v5 lite"). Source: Google Cloud documentation, "TPU v5e" system
-# architecture — 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at
-# 819 GB/s. A TPU that is not in the table is an error, not a default.
-TPU_PEAKS = {p.kind: p for p in (
-    ChipPeaks("TPU v5 lite", 197.0, 393.0, 819.0),
-)}
-
-# histogram channels: (grad, hess, count)
-HIST_CH = 3
 
 _MODULE_RE = re.compile(r"^HloModule\s+([^\s,]+)", re.MULTILINE)
 _COMP_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.-]+)\s*\(.*\)\s*->"
@@ -60,104 +42,90 @@ _NOOP_OPCODES = frozenset({"parameter", "constant", "tuple",
                            "get-tuple-element", "bitcast"})
 
 
-def chip_peaks() -> Optional[ChipPeaks]:
-    """Peaks of device 0: None off-TPU (a CPU host has no roofline to
-    state), the table row for a known ``device_kind``, and LookupError
-    for a TPU the table does not know — every roofline field is a
-    ratio against these numbers, so there is no honest default."""
-    import jax
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        return None
-    try:
-        return TPU_PEAKS[dev.device_kind]
-    except KeyError:
-        raise LookupError(
-            f"no published peaks for TPU device_kind "
-            f"{dev.device_kind!r}; add it to costmodel.TPU_PEAKS with "
-            f"its source (known: {sorted(TPU_PEAKS)})") from None
+def _ceil_to(x, m: int):
+    return -(-x // m) * m
 
 
-# ----------------------------------------------------------------------
-# Analytical histogram-kernel counts
+def stage_work(shape: Dict[str, int], log: Sequence[Any], *,
+               plan_bytes: Optional[Dict[str, Any]] = None,
+               pair_slots: int = 0,
+               fullest: bool = False) -> Dict[str, Tuple[float, str]]:
+    """``{stage: (count, unit)}``: what each stage of the fused step worked
+    through over the trees of ``log`` (``GBDT.round_log`` records), from
+    the round log and the step's shape (``phases.STEP_SHAPE`` fields,
+    ``shape``) alone, in the units ``phases.STAGE_WORK`` fixes. Counted
+    at the boundaries of the stage scopes, so that a stage's device
+    seconds over its count is what one unit costs:
 
-def analytical_hist_counts(R: int, F: int, B: int,
-                           L: int) -> Tuple[float, float]:
-    """(flops, bytes) of one histogram build as hand-derived: FLOPs
-    count the one-hot matmul as executed on the MXU
-    (2·R·(F·B)·(L·CH)); bytes count the irreducible streams (bins
-    uint8 + gh f32 in, hist f32 out)."""
-    flops = 2.0 * R * (F * B) * (L * HIST_CH)
-    bytes_ = R * F + R * HIST_CH * 4 + F * B * L * HIST_CH * 4
-    return flops, bytes_
+    - ``hist_gather``: stream positions gathered, ``sum(stream_rows)``;
+    - ``hist_relayout``: elements re-laid, positions x stored columns,
+      plus the root's rows x stored columns a tree;
+    - ``hist_kernel``: one-hot elements sent through the MXU: the rows
+      the kernel's grid steps cover (a round's live rows rounded up to
+      the plan's row block, the root's padded rows a tree) x chunks x
+      feature chunk x padded bins. Padding of bins, features and the row
+      block is counted (the pass pays for it); positions of a chunk past
+      the last live row block are not (the kernel skips them);
+    - ``compact``: elements sorted, rounds x rows, where the step
+      compacts; ``apply``, ``count``: row passes, rounds x rows each;
+    - ``find``, ``subtract``: lattice positions scanned, rounds x slots x
+      positions a slot; ``unbundle`` the same plus the root's (its scope
+      is open at the root too); ``root_pass``: the root's scan, trees x
+      slots x positions a slot;
+    - ``update``, ``grads``: rows x trees; ``rank_pairs``: a ranking
+      objective's ``pair_slots`` x trees;
+    - ``hist_merge``, ``winner_sync``: bytes one chip puts on the wire,
+      rounds x the plan's bytes a round plus trees x its bytes a tree
+      (``plan_bytes``: the ``phases.PLAN_COUNTERS`` of the step).
 
-
-def roofline_utilization(tflops: float, gbps: float) -> Dict[str, Any]:
-    """MFU / HBM utilization vs the chip peak, when on a known TPU."""
-    peaks = chip_peaks()
-    if peaks is None:
-        return {}
-    return {"hist_mfu": round(tflops / peaks.bf16_tflops, 4),
-            "hist_hbm_util": round(gbps / peaks.hbm_gbps, 4),
-            "chip": peaks.kind}
-
-
-def kernel_roofline_fields(platform: str, t_hist_s: float,
-                           R: int, F: int, B: int, L: int) -> dict:
-    """Derived FLOP/s + HBM bandwidth for one histogram build vs chip
-    peak. Off-TPU the achieved-rate fields are still emitted, labelled
-    by `platform`, with no peak comparison."""
-    flops, bytes_ = analytical_hist_counts(R, F, B, L)
-    out = {"hist_tflops": round(flops / t_hist_s / 1e12, 3),
-           "hist_hbm_gbps": round(bytes_ / t_hist_s / 1e9, 2)}
-    if platform == "tpu":
-        out.update(roofline_utilization(out["hist_tflops"],
-                                        out["hist_hbm_gbps"]))
-    return out
-
-
-def _cost_dict(compiled) -> Dict[str, float]:
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:  # noqa: BLE001 — backend may not implement it
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if isinstance(ca, dict) else {}
-
-
-def hist_xla_cost(R: int, F: int, B: int, L: int, *,
-                  impl: str = "matmul",
-                  hist_dtype: str = "bfloat16") -> Dict[str, float]:
-    """XLA's own price of one histogram build: compile
-    ``ops.histogram.build_histograms`` at the given lattice and read
-    ``cost_analysis``. ``impl='matmul'`` is the formulation the
-    analytical count models (one-hot MXU matmul), so these two must
-    agree within 2x (``tests/test_perf_observability.py`` asserts it).
-
-    Compiled with ``block_rows=R`` (one block): ``cost_analysis``
-    prices a while-loop body ONCE regardless of trip count, so the
-    production row-chunked program under-reports total flops by the
-    number of blocks. The unchunked program does the same logical work
-    in straight-line HLO, which is what both the analytical count and
-    a measured wall-clock divide against."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..ops.histogram import build_histograms
-    bins = jnp.zeros((R, F), jnp.uint8)
-    gh = jnp.zeros((R, HIST_CH), jnp.float32)
-    rl = jnp.zeros((R,), jnp.int32)
-    lids = jnp.arange(L, dtype=jnp.int32)
-
-    def fn(b, g, r, li):
-        return build_histograms(b, g, r, li, num_bins=B,
-                                hist_dtype=hist_dtype, impl=impl,
-                                block_rows=R)
-    compiled = jax.jit(fn).lower(bins, gh, rl, lids).compile()
-    ca = _cost_dict(compiled)
-    return {"flops": float(ca.get("flops", 0.0)),
-            "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
+    A round is one that built for at least one leaf. All counts are ONE
+    device's. Under a row-sharded plan the log is ``[n_shards, rounds]``:
+    the counts are the mean over the shards (what the chips' mean seconds
+    go with), or the fullest shard's with ``fullest`` (the shard a round
+    waits for)."""
+    rows = shape[PHS.SHAPE_ROWS]
+    cols = shape[PHS.SHAPE_STORED_COLUMNS]
+    blk = shape[PHS.SHAPE_KERNEL_ROW_BLOCK]
+    a_row = (shape[PHS.SHAPE_KERNEL_CHUNKS]
+             * shape[PHS.SHAPE_KERNEL_FEATURE_CHUNK]
+             * shape[PHS.SHAPE_KERNEL_PADDED_BINS])
+    scan = shape[PHS.SHAPE_SLOTS] * shape[PHS.SHAPE_SEARCH_POSITIONS]
+    trees = len(log)
+    rounds, positions, covered = 0, 0.0, 0.0
+    for rec in log:
+        built = np.asarray(rec.leaves) > 0
+        rounds += int(built.sum())
+        # [shards, rounds] (one shard where the log keeps no shard axis)
+        live = np.asarray(rec.rows).reshape(-1, built.shape[-1])[:, built]
+        stream = np.asarray(rec.stream_rows).reshape(
+            -1, built.shape[-1])[:, built]
+        over = np.max if fullest else np.mean
+        positions += float(over(stream.sum(axis=1, dtype=np.int64)))
+        covered += float(over(
+            _ceil_to(live.astype(np.int64), blk).sum(axis=1)))
+    root_rows = _ceil_to(rows, shape[PHS.SHAPE_KERNEL_ROOT_ROW_BLOCK])
+    counts = {
+        PHS.HIST_GATHER: positions,
+        PHS.HIST_RELAYOUT: (positions + trees * rows) * cols,
+        PHS.HIST_KERNEL: (covered + trees * root_rows) * a_row,
+        PHS.COMPACT: rounds * rows * shape[PHS.SHAPE_STREAM_COMPACTED],
+        PHS.APPLY: rounds * rows,
+        PHS.COUNT: rounds * rows,
+        PHS.FIND: rounds * scan,
+        PHS.SUBTRACT: rounds * scan,
+        PHS.UNBUNDLE: (rounds + trees) * scan,
+        PHS.ROOT_PASS: trees * scan,
+        PHS.UPDATE: trees * rows,
+        PHS.GRADS: trees * rows,
+        PHS.RANK_PAIRS: trees * int(pair_slots),
+    }
+    a_round = (plan_bytes or {}).get(PHS.PLAN_ROUND_BYTES_BY_STAGE) or {}
+    a_tree = (plan_bytes or {}).get(PHS.PLAN_TREE_BYTES_BY_STAGE) or {}
+    for stage in PHS.COLLECTIVE_PHASES:
+        counts[stage] = (rounds * a_round.get(stage, 0)
+                         + trees * a_tree.get(stage, 0))
+    return {stage: (count, PHS.STAGE_WORK[stage])
+            for stage, count in counts.items() if count}
 
 
 # ----------------------------------------------------------------------

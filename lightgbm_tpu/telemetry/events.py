@@ -57,7 +57,6 @@ EVENT_TYPES: Dict[str, tuple] = {
     "log": ("level", "msg"),
     "serving": ("action", "model"),
     "train_end": ("iter", "trees", "wall_s"),
-    "cost_model": ("label", "flops", "bytes_accessed"),
     # out-of-core ingest (data/ingest.py): one record per completed
     # pass; shard writes are individually atomic so the log is
     # observability, not recovery state

@@ -64,7 +64,9 @@ class IntrospectionServer:
                  capture_root: Optional[str] = None,
                  phase_map_fn: Optional[
                      Callable[[], Dict[str, Dict[str, str]]]] = None,
-                 keep_captures: int = 4):
+                 keep_captures: int = 4,
+                 step_work_fn: Optional[
+                     Callable[[int], Optional[dict]]] = None):
         self.registry = registry
         self.event_log = event_log
         self.health_fn = health_fn
@@ -80,6 +82,9 @@ class IntrospectionServer:
         # the fused jit, and doing that from this HTTP thread would
         # race a concurrent dispatch's trace-time attribute rebinding.
         self.phase_map_fn = phase_map_fn
+        # the sidecar's step_work entry for a capture of N iterations
+        # (the step's shape and the work of its trees); host state only
+        self.step_work_fn = step_work_fn
         self.keep_captures = max(1, int(keep_captures))
         self._httpd: Optional[ThreadingHTTPServer] = None
         self._thread: Optional[threading.Thread] = None
@@ -181,6 +186,12 @@ class IntrospectionServer:
                     xprof.save_phase_map(log_dir, maps)
                 prof = xprof.parse_trace(log_dir,
                                          phase_maps=maps or None)
+                if maps and self.step_work_fn is not None:
+                    # how many trees the capture holds is known only
+                    # now: the sidecar is written again with their work
+                    prof.step_work = self.step_work_fn(
+                        prof.iterations()) or {}
+                    xprof.save_phase_map(log_dir, maps, prof.step_work)
                 resp.update(prof.summary_dict())
             except Exception as e:  # noqa: BLE001 — the capture is
                 # still on disk and usable offline even if parsing it

@@ -168,7 +168,8 @@ def find_captures(target: str) -> List[str]:
 
 
 def render_perf(capture: str,
-                records: Optional[List[Dict[str, Any]]] = None) -> str:
+                records: Optional[List[Dict[str, Any]]] = None,
+                prof=None) -> str:
     """``monitor --perf``: one capture reduced by ``xprof.parse_trace``
     with the saved ``phase_map.json`` — device seconds by stage (a tree,
     where the capture holds step markers or dispatch spans), the ten
@@ -182,7 +183,8 @@ def render_perf(capture: str,
     from . import xprof
     out: List[str] = [f"-- capture {capture} --"]
     try:
-        prof = xprof.parse_trace(capture)
+        if prof is None:    # (a caller that parsed it already hands it in)
+            prof = xprof.parse_trace(capture)
     except (FileNotFoundError, ValueError) as e:
         return "\n".join(out + [f"  unparseable: {e}"])
     out.append(prof.render())

@@ -28,7 +28,10 @@ Attribution, per device event:
    and looked up by (module, instruction name). It is the only road
    from a device event to a source scope. Captures taken through the
    telemetry server save it as ``phase_map.json`` next to the trace so
-   offline ``monitor --perf`` has it.
+   offline ``monitor --perf`` has it; the same sidecar holds, under
+   ``step_work``, the step's shape and the count of each stage's work
+   over the captured trees (:func:`step_work_of`), which puts a count,
+   its unit and the cost of one unit beside a stage's seconds.
 2. **Host-span overlap** — the legacy driver dispatches one program per
    phase under a host phase span, so what the map misses is attributed
    to the host phase span(s) it overlaps.
@@ -61,10 +64,12 @@ from ..profiler import ANNOTATION_PREFIX
 __all__ = ["PhaseProfile", "OpEvent", "HostSpan", "Capture", "parse_trace",
            "reduce_capture", "load_xplane", "load_trace_json",
            "find_trace_files", "save_phase_map", "load_phase_map",
+           "step_work_of",
            "find_phase_map", "stage_of_path", "instruction_of",
            "PHASE_MAP_NAME", "UNKNOWN"]
 
 PHASE_MAP_NAME = "phase_map.json"
+STEP_WORK_KEY = "step_work"     # the sidecar's one entry that is no module
 UNKNOWN = "unknown"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -152,25 +157,51 @@ def find_trace_files(source: str) -> List[str]:
                      if os.path.dirname(p) not in dirs]
 
 
-def save_phase_map(log_dir: str, maps: Dict[str, Any]) -> str:
+def save_phase_map(log_dir: str, maps: Dict[str, Any],
+                   step_work: Optional[Dict[str, Any]] = None) -> str:
     """Write the stage maps next to a capture so offline parsers can
-    attribute its events."""
+    attribute its events; with ``step_work`` (:func:`step_work_of`) also
+    the step's shape and the work of the captured trees, so that they can
+    put a count and a cost a unit beside a stage's seconds."""
     doc = {m: {"stages": sm.stages, "scopes": sm.scopes,
                "mixed_fusions": sm.mixed_fusions}
            if hasattr(sm, "stages") else dict(sm)
            for m, sm in maps.items()}
+    if step_work:
+        doc[STEP_WORK_KEY] = step_work
     path = os.path.join(log_dir, PHASE_MAP_NAME)
     with open(path, "w", encoding="utf-8") as f:
         json.dump(doc, f, sort_keys=True)
     return path
 
 
-def load_phase_map(path: str) -> Dict[str, Any]:
+def step_work_of(trainer, trees: int) -> Optional[Dict[str, Any]]:
+    """The sidecar's ``step_work`` entry for a capture of ``trees`` trees:
+    the trainer's step shape (``phases.STEP_SHAPE``) and the work of the
+    newest ``trees`` trees its round log holds (``GBDT.stage_work``).
+    None where the trainer has neither yet."""
+    shape = getattr(trainer, "step_shape", None)
+    held = min(int(trees), len(getattr(trainer, "round_log", ())))
+    if not shape or held <= 0:
+        return None
+    return {"step_shape": dict(shape), "trees": held,
+            "stage_work": {k: [c, u] for k, (c, u) in
+                           trainer.stage_work(held).items()}}
+
+
+def _load_sidecar(path: str) -> Dict[str, Any]:
     with open(path, "r", encoding="utf-8") as f:
         return dict(json.load(f) or {})
 
 
-def find_phase_map(trace_file: str, max_up: int = 4) -> Dict[str, Any]:
+def load_phase_map(path: str) -> Dict[str, Any]:
+    """The stage maps of a sidecar file, by module."""
+    doc = _load_sidecar(path)
+    doc.pop(STEP_WORK_KEY, None)
+    return doc
+
+
+def _find_sidecar(trace_file: str, max_up: int = 4) -> Dict[str, Any]:
     """Walk up from a trace file looking for ``phase_map.json`` (the
     capture root is a few levels above ``plugins/profile/<ts>/``)."""
     d = os.path.dirname(os.path.abspath(trace_file))
@@ -178,7 +209,7 @@ def find_phase_map(trace_file: str, max_up: int = 4) -> Dict[str, Any]:
         cand = os.path.join(d, PHASE_MAP_NAME)
         if os.path.isfile(cand):
             try:
-                return load_phase_map(cand)
+                return _load_sidecar(cand)
             except (OSError, ValueError):
                 return {}
         parent = os.path.dirname(d)
@@ -186,6 +217,13 @@ def find_phase_map(trace_file: str, max_up: int = 4) -> Dict[str, Any]:
             break
         d = parent
     return {}
+
+
+def find_phase_map(trace_file: str, max_up: int = 4) -> Dict[str, Any]:
+    """The stage maps of the sidecar found above a trace file."""
+    doc = _find_sidecar(trace_file, max_up)
+    doc.pop(STEP_WORK_KEY, None)
+    return doc
 
 
 class _Lookup:
@@ -450,6 +488,9 @@ class PhaseProfile:
     dispatches: int = 0       # lgbtpu:gbdt.dispatch spans in the capture
     mixed_fusions: int = 0    # of the stage maps used
     epoch_ns: Optional[int] = None
+    # the sidecar's ``step_work`` entry (:func:`step_work_of`): the step's
+    # shape, and {stage: [count, unit]} over ``trees`` trees, one device's
+    step_work: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     def iterations(self) -> int:
         """Trees of the capture: its ``boost_iter`` markers, else its
@@ -470,6 +511,20 @@ class PhaseProfile:
     def unknown_share(self) -> float:
         tot = sum(self.device_phase_s.values())
         return self.device_phase_s.get(UNKNOWN, 0.0) / tot if tot else 0.0
+
+    def stage_costs(self) -> Dict[str, Tuple[float, str, float]]:
+        """``{stage: (count, unit, seconds a unit)}`` for the stages the
+        sidecar counted work for and the capture has device seconds of:
+        a device's seconds (the devices' mean) over a device's count.
+        Empty without the sidecar's ``step_work``."""
+        devices = max(len(self.per_device), 1)
+        out = {}
+        for stage, (count, unit) in sorted(
+                (self.step_work.get("stage_work") or {}).items()):
+            dv = self.device_phase_s.get(stage, 0.0) / devices
+            if count > 0 and dv > 0:
+                out[stage] = (count, unit, dv / count)
+        return out
 
     def summary_dict(self) -> Dict[str, Any]:
         """JSON-ready summary (the ``/trace`` response body)."""
@@ -495,6 +550,13 @@ class PhaseProfile:
                                       for k, v in sorted(per_iter.items())}
             d["dispatch_gap_s_per_iter"] = round(
                 self.dispatch_gap_s / max(self.iterations(), 1), 6)
+        costs = self.stage_costs()
+        if costs:
+            d["step_shape"] = dict(self.step_work.get("step_shape") or {})
+            d["work_trees"] = self.step_work.get("trees")
+            d["stage_work"] = {
+                k: {"count": c, "unit": u, "ns_per_unit": s * 1e9}
+                for k, (c, u, s) in costs.items()}
         return d
 
     def render(self) -> str:
@@ -507,10 +569,13 @@ class PhaseProfile:
         names = sorted(set(self.device_phase_s) | set(self.host_phase_s),
                        key=lambda k: -self.device_phase_s.get(k, 0.0))
         tot = sum(self.device_phase_s.values())
+        costs = self.stage_costs()
         if names:
             rows.append(f"  {'stage':<16} {'device ms':>12} {'share':>7} "
                         f"{'host ms':>12}"
-                        + (f" {'device ms/iter':>16}" if its else ""))
+                        + (f" {'device ms/iter':>16}" if its else "")
+                        + (f" {'count':>16} {'unit':<17} {'a unit':>10}"
+                           if costs else ""))
             for name in names:
                 dv = self.device_phase_s.get(name, 0.0)
                 hv = self.host_phase_s.get(name, 0.0) * 1e3
@@ -519,7 +584,17 @@ class PhaseProfile:
                         f"{hv:12.3f}")
                 if its:
                     line += f" {dv * 1e3 / its:16.4f}"
+                if name in costs:
+                    count, unit, s = costs[name]
+                    line += (f" {count:16.6g} {unit:<17} "
+                             f"{_unit_cost(s):>10}")
                 rows.append(line)
+            if costs:
+                rows.append(
+                    f"  counts: one device's work over "
+                    f"{self.step_work.get('trees')} tree(s), from the "
+                    "round log and the step's shape; a unit = the "
+                    "devices' mean seconds / count")
         rows.append(f"  device busy {self.device_busy_s * 1e3:.3f} ms, "
                     f"self time by stage {tot * 1e3:.3f} ms "
                     f"({UNKNOWN} {100.0 * self.unknown_share():.2f}%), "
@@ -542,6 +617,14 @@ class PhaseProfile:
             for dev, sp, s in self.idle_gaps:
                 rows.append(f"    {s * 1e3:12.3f} ms  {dev}  {sp}")
         return "\n".join(rows)
+
+
+def _unit_cost(seconds: float) -> str:
+    """Seconds a unit at the scale they read at: ``1.63 ps``."""
+    for scale, name in ((1e-6, "us"), (1e-9, "ns")):
+        if seconds >= scale:
+            return f"{seconds / scale:.3f} {name}"
+    return f"{seconds / 1e-12:.3f} ps"
 
 
 def _is_wrapper(name: str) -> bool:
@@ -680,14 +763,16 @@ def parse_trace(source: str,
                 ) -> PhaseProfile:
     """Parse one capture (file, log dir, or run dir — every capture file
     found under ``source`` merges into one profile). ``phase_maps``
-    overrides the per-capture ``phase_map.json`` discovery."""
+    overrides the stage maps of the per-capture ``phase_map.json``
+    discovery; the sidecar's ``step_work`` entry is read either way."""
     files = find_trace_files(source)
     if not files:
         raise FileNotFoundError(f"no profiler capture under {source!r}")
-    maps = phase_maps
-    if maps is None:
-        maps = {}
-        for path in files:
-            maps.update(find_phase_map(path))
-    return reduce_capture(merge_captures([load_capture(p) for p in files]),
-                          maps)
+    found: Dict[str, Any] = {}
+    for path in files:
+        found.update(_find_sidecar(path))
+    step_work = found.pop(STEP_WORK_KEY, None) or {}
+    prof = reduce_capture(merge_captures([load_capture(p) for p in files]),
+                          found if phase_maps is None else phase_maps)
+    prof.step_work = step_work
+    return prof

@@ -10,7 +10,9 @@ from the compiled module, traces ``--trees`` more trees under the
 profiler, and prints what ``python -m lightgbm_tpu monitor --perf``
 prints for the capture: device seconds by stage, the ten longest
 instructions with stage and source scope, the longest idle gaps with the
-program span over each. Also checked, and printed as one JSON line
+program span over each, and beside a stage's seconds the count of its work over
+the traced trees (``GBDT.stage_work``), its unit and what one unit costs.
+Also checked, and printed as one JSON line
 (``stage_trace:``): that the compiled step's text is the same inside and
 outside a profiler session, the offset between each
 ``lgbtpu:gbdt.dispatch`` annotation in the xplane's host plane and the
@@ -21,7 +23,8 @@ the share of the touched stream positions that were live, how many
 fetches by the chunk's index; 3 before the row's leaf rode ``gh``'s
 table, 2 since), and the SHA-256 of the model text (tree 0 and the
 traced trees; two commits that grow the same trees print the same
-one). The capture (xplane and ``phase_map.json``), ``model.txt`` and the
+one). The capture (xplane and ``phase_map.json``, which also holds the
+step's shape and that work), ``model.txt`` and the
 compiled step's text
 (``step.hlo.txt.gz``: which instruction a device op of the capture is,
 its operands and their memory-space marks) stay under ``--out``.
@@ -91,8 +94,11 @@ def main(argv=None) -> int:
         hashlib.sha256(text.encode()).hexdigest()
     jax.profiler.stop_trace()
     bst._sync_trees()
-    xprof.save_phase_map(args.out, maps)
-    print(render_perf(args.out), flush=True)
+    xprof.save_phase_map(args.out, maps,
+                         xprof.step_work_of(gb, args.trees))
+    prof = xprof.parse_trace(args.out)
+    print(render_perf(args.out, prof=prof), flush=True)
+    costs = prof.stage_costs()
 
     # the annotations against the ring, span by span
     plane = sorted(glob.glob(os.path.join(
@@ -132,6 +138,9 @@ def main(argv=None) -> int:
         "live_row_share_pct": 100.0 * live / max(
             rounds * cfg["shape"]["rows"], 1),
         "stream_row_share_pct": 100.0 * live / touched if touched else None,
+        "step_shape": gb.step_shape,
+        "stage_count_unit_ns": {k: [c, u, s * 1e9]
+                                for k, (c, u, s) in costs.items()},
         "model_sha256": hashlib.sha256(model_text.encode()).hexdigest(),
         "host_sync_count": gb.host_sync_count}), flush=True)
     with gzip.open(os.path.join(args.out, "step.hlo.txt.gz"), "wt") as g:

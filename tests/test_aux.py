@@ -117,8 +117,6 @@ def test_tree_digraph_dot_source(booster):
 
 def test_profiler_annotations_smoke(booster, rng, tmp_path):
     import lightgbm_tpu.profiler as prof
-    with prof.annotate("scope"):
-        pass
     with prof.step_annotation("step", step_num=3):
         pass
 

@@ -1,6 +1,5 @@
 """Performance-observability subsystem: xprof trace parsing against the
-golden fixture, phase-totals thread safety, capture retention and the
-cost-model cross-check."""
+golden fixture, phase-totals thread safety and capture retention."""
 
 import json
 import os
@@ -436,23 +435,6 @@ def test_trace_endpoint_500_on_capture_error(monkeypatch, tmp_path):
 
 
 # ----------------------------------------------------------------------
-# cost model: the XLA-vs-analytical histogram cross-check
-
-
-def test_hist_xla_flops_within_2x_of_analytical():
-    from lightgbm_tpu.telemetry import costmodel
-    R, F, B, L = 4096, 8, 16, 7
-    xla = costmodel.hist_xla_cost(R, F, B, L, impl="matmul")
-    ana_flops, ana_bytes = costmodel.analytical_hist_counts(R, F, B, L)
-    assert xla["flops"] > 0 and ana_flops > 0
-    ratio = xla["flops"] / ana_flops
-    assert 0.5 <= ratio <= 2.0, (
-        f"XLA prices the one-hot hist matmul at {ratio:.2f}x the "
-        "analytical count — one of the two models is wrong")
-    assert xla["bytes_accessed"] >= ana_bytes  # analytical is the floor
-
-
-# ----------------------------------------------------------------------
 # monitor --perf over a synthetic run dir
 
 
@@ -470,43 +452,6 @@ def _fake_run_dir(tmp_path):
     ]
     log.write_text("".join(json.dumps(r) + "\n" for r in recs))
     return tmp_path
-
-
-class _FakeDev:
-    def __init__(self, platform, kind):
-        self.platform, self.device_kind = platform, kind
-
-
-def test_chip_peaks_exact_kind_lookup(monkeypatch):
-    """The installed runtime names a v5e chip "TPU v5 lite": the table
-    is keyed by that exact string (a "v5e" substring match returned
-    None on the very chip builders have)."""
-    import jax
-    monkeypatch.setattr(jax, "devices",
-                        lambda *a: [_FakeDev("tpu", "TPU v5 lite")])
-    pk = costmodel.chip_peaks()
-    assert (pk.kind, pk.bf16_tflops, pk.int8_tops, pk.hbm_gbps) == (
-        "TPU v5 lite", 197.0, 393.0, 819.0)
-    util = costmodel.roofline_utilization(98.5, 409.5)
-    assert util == {"hist_mfu": 0.5, "hist_hbm_util": 0.5,
-                    "chip": "TPU v5 lite"}
-
-
-def test_chip_peaks_unknown_tpu_raises_and_cpu_has_none(monkeypatch):
-    """A TPU kind the table does not know is an error on every path
-    that computes a roofline field; a CPU host simply has no peaks."""
-    import jax
-    monkeypatch.setattr(jax, "devices",
-                        lambda *a: [_FakeDev("tpu", "TPU v9 mega")])
-    with pytest.raises(LookupError, match="TPU v9 mega"):
-        costmodel.chip_peaks()
-    with pytest.raises(LookupError):
-        costmodel.kernel_roofline_fields("tpu", 1e-3, 1024, 8, 16, 4)
-    monkeypatch.setattr(jax, "devices",
-                        lambda *a: [_FakeDev("cpu", "cpu")])
-    assert costmodel.chip_peaks() is None
-    assert "hist_mfu" not in costmodel.kernel_roofline_fields(
-        "cpu", 1e-3, 1024, 8, 16, 4)
 
 
 def test_find_captures(tmp_path):
