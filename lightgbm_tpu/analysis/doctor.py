@@ -203,8 +203,8 @@ def doctor_fused_step(bst, *, label: str = "fused_step",
     args = _fused_trace_args(gb)
     closed = jax.make_jaxpr(gb._fused_step_entry)(*args)
     # TD005 budget: one build per program when single-class or when the
-    # class-batch gate is open; a config the gate excludes (linear /
-    # forced / CEGB) legitimately unrolls, so the rule is skipped
+    # class-batch gate is open; a config the gate excludes (forced /
+    # CEGB) legitimately unrolls, so the rule is skipped
     build_budget = 1 if (gb.K == 1 or gb.class_batch_ok) else None
     build_rows = None
     if round_body:

@@ -220,8 +220,7 @@ def lint_jaxpr(closed, *, label: str,
     ``max_build_programs`` enables TD005: the program may stage at most
     that many ``build``-phase grow loops (1 for a class-batched or
     single-class trainer; ``None`` skips the rule for programs with a
-    legitimate sequential fallback — linear trees, forced splits,
-    CEGB). ``build_rows`` enables TD008: the rows one device holds
+    legitimate sequential fallback — forced splits, CEGB). ``build_rows`` enables TD008: the rows one device holds
     (after padding), against which the ``build`` stage's gathers and
     scatter-adds are sized.
     """

@@ -14,8 +14,8 @@ Structure (TPU-first):
   (Tree.from_device) happens in batches at sync points only — eval
   cadence boundaries and end of training — so the steady-state loop
   dispatches ahead with zero host syncs between eval points. Configs
-  that need per-iteration host work (custom fobj, linear trees, CEGB,
-  multi-process meshes, position-bias ranking) fall back to the legacy
+  that need per-iteration host work (custom fobj, CEGB, multi-process
+  meshes, position-bias ranking) fall back to the legacy
   loop (_train_one_iter_legacy: ~5 dispatches + a per-tree sync,
   mirroring the CUDA learner's scalars-only host boundary,
   cuda_single_gpu_tree_learner.cpp:246-273); LIGHTGBM_TPU_FUSED_TRAIN=0
@@ -27,6 +27,10 @@ Structure (TPU-first):
   via AddBias, exactly like gbdt.cpp:416 — saved models are self-contained.
 - Validation sets are co-partitioned during growth (see tree_builder), so
   validation scores update with a gather, no full predict pass.
+- Linear leaves (linear_tree) are fitted on the device after each build,
+  from raw values kept beside every bin matrix (ops/linear_leaves.py), in
+  the fused step and the legacy loop alike; the score update reads each
+  row's linear output.
 """
 
 from __future__ import annotations
@@ -68,6 +72,9 @@ class RoundRecord(NamedTuple):
     rows: np.ndarray     # [rounds] int32 ([n_shards, rounds] when sharded)
     leaves: np.ndarray   # [rounds] int32
     stream_rows: np.ndarray  # like ``rows``: stream positions touched
+    # a linear tree's (Gram multiply-adds, path features) summed over its
+    # leaves (GBDT._linear_work); None for constant leaves
+    linear: Optional[Tuple[int, int]] = None
 
 
 def _pad_rows(arr: np.ndarray, r_pad: int, fill=0):
@@ -108,6 +115,7 @@ class _DeviceData:
                 self.bins = jnp.asarray(bins)
                 self.row_leaf0 = jnp.asarray(row_leaf0)
             jax.block_until_ready((self.bins, self.row_leaf0))
+        self.raw = None     # [Fp, r_pad] raw values under linear_tree
 
 
 class _ChunkedDeviceData:
@@ -205,6 +213,14 @@ class GBDT:
                         "dataset; binary dataset caches do not retain "
                         "them — construct Datasets from arrays or text "
                         "files")
+            if int(config.num_machines) > 1:
+                # reference parity: "linear tree learner must be serial
+                # type" (config.cpp:429-437 forces tree_learner=serial),
+                # so distributed linear trees do not exist there either
+                raise NotImplementedError(
+                    "linear_tree requires single-host training (the "
+                    "reference forces tree_learner=serial for linear "
+                    "trees too, config.cpp:429)")
         if int(config.num_machines) > 1:
             # multi-host bootstrap (Network::Init analog): after this,
             # jax.devices() spans every host and the mesh plans below
@@ -216,14 +232,17 @@ class GBDT:
         # CEGB and feature_contri run on the serial learner only — the
         # reference ties CEGB to SerialTreeLearner; we follow its
         # force-serial-with-warning pattern (config.cpp:434-437 style)
+        # and so do linear leaves ("linear tree learner must be serial",
+        # config.cpp)
         needs_serial = bool(
             config.cegb_tradeoff < 1.0 or config.cegb_penalty_split > 0.0
             or config.cegb_penalty_feature_coupled
-            or config.cegb_penalty_feature_lazy or config.feature_contri)
+            or config.cegb_penalty_feature_lazy or config.feature_contri
+            or config.linear_tree)
         if needs_serial and n_dev > 1 and config.tree_learner != "serial":
             from .. import log as _log
-            _log.warning("CEGB/feature_contri require the serial tree "
-                         "learner; forcing tree_learner=serial")
+            _log.warning("CEGB/feature_contri/linear_tree require the "
+                         "serial tree learner; forcing tree_learner=serial")
         if not needs_serial and n_dev > 1 \
                 and config.tree_learner != "serial":
             from ..parallel.data_parallel import (
@@ -432,20 +451,15 @@ class GBDT:
                         unbundle=self._unbundle_feature)
             for v in valid_sets]
         self.valid_sets = list(valid_sets)
+        self._linear = bool(config.linear_tree)
+        if self._linear:
+            self._linear_setup()
 
         R = self.train_dd.r_pad
         R_loc = self.train_dd.r_local
         lbl = self.train_set.get_label()
         self._mp = bool(self.plan is not None
                         and getattr(self.plan, "multi_process", False))
-        if self._mp and bool(config.linear_tree):
-            # reference parity: "linear tree learner must be serial
-            # type" (config.cpp:429-437 forces tree_learner=serial), so
-            # distributed linear trees do not exist there either
-            raise NotImplementedError(
-                "linear_tree requires single-host training (the "
-                "reference forces tree_learner=serial for linear trees "
-                "too, config.cpp:429)")
         # multi-host ranking (VERDICT r4 #4): the padded-query lattice
         # holds LOCAL row ids, so ranking gradients are computed PER
         # PROCESS on the host's own score block (each host owns whole
@@ -629,6 +643,7 @@ class GBDT:
                 raise ValueError("top_rate + other_rate must be <= 1")
 
         self._update_score_jit = jax.jit(self._update_score_impl)
+        self._linear_jit = None
         self._goss_jit = jax.jit(self._goss_impl)
 
         # fused boosting step state (see train_one_iter): the pending
@@ -1178,7 +1193,8 @@ class GBDT:
             hist_dtype=cfg.hist_dtype, block_rows=self.block,
             hist_sub=self._hist_sub, parallel_mode=mode,
             hist_merge=getattr(plan, "hist_merge", "allreduce"),
-            n_shards=n, search=getattr(self._bundle_meta, "search", None))
+            n_shards=n, search=getattr(self._bundle_meta, "search", None),
+            linear_columns=features + 1 if self._linear else 0)
 
     def stage_work(self, n: Optional[int] = None, *,
                    fullest: bool = False) -> Dict[str, Tuple[float, str]]:
@@ -1263,8 +1279,6 @@ class GBDT:
             return "single model per iteration"
         if type(self) is not GBDT:
             return "boosting mode overrides the iteration loop"
-        if bool(cfg.linear_tree):
-            return "linear leaves solve per-class on host raw values"
         if self._forced_splits is not None:
             return "forced splits assign node slots sequentially"
         if self._cegb is not None:
@@ -1295,8 +1309,8 @@ class GBDT:
         a leading K axis, row_leaf [K, R], valid_row_leafs tuple of
         [K, Rv], RoundLog with a leading K). Only reachable when
         :meth:`_class_batch_reason`
-        cleared, so the forced/CEGB/linear extras of
-        :meth:`_build_one_tree` never arise here."""
+        cleared, so the forced/CEGB extras of :meth:`_build_one_tree`
+        never arise here."""
         cfg = self.config
         if it is None:
             it = self.iter_
@@ -1472,86 +1486,72 @@ class GBDT:
         return tree_arrays._replace(leaf_values=new_leaf,
                                     node_value=node_value)
 
-    # ------------------------------------------------------------------
-    def _fit_linear_leaves(self, tree, row_leaf, g, h, shrink: float):
-        """Per-leaf ridge solve on raw feature values
-        (LinearTreeLearner::CalculateLinear, linear_tree_learner.cpp:
-        280-385): for each leaf, regress -g on the raw values of the
-        features along its path, weighted by h, ridge linear_lambda.
-        Host NumPy: the solves are tiny ((d+1)^2 per leaf); the heavy
-        segment sums vectorize over rows per leaf."""
-        raw = self.train_set.raw_values
-        lam = float(self.config.linear_lambda)
-        n = self.train_set.num_data
-        rl = np.asarray(row_leaf)[:n]
-        g = np.asarray(g)[:n].astype(np.float64)
-        h = np.asarray(h)[:n].astype(np.float64)
+    # -- linear leaves (linear_tree) -----------------------------------
 
-        # path features per leaf (global ids, first-use order)
-        paths = [[] for _ in range(tree.num_leaves)]
-        if tree.num_leaves > 1:
-            stack = [(0, [])]
-            while stack:
-                node, feats = stack.pop()
-                if node < 0:
-                    paths[~node] = feats
-                    continue
-                f = int(tree.split_feature[node])
-                nf = feats if f in feats else feats + [f]
-                stack.append((int(tree.left_child[node]), nf))
-                stack.append((int(tree.right_child[node]), nf))
+    def _linear_setup(self) -> None:
+        """Raw values of the used features on the device beside every bin
+        matrix, feature-major (``ops.linear_leaves.raw_lanes``), and the
+        fit's form: the Pallas kernels on a TPU where every set's shape
+        fits them, segment sums in XLA elsewhere (any width; the reason
+        is kept as ``linear_impl_reason``)."""
+        from ..ops import linear_leaves as LL
+        self._linear_impl, self.linear_impl_reason = LL.choose_impl(
+            jax.default_backend() == "tpu", self.train_set.num_features,
+            int(self.config.num_leaves) + 1,
+            [dd.r_local for dd in (self.train_dd, *self.valid_dd)])
+        self._numerical = jnp.asarray(
+            ~np.asarray(self.train_set.per_feature_is_categorical()))
+        uf = self.train_set.used_features
+        for ds_, dd in zip((self.train_set, *self.valid_sets),
+                           (self.train_dd, *self.valid_dd)):
+            with profiler.span("gbdt.to_device"):
+                dd.raw = jax.block_until_ready(jnp.asarray(
+                    LL.raw_lanes(ds_.construct().raw_values, uf,
+                                 dd.r_local)))
 
-        tree.is_linear = True
-        for s in range(tree.num_leaves):
-            feats = paths[s]
-            rows = np.nonzero(rl == s)[0]
-            tree.leaf_features[s] = []
-            tree.leaf_coeff[s] = []
-            tree.leaf_const[s] = tree.leaf_value[s]
-            if not feats or len(rows) == 0:
-                continue
-            vals = raw[np.ix_(rows, feats)].astype(np.float64)
-            ok = ~np.isnan(vals).any(axis=1)
-            if ok.sum() < len(feats) + 1:
-                continue  # too few clean rows: constant leaf
-            X = np.concatenate([vals[ok], np.ones((ok.sum(), 1))], axis=1)
-            hw = h[rows][ok]
-            gw = g[rows][ok]
-            A = (X * hw[:, None]).T @ X
-            d = len(feats)
-            A[np.arange(d), np.arange(d)] += lam
-            b = X.T @ gw
-            try:
-                beta = -np.linalg.solve(A, b)
-            except np.linalg.LinAlgError:
-                continue
-            if not np.isfinite(beta).all():
-                continue
-            keep = np.abs(beta[:d]) > 1e-35   # kZeroThreshold
-            tree.leaf_features[s] = [feats[i] for i in range(d) if keep[i]]
-            tree.leaf_coeff[s] = [float(beta[i] * shrink)
-                                  for i in range(d) if keep[i]]
-            tree.leaf_const[s] = float(beta[d] * shrink)
+    def _first_tree(self, it):
+        """Whether iteration ``it`` grows the model's first trees, whose
+        leaves stay constant (``is_first_tree`` of CalculateLinear:
+        ``models_.size() < num_tree_per_iteration_``, gbdt.cpp)."""
+        if self.num_init_iteration > 0:
+            return False
+        return it == 0
 
-    def _linear_score_delta(self, tree, raw, row_leaf, r_pad):
-        """Per-row SHRUNK outputs of a linear tree (AddPredictionToScore
-        linear path, tree.cpp:120-149) for the score update."""
-        n = raw.shape[0]
-        rl = np.asarray(row_leaf)[:n]
-        out = np.zeros(r_pad, np.float32)
-        for s in range(tree.num_leaves):
-            rows = np.nonzero(rl == s)[0]
-            if len(rows) == 0:
+    def _linear_leaves(self, tree, row_leaf, valid_rls, g, h, count, first,
+                       raw, valid_raw):
+        """Fit ``tree``'s linear leaves and return it with the per-row
+        outputs (unshrunk) of the training rows and of each validation
+        set's: ``(tree, [[R], [Rv], ...])``. Pure in its arguments (the raw
+        values among them), so the legacy loop jits it as it is."""
+        from ..ops import linear_leaves as LL
+        impl = self._linear_impl
+        tree = LL.fit_leaves(tree, raw, row_leaf, g, h, count,
+                             numerical=self._numerical,
+                             lam=float(self.config.linear_lambda),
+                             first=first, impl=impl)
+        return tree, [LL.leaf_outputs(tree, x, rl, impl=impl)
+                      for x, rl in zip((raw, *valid_raw),
+                                       (row_leaf, *valid_rls))]
+
+    def _linear_work(self, tree: Tree) -> Tuple[int, int]:
+        """(Gram multiply-adds, path features) of a linear tree, summed
+        over its leaves: ``rows_l ((k_l + 1)(k_l + 2) / 2 + k_l + 1)`` and
+        ``k_l``, ``k_l`` the numerical features on leaf ``l``'s branch."""
+        macs = feats = 0
+        stack = [(0, frozenset())]
+        while stack and tree.num_leaves > 1:
+            node, path = stack.pop()
+            if node < 0:
+                k = len(path)
+                macs += int(tree.leaf_count[~node]) * (
+                    (k + 1) * (k + 2) // 2 + k + 1)
+                feats += k
                 continue
-            feats = tree.leaf_features[s]
-            if not feats:
-                out[rows] = tree.leaf_const[s]
-                continue
-            vals = raw[np.ix_(rows, feats)].astype(np.float64)
-            nan = np.isnan(vals).any(axis=1)
-            lin = tree.leaf_const[s] + vals @ np.asarray(tree.leaf_coeff[s])
-            out[rows] = np.where(nan, tree.leaf_value[s], lin)
-        return out
+            if not tree.decision_type[node] & 1:
+                path = path | {int(tree.split_feature[node])}
+            stack += [(int(tree.left_child[node]), path),
+                      (int(tree.right_child[node]), path)]
+        return macs, feats
 
     def _bias_adjust_device(self, tree_arrays: TreeArrays, bias: float,
                             shrink: float) -> TreeArrays:
@@ -1591,8 +1591,6 @@ class GBDT:
             return "boosting mode overrides the iteration loop"
         if self.objective is None:
             return "custom objective gradients are host-supplied"
-        if bool(cfg.linear_tree):
-            return "linear leaves solve on host raw values"
         if self._cegb is not None:
             return "CEGB threads model-level host state"
         if self._mp:
@@ -1669,17 +1667,36 @@ class GBDT:
                     trees_k = jax.vmap(self._renew_leaf_impl)(
                         trees_k, row_leaf_k, g_true, h_true)
             grew_k = trees_k.num_leaves > 1                 # [K] bool
+            if self._linear:
+                fitted, outs = [], []
+                for k in range(self.K):
+                    tree_k, out_k = self._linear_leaves(
+                        jax.tree.map(lambda a: a[k], trees_k),
+                        row_leaf_k[k], [v[k] for v in valid_rls_k],
+                        g_true[k], h_true[k], count_mask,
+                        self._first_tree(it), self.train_dd.raw,
+                        [dd.raw for dd in self.valid_dd])
+                    fitted.append(tree_k)
+                    outs.append(out_k)
+                trees_k = jax.tree.map(lambda *a: jnp.stack(a), *fitted)
+                outs = [jnp.stack(o) for o in zip(*outs)]   # per dataset
             with profiler.stage("update"):
                 # per-class rows are independent, so the batched
                 # where() equals the sequential .at[k].set chain
-                upd = jax.vmap(self._update_score_impl,
-                               in_axes=(0, 0, 0, None))(
-                    new_scores, trees_k.leaf_values, row_leaf_k, lr)
+                if self._linear:
+                    upd = new_scores + lr * outs[0]
+                else:
+                    upd = jax.vmap(self._update_score_impl,
+                                   in_axes=(0, 0, 0, None))(
+                        new_scores, trees_k.leaf_values, row_leaf_k, lr)
                 new_scores = jnp.where(grew_k[:, None], upd, new_scores)
                 for vi, vrl_k in enumerate(valid_rls_k):
-                    vupd = jax.vmap(self._update_score_impl,
-                                    in_axes=(0, 0, 0, None))(
-                        new_valid[vi], trees_k.leaf_values, vrl_k, lr)
+                    if self._linear:
+                        vupd = new_valid[vi] + lr * outs[1 + vi]
+                    else:
+                        vupd = jax.vmap(self._update_score_impl,
+                                        in_axes=(0, 0, 0, None))(
+                            new_valid[vi], trees_k.leaf_values, vrl_k, lr)
                     new_valid[vi] = jnp.where(grew_k[:, None], vupd,
                                               new_valid[vi])
                 finite = finite & jnp.all(jnp.isfinite(new_scores))
@@ -1703,17 +1720,29 @@ class GBDT:
                     tree_arrays = self._renew_leaf_impl(
                         tree_arrays, row_leaf, g_true[k], h_true[k])
             grew = tree_arrays.num_leaves > 1
+            if self._linear:
+                tree_arrays, outs = self._linear_leaves(
+                    tree_arrays, row_leaf, valid_rls, g_true[k],
+                    h_true[k], count_mask, self._first_tree(it),
+                    self.train_dd.raw, [dd.raw for dd in self.valid_dd])
             with profiler.stage("update"):
                 # score updates apply only when the tree grew — the
                 # device form of the legacy num_leaves>1 host check
-                upd = self._update_score_impl(
-                    new_scores[k], tree_arrays.leaf_values, row_leaf, lr)
+                if self._linear:
+                    upd = new_scores[k] + lr * outs[0]
+                else:
+                    upd = self._update_score_impl(
+                        new_scores[k], tree_arrays.leaf_values, row_leaf,
+                        lr)
                 new_scores = new_scores.at[k].set(
                     jnp.where(grew, upd, new_scores[k]))
                 for vi, vrl in enumerate(valid_rls):
-                    vupd = self._update_score_impl(
-                        new_valid[vi][k], tree_arrays.leaf_values, vrl,
-                        lr)
+                    if self._linear:
+                        vupd = new_valid[vi][k] + lr * outs[1 + vi]
+                    else:
+                        vupd = self._update_score_impl(
+                            new_valid[vi][k], tree_arrays.leaf_values,
+                            vrl, lr)
                     new_valid[vi] = new_valid[vi].at[k].set(
                         jnp.where(grew, vupd, new_valid[vi][k]))
             trees.append(tree_arrays)
@@ -1740,6 +1769,8 @@ class GBDT:
             bins_cm=self._bins_cm,
             valid_bins=tuple(dd.bins for dd in self.valid_dd),
             valid_rl0=tuple(dd.row_leaf0 for dd in self.valid_dd),
+            raw=self.train_dd.raw,
+            valid_raw=tuple(dd.raw for dd in self.valid_dd),
             rank=(self.objective.device_state
                   if self.objective.is_ranking else None))
 
@@ -1753,8 +1784,8 @@ class GBDT:
         never re-enter Python here."""
         saved = (self.train_dd.bins, self.train_dd.row_leaf0,
                  self.label_dev, self.weight_dev, self._bins_cm,
-                 [dd.bins for dd in self.valid_dd],
-                 [dd.row_leaf0 for dd in self.valid_dd])
+                 self.train_dd.raw,
+                 [(dd.bins, dd.row_leaf0, dd.raw) for dd in self.valid_dd])
         ranking = data["rank"] is not None
         if ranking:
             rank_saved = self.objective.bind_device_state(data["rank"])
@@ -1764,16 +1795,18 @@ class GBDT:
             self.label_dev = data["label"]
             self.weight_dev = data["weight"]
             self._bins_cm = data["bins_cm"]
-            for dd, b, rl in zip(self.valid_dd, data["valid_bins"],
-                                 data["valid_rl0"]):
-                dd.bins, dd.row_leaf0 = b, rl
+            self.train_dd.raw = data["raw"]
+            for dd, b, rl, x in zip(self.valid_dd, data["valid_bins"],
+                                    data["valid_rl0"], data["valid_raw"]):
+                dd.bins, dd.row_leaf0, dd.raw = b, rl, x
             return self._fused_step_impl(scores, valid_scores, bag_mask,
                                          fmask, it, lr)
         finally:
             (self.train_dd.bins, self.train_dd.row_leaf0, self.label_dev,
-             self.weight_dev, self._bins_cm, vb, vr) = saved
-            for dd, b, rl in zip(self.valid_dd, vb, vr):
-                dd.bins, dd.row_leaf0 = b, rl
+             self.weight_dev, self._bins_cm, self.train_dd.raw,
+             valid) = saved
+            for dd, (b, rl, x) in zip(self.valid_dd, valid):
+                dd.bins, dd.row_leaf0, dd.raw = b, rl, x
             if ranking:
                 self.objective.bind_device_state(rank_saved)
 
@@ -1937,10 +1970,10 @@ class GBDT:
                            for k in range(self.K)]
                 rounds_h = [jax.tree.map(lambda a: a[k], rounds_h)
                             for k in range(self.K)]
-            for k, rl in enumerate(rounds_h):
-                self._log_rounds(it, k, rl)
-            for k, tree in enumerate(Tree.from_device_batch(
-                    trees_h, bm, uf, shrink)):
+            trees = Tree.from_device_batch(trees_h, bm, uf, shrink)
+            for k, (rl, tree) in enumerate(zip(rounds_h, trees)):
+                self._log_rounds(it, k, rl, tree)
+            for k, tree in enumerate(trees):
                 bias = self._init_scores[k]
                 if it == 0 and abs(bias) > kEpsilon:
                     # AddBias (gbdt.cpp:416): fold init score into the
@@ -1949,6 +1982,8 @@ class GBDT:
                     # which does, is legacy-only).
                     tree.leaf_value += bias
                     tree.internal_value += bias
+                    if tree.is_linear:  # AddBias touches leaf_const too
+                        tree.leaf_const += bias
                 self.models.append(tree)
             kept += 1
         self.iter_ = pending[0][0] + kept
@@ -1972,11 +2007,14 @@ class GBDT:
         ref = GBDT._latest
         return ref() if ref is not None else None
 
-    def _log_rounds(self, it: int, k: int, rounds) -> None:
+    def _log_rounds(self, it: int, k: int, rounds,
+                    tree: Optional[Tree] = None) -> None:
         if rounds is not None:
             self.round_log.append(RoundRecord(
                 int(it), int(k), np.asarray(rounds.rows),
-                np.asarray(rounds.leaves), np.asarray(rounds.stream_rows)))
+                np.asarray(rounds.leaves), np.asarray(rounds.stream_rows),
+                self._linear_work(tree) if tree is not None
+                and tree.is_linear else None))
 
     def train_one_iter(self, gradients: Optional[np.ndarray] = None,
                        hessians: Optional[np.ndarray] = None, *,
@@ -2092,7 +2130,7 @@ class GBDT:
                 raise NumericDivergenceError(self.iter_)
 
         fmask = self._feature_mask()
-        linear = bool(self.config.linear_tree)
+        linear = self._linear
         should_continue = False
         trees_k = None
         if self.class_batch_ok:
@@ -2138,33 +2176,29 @@ class GBDT:
                             tree_arrays, row_leaf, g_true[k], h_true[k])
                 host, rounds_host = jax.tree.map(
                     np.asarray, (tree_arrays, rounds_1))
-            self._log_rounds(self.iter_, k, rounds_host)
             num_leaves_trained = int(host.num_leaves)
             shrink = self.shrinkage
+            if linear and num_leaves_trained > 1:
+                # the fused step's device fit, one dispatch
+                if self._linear_jit is None:
+                    self._linear_jit = jax.jit(self._linear_leaves)
+                tree_arrays, outs = self._linear_jit(
+                    tree_arrays, row_leaf, valid_rls, g_true[k], h_true[k],
+                    count_mask, self._first_tree(self.iter_),
+                    self.train_dd.raw, [dd.raw for dd in self.valid_dd])
+                host = jax.tree.map(np.asarray, tree_arrays)
             tree = Tree.from_device(host, self.train_set.bin_mappers,
                                     self.train_set.used_features, shrink)
-            if linear and num_leaves_trained > 1:
-                self._fit_linear_leaves(tree, row_leaf, g_true[k],
-                                        h_true[k], shrink)
+            self._log_rounds(self.iter_, k, rounds_host, tree)
             if num_leaves_trained > 1:
                 should_continue = True
                 with profiler.phase("update"):
                     if linear:
-                        # linear outputs live on host (raw feature
-                        # values); scores updated from the per-row
-                        # linear deltas
-                        delta = self._linear_score_delta(
-                            tree, self.train_set.raw_values, row_leaf,
-                            self.train_dd.r_pad)
-                        self.scores = self.scores.at[k].add(
-                            jnp.asarray(delta))
-                        for vi, vrl in enumerate(valid_rls):
-                            vds = self.valid_sets[vi]
-                            vdelta = self._linear_score_delta(
-                                tree, vds.raw_values, vrl,
-                                self.valid_dd[vi].r_pad)
+                        lr = jnp.asarray(shrink, jnp.float32)
+                        self.scores = self.scores.at[k].add(lr * outs[0])
+                        for vi in range(len(valid_rls)):
                             self.valid_scores[vi] = self.valid_scores[vi] \
-                                .at[k].add(jnp.asarray(vdelta))
+                                .at[k].add(lr * outs[1 + vi])
                     else:
                         lr = jnp.asarray(shrink, jnp.float32)
                         self.scores = self.scores.at[k].set(

@@ -124,6 +124,15 @@ class TreeArrays(NamedTuple):
     leaf_values: jax.Array     # [L+1] f32 output per leaf slot (unshrunk)
     num_leaves: jax.Array      # scalar int32
     num_nodes: jax.Array       # scalar int32
+    # linear leaves (linear_tree; ops/linear_leaves.fit_leaves), after the
+    # build: per slot the model's constant [L+1] and coefficients [L+1, F]
+    # (unshrunk, builder feature order, 0 = not in the model)
+    leaf_const: Optional[jax.Array] = None
+    leaf_coeff: Optional[jax.Array] = None
+
+
+# fields the builder fills (the rest are None until linear leaves fit)
+GROWN_FIELDS = len(TreeArrays._fields) - len(TreeArrays._field_defaults)
 
 
 class RoundLog(NamedTuple):
@@ -168,6 +177,7 @@ class StepShape(NamedTuple):
     kernel_lanes: int
     rounds_bound: int
     search_classes: int
+    linear_columns: int = 0
 
     def fields(self) -> dict:
         """``{phases.STEP_SHAPE name: int}``: the span's fields."""
@@ -268,7 +278,8 @@ def step_shape(*, rows: int, stored_columns: int, features: int,
                parallel_mode: Optional[str] = None,
                hist_merge: str = "allreduce",
                n_shards: int = 1,
-               search: Optional[SearchPlan] = None) -> StepShape:
+               search: Optional[SearchPlan] = None,
+               linear_columns: int = 0) -> StepShape:
     """The sizes :func:`_build_tree_impl` builds with, from its static
     arguments alone: host integers, no trace, no device. The builder
     calls this for its own W, chunk, compaction and loop bound, and the
@@ -282,7 +293,9 @@ def step_shape(*, rows: int, stored_columns: int, features: int,
     ``feature``), ``parallel_mode`` None off a mesh, ``search`` a
     bundled matrix's width classes (``BundleMeta.search``), which the
     search runs over one device's and the data-parallel plans' search
-    (not voting's, which elects columns of the padded lattice)."""
+    (not voting's, which elects columns of the padded lattice).
+    ``linear_columns`` is the linear-leaf Gram's width, which gbdt.py
+    fits after the build (0: constant leaves)."""
     nb_in = bundle_bins or num_bins
     impl = effective_impl(hist_impl, nb_in)
     W = max(1, min(leaf_batch, num_leaves - 1))
@@ -310,7 +323,8 @@ def step_shape(*, rows: int, stored_columns: int, features: int,
         kernel_row_block=blk, kernel_root_row_block=root_blk,
         kernel_feature_chunk=fc, kernel_chunks=n_fb, kernel_padded_bins=bp,
         kernel_lanes=lanes, rounds_bound=max_rounds_for(num_leaves, W),
-        search_classes=len(packed.widths) if packed is not None else 1)
+        search_classes=len(packed.widths) if packed is not None else 1,
+        linear_columns=linear_columns)
 
 
 def _round_int(x):

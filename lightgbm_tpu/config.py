@@ -173,8 +173,8 @@ PARAMS: Dict[str, ParamSpec] = {
                "step (grads+sampling+build+update in one jitted program, "
                "trees materialized in batches at eval points). false "
                "pins the legacy per-phase dispatch loop; configs the "
-               "fused step cannot express (custom fobj, linear trees, "
-               "CEGB, multi-process meshes) fall back automatically. "
+               "fused step cannot express (custom fobj, CEGB, "
+               "multi-process meshes) fall back automatically. "
                "LIGHTGBM_TPU_FUSED_TRAIN=0 pins legacy from the env"),
         _p("eval_period", 1, int, aliases=("eval_freq",),
            check=lambda v: v >= 1,
@@ -193,8 +193,8 @@ PARAMS: Dict[str, ParamSpec] = {
                "compile time stop scaling with num_class and every "
                "histogram dispatch gets K x more MXU work); off pins "
                "the sequential per-class loop. Configs the batched "
-               "build cannot express (linear trees, forced splits, "
-               "CEGB, feature-parallel learners) fall back "
+               "build cannot express (forced splits, CEGB, "
+               "feature-parallel learners) fall back "
                "automatically; results are bit-identical either way. "
                "LIGHTGBM_TPU_CLASS_BATCH=0/1 pins from the env"),
         _p("dp_hist_merge", "auto", str,

@@ -59,7 +59,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.split import SplitParams
-from ..boosting.tree_builder import RoundLog, build_tree, TreeArrays
+from ..boosting.tree_builder import (GROWN_FIELDS, RoundLog, TreeArrays,
+                                     build_tree)
 
 __all__ = ["make_mesh", "shard_rows", "replicate", "build_tree_dp",
            "resolve_hist_merge",
@@ -437,7 +438,7 @@ def _build_tree_fp_jit(mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
     fp_extras = (qs, groups, key, csm)
 
     tree_specs = jax.tree.map(lambda _: rep, TreeArrays(
-        *([0] * len(TreeArrays._fields))))
+        *([0] * GROWN_FIELDS)))
     extras_specs = jax.tree.map(lambda _: rep, fp_extras)
 
     if sharded:
@@ -516,7 +517,7 @@ def _build_tree_dp_jit(mesh, bins, gh, row_leaf0, num_bins_pf, nan_bin_pf,
             stream_rows=rounds.stream_rows[..., None, :])
 
     tree_specs = jax.tree.map(lambda _: rep, TreeArrays(
-        *([0] * len(TreeArrays._fields))))
+        *([0] * GROWN_FIELDS)))
     valid_in_specs = tuple([row2] * n_valid + [row] * n_valid)
     # constraint metadata and PRNG key are replicated: every chip samples
     # and constrains identically, keeping the replicated argmax in sync

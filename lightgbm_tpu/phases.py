@@ -30,7 +30,8 @@ __all__ = ["GRADS", "SAMPLING", "BUILD", "UPDATE", "EVAL",
            "COUNT", "COMPACT", "HIST_GATHER", "HIST_RELAYOUT",
            "HIST_KERNEL", "UNBUNDLE", "SUBTRACT", "FIND", "RANK_GATHER",
            "RANK_SORT",
-           "RANK_PAIRS", "RANK_SCATTER", "TRAIN_PHASES",
+           "RANK_PAIRS", "RANK_SCATTER", "LINEAR_GRAM", "LINEAR_SOLVE",
+           "LINEAR_UPDATE", "LINEAR_STAGES", "TRAIN_PHASES",
            "INGEST_PHASES", "COLLECTIVE_PHASES", "BUILD_STAGES",
            "GRADS_STAGES", "KNOWN_PHASES", "HOST_SPANS",
            "PLAN_SHARDS", "PLAN_ROWS_PER_SHARD",
@@ -42,10 +43,12 @@ __all__ = ["GRADS", "SAMPLING", "BUILD", "UPDATE", "EVAL",
            "SHAPE_KERNEL_ROW_BLOCK", "SHAPE_KERNEL_ROOT_ROW_BLOCK",
            "SHAPE_KERNEL_FEATURE_CHUNK", "SHAPE_KERNEL_CHUNKS",
            "SHAPE_KERNEL_PADDED_BINS", "SHAPE_KERNEL_LANES",
-           "SHAPE_ROUNDS_BOUND", "STEP_SHAPE", "STAGE_WORK",
+           "SHAPE_ROUNDS_BOUND", "SHAPE_LINEAR_COLUMNS", "STEP_SHAPE",
+           "STAGE_WORK",
            "UNIT_POSITIONS", "UNIT_ELEMENTS", "UNIT_ONEHOT",
            "UNIT_SORTED", "UNIT_ROW_PASSES", "UNIT_LATTICE", "UNIT_ROWS",
-           "UNIT_PAIR_SLOTS", "UNIT_BYTES"]
+           "UNIT_PAIR_SLOTS", "UNIT_BYTES", "UNIT_GRAM_MACS",
+           "UNIT_PATH_FEATURES"]
 
 # training phases (both drivers, boosting/gbdt.py + engine.train's eval)
 GRADS = "grads"
@@ -110,6 +113,16 @@ RANK_PAIRS = "rank_pairs"        # the [Q_b, T, W] pair lattice and its two
 #                                  reductions (XE-NDCG: the softmax a query)
 RANK_SCATTER = "rank_scatter"    # every bucket's g, h back to rows, each row
 #                                  reading its slot (an index fixed at init)
+
+# stages of linear leaves (linear_tree), after a tree's build and before
+# its update in the fused step (ops/linear_leaves.py)
+LINEAR_GRAM = "linear_gram"      # per leaf: the moments pass, then the
+#                                  centred Gram and right-hand side (the
+#                                  two row-streaming kernels)
+LINEAR_SOLVE = "linear_solve"    # path masks from the tree arrays, the
+#                                  shift, the batched solve and the rules
+LINEAR_UPDATE = "linear_update"  # each row's output from its leaf's model
+#                                  (the score add itself stays ``update``)
 
 # host spans of the span record (profiler.span): boundaries that happen
 # once a tree or more rarely. Each is also a TraceAnnotation named
@@ -184,12 +197,15 @@ SHAPE_KERNEL_CHUNKS = "shape_kernel_chunks"
 SHAPE_KERNEL_PADDED_BINS = "shape_kernel_padded_bins"
 SHAPE_KERNEL_LANES = "shape_kernel_lanes"
 SHAPE_ROUNDS_BOUND = "shape_rounds_bound"    # the grow loop's bound
+SHAPE_LINEAR_COLUMNS = "shape_linear_columns"  # linear leaves: the Gram's
+#                            width, F + 1 (the constant column); else 0
 STEP_SHAPE = (SHAPE_ROWS, SHAPE_STORED_COLUMNS, SHAPE_STORED_BINS,
               SHAPE_SEARCH_POSITIONS, SHAPE_SLOTS, SHAPE_STREAM_CHUNK_ROWS,
               SHAPE_STREAM_COMPACTED, SHAPE_KERNEL_ROW_BLOCK,
               SHAPE_KERNEL_ROOT_ROW_BLOCK, SHAPE_KERNEL_FEATURE_CHUNK,
               SHAPE_KERNEL_CHUNKS, SHAPE_KERNEL_PADDED_BINS,
-              SHAPE_KERNEL_LANES, SHAPE_ROUNDS_BOUND, SHAPE_SEARCH_CLASSES)
+              SHAPE_KERNEL_LANES, SHAPE_ROUNDS_BOUND, SHAPE_SEARCH_CLASSES,
+              SHAPE_LINEAR_COLUMNS)
 
 # the unit a stage's work is counted in (telemetry/costmodel.stage_work:
 # the counts come from the round log and the step's shape, at the
@@ -204,6 +220,11 @@ UNIT_LATTICE = "lattice_positions"     # slots x positions a slot
 UNIT_ROWS = "rows"                     # rows x trees
 UNIT_PAIR_SLOTS = "pair_slots"         # a ranking objective's layout
 UNIT_BYTES = "wire_bytes"              # one chip's, ring estimates
+UNIT_GRAM_MACS = "gram_macs"           # sum over leaves of rows x (the
+#                                        Gram's upper triangle + its
+#                                        right-hand side) at k + 1 columns
+UNIT_PATH_FEATURES = "path_features"   # sum over leaves of k, the leaf's
+#                                        numerical path features
 STAGE_WORK = {
     HIST_GATHER: UNIT_POSITIONS, HIST_RELAYOUT: UNIT_ELEMENTS,
     HIST_KERNEL: UNIT_ONEHOT, COMPACT: UNIT_SORTED,
@@ -211,7 +232,8 @@ STAGE_WORK = {
     FIND: UNIT_LATTICE, SUBTRACT: UNIT_LATTICE, UNBUNDLE: UNIT_LATTICE,
     ROOT_PASS: UNIT_LATTICE, UPDATE: UNIT_ROWS, GRADS: UNIT_ROWS,
     RANK_PAIRS: UNIT_PAIR_SLOTS, HIST_MERGE: UNIT_BYTES,
-    WINNER_SYNC: UNIT_BYTES}
+    WINNER_SYNC: UNIT_BYTES, LINEAR_GRAM: UNIT_GRAM_MACS,
+    LINEAR_SOLVE: UNIT_PATH_FEATURES, LINEAR_UPDATE: UNIT_ROWS}
 
 TRAIN_PHASES = frozenset({GRADS, SAMPLING, BUILD, UPDATE, EVAL})
 INGEST_PHASES = frozenset({INGEST_SKETCH, INGEST_WRITE, PREFETCH})
@@ -221,5 +243,6 @@ BUILD_STAGES = frozenset({ROOT_PASS, POP, APPLY, COUNT, COMPACT,
                           UNBUNDLE, SUBTRACT, FIND}) | COLLECTIVE_PHASES
 GRADS_STAGES = frozenset({RANK_GATHER, RANK_SORT, RANK_PAIRS,
                           RANK_SCATTER})
+LINEAR_STAGES = frozenset({LINEAR_GRAM, LINEAR_SOLVE, LINEAR_UPDATE})
 KNOWN_PHASES = (TRAIN_PHASES | INGEST_PHASES | BUILD_STAGES
-                | GRADS_STAGES)
+                | GRADS_STAGES | LINEAR_STAGES)

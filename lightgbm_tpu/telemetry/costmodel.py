@@ -76,7 +76,12 @@ def stage_work(shape: Dict[str, int], log: Sequence[Any], *,
       objective's ``pair_slots`` x trees;
     - ``hist_merge``, ``winner_sync``: bytes one chip puts on the wire,
       rounds x the plan's bytes a round plus trees x its bytes a tree
-      (``plan_bytes``: the ``phases.PLAN_COUNTERS`` of the step).
+      (``plan_bytes``: the ``phases.PLAN_COUNTERS`` of the step);
+    - linear leaves, from the records' ``linear`` entries
+      (``GBDT._linear_work``): ``linear_gram`` the Gram's multiply-adds,
+      ``sum rows_l ((k_l + 1)(k_l + 2) / 2 + k_l + 1)``; ``linear_solve``
+      the path features ``sum k_l``; ``linear_update`` rows x linear
+      trees.
 
     A round is one that built for at least one leaf. All counts are ONE
     device's. Under a row-sharded plan the log is ``[n_shards, rounds]``:
@@ -92,6 +97,8 @@ def stage_work(shape: Dict[str, int], log: Sequence[Any], *,
     scan = shape[PHS.SHAPE_SLOTS] * shape[PHS.SHAPE_SEARCH_POSITIONS]
     trees = len(log)
     rounds, positions, covered = 0, 0.0, 0.0
+    linear = [lin for lin in (getattr(rec, "linear", None) for rec in log)
+              if lin is not None]
     for rec in log:
         built = np.asarray(rec.leaves) > 0
         rounds += int(built.sum())
@@ -118,6 +125,9 @@ def stage_work(shape: Dict[str, int], log: Sequence[Any], *,
         PHS.UPDATE: trees * rows,
         PHS.GRADS: trees * rows,
         PHS.RANK_PAIRS: trees * int(pair_slots),
+        PHS.LINEAR_GRAM: sum(lin[0] for lin in linear),
+        PHS.LINEAR_SOLVE: sum(lin[1] for lin in linear),
+        PHS.LINEAR_UPDATE: len(linear) * rows,
     }
     a_round = (plan_bytes or {}).get(PHS.PLAN_ROUND_BYTES_BY_STAGE) or {}
     a_tree = (plan_bytes or {}).get(PHS.PLAN_TREE_BYTES_BY_STAGE) or {}

@@ -139,6 +139,19 @@ class Tree:
             tree.leaf_value[s] = float(val[n]) * shrinkage
             tree.leaf_weight[s] = float(hes[n])
             tree.leaf_count[s] = int(cnt[n])
+        if getattr(t, "leaf_coeff", None) is not None:
+            # linear leaves fitted on the device (ops/linear_leaves): a
+            # leaf's model is its nonzero coefficients, ascending feature
+            # (the reference sorts a branch's features)
+            tree.is_linear = True
+            coeff = np.asarray(t.leaf_coeff)[:num_leaves]
+            const = np.asarray(t.leaf_const)[:num_leaves]
+            for s in range(num_leaves):
+                nz = np.flatnonzero(coeff[s])
+                tree.leaf_features[s] = [int(used_features[f]) for f in nz]
+                tree.leaf_coeff[s] = [float(coeff[s, f]) * shrinkage
+                                      for f in nz]
+                tree.leaf_const[s] = float(const[s]) * shrinkage
         return tree
 
     @classmethod

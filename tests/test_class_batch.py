@@ -164,12 +164,15 @@ def test_gate_fallbacks():
     """Configs the batched build cannot express pin the sequential
     path (and say why) instead of failing."""
     X, y = _mc_data()
-    for extra, frag in ((dict(linear_tree=True), "linear"),
+    for extra, frag in ((dict(cegb_penalty_split=0.1), "CEGB"),
                         (dict(class_batch="off"), "class_batch=off")):
         bst, _ = _train(dict(BASE, **extra), 2, False, X, y)
         gb = bst._gbdt
         assert not gb.class_batch_ok
         assert frag in gb.class_batch_reason
+    # linear leaves fit on the device after the batched build
+    bst, _ = _train(dict(BASE, linear_tree=True), 2, False, X, y)
+    assert bst._gbdt.class_batch_ok
     # binary objective: one model per iteration, nothing to batch
     rng = np.random.RandomState(0)
     Xb = rng.normal(size=(120, 4)).astype(np.float32)

@@ -304,3 +304,37 @@ def test_bundled_search_lays_the_features_on_the_lanes(v5e, monkeypatch):
             if dims[int(m.group(2).split(",")[0])] == 3:
                 bad.append(m.group(0))
     assert seen and not bad, bad[:5]
+
+
+# (F, L, R): the higgs-linear cell's plan (28 features, 255 leaves, its
+# padded rows), a narrow tree, the 64-feature ceiling of the device Gram,
+# and 1023 leaves (four lane tiles of leaf slots)
+LINEAR_SHAPES = [(28, 255, 10502144), (5, 7, 4096), (64, 255, 1 << 16),
+                 (12, 1023, 1 << 16)]
+
+
+@pytest.mark.parametrize("F,L,R", LINEAR_SHAPES)
+def test_linear_leaf_kernels_compile(v5e, F, L, R):
+    """The moments, Gram and output kernels of ops/linear_leaves.py with
+    the fit's solve between them, as the fused step runs them."""
+    from lightgbm_tpu.boosting.tree_builder import TreeArrays
+    from lightgbm_tpu.ops import linear_leaves as LL
+    sds = _sds(v5e[0])
+    N = 2 * L
+    tree = TreeArrays(
+        sds((N,), I32), sds((N,), I32), sds((N,), bool), sds((N,), bool),
+        sds((N,), I32), sds((N,), I32), sds((N,), F32), sds((N,), F32),
+        sds((N,), F32), sds((N,), F32), sds((N, 2), jnp.uint32),
+        sds((L + 1,), I32), sds((L + 1,), F32), sds((), I32), sds((), I32))
+    assert LL.device_reason(F, L + 1, R) == ""
+
+    def fit(t, x, rl, g, h, c, num):
+        t = LL.fit_leaves(t, x, rl, g, h, c, numerical=num, lam=0.0,
+                          first=False)
+        return t, LL.leaf_outputs(t, x, rl)
+    text = _compile(fit, tree, sds((LL.padded_features(F), R), F32),
+                    sds((R,), I32), sds((R,), F32), sds((R,), F32),
+                    sds((R,), F32), sds((F,), bool)).as_text()
+    for name in (LL.MOMENTS_KERNEL_NAME, LL.GRAM_KERNEL_NAME,
+                 LL.OUTPUT_KERNEL_NAME):
+        assert name in text

@@ -36,12 +36,38 @@ for _p in (os.path.join(_ROOT, "benchmarks", "tests"),
     if _p not in sys.path:
         sys.path.insert(0, _p)
 from test_stage_cost_metrics import (  # noqa: E402,F401  (collected here too)
-    test_manifest_has_the_seven_entries_appended_for_their_cells,
+    METRICS as _SEVEN,
     test_a_reader_gives_the_hand_arithmetic,
     test_a_reader_takes_no_shape_from_the_configuration,
     test_a_reader_with_nothing_to_read_returns_nothing,
     test_the_stage_without_seconds_or_without_a_count_reads_nothing,
     test_on_several_chips_seconds_and_counts_are_both_a_chips)
+
+
+def test_manifest_has_the_seven_entries_appended_for_their_cells():
+    """The seven stage-cost metrics, together and in their order in the
+    per-layer list, each as it was written; entries appended after them
+    do not move them (the benchmark's own copy of this case reads the
+    list's last seven)."""
+    from harness.manifest import Manifest
+    man = Manifest(_ROOT)
+    assert man.problems() == []
+    names = [m["name"] for m in man.doc["per_layer"]]
+    at = names.index(next(iter(_SEVEN)))
+    block = man.doc["per_layer"][at:at + len(_SEVEN)]
+    assert [m["name"] for m in block] == list(_SEVEN)
+    for m in block:
+        unit, layer, cells = _SEVEN[m["name"]]
+        assert m == {"name": m["name"], "unit": unit, "better": "lower",
+                     "source": "device_trace", "layer": layer,
+                     "moves": "train_row_trees_per_s",
+                     "workloads": m["workloads"]}
+        assert sorted(m["workloads"]) == sorted(cells)
+    # none is a share: nothing here can read over 100% of anything
+    assert not any("roofline" in n or "mfu" in n or n.endswith("_share")
+                   for n in _SEVEN)
+
+
 BASE = {"objective": "binary", "num_leaves": 15, "leaf_batch": 4,
         "max_bin": 31, "min_data_in_leaf": 5, "verbosity": -1,
         "tree_learner": "serial"}
