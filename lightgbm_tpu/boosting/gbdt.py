@@ -75,6 +75,9 @@ class RoundRecord(NamedTuple):
     # a linear tree's (Gram multiply-adds, path features) summed over its
     # leaves (GBDT._linear_work); None for constant leaves
     linear: Optional[Tuple[int, int]] = None
+    # like ``rows``: 1 where the round took the dense route
+    # (tree_builder.dense_round_rows)
+    dense: Optional[np.ndarray] = None
 
 
 def _pad_rows(arr: np.ndarray, r_pad: int, fill=0):
@@ -1194,7 +1197,8 @@ class GBDT:
             hist_sub=self._hist_sub, parallel_mode=mode,
             hist_merge=getattr(plan, "hist_merge", "allreduce"),
             n_shards=n, search=getattr(self._bundle_meta, "search", None),
-            linear_columns=features + 1 if self._linear else 0)
+            linear_columns=features + 1 if self._linear else 0,
+            class_batched=self.class_batch_ok)
 
     def stage_work(self, n: Optional[int] = None, *,
                    fullest: bool = False) -> Dict[str, Tuple[float, str]]:
@@ -2014,7 +2018,7 @@ class GBDT:
                 int(it), int(k), np.asarray(rounds.rows),
                 np.asarray(rounds.leaves), np.asarray(rounds.stream_rows),
                 self._linear_work(tree) if tree is not None
-                and tree.is_linear else None))
+                and tree.is_linear else None, np.asarray(rounds.dense)))
 
     def train_one_iter(self, gradients: Optional[np.ndarray] = None,
                        hessians: Optional[np.ndarray] = None, *,

@@ -42,6 +42,16 @@ Design (TPU-first; not a translation):
        then ONE kernel call bounded by the same ``n_small``). So a
        round over a 1%-sized leaf pays about a chunk of a full pass,
        on every path; RoundLog.stream_rows counts what it touched.
+       Not every round compacts: where the Pallas kernel's work a row
+       is cheap next to a gathered position (a narrow bin matrix such
+       as Higgs's 28 x 63), a round whose smaller children hold more
+       rows than ``step_shape``'s ``dense_round_rows`` (a rule of the
+       step's shape alone: :func:`dense_round_rows`) skips the sort and
+       the gathers for one kernel call over all rows in stored order,
+       on ``bins`` and ``gh`` laid out once a tree; rows outside the
+       small children add zeros (RoundLog.dense). A ``lax.cond`` on the
+       round's live count picks the route; both branches give the
+       LOCAL histogram, and any merge follows the cond.
        The cache holds RAW histograms ([L+1, F, B, 3] f32, int32 when
        quantized — subtraction stays exact), ~5 MB at Higgs shape;
        callers disable hist_sub when the cache would not fit
@@ -100,7 +110,8 @@ from ..ops.split import (SplitParams, find_best_splits,
 
 __all__ = ["TreeArrays", "RoundLog", "StepShape", "SearchPlan",
            "BundleMeta", "build_tree", "build_impl", "max_rounds_for",
-           "search_plan", "searched_classes", "step_shape"]
+           "search_plan", "searched_classes", "step_shape",
+           "dense_round_rows"]
 
 NEG_INF = -jnp.inf
 F32_MAX = 3.4e38  # monotone bounds start effectively unconstrained
@@ -147,8 +158,13 @@ class RoundLog(NamedTuple):
     #                     touched to feed the histogram: trips x chunk
     #                     of the compacted stream's loop
     #                     (ops.histogram.stream_chunk_rows), R where the
-    #                     stream is unbounded (native, hist_sub off);
+    #                     stream is unbounded (native, hist_sub off), R
+    #                     up to the kernel's row block in a dense round;
     #                     per shard like ``rows``
+    dense: jax.Array    # [rounds] int32: 1 where the round's histogram
+    #                     took the dense route (one kernel pass over all
+    #                     rows, no compacted stream); per shard like
+    #                     ``rows``
 
 
 def max_rounds_for(num_leaves: int, leaf_batch: int) -> int:
@@ -178,6 +194,7 @@ class StepShape(NamedTuple):
     rounds_bound: int
     search_classes: int
     linear_columns: int = 0
+    dense_round_rows: int = 0
 
     def fields(self) -> dict:
         """``{phases.STEP_SHAPE name: int}``: the span's fields."""
@@ -271,6 +288,42 @@ def feature_block(num_features: int, n_shards: int) -> int:
     return -(-num_features // n_shards)
 
 
+# What a round's histogram costs by either route, in one-hot elements of
+# the kernel, which costs a row its own ``a_row`` of them; one element
+# is 1.657 ps on a v5e (kernels.hist_ps_per_onehot_element at Higgs, the
+# benchmark's reading, PERF.md section 5).
+# A stream position, gathered and re-laid: 24.5 ns at Higgs (0.900 s of
+# hist_gather over the 36.1M positions of a tree, PERF.md section 5),
+# 14,800 elements; but 12.6 ns where the operands sit in fast memory
+# (histwrap.gather_ns_per_position at MS-LTR), whose a_row of 8,960
+# must not take the dense route: hence under that.
+DENSE_GATHER_ELEMENTS = 8000
+# The compacted stream's index, one sort of s32[R] a round: 0.79 ns a
+# row (builder.compact_ns_per_row_round; sort.23 at Higgs, 8.4 ms over
+# 10.5M rows), 480 elements.
+DENSE_SORT_ELEMENTS = 480
+
+
+def dense_round_rows(impl: str, compacted: bool, class_batched: bool,
+                     a_row: int, kernel_rows: int) -> int:
+    """The rows over which a round's smaller children go to the kernel
+    over all rows (``kernel_rows``, the row count up to the kernel's
+    row block) instead of through the compacted stream, or 0 where no
+    round could: a round goes dense when ``n_small x (a_row + G) >
+    kernel_rows x (a_row - S)`` (``G`` = :data:`DENSE_GATHER_ELEMENTS`,
+    ``S`` = :data:`DENSE_SORT_ELEMENTS`). The route is compiled only
+    where ``a_row < G``: smaller children hold at most half the rows,
+    so past that a round would win little or nothing by it. Only the
+    Pallas kernel over a compacted stream has the route, and never a
+    class-batched build (under ``vmap`` a ``cond`` runs both
+    branches)."""
+    if (impl != "pallas" or not compacted or class_batched
+            or a_row >= DENSE_GATHER_ELEMENTS):
+        return 0
+    return max(1, kernel_rows * (a_row - DENSE_SORT_ELEMENTS)
+               // (a_row + DENSE_GATHER_ELEMENTS))
+
+
 def step_shape(*, rows: int, stored_columns: int, features: int,
                num_bins: int, bundle_bins: int, num_leaves: int,
                leaf_batch: int, hist_impl: str, gh_dtype, hist_dtype: str,
@@ -279,7 +332,8 @@ def step_shape(*, rows: int, stored_columns: int, features: int,
                hist_merge: str = "allreduce",
                n_shards: int = 1,
                search: Optional[SearchPlan] = None,
-               linear_columns: int = 0) -> StepShape:
+               linear_columns: int = 0,
+               class_batched: bool = False) -> StepShape:
     """The sizes :func:`_build_tree_impl` builds with, from its static
     arguments alone: host integers, no trace, no device. The builder
     calls this for its own W, chunk, compaction and loop bound, and the
@@ -295,7 +349,9 @@ def step_shape(*, rows: int, stored_columns: int, features: int,
     search runs over one device's and the data-parallel plans' search
     (not voting's, which elects columns of the padded lattice).
     ``linear_columns`` is the linear-leaf Gram's width, which gbdt.py
-    fits after the build (0: constant leaves)."""
+    fits after the build (0: constant leaves); ``class_batched`` a build
+    vmapped over the classes, which compiles no dense route
+    (:func:`dense_round_rows`)."""
     nb_in = bundle_bins or num_bins
     impl = effective_impl(hist_impl, nb_in)
     W = max(1, min(leaf_batch, num_leaves - 1))
@@ -313,18 +369,22 @@ def step_shape(*, rows: int, stored_columns: int, features: int,
                               parallel_mode)
     if packed is not None:
         positions = packed.positions
+    # only the native C kernel skips compaction: its partition op
+    # already keeps exact per-leaf row lists
+    compacted = bool(hist_sub) and impl != "native"
     return StepShape(
         rows=rows, stored_columns=stored_columns, stored_bins=nb_in,
         search_positions=positions, slots=2 * W,
         stream_chunk_rows=stream_chunk_rows(*plan_args, W, *dtypes),
-        # only the native C kernel skips compaction: its partition op
-        # already keeps exact per-leaf row lists
-        stream_compacted=int(bool(hist_sub) and impl != "native"),
+        stream_compacted=int(compacted),
         kernel_row_block=blk, kernel_root_row_block=root_blk,
         kernel_feature_chunk=fc, kernel_chunks=n_fb, kernel_padded_bins=bp,
         kernel_lanes=lanes, rounds_bound=max_rounds_for(num_leaves, W),
         search_classes=len(packed.widths) if packed is not None else 1,
-        linear_columns=linear_columns)
+        linear_columns=linear_columns,
+        dense_round_rows=dense_round_rows(
+            impl, compacted, class_batched, n_fb * fc * bp,
+            -(-rows // blk) * blk))
 
 
 def _round_int(x):
@@ -497,7 +557,8 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                feature_sharded: bool = False,
                hist_merge: str = "allreduce",
                n_shards: int = 1,
-               root_hist: Optional[jax.Array] = None):
+               root_hist: Optional[jax.Array] = None,
+               class_batched: bool = False):
     """Grow one tree. Returns (TreeArrays, row_leaf, valid_row_leafs,
     RoundLog) — and the CEGB state as a fifth element when ``cegb`` is
     given.
@@ -553,7 +614,8 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         block_rows=block_rows, hist_sub=hist_sub,
         parallel_mode=mode if axis_name is not None else None,
         hist_merge=hist_merge, n_shards=n_shards,
-        search=getattr(bundle_meta, "search", None))
+        search=getattr(bundle_meta, "search", None),
+        class_batched=class_batched)
     # Row compaction redirects the row streams through a gathered index
     # order, and everything downstream of the index is bounded by the
     # live rows: the matmul one-hot (R*F*B bf16) and the CPU scatter
@@ -566,6 +628,13 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     # partition op already maintains exact per-leaf row lists, so a
     # sort + gather pass over R would cost more than it saves.
     hist_compact = bool(shape.stream_compacted)
+    # The dense route (dense_round_rows): where the kernel's work a row
+    # is cheap next to a gathered position, a round whose smaller
+    # children hold more than shape.dense_round_rows rows skips the sort
+    # and the stream and runs the kernel over all rows in stored order,
+    # on operands laid out once a tree (the root pass reads them too).
+    # ``class_batched`` (the vmapped build) turns it off statically.
+    dense_route = shape.dense_round_rows > 0
     # native CPU backend: maintain the DataPartition analog — `perm`
     # holds row indices grouped by leaf (leaf_begin/leaf_cnt segments,
     # data_partition.hpp:116 Split semantics) as loop-carried state, so
@@ -885,7 +954,19 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                     "reduce_scatter" if rs_data else True, n_shards)
         return h
 
-    def hist_raw_for(slots, rl, row_gather=None, num_rows=None, part=None):
+    if mode in ("feature", "voting"):
+        hist_merge_kind = False
+    elif rs_data:
+        hist_merge_kind = "reduce_scatter"
+    else:
+        hist_merge_kind = True
+
+    def merged(h):
+        """A LOCAL raw histogram merged as :func:`hist_raw_for` merges."""
+        return merge_histograms(h, axis_name, hist_merge_kind, n_shards)
+
+    def hist_raw_for(slots, rl, row_gather=None, num_rows=None, part=None,
+                     merge=True):
         """RAW histogram for the given leaf slots — before dequant and
         EFB unbundling, both of which are LINEAR, so parent-minus-child
         subtraction happens in this space (exactly, int32, when
@@ -901,22 +982,18 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
           this chip's slot block of the merged histogram, scattered
           along the STORAGE lattice's feature axis (bundle columns when
           EFB is on: a feature never spans bundles, so whole features
-          stay chip-local and the raw cache stays exact)."""
+          stay chip-local and the raw cache stays exact).
+        ``merge=False``: the LOCAL histogram (:func:`merged` merges it)."""
         if use_native_part and part is not None:
             return hist_perm_for(slots, part)
         mat = local_bins if mode == "feature" else bins
         nb_in = bundle_bins if use_bundle else B
-        if mode in ("feature", "voting"):
-            merge = False
-        elif rs_data:
-            merge = "reduce_scatter"
-        else:
-            merge = True
         return build_histograms(
             mat, gh, rl, slots,
             num_bins=nb_in, block_rows=block_rows, axis_name=axis_name,
-            merge=merge, n_shards=n_shards, hist_dtype=hist_dtype,
-            impl=hist_impl, row_gather=row_gather, num_rows=num_rows)
+            merge=hist_merge_kind if merge else False, n_shards=n_shards,
+            hist_dtype=hist_dtype, impl=hist_impl, row_gather=row_gather,
+            num_rows=num_rows)
 
     def compact_small(row_leaf, small_slots):
         """The compacted stream of the small children's rows:
@@ -936,6 +1013,27 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         section 6, PR 38)."""
         m, _ = select_by_slot(row_leaf, small_slots, small_slots >= 0)
         return stream_index(m)
+
+    def dense_operands():
+        """``bins`` and ``gh`` in the kernel's layout for the dense route
+        (``PH.resident_operands``), over a lane extent that is a multiple
+        of the row block of the rounds' calls and of the root's."""
+        blk = int(np.lcm(shape.kernel_row_block,
+                         shape.kernel_root_row_block))
+        return PH.resident_operands(
+            local_bins if mode == "feature" else bins, gh, -(-R // blk) * blk,
+            num_bins=bundle_bins if use_bundle else B, hist_dtype=hist_dtype)
+
+    def hist_dense_for(slots, rl, operands):
+        """The LOCAL raw histogram of ``slots`` from one kernel pass over
+        all rows in stored order (the dense route), on the tree's
+        :func:`dense_operands`: rows outside the slots add zeros, so the
+        sums are the compacted stream's."""
+        return PH.build_histograms_pallas_dense(
+            *operands, rl, slots,
+            num_features=(local_bins if mode == "feature" else bins)
+            .shape[1], num_bins=bundle_bins if use_bundle else B,
+            hist_dtype=hist_dtype)
 
     def small_child(row_leaf, sel_s, right_slot, leaf_cnt=None):
         """Which child of each lane has fewer rows, ``(small_is_left
@@ -1449,6 +1547,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
     # ---------------- root ----------------
     with profiler.stage(PHS.ROOT_PASS):
         part0 = None
+        dense_ops = None
         if use_native_part:
             # DataPartition init: live rows (all slot 0 at the root) first,
             # original order preserved; dead/padded rows trail unused
@@ -1481,6 +1580,11 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             # both formulations (no row carries the -2 sentinel)
             hraw0 = jnp.zeros((2 * W,) + root_hist.shape,
                               root_hist.dtype).at[0].set(root_hist)
+        elif dense_route:
+            # made once a tree: the root pass and every dense round read
+            # them
+            dense_ops = dense_operands()
+            hraw0 = merged(hist_dense_for(root_slots, row_leaf0, dense_ops))
         else:
             hraw0 = hist_raw_for(root_slots, row_leaf0, part=part0)
         hist0 = hist_finish(hraw0)
@@ -1543,6 +1647,8 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         round_rows0 = _pvary(round_rows0, axis_name)
     state["round_rows"] = round_rows0
     state["round_stream"] = round_rows0
+    if dense_route:
+        state["round_dense"] = round_rows0
     state["round_leaves"] = jnp.zeros((rounds_bound,), jnp.int32)
 
     state.update(tree=tree, bs_gain=bs_gain, bs_feat=bs_feat, bs_thr=bs_thr,
@@ -1982,8 +2088,10 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
 
         # -- 4. children histograms. hist_sub: the SMALLER child (by raw
         # row count — that is what bounds the stream) is histogrammed
-        # directly over a compacted, dynamically-bounded row stream; the
-        # sibling is parent minus child from the raw cache
+        # directly over a compacted, dynamically-bounded row stream, or
+        # where it holds many rows by one kernel pass over all of them
+        # (dense_route); the sibling is parent minus child from the raw
+        # cache
         # (serial_tree_learner.cpp:567-592 Subtract). Otherwise both
         # children are histogrammed directly over all R rows.
         slots2w = jnp.concatenate([jnp.where(valid, sel_s, -2),
@@ -2000,6 +2108,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         # rows this round's histogram stream is bounded by, and the
         # stream positions it touches (RoundLog)
         rows_r = stream_r = R_i32
+        new_state_route = {}
         if hist_sub:
             stg(PHS.COUNT)
             # the native partition maintains the counts
@@ -2008,7 +2117,32 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                 leaf_cnt=lc_n if use_native_part else None)
             small_slots = jnp.where(
                 valid, jnp.where(small_is_left, sel_s, right_slot), -2)
-            if hist_compact:
+            if dense_route:
+                n_live = jnp.where(valid, small_loc, 0).sum() \
+                    .astype(jnp.int32)
+                go_dense = n_live > shape.dense_round_rows
+                stg(PHS.COMPACT)
+
+                def compacted():
+                    c_idx, n_small = compact_small(row_leaf, small_slots)
+                    return hist_raw_for(small_slots, row_leaf,
+                                        row_gather=c_idx, num_rows=n_small,
+                                        merge=False)
+                # each branch gives this shard's LOCAL histogram: shards
+                # may take different branches, so the merge comes after
+                hsmall = merged(jax.lax.cond(
+                    go_dense,
+                    lambda: hist_dense_for(small_slots, row_leaf,
+                                           dense_ops),
+                    compacted))
+                rows_r = n_live
+                new_state_route["round_dense"] = st["round_dense"].at[
+                    st["r"]].set(go_dense.astype(jnp.int32))
+                # the kernel covers every row up to its row block
+                stream_r = jnp.where(
+                    go_dense, -(-R // shape.kernel_row_block)
+                    * shape.kernel_row_block, stream_rows_for(n_live))
+            elif hist_compact:
                 stg(PHS.COMPACT)
                 c_idx, n_small = compact_small(row_leaf, small_slots)
                 rows_r = n_small
@@ -2064,12 +2198,14 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                    round_leaves=st["round_leaves"].at[st["r"]].set(n_valid),
                    **new_state_extra, **new_state_mono,
                    **new_state_forced, **new_state_hist,
-                   **new_state_part)
+                   **new_state_part, **new_state_route)
         return out
 
     state = jax.lax.while_loop(cond, body, state)
     rounds = RoundLog(rows=state["round_rows"], leaves=state["round_leaves"],
-                      stream_rows=state["round_stream"])
+                      stream_rows=state["round_stream"],
+                      dense=state["round_dense"] if dense_route
+                      else round_rows0)
     if use_cegb:
         cegb_out = (state["cegb_feat_used"],
                     state.get("cegb_used_rows"))
@@ -2165,7 +2301,7 @@ def _build_tree_class_batched(bins, gh, row_leaf0, num_bins_pf,
                                 nan_bin_pf, is_cat_pf, feature_mask,
                                 rng_key=key_k, quant_scales=qs_k,
                                 hist_impl=hist_impl, root_hist=rh_k,
-                                **kw)
+                                class_batched=True, **kw)
 
     return jax.vmap(
         one, in_axes=(0,

@@ -318,7 +318,15 @@ def build_histograms(bins: jax.Array, gh: jax.Array, row_leaf: jax.Array,
     without ``row_gather`` bounds an already-ordered stream: rows past
     it must then carry ``row_leaf == -1``. Works inside shard_map: each
     shard bounds its own stream (no collective sits inside the loops);
-    the merge after the kernel re-syncs.
+    the merge after the kernel re-syncs. The grow loop does not send
+    every round here: with ``impl='pallas'`` on a narrow bin matrix, a
+    round whose smaller children hold more rows than
+    ``tree_builder.step_shape``'s ``dense_round_rows`` (a rule of the
+    step's shape) skips the index and this stream and calls the kernel
+    once over all rows in stored order
+    (``pallas_histogram.build_histograms_pallas_dense``); the tree
+    builder then merges after its choice of route, with ``merge=False``
+    here.
 
     Carried accumulation (out-of-core, data/chunked.py): ``init``
     [L, F, B, CH] seeds the accumulator, so a row stream too large for

@@ -41,8 +41,9 @@ narrow-dtype arithmetic):
 - ``hist[fc*Bp, lanes] += onehot @ ghl^T`` (contract the row axis of
   both — the q @ k^T form).
 
-Compacted streams (``row_gather`` + ``num_rows``, what every round of
-the grow loop after the root pass sends): the lane-major operands are
+Compacted streams (``row_gather`` + ``num_rows``, what a round of the
+grow loop after the root pass sends unless it takes the dense route
+below): the lane-major operands are
 not made from a gathered copy of the whole matrix. :func:`_stream_operands`
 runs ``ceil(num_rows / chunk)`` trips of gather -> transpose ->
 ``dynamic_update_slice`` into the operand buffers, ``chunk`` a multiple
@@ -57,6 +58,16 @@ The kernel is called once on the buffers with ``num_rows`` as its
 scalar-prefetch bound
 (:func:`build_histograms_pallas_lanes` is that call, for operands laid
 out already). What lies past the last chunk written is never read.
+
+Dense rounds: where the kernel's work a row is small next to what a
+gathered position costs (a narrow bin matrix such as Higgs's 28 x 63),
+a round whose smaller children hold more rows than
+``tree_builder.step_shape``'s ``dense_round_rows`` skips the stream's
+sort and gathers and calls the kernel once over all rows in stored
+order (:func:`build_histograms_pallas_dense`), on ``bins`` and ``gh``
+laid out once a tree (:func:`resident_operands`, which the root pass
+reads too); rows outside the round's slots add zeros. The gate and the
+threshold come from the step's shape alone.
 
 Grid: ``(feature_chunks, row_blocks)`` with rows innermost, so each
 feature chunk's accumulator stays pinned in VMEM across the whole row
@@ -94,6 +105,7 @@ from .histogram import (HIST_CH, _gather_rows, _row_table,
                         pallas_shape_reason, stream_trips)
 
 __all__ = ["build_histograms_pallas", "build_histograms_pallas_lanes",
+           "build_histograms_pallas_dense", "resident_operands",
            "stream_chunk", "build_root_histograms_classes"]
 
 # The histogram kernel's custom call is named after this in the compiled
@@ -298,6 +310,43 @@ def _stream_operands(bins, gh, row_leaf, row_gather, live, *, chunk: int,
 
     return jax.lax.fori_loop(0, stream_trips(live[0], chunk, R), put_chunk,
                              bufs)
+
+
+def resident_operands(bins, gh, r_pad: int, *, num_bins: int,
+                      hist_dtype: str = "bfloat16"):
+    """``(bins_t [n_fb, fc, r_pad] int32, gh_t [3, r_pad])``: the kernel's
+    bin and addend operands over every row in stored order, laid out once
+    for all the calls of a tree that read them so (the root pass and the
+    grow loop's dense rounds: :func:`build_histograms_pallas_dense`).
+    ``r_pad`` must be a multiple of each such call's row block. The
+    feature chunking does not depend on the slot count, so one layout
+    serves every call."""
+    cdt, acc_dt = _kernel_dtypes(gh.dtype, hist_dtype)
+    _, fc, n_fb, _, _ = _plan(bins.shape[1], int(num_bins), HIST_CH,
+                              jnp.dtype(cdt).itemsize)
+    with profiler.stage(phases.HIST_RELAYOUT):
+        return (_bins_chunks(bins, r_pad, fc, n_fb),
+                _rows_to_lanes(gh.astype(acc_dt), r_pad))
+
+
+def build_histograms_pallas_dense(bins_t, gh_t, row_leaf, leaf_ids, *,
+                                  num_features: int, num_bins: int,
+                                  hist_dtype: str = "bfloat16"):
+    """One kernel pass over ALL rows in stored order for ``leaf_ids``,
+    from :func:`resident_operands`: only ``row_leaf`` is re-laid, and
+    the kernel's slot compare zeroes every row outside the slots, so the
+    result equals the compacted stream's over the same rows (f32 sums
+    may differ by reassociation; integer ones are exact). The grow
+    loop's route for a round whose smaller children hold so many rows
+    that the stream's sort and gathers cost more than this pass
+    (``tree_builder.step_shape``'s ``dense_round_rows``)."""
+    R = row_leaf.shape[0]
+    with profiler.stage(phases.HIST_RELAYOUT):
+        leaf_t = _leaf_lanes(row_leaf, bins_t.shape[-1])
+    return build_histograms_pallas_lanes(
+        bins_t, gh_t, leaf_t, leaf_ids, _live_rows(None, R),
+        num_features=num_features, num_bins=num_bins,
+        hist_dtype=hist_dtype)
 
 
 def _row_stream(bins, gh, row_leaf, row_gather, num_rows, *, blk: int,

@@ -43,7 +43,8 @@ __all__ = ["GRADS", "SAMPLING", "BUILD", "UPDATE", "EVAL",
            "SHAPE_KERNEL_ROW_BLOCK", "SHAPE_KERNEL_ROOT_ROW_BLOCK",
            "SHAPE_KERNEL_FEATURE_CHUNK", "SHAPE_KERNEL_CHUNKS",
            "SHAPE_KERNEL_PADDED_BINS", "SHAPE_KERNEL_LANES",
-           "SHAPE_ROUNDS_BOUND", "SHAPE_LINEAR_COLUMNS", "STEP_SHAPE",
+           "SHAPE_ROUNDS_BOUND", "SHAPE_LINEAR_COLUMNS",
+           "SHAPE_DENSE_ROUND_ROWS", "STEP_SHAPE",
            "STAGE_WORK",
            "UNIT_POSITIONS", "UNIT_ELEMENTS", "UNIT_ONEHOT",
            "UNIT_SORTED", "UNIT_ROW_PASSES", "UNIT_LATTICE", "UNIT_ROWS",
@@ -199,13 +200,17 @@ SHAPE_KERNEL_LANES = "shape_kernel_lanes"
 SHAPE_ROUNDS_BOUND = "shape_rounds_bound"    # the grow loop's bound
 SHAPE_LINEAR_COLUMNS = "shape_linear_columns"  # linear leaves: the Gram's
 #                            width, F + 1 (the constant column); else 0
+SHAPE_DENSE_ROUND_ROWS = "shape_dense_round_rows"  # a round whose smaller
+#                            children hold more rows than this skips the
+#                            compacted stream for one kernel pass over
+#                            all rows; 0 where no such route is compiled
 STEP_SHAPE = (SHAPE_ROWS, SHAPE_STORED_COLUMNS, SHAPE_STORED_BINS,
               SHAPE_SEARCH_POSITIONS, SHAPE_SLOTS, SHAPE_STREAM_CHUNK_ROWS,
               SHAPE_STREAM_COMPACTED, SHAPE_KERNEL_ROW_BLOCK,
               SHAPE_KERNEL_ROOT_ROW_BLOCK, SHAPE_KERNEL_FEATURE_CHUNK,
               SHAPE_KERNEL_CHUNKS, SHAPE_KERNEL_PADDED_BINS,
               SHAPE_KERNEL_LANES, SHAPE_ROUNDS_BOUND, SHAPE_SEARCH_CLASSES,
-              SHAPE_LINEAR_COLUMNS)
+              SHAPE_LINEAR_COLUMNS, SHAPE_DENSE_ROUND_ROWS)
 
 # the unit a stage's work is counted in (telemetry/costmodel.stage_work:
 # the counts come from the round log and the step's shape, at the

@@ -35,6 +35,9 @@ _COMP_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.-]+)\s*\(.*\)\s*->"
                       r"\s*.+\{\s*$")
 _CALLS_RE = re.compile(r"\b(?:calls|to_apply|body|condition)="
                        r"%?([\w.-]+)")
+# a conditional's branches (lax.cond: the grow loop's route of a round)
+_BRANCHES_RE = re.compile(r"\b(?:branch_computations=\{([^}]*)\}|"
+                          r"(?:true|false)_computation=%?([\w.-]+))")
 
 # opcodes that never execute (metadata / plumbing) — excluded from the
 # per-phase op/byte attribution so it reflects real work
@@ -57,17 +60,23 @@ def stage_work(shape: Dict[str, int], log: Sequence[Any], *,
     at the boundaries of the stage scopes, so that a stage's device
     seconds over its count is what one unit costs:
 
-    - ``hist_gather``: stream positions gathered, ``sum(stream_rows)``;
+    - ``hist_gather``: stream positions gathered, ``sum(stream_rows)``
+      over the rounds that streamed (not the dense ones, which gather
+      nothing: ``RoundLog.dense``);
     - ``hist_relayout``: elements re-laid, positions x stored columns,
-      plus the root's rows x stored columns a tree;
+      plus the root's rows x stored columns a tree, plus a dense round's
+      one leaf row over the resident operands' lanes (the rows up to a
+      multiple of both row blocks);
     - ``hist_kernel``: one-hot elements sent through the MXU: the rows
       the kernel's grid steps cover (a round's live rows rounded up to
-      the plan's row block, the root's padded rows a tree) x chunks x
-      feature chunk x padded bins. Padding of bins, features and the row
-      block is counted (the pass pays for it); positions of a chunk past
-      the last live row block are not (the kernel skips them);
-    - ``compact``: elements sorted, rounds x rows, where the step
-      compacts; ``apply``, ``count``: row passes, rounds x rows each;
+      the plan's row block, all rows so rounded in a dense round, the
+      root's padded rows a tree) x chunks x feature chunk x padded bins.
+      Padding of bins, features and the row block is counted (the pass
+      pays for it); positions of a chunk past the last live row block
+      are not (the kernel skips them);
+    - ``compact``: elements sorted, rows x the rounds that streamed,
+      where the step compacts; ``apply``, ``count``: row passes, rounds
+      x rows each;
     - ``find``, ``subtract``: lattice positions scanned, rounds x slots x
       positions a slot; ``unbundle`` the same plus the root's (its scope
       is open at the root too); ``root_pass``: the root's scan, trees x
@@ -96,7 +105,10 @@ def stage_work(shape: Dict[str, int], log: Sequence[Any], *,
              * shape[PHS.SHAPE_KERNEL_PADDED_BINS])
     scan = shape[PHS.SHAPE_SLOTS] * shape[PHS.SHAPE_SEARCH_POSITIONS]
     trees = len(log)
+    kernel_rows = _ceil_to(rows, blk)
+    lanes = int(np.lcm(blk, shape[PHS.SHAPE_KERNEL_ROOT_ROW_BLOCK]))
     rounds, positions, covered = 0, 0.0, 0.0
+    dense_rounds, streamed_rounds = 0.0, 0.0
     linear = [lin for lin in (getattr(rec, "linear", None) for rec in log)
               if lin is not None]
     for rec in log:
@@ -106,16 +118,28 @@ def stage_work(shape: Dict[str, int], log: Sequence[Any], *,
         live = np.asarray(rec.rows).reshape(-1, built.shape[-1])[:, built]
         stream = np.asarray(rec.stream_rows).reshape(
             -1, built.shape[-1])[:, built]
+        # a log from before the dense route has no ``dense``
+        dense = getattr(rec, "dense", None)
+        dense = (np.zeros(live.shape, bool) if dense is None else
+                 np.asarray(dense).reshape(-1, built.shape[-1])[:, built]
+                 > 0)
         over = np.max if fullest else np.mean
-        positions += float(over(stream.sum(axis=1, dtype=np.int64)))
-        covered += float(over(
-            _ceil_to(live.astype(np.int64), blk).sum(axis=1)))
+        positions += float(over(np.where(dense, 0, stream).sum(
+            axis=1, dtype=np.int64)))
+        covered += float(over(np.where(
+            dense, kernel_rows, _ceil_to(live.astype(np.int64), blk))
+            .sum(axis=1)))
+        dense_rounds += float(over(dense.sum(axis=1)))
+        streamed_rounds += float(over((~dense).sum(axis=1)))
     root_rows = _ceil_to(rows, shape[PHS.SHAPE_KERNEL_ROOT_ROW_BLOCK])
+    lanes_rows = _ceil_to(rows, lanes)
     counts = {
         PHS.HIST_GATHER: positions,
-        PHS.HIST_RELAYOUT: (positions + trees * rows) * cols,
+        PHS.HIST_RELAYOUT: ((positions + trees * rows) * cols
+                            + dense_rounds * lanes_rows),
         PHS.HIST_KERNEL: (covered + trees * root_rows) * a_row,
-        PHS.COMPACT: rounds * rows * shape[PHS.SHAPE_STREAM_COMPACTED],
+        PHS.COMPACT: (streamed_rounds * rows
+                      * shape[PHS.SHAPE_STREAM_COMPACTED]),
         PHS.APPLY: rounds * rows,
         PHS.COUNT: rounds * rows,
         PHS.FIND: rounds * scan,
@@ -159,6 +183,12 @@ class _Instr(NamedTuple):
     comp: str                 # enclosing computation
     callee: Optional[str]     # computation a fusion/call/while runs
     operands: Tuple[str, ...]
+    branches: Tuple[str, ...] = ()   # a conditional's computations
+
+    @property
+    def called(self) -> Tuple[str, ...]:
+        """Every computation this instruction runs."""
+        return ((self.callee,) if self.callee else ()) + self.branches
 
 
 _OPERAND_RE = re.compile(r"%([\w.-]+)")
@@ -179,8 +209,11 @@ def _instructions(hlo_text: str) -> List[_Instr]:
         body = line.split(", metadata=", 1)[0]
         calls = _CALLS_RE.findall(body)
         rest = body.partition(" " + op.opcode + "(")[2]
+        branches = tuple(
+            b.strip().lstrip("%") for group, one in _BRANCHES_RE.findall(body)
+            for b in (group.split(",") if group else [one]) if b.strip())
         rows.append(_Instr(op, comp, calls[-1] if calls else None,
-                           tuple(_OPERAND_RE.findall(rest))))
+                           tuple(_OPERAND_RE.findall(rest)), branches))
     return rows
 
 
@@ -243,8 +276,8 @@ def _resolved_phases(hlo_text: str, rows: Optional[List[_Instr]] = None):
                     + [own.get(u) for u in real_users(r.op.name)])
             own[r.op.name] = next((p for p in near if p is not None), None)
     for r in rows:
-        if r.callee is not None:
-            caller_stage.setdefault(r.callee, own[r.op.name])
+        for c in r.called:
+            caller_stage.setdefault(c, own[r.op.name])
     changed = True
     while changed:      # nested callees inherit through their callers
         changed = False
@@ -254,10 +287,11 @@ def _resolved_phases(hlo_text: str, rows: Optional[List[_Instr]] = None):
                 if ph is not None:
                     own[r.op.name] = ph
                     changed = True
-            if r.callee is not None and caller_stage.get(r.callee) is None \
-                    and own[r.op.name] is not None:
-                caller_stage[r.callee] = own[r.op.name]
-                changed = True
+            for c in r.called:
+                if caller_stage.get(c) is None \
+                        and own[r.op.name] is not None:
+                    caller_stage[c] = own[r.op.name]
+                    changed = True
     return [(r.op, r.comp, own[r.op.name]) for r in rows], mixed
 
 
@@ -279,8 +313,8 @@ def staged_ops(hlo_text: str) -> List[StagedOp]:
     loops = {r.callee for r in rows if r.op.opcode == "while"}
     called_from: Dict[str, str] = {}
     for r in rows:
-        if r.callee is not None:
-            called_from.setdefault(r.callee, r.comp)
+        for c in r.called:
+            called_from.setdefault(c, r.comp)
 
     def in_loop(comp):
         seen = set()

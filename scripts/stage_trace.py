@@ -120,6 +120,10 @@ def main(argv=None) -> int:
     # None on a commit whose round log has no stream_rows yet
     touched = sum(int(getattr(r, "stream_rows", r.rows * 0).sum())
                   for r in log)
+    # rounds that took the dense route (None before the route existed)
+    dense = (sum(int(r.dense[r.leaves > 0].sum()) for r in log)
+             if all(getattr(r, "dense", None) is not None for r in log)
+             else None)
     model_text = bst.model_to_string()
     with open(os.path.join(args.out, "model.txt"), "w") as f:
         f.write(model_text)
@@ -138,6 +142,8 @@ def main(argv=None) -> int:
         "live_row_share_pct": 100.0 * live / max(
             rounds * cfg["shape"]["rows"], 1),
         "stream_row_share_pct": 100.0 * live / touched if touched else None,
+        "dense_rounds_per_tree": (dense / max(len(log), 1)
+                                  if dense is not None else None),
         "step_shape": gb.step_shape,
         "stage_count_unit_ns": {k: [c, u, s * 1e9]
                                 for k, (c, u, s) in costs.items()},

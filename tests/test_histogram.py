@@ -330,10 +330,11 @@ def test_pallas_tree_with_subtraction_matches_scatter(rng, interp):
     the kernel over the COMPACTED dynamic row stream (row_gather +
     num_rows — VERDICT r4 #3's reachability: the same call
     tree_builder makes on TPU, here through the interpreter). Must grow
-    the scatter tree."""
+    the scatter tree. The lattice is wide enough (20 x 255) that no round
+    takes the dense route (tests/test_dense_round.py has that route)."""
     from lightgbm_tpu.boosting.tree_builder import build_tree
     from lightgbm_tpu.ops.split import SplitParams
-    R, F, B = 1024, 6, 16
+    R, F, B = 1024, 20, 255
     bins = rng.randint(0, B, size=(R, F)).astype(np.uint8)
     y = rng.normal(size=R)
     g = (y - y.mean()).astype(np.float32)
@@ -354,6 +355,7 @@ def test_pallas_tree_with_subtraction_matches_scatter(rng, interp):
             num_leaves=15, leaf_batch=2, max_depth=-1, num_bins=B,
             split_params=sp, hist_dtype="float32", hist_impl=impl,
             block_rows=256, hist_sub=True)
+        assert not np.asarray(_rounds.dense).any()
         out[impl] = (np.asarray(t.split_feature),
                      np.asarray(t.threshold_bin), np.asarray(rl))
     np.testing.assert_array_equal(out["pallas"][0], out["scatter"][0])
@@ -481,6 +483,39 @@ def test_pallas_interpret_training_matches_scatter(rng, interp, monkeypatch,
         np.testing.assert_array_equal(pred_p, pred_s)
     else:
         np.testing.assert_allclose(pred_p, pred_s, rtol=0, atol=1e-6)
+
+
+def test_pallas_interpret_training_takes_the_dense_route(rng, interp):
+    """The sibling of the test above on a lattice where the dense route
+    engages (8 x 255: ``tree_builder.dense_round_rows``): a whole
+    ``lgb.train`` with ``hist_impl=pallas`` sends some rounds to one
+    kernel pass over all rows and the others through the compacted
+    stream (``round_log[i].dense``), and grows the trees
+    ``hist_impl=scatter`` grows."""
+    import lightgbm_tpu as lgb
+    # enough rows that the rows the Dataset pads to (16,384) leave the
+    # first rounds' smaller children over the threshold
+    X, y, _ = _interp_data(rng, "plain", n=12000, f=8)
+    params = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
+              "verbosity": -1, "tree_learner": "serial", "max_bin": 255,
+              "hist_dtype": "float32", "deterministic": True}
+    out = {}
+    for impl in ("pallas", "scatter"):
+        bst = lgb.train(dict(params, hist_impl=impl),
+                        lgb.Dataset(X, label=y), num_boost_round=3)
+        out[impl] = (bst._all_trees(), bst.predict(X), bst._gbdt)
+    gb = out["pallas"][2]
+    assert gb._step_shape().dense_round_rows > 0
+    assert out["scatter"][2]._step_shape().dense_round_rows == 0
+    routes = np.concatenate([rec.dense[rec.leaves > 0]
+                             for rec in gb.round_log])
+    assert routes.any() and not routes.all()
+    for tp, ts in zip(out["pallas"][0], out["scatter"][0]):
+        for field in ("split_feature", "threshold_bin", "leaf_count"):
+            np.testing.assert_array_equal(
+                getattr(tp, field), getattr(ts, field), err_msg=field)
+    np.testing.assert_allclose(out["pallas"][1], out["scatter"][1],
+                               rtol=0, atol=1e-6)
 
 
 @pytest.mark.parametrize("backend,impl,num_bins,want", [
@@ -1029,7 +1064,8 @@ def test_compacted_tree_equals_uncompacted_tree_bit_for_bit(plan_kind):
     chunk = stream_chunk_rows("scatter", r_shard, F, 32, 4, jnp.float32,
                               "float32", block)
     assert chunk == block
-    rows, leaves, stream = (np.asarray(a) for a in log1)
+    rows, leaves, stream, dense = (np.asarray(a) for a in log1)
+    assert not dense.any()      # scatter: no dense route
     ran = leaves > 0
     assert ran.sum() >= 16
     assert (stream >= rows).all() and (stream % chunk == 0).all()

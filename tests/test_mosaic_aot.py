@@ -181,6 +181,7 @@ def test_stage_map_names_the_grow_loop_at_benchmark_shapes(v5e, config,
     import re
 
     from lightgbm_tpu import phases
+    from lightgbm_tpu.boosting import tree_builder
     from lightgbm_tpu.boosting.tree_builder import _build_tree_jit
     from lightgbm_tpu.ops.pallas_histogram import HIST_KERNEL_NAME
     from lightgbm_tpu.ops.split import SplitParams
@@ -197,9 +198,21 @@ def test_stage_map_names_the_grow_loop_at_benchmark_shapes(v5e, config,
     assert set(sm.stages.values()) <= phases.KNOWN_PHASES
     rows = costmodel._instructions(text)
     whiles = {r.callee: r.comp for r in rows if r.op.opcode == "while"}
-    # the chunk loop is the while that runs inside another's body
-    chunk_body = next(c for c, parent in whiles.items() if parent in whiles)
-    body = whiles[chunk_body]
+    called_from = {c: r.comp for r in rows for c in r.called}
+
+    def loop_of(comp):
+        """The body of the innermost while that runs ``comp``."""
+        while comp is not None and comp not in whiles:
+            comp = called_from.get(comp)
+        return comp
+    # the chunk loop is the while that runs inside another's body (since
+    # the dense route, inside a branch of the round's conditional)
+    chunk_body = next(c for c, parent in whiles.items()
+                      if loop_of(parent) is not None)
+    body = loop_of(whiles[chunk_body])
+    # the round: the grow loop's body and its conditional's branches
+    round_comps = {body} | {b for r in rows if r.comp == body
+                            for b in r.branches}
     shape = re.compile(r"\b(?:pred|bf16|[sufc]\d+)\[([0-9,]*)\]")
     lines = {m.group(1): ln.split(", metadata=")[0]
              for ln in text.splitlines()
@@ -207,7 +220,8 @@ def test_stage_map_names_the_grow_loop_at_benchmark_shapes(v5e, config,
     deep = phases.BUILD_STAGES
     seen = {body: 0, chunk_body: 0}
     for r in rows:
-        if r.comp not in seen or r.op.opcode in costmodel._NOOP_OPCODES:
+        comp = body if r.comp in round_comps else r.comp
+        if comp not in seen or r.op.opcode in costmodel._NOOP_OPCODES:
             continue
         elems = max((int(np.prod([int(x) for x in d.split(",") if x]))
                      for d in shape.findall(lines[r.op.name])), default=0)
@@ -221,7 +235,7 @@ def test_stage_map_names_the_grow_loop_at_benchmark_shapes(v5e, config,
                 # or in slices that a ConcatBitcast joins: no source
                 # line to name, bare ``build`` in a stage table
                 continue
-            seen[r.comp] += 1
+            seen[comp] += 1
             assert sm.stages.get(r.op.name) in deep, (
                 r.op.name, sm.stages.get(r.op.name), r.op.op_name)
             longest = max(int(x) for d in shape.findall(lines[r.op.name])
@@ -238,7 +252,17 @@ def test_stage_map_names_the_grow_loop_at_benchmark_shapes(v5e, config,
     staged = {sm.stages.get(r.op.name) for r in rows if r.comp == chunk_body}
     assert {phases.HIST_GATHER, phases.HIST_RELAYOUT} <= staged
     kernels = [r for r in rows if r.op.name.startswith(HIST_KERNEL_NAME)]
-    assert len(kernels) == 2      # the root pass and the in-loop call
+    # the root pass and the compacted stream's call; at Higgs's narrow
+    # lattice also the dense route's pass over all rows
+    # (tree_builder.dense_round_rows), and only there
+    dense = tree_builder.step_shape(
+        rows=R, stored_columns=F, features=F, num_bins=kw["num_bins"],
+        bundle_bins=0, num_leaves=kw["num_leaves"],
+        leaf_batch=kw["leaf_batch"], hist_impl="pallas",
+        gh_dtype=jnp.float32, hist_dtype=kw["hist_dtype"],
+        block_rows=kw["block_rows"], hist_sub=True).dense_round_rows
+    assert bool(dense) == (config == "higgs")
+    assert len(kernels) == (3 if dense else 2)
     assert all(sm.stages[r.op.name] == phases.HIST_KERNEL for r in kernels)
 
 

@@ -308,6 +308,65 @@ def test_a_ranking_objective_counts_its_pair_slots():
     assert "rank_pairs" not in costmodel.stage_work(shape, [rec])
 
 
+def _mixed_shape():
+    shape = dict.fromkeys(phases.STEP_SHAPE, 1)
+    shape.update({phases.SHAPE_ROWS: 1000, phases.SHAPE_STORED_COLUMNS: 4,
+                  phases.SHAPE_KERNEL_ROW_BLOCK: 128,
+                  phases.SHAPE_KERNEL_ROOT_ROW_BLOCK: 256,
+                  phases.SHAPE_KERNEL_FEATURE_CHUNK: 4,
+                  phases.SHAPE_KERNEL_PADDED_BINS: 64,
+                  phases.SHAPE_SLOTS: 2, phases.SHAPE_SEARCH_POSITIONS: 10})
+    return shape
+
+
+def test_a_dense_round_is_counted_by_its_route():
+    """A round log with both routes (``RoundLog.dense``): a dense round
+    gathers and sorts nothing, its kernel covers every row up to the row
+    block (1,024), and it re-lays one leaf row over the resident
+    operands' lanes (rows up to a multiple of both row blocks: 1,024)."""
+    rec = type("R", (), {})()
+    rec.leaves = np.array([1, 2, 2, 0])
+    rec.rows = np.array([400, 90, 300, 0])
+    rec.stream_rows = np.array([1024, 128, 1024, 0])
+    rec.dense = np.array([1, 0, 1, 0])
+    got = {k: c for k, (c, _) in
+           costmodel.stage_work(_mixed_shape(), [rec]).items()}
+    assert got["hist_gather"] == 128
+    assert got["hist_relayout"] == (128 + 1000) * 4 + 2 * 1024
+    # the rounds' 1024 + 128 + 1024 rows and the root's 1024, x 256
+    assert got["hist_kernel"] == (1024 + 128 + 1024 + 1024) * 256
+    assert got["compact"] == 1 * 1000
+    assert got["apply"] == got["count"] == 3 * 1000
+    # the same log without ``dense`` (a program before the route) counts
+    # every round as streamed
+    del rec.dense
+    rec.stream_rows = np.array([512, 128, 384, 0])
+    old = {k: c for k, (c, _) in
+           costmodel.stage_work(_mixed_shape(), [rec]).items()}
+    assert old["hist_gather"] == 1024 and old["compact"] == 3000
+    assert old["hist_kernel"] == (512 + 128 + 384 + 1024) * 256
+
+
+@pytest.mark.parametrize("fullest", [False, True])
+def test_shards_that_take_different_routes(fullest):
+    """Under a row mesh each shard picks its own route: a shard's counts
+    follow its own, and the mean or the fullest shard is taken as for
+    every other count."""
+    rec = type("R", (), {})()
+    rec.leaves = np.array([1, 2])
+    rec.rows = np.array([[400, 90], [300, 200]])
+    rec.stream_rows = np.array([[1024, 128], [384, 256]])
+    rec.dense = np.array([[1, 0], [0, 0]])
+    got = {k: c for k, (c, _) in costmodel.stage_work(
+        _mixed_shape(), [rec], fullest=fullest).items()}
+    pick = max if fullest else (lambda a, b: (a + b) / 2)
+    assert got["hist_gather"] == pick(128, 384 + 256)
+    assert got["compact"] == pick(1, 2) * 1000
+    assert got["hist_kernel"] == (pick(1024 + 128, 384 + 256) + 1024) * 256
+    assert got["hist_relayout"] == (pick(128, 640) + 1000) * 4 \
+        + pick(1, 0) * 1024
+
+
 _START = """
 import numpy as np, jax, jax.monitoring
 import lightgbm_tpu as lgb
