@@ -60,8 +60,9 @@ from .tree_builder import (BundleMeta, StepShape, TreeArrays, build_impl,
 __all__ = ["GBDT"]
 
 kEpsilon = 1e-15
-# more than any benchmark window holds (83 trees of 0.55 s at MS-LTR
-# since PR 33; at 64 the window's readers saw only its last 64 trees)
+# iterations GBDT.round_log keeps, a record a tree (K a multiclass
+# iteration): more than any benchmark window holds (83 trees of 0.55 s at
+# MS-LTR since PR 33; at 64 the window's readers saw only its last 64)
 ROUND_LOG_TREES = 256
 
 
@@ -78,6 +79,33 @@ class RoundRecord(NamedTuple):
     # like ``rows``: 1 where the round took the dense route
     # (tree_builder.dense_round_rows)
     dense: Optional[np.ndarray] = None
+    # a class-batched iteration's: the rounds its batched grow loop ran,
+    # the same on each of its K records (the most rounds with
+    # ``leaves > 0`` of any class: _build_tree_class_batched); None
+    # where each tree had a loop of its own
+    batched_rounds: Optional[int] = None
+
+
+def _batched_rounds(rounds) -> int:
+    """Rounds a class-batched build's loop ran, from its RoundLog with a
+    leading K: the most rounds any class split in (a round that runs
+    splits at least one leaf, and a class whose loop has ended keeps
+    ``leaves`` 0 in the batched loop's later rounds)."""
+    leaves = np.asarray(rounds.leaves)
+    return int((leaves > 0).reshape(leaves.shape[0], -1).sum(axis=1).max())
+
+
+def _as_constant(tree: Tree) -> None:
+    """A tree that did not grow is the model's constant 0 (before the
+    init score AddBias folds into iteration 0's trees): its iteration
+    left the class's scores as they were, and LightGBM keeps such a
+    tree as the fresh one-leaf ``Tree``, whose output is 0, and makes
+    only the first iteration's ``AsConstantTree(init_score)``
+    (gbdt.cpp ``TrainOneIter``, the ``num_leaves() > 1`` else branch).
+    Only a multiclass iteration keeps such a tree (a binary one stops
+    training, iteration 0 aside)."""
+    if tree.num_leaves == 1 and not tree.is_linear:
+        tree.leaf_value[0] = 0.0
 
 
 def _pad_rows(arr: np.ndarray, r_pad: int, fill=0):
@@ -658,12 +686,12 @@ class GBDT:
         self._fused_jit = None
         self._full_mask_cache: Optional[Tuple] = None
         self.host_sync_count = 0
-        # the last ROUND_LOG_TREES trees' per-round counters on the host
-        # (tree_builder.RoundLog as numpy, plus iteration and class):
-        # fetched by the transfer that brings the trees, never by one of
-        # their own
+        # the last ROUND_LOG_TREES iterations' per-round counters on the
+        # host, a record a tree (tree_builder.RoundLog as numpy, plus
+        # iteration and class): fetched by the transfer that brings the
+        # trees, never by one of their own
         self.round_log: collections.deque = collections.deque(
-            maxlen=ROUND_LOG_TREES)
+            maxlen=ROUND_LOG_TREES * self.K)
         GBDT._latest = weakref.ref(self)
         # the fused step's shape (phases.STEP_SHAPE) and, under a
         # parallel plan, the plan's counters (phases.PLAN_COUNTERS): set
@@ -1198,7 +1226,7 @@ class GBDT:
             hist_merge=getattr(plan, "hist_merge", "allreduce"),
             n_shards=n, search=getattr(self._bundle_meta, "search", None),
             linear_columns=features + 1 if self._linear else 0,
-            class_batched=self.class_batch_ok)
+            class_batched=self.class_batch_ok, classes=self.K)
 
     def stage_work(self, n: Optional[int] = None, *,
                    fullest: bool = False) -> Dict[str, Tuple[float, str]]:
@@ -1830,9 +1858,11 @@ class GBDT:
         sync. Host-RNG inputs (bagging mask, feature mask) are drawn
         here — pure host computation — so fused and legacy consume the
         identical RNG streams in the identical order. The whole of it
-        is one ``gbdt.dispatch`` span: the driver's busy time a tree."""
+        is one ``gbdt.dispatch`` span: the driver's busy time for the
+        iteration's K trees, which the span record keeps as K records of
+        a K-th each (``profiler.span(parts=)``), one a tree."""
         profiler.recorder.iteration = self.iter_
-        with profiler.span("gbdt.dispatch"):
+        with profiler.span("gbdt.dispatch", parts=self.K):
             self._fused_dispatch_impl()
 
     def _fused_dispatch_impl(self):
@@ -1966,18 +1996,21 @@ class GBDT:
                 # reference gbdt.cpp:441-447
                 stop = True
                 break
+            batched = None
             if isinstance(trees_h, TreeArrays):
                 # class-batched iteration: ONE stacked TreeArrays with
                 # a leading K axis; unstack into per-class host views
                 # (zero-copy numpy slices)
                 trees_h = [jax.tree.map(lambda a: a[k], trees_h)
                            for k in range(self.K)]
+                batched = _batched_rounds(rounds_h)
                 rounds_h = [jax.tree.map(lambda a: a[k], rounds_h)
                             for k in range(self.K)]
             trees = Tree.from_device_batch(trees_h, bm, uf, shrink)
             for k, (rl, tree) in enumerate(zip(rounds_h, trees)):
-                self._log_rounds(it, k, rl, tree)
+                self._log_rounds(it, k, rl, tree, batched)
             for k, tree in enumerate(trees):
+                _as_constant(tree)
                 bias = self._init_scores[k]
                 if it == 0 and abs(bias) > kEpsilon:
                     # AddBias (gbdt.cpp:416): fold init score into the
@@ -2012,13 +2045,15 @@ class GBDT:
         return ref() if ref is not None else None
 
     def _log_rounds(self, it: int, k: int, rounds,
-                    tree: Optional[Tree] = None) -> None:
+                    tree: Optional[Tree] = None,
+                    batched_rounds: Optional[int] = None) -> None:
         if rounds is not None:
             self.round_log.append(RoundRecord(
                 int(it), int(k), np.asarray(rounds.rows),
                 np.asarray(rounds.leaves), np.asarray(rounds.stream_rows),
                 self._linear_work(tree) if tree is not None
-                and tree.is_linear else None, np.asarray(rounds.dense)))
+                and tree.is_linear else None, np.asarray(rounds.dense),
+                batched_rounds))
 
     def train_one_iter(self, gradients: Optional[np.ndarray] = None,
                        hessians: Optional[np.ndarray] = None, *,
@@ -2193,7 +2228,10 @@ class GBDT:
                 host = jax.tree.map(np.asarray, tree_arrays)
             tree = Tree.from_device(host, self.train_set.bin_mappers,
                                     self.train_set.used_features, shrink)
-            self._log_rounds(self.iter_, k, rounds_host, tree)
+            self._log_rounds(self.iter_, k, rounds_host, tree,
+                             None if trees_k is None
+                             else _batched_rounds(rounds_k_host))
+            _as_constant(tree)
             if num_leaves_trained > 1:
                 should_continue = True
                 with profiler.phase("update"):

@@ -195,6 +195,7 @@ class StepShape(NamedTuple):
     search_classes: int
     linear_columns: int = 0
     dense_round_rows: int = 0
+    classes: int = 1
 
     def fields(self) -> dict:
         """``{phases.STEP_SHAPE name: int}``: the span's fields."""
@@ -333,7 +334,8 @@ def step_shape(*, rows: int, stored_columns: int, features: int,
                n_shards: int = 1,
                search: Optional[SearchPlan] = None,
                linear_columns: int = 0,
-               class_batched: bool = False) -> StepShape:
+               class_batched: bool = False,
+               classes: int = 1) -> StepShape:
     """The sizes :func:`_build_tree_impl` builds with, from its static
     arguments alone: host integers, no trace, no device. The builder
     calls this for its own W, chunk, compaction and loop bound, and the
@@ -351,7 +353,12 @@ def step_shape(*, rows: int, stored_columns: int, features: int,
     ``linear_columns`` is the linear-leaf Gram's width, which gbdt.py
     fits after the build (0: constant leaves); ``class_batched`` a build
     vmapped over the classes, which compiles no dense route
-    (:func:`dense_round_rows`)."""
+    (:func:`dense_round_rows`), and ``classes`` the K it grows at once.
+    Where such a build's root is the one class-root kernel call for all
+    K (:func:`_build_tree_class_batched`: Pallas, one device, no
+    bundles), the root's row block is that call's, whose lanes are
+    K x 3, and ``StepShape.classes`` is K; it is 1 wherever each tree
+    has a root pass of its own."""
     nb_in = bundle_bins or num_bins
     impl = effective_impl(hist_impl, nb_in)
     W = max(1, min(leaf_batch, num_leaves - 1))
@@ -359,6 +366,10 @@ def step_shape(*, rows: int, stored_columns: int, features: int,
     dtypes = (gh_dtype, hist_dtype, block_rows)
     blk, fc, n_fb, bp, lanes = kernel_plan(*plan_args, W, *dtypes)
     root_blk = kernel_plan(*plan_args, 2 * W, *dtypes)[0]
+    class_root = class_batched and class_root_call(
+        impl, parallel=parallel_mode is not None, bundled=bool(bundle_bins))
+    if class_root:
+        root_blk = kernel_plan(*plan_args, classes, *dtypes)[0]
     positions = features * num_bins
     if (parallel_mode == "data" and hist_merge == "reduce_scatter"
             and n_shards > 1 and not bundle_bins):
@@ -384,7 +395,33 @@ def step_shape(*, rows: int, stored_columns: int, features: int,
         linear_columns=linear_columns,
         dense_round_rows=dense_round_rows(
             impl, compacted, class_batched, n_fb * fc * bp,
-            -(-rows // blk) * blk))
+            -(-rows // blk) * blk),
+        classes=classes if class_root else 1)
+
+
+def class_root_call(hist_impl: str, *, parallel: bool,
+                    bundled: bool) -> bool:
+    """Whether a class-batched build's root is ONE call of the class-root
+    kernel for all K classes (``build_root_histograms_classes``): a plain
+    single-device Pallas build. Under a mesh (``axis_name``; a feature
+    shard has one too) or EFB bundles each class's root runs vmapped
+    inside the build. :func:`_build_tree_class_batched` makes the call by
+    this rule and :func:`step_shape` describes it by the same rule."""
+    return hist_impl == "pallas" and not parallel and not bundled
+
+
+def _class_by_class(build):
+    """``build(bins, gh, row_leaf, slots, row_gather=, num_rows=)`` (a
+    compacted stream's histogram) that a ``vmap`` over the classes runs
+    one class at a time (``jax.custom_batching.sequential_vmap``) instead
+    of through the batching rules. Batched, the stream's operand buffers
+    gain the class axis: at KDD Cup 1999's shape (4.9M rows, 41 columns,
+    23 classes) ``s32[23, 2, 21, R]`` alone is 21.6 GB of a 16 GB v5e.
+    One class at a time they are one class's, and each class's stream
+    stops at its own live rows."""
+    return jax.custom_batching.sequential_vmap(
+        lambda b, g, r, s, c, n: build(b, g, r, s, row_gather=c,
+                                       num_rows=n))
 
 
 def _round_int(x):
@@ -558,7 +595,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
                hist_merge: str = "allreduce",
                n_shards: int = 1,
                root_hist: Optional[jax.Array] = None,
-               class_batched: bool = False):
+               class_batched: bool = False, classes: int = 1):
     """Grow one tree. Returns (TreeArrays, row_leaf, valid_row_leafs,
     RoundLog) — and the CEGB state as a fifth element when ``cegb`` is
     given.
@@ -615,7 +652,7 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
         parallel_mode=mode if axis_name is not None else None,
         hist_merge=hist_merge, n_shards=n_shards,
         search=getattr(bundle_meta, "search", None),
-        class_batched=class_batched)
+        class_batched=class_batched, classes=classes)
     # Row compaction redirects the row streams through a gathered index
     # order, and everything downstream of the index is bounded by the
     # live rows: the matmul one-hot (R*F*B bf16) and the CPU scatter
@@ -988,12 +1025,16 @@ def _build_tree_impl(bins: jax.Array, gh: jax.Array, row_leaf0: jax.Array,
             return hist_perm_for(slots, part)
         mat = local_bins if mode == "feature" else bins
         nb_in = bundle_bins if use_bundle else B
-        return build_histograms(
-            mat, gh, rl, slots,
+        build = functools.partial(
+            build_histograms,
             num_bins=nb_in, block_rows=block_rows, axis_name=axis_name,
             merge=hist_merge_kind if merge else False, n_shards=n_shards,
-            hist_dtype=hist_dtype, impl=hist_impl, row_gather=row_gather,
-            num_rows=num_rows)
+            hist_dtype=hist_dtype, impl=hist_impl)
+        if class_batched and row_gather is not None and axis_name is None:
+            return _class_by_class(build)(mat, gh, rl, slots, row_gather,
+                                          num_rows)
+        return build(mat, gh, rl, slots, row_gather=row_gather,
+                     num_rows=num_rows)
 
     def compact_small(row_leaf, small_slots):
         """The compacted stream of the small children's rows:
@@ -2267,6 +2308,24 @@ def _build_tree_class_batched(bins, gh, row_leaf0, num_bins_pf,
     kernels (no vmap rule over custom calls; ``build_tree`` remaps
     native -> scatter, which is bit-identical by the native parity
     tests).
+
+    What stays per class inside the batched loop: the compacted stream
+    of a round (single device: :func:`_class_by_class` runs its gathers,
+    relayout and kernel call one class at a time, so its operand buffers
+    are one class's), and the dense route, which is compiled out
+    (:func:`dense_round_rows`: under ``vmap`` its ``cond`` would run both
+    branches). The root pass is ONE call for all K classes
+    (``build_root_histograms_classes``, its custom call named
+    ``PH.HIST_CLASS_ROOT_NAME``) where the plan is a plain single-device
+    Pallas build: not under EFB bundles (``bundle_meta``), a mesh
+    (``axis_name``) or a feature shard (``local_bins`` /
+    ``feature_sharded``), whose roots run vmapped inside the build.
+    The batched loop runs as many rounds as the class that needs the
+    most; ``RoundLog.leaves`` of a class is 0 in the rounds after its
+    own last (a round that runs splits at least one leaf), so the loop's
+    trip count is the largest count of rounds with ``leaves > 0`` over
+    the classes: the arrays are padded to ``max_rounds_for(L, W)``, not
+    to that count.
     """
     if forced is not None:
         raise ValueError(
@@ -2285,23 +2344,24 @@ def _build_tree_class_batched(bins, gh, row_leaf0, num_bins_pf,
     # On the Pallas serial path, build the K root histograms pre-vmap
     # with ONE kernel whose MXU N-dim is the class axis (bins read once)
     # and hand each class its slice via the builder's ``root_hist``
-    # seam. Gated to plans where the root build is a plain single-device
-    # Pallas launch (no EFB bundling, no mesh merge, no feature shard).
+    # seam, under the root pass's stage like the single-class root.
+    # Gated by class_root_call, the rule step_shape describes it by.
     root_hist = None
-    if (hist_impl == "pallas" and kw.get("axis_name") is None
-            and kw.get("bundle_meta") is None
-            and kw.get("local_bins") is None
-            and not kw.get("feature_sharded", False)):
-        root_hist = PH.build_root_histograms_classes(
-            bins, gh, row_leaf0, num_bins=kw["num_bins"],
-            hist_dtype=kw.get("hist_dtype", "bfloat16"))
+    if class_root_call(effective_impl(hist_impl, kw["num_bins"]),
+                       parallel=kw.get("axis_name") is not None,
+                       bundled=kw.get("bundle_meta") is not None):
+        with profiler.stage(PHS.ROOT_PASS):
+            root_hist = PH.build_root_histograms_classes(
+                bins, gh, row_leaf0, num_bins=kw["num_bins"],
+                hist_dtype=kw.get("hist_dtype", "bfloat16"))
 
     def one(gh_k, key_k, qs_k, rh_k):
         return _build_tree_impl(bins, gh_k, row_leaf0, num_bins_pf,
                                 nan_bin_pf, is_cat_pf, feature_mask,
                                 rng_key=key_k, quant_scales=qs_k,
                                 hist_impl=hist_impl, root_hist=rh_k,
-                                class_batched=True, **kw)
+                                class_batched=True,
+                                classes=int(gh.shape[0]), **kw)
 
     return jax.vmap(
         one, in_axes=(0,

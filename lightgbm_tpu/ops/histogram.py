@@ -35,6 +35,13 @@ Design (TPU-first, NOT a translation):
   leaf-slot (N) dimension, hist [K, F·B, S·3] from ONE dispatch with K×
   the MXU work per dispatch instead of K sequential calls. The scatter
   path batches the same way (one scatter-add with a class index axis).
+  On one device a round's compacted stream (``row_gather``) runs one
+  class at a time instead (``tree_builder._class_by_class``), whatever
+  the impl: batched, the Pallas stream's lane-major operand buffers
+  would be K copies of the bin matrix. The root pass of a Pallas build
+  is one call for all K (``pallas_histogram.build_root_histograms_classes``,
+  its custom call named ``HIST_CLASS_ROOT_NAME`` so that a trace finds
+  it as the program's kernel outside every loop).
   The ``native`` FFI kernel has no vmap rule — the class-batched entry
   remaps native→scatter (bit-identical; see tests/test_histogram.py
   native↔scatter parity). ``merge_histograms`` collectives batch too:

@@ -44,7 +44,7 @@ __all__ = ["GRADS", "SAMPLING", "BUILD", "UPDATE", "EVAL",
            "SHAPE_KERNEL_FEATURE_CHUNK", "SHAPE_KERNEL_CHUNKS",
            "SHAPE_KERNEL_PADDED_BINS", "SHAPE_KERNEL_LANES",
            "SHAPE_ROUNDS_BOUND", "SHAPE_LINEAR_COLUMNS",
-           "SHAPE_DENSE_ROUND_ROWS", "STEP_SHAPE",
+           "SHAPE_DENSE_ROUND_ROWS", "SHAPE_CLASSES", "STEP_SHAPE",
            "STAGE_WORK",
            "UNIT_POSITIONS", "UNIT_ELEMENTS", "UNIT_ONEHOT",
            "UNIT_SORTED", "UNIT_ROW_PASSES", "UNIT_LATTICE", "UNIT_ROWS",
@@ -204,13 +204,21 @@ SHAPE_DENSE_ROUND_ROWS = "shape_dense_round_rows"  # a round whose smaller
 #                            children hold more rows than this skips the
 #                            compacted stream for one kernel pass over
 #                            all rows; 0 where no such route is compiled
+SHAPE_CLASSES = "shape_classes"  # trees one root pass histograms: K
+#                            where the class-batched multiclass build
+#                            makes one class-root kernel call for all K
+#                            (Pallas, one device, no bundles; the root
+#                            row block above is that call's), 1 for every
+#                            other build (binary, multiclass grown class
+#                            by class, a batched build whose roots run
+#                            per class)
 STEP_SHAPE = (SHAPE_ROWS, SHAPE_STORED_COLUMNS, SHAPE_STORED_BINS,
               SHAPE_SEARCH_POSITIONS, SHAPE_SLOTS, SHAPE_STREAM_CHUNK_ROWS,
               SHAPE_STREAM_COMPACTED, SHAPE_KERNEL_ROW_BLOCK,
               SHAPE_KERNEL_ROOT_ROW_BLOCK, SHAPE_KERNEL_FEATURE_CHUNK,
               SHAPE_KERNEL_CHUNKS, SHAPE_KERNEL_PADDED_BINS,
               SHAPE_KERNEL_LANES, SHAPE_ROUNDS_BOUND, SHAPE_SEARCH_CLASSES,
-              SHAPE_LINEAR_COLUMNS, SHAPE_DENSE_ROUND_ROWS)
+              SHAPE_LINEAR_COLUMNS, SHAPE_DENSE_ROUND_ROWS, SHAPE_CLASSES)
 
 # the unit a stage's work is counted in (telemetry/costmodel.stage_work:
 # the counts come from the round log and the step's shape, at the

@@ -150,12 +150,24 @@ recorder = SpanRecorder()
 
 
 @contextlib.contextmanager
-def span(name: str, **fields) -> Iterator[Dict[str, Any]]:
+def span(name: str, *, parts: int = 1, **fields) -> Iterator[Dict[str, Any]]:
     """Host span: one record in :data:`recorder` and one
     ``TraceAnnotation`` named ``lgbtpu:<name>``. Yields the span's
     ``fields`` dict, which the body may add to. ``name`` is one of
     ``phases.HOST_SPANS`` or a canonical phase: the span sites are a
-    fixed list, so a reader of the ring knows every name it can meet."""
+    fixed list, so a reader of the ring knows every name it can meet.
+
+    ``parts`` > 1: the span is that many units of its site's work at once
+    (a fused dispatch that grows the K trees of a multiclass iteration),
+    and the ring keeps it as ``parts`` consecutive records, each a
+    ``parts``-th of its time with ``part`` and ``parts`` among its
+    fields, so that one record is one unit wherever the site's unit is
+    (one ``gbdt.dispatch`` record a tree). The annotation stays one.
+    TEMPORARY: the split times are made up, and ``parts`` exists only
+    because the benchmark's ``driver.dispatch_ms_per_tree`` reads one
+    record a tree. Once that reader divides a record by a field that
+    counts its trees, the dispatch site keeps one record with that field
+    and ``parts`` goes."""
     if name not in HOST_SPANS:
         _check_phase(name)
     import jax
@@ -169,7 +181,13 @@ def span(name: str, **fields) -> Iterator[Dict[str, Any]]:
     finally:
         t1 = time.time_ns()
         stack.pop()
-        recorder.record(name, t0, t1, parent, fields)
+        if parts == 1:
+            recorder.record(name, t0, t1, parent, fields)
+        else:
+            cut = [t0 + (t1 - t0) * k // parts for k in range(parts + 1)]
+            for k in range(parts):
+                recorder.record(name, cut[k], cut[k + 1], parent,
+                                dict(fields, part=k, parts=parts))
 
 
 def _check_phase(name: str) -> None:

@@ -92,7 +92,17 @@ def stage_work(shape: Dict[str, int], log: Sequence[Any], *,
       the path features ``sum k_l``; ``linear_update`` rows x linear
       trees.
 
-    A round is one that built for at least one leaf. All counts are ONE
+    A round is one that built for at least one leaf. Under the
+    class-batched multiclass build a record (a tree) carries its
+    iteration's ``batched_rounds``: the row-wide stages (``apply``,
+    ``count``, ``compact``, ``find``, ``subtract``, ``unbundle``) run for
+    every class in every round of the batched loop, so a tree counts that
+    many rounds there, while the stream's stages count a class's own
+    rounds (its stream runs alone and stops at its own live rows). One
+    root pass histograms ``shape_classes`` trees (the class-root kernel's
+    K; 1 where each tree has its own): a tree counts that share of one
+    root pass in ``hist_kernel`` and ``hist_relayout``; the shape is read
+    with 1 where it has no ``shape_classes``. All counts are ONE
     device's. Under a row-sharded plan the log is ``[n_shards, rounds]``:
     the counts are the mean over the shards (what the chips' mean seconds
     go with), or the fullest shard's with ``fullest`` (the shard a round
@@ -105,6 +115,7 @@ def stage_work(shape: Dict[str, int], log: Sequence[Any], *,
              * shape[PHS.SHAPE_KERNEL_PADDED_BINS])
     scan = shape[PHS.SHAPE_SLOTS] * shape[PHS.SHAPE_SEARCH_POSITIONS]
     trees = len(log)
+    roots = trees / shape.get(PHS.SHAPE_CLASSES, 1)
     kernel_rows = _ceil_to(rows, blk)
     lanes = int(np.lcm(blk, shape[PHS.SHAPE_KERNEL_ROOT_ROW_BLOCK]))
     rounds, positions, covered = 0, 0.0, 0.0
@@ -113,7 +124,8 @@ def stage_work(shape: Dict[str, int], log: Sequence[Any], *,
               if lin is not None]
     for rec in log:
         built = np.asarray(rec.leaves) > 0
-        rounds += int(built.sum())
+        batched = getattr(rec, "batched_rounds", None)
+        rounds += int(built.sum()) if batched is None else int(batched)
         # [shards, rounds] (one shard where the log keeps no shard axis)
         live = np.asarray(rec.rows).reshape(-1, built.shape[-1])[:, built]
         stream = np.asarray(rec.stream_rows).reshape(
@@ -130,14 +142,15 @@ def stage_work(shape: Dict[str, int], log: Sequence[Any], *,
             dense, kernel_rows, _ceil_to(live.astype(np.int64), blk))
             .sum(axis=1)))
         dense_rounds += float(over(dense.sum(axis=1)))
-        streamed_rounds += float(over((~dense).sum(axis=1)))
+        streamed_rounds += (float(over((~dense).sum(axis=1)))
+                            if batched is None else int(batched))
     root_rows = _ceil_to(rows, shape[PHS.SHAPE_KERNEL_ROOT_ROW_BLOCK])
     lanes_rows = _ceil_to(rows, lanes)
     counts = {
         PHS.HIST_GATHER: positions,
-        PHS.HIST_RELAYOUT: ((positions + trees * rows) * cols
+        PHS.HIST_RELAYOUT: ((positions + roots * rows) * cols
                             + dense_rounds * lanes_rows),
-        PHS.HIST_KERNEL: (covered + trees * root_rows) * a_row,
+        PHS.HIST_KERNEL: (covered + roots * root_rows) * a_row,
         PHS.COMPACT: (streamed_rounds * rows
                       * shape[PHS.SHAPE_STREAM_COMPACTED]),
         PHS.APPLY: rounds * rows,

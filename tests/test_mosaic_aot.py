@@ -125,6 +125,51 @@ def test_class_root_kernel_compiles(v5e, quant):
         sds((K, R, 3), I8 if quant else F32), sds((R,), I32))
 
 
+def test_class_batched_build_names_its_root_pass_outside_every_loop(
+        v5e, monkeypatch):
+    """The class-batched tree build at KDD Cup 1999's width (41 columns,
+    63 bins) for 5 classes, compiled for a described v5e: its one root
+    pass is a custom call named ``pallas_hist_class_root`` that runs in
+    no ``while`` (the benchmark's trace takes a kernel call outside every
+    loop as a root pass, and a kernel by a name holding "pallas"); the
+    stage map puts it under ``root_pass`` as ``hist_kernel``; the grow
+    loop's kernel is ``pallas_hist_kernel`` inside the loop."""
+    from lightgbm_tpu import phases
+    from lightgbm_tpu.boosting.tree_builder import _build_tree_cb_jit
+    from lightgbm_tpu.ops.pallas_histogram import (HIST_CLASS_ROOT_NAME,
+                                                   HIST_KERNEL_NAME)
+    from lightgbm_tpu.ops.split import SplitParams
+    from lightgbm_tpu.telemetry import costmodel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sds = _sds(v5e[0])
+    K, R, F = 5, 1 << 16, 41
+    args = _tree_args(lambda s, d, _: sds(s, d), R, F)
+    args[1] = sds((K, R, 3), F32)
+    text = _build_tree_cb_jit.trace(
+        *args, split_params=SplitParams(min_data_in_leaf=1,
+                                        min_sum_hessian_in_leaf=100.0),
+        hist_impl="pallas", **dict(_HIGGS, block_rows=1 << 13)
+    ).lower().compile().as_text()
+    rows = costmodel._instructions(text)
+    bodies = {r.callee for r in rows if r.op.opcode == "while"}
+    called_from = {c: r.comp for r in rows for c in r.called}
+
+    def in_loop(comp):
+        while comp is not None:
+            if comp in bodies:
+                return True
+            comp = called_from.get(comp)
+        return False
+    roots = [r for r in rows if r.op.name.startswith(HIST_CLASS_ROOT_NAME)]
+    inner = [r for r in rows if r.op.name.startswith(HIST_KERNEL_NAME)]
+    assert len(roots) == 1 and roots[0].op.opcode == "custom-call"
+    assert "pallas" in roots[0].op.name and not in_loop(roots[0].comp)
+    assert inner and all(in_loop(r.comp) for r in inner)
+    sm = costmodel.instruction_phase_map(text)
+    assert sm.stages[roots[0].op.name] == phases.HIST_KERNEL
+    assert phases.ROOT_PASS + "/" in sm.scopes[roots[0].op.name]
+
+
 def _tree_args(make, R, F):
     return [make((R, F), U8, 2), make((R, 3), F32, 2), make((R,), I32, 1),
             make((F,), I32, 0), make((F,), I32, 0),

@@ -367,6 +367,57 @@ def test_shards_that_take_different_routes(fullest):
         + pick(1, 0) * 1024
 
 
+def _class_rec(leaves, rows, batched, it=0):
+    rec = type("R", (), {})()
+    rec.iteration = it
+    rec.leaves = np.array(leaves)
+    rec.rows = np.array(rows)
+    rec.stream_rows = np.where(rec.leaves > 0, 128, 0)
+    rec.dense = np.zeros(len(leaves), np.int32)
+    rec.batched_rounds = batched
+    return rec
+
+
+def test_a_class_batched_log_makes_one_root_pass_an_iteration():
+    """Two iterations of K = 3 classes grown by one class-batched build
+    (``shape_classes`` 3): one class-root pass an iteration over K x 3
+    channels, so the root's one-hot and its relayout count twice and not
+    six times; the row-wide stages run for every class in every round of
+    the batched loop (``batched_rounds``), frozen classes too; the
+    stream's stages count each class's own rounds."""
+    shape = _mixed_shape()
+    shape[phases.SHAPE_CLASSES] = 3
+    log = [_class_rec([2, 1, 1], [300, 90, 40], 3, it)
+           for it in range(2)] + [
+        _class_rec([1, 0, 0], [200, 0, 0], 3, it) for it in range(2)] + [
+        _class_rec([0, 0, 0], [0, 0, 0], 3, it) for it in range(2)]
+    got = {k: c for k, (c, _) in costmodel.stage_work(shape, log).items()}
+    iterations, trees, batched = 2, 6, 3
+    own = 2 * (3 + 1)                       # class-rounds that built
+    assert got["hist_kernel"] == (2 * (384 + 128 + 128 + 256)
+                                  + iterations * 1024) * 256
+    assert got["hist_relayout"] == (own * 128 + iterations * 1000) * 4
+    assert got["hist_gather"] == own * 128
+    for stage in ("apply", "count", "compact"):
+        assert got[stage] == trees * batched * 1000, stage
+    assert got["find"] == got["subtract"] == trees * batched * 2 * 10
+    assert got["root_pass"] == trees * 2 * 10
+    assert got["update"] == got["grads"] == trees * 1000
+    # each tree with a root pass of its own (``shape_classes`` 1) and no
+    # batched loop: the binary counting, unchanged
+    for rec in log:
+        rec.batched_rounds = None
+    shape[phases.SHAPE_CLASSES] = 1
+    one = {k: c for k, (c, _) in costmodel.stage_work(shape, log).items()}
+    assert one["hist_kernel"] == (2 * (384 + 128 + 128 + 256)
+                                  + trees * 1024) * 256
+    assert one["apply"] == own * 1000
+    # a shape from before ``shape_classes`` reads as 1
+    del shape[phases.SHAPE_CLASSES]
+    assert {k: c for k, (c, _) in
+            costmodel.stage_work(shape, log).items()} == one
+
+
 _START = """
 import numpy as np, jax, jax.monitoring
 import lightgbm_tpu as lgb
